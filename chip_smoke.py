@@ -1,0 +1,397 @@
+#!/usr/bin/env python
+"""chip_smoke.py: the quickest proof that the served SQL path still
+starts on the chip.
+
+One process, one TPU chip, the entry points a user calls:
+
+1. device   jax must report a TPU, or the script exits non-zero before
+            any work. There is no CPU path here.
+2. data     TPC-H at SF1 (the specification's smallest scale), made
+            from --seed under <checkout>/data/tpch (git-ignored).
+3. serve    `SqlService` on an ephemeral port; Q1, Q6 and Q3 over HTTP
+            `POST /sql`, cold then warm, each compared with the
+            independent pandas golden; every status record must be
+            `ok` with no fault events, and `/metrics` must count no
+            retry, no OOM-ladder rung and no mesh fallback.
+4. aggregate  the reference AggregateBenchmark's "linear keys" shape at
+            full width (83,886,080 rows into 65,536 groups) under
+            `aggregate.kernelMode=auto` and `scatter`: both equal the
+            closed form and each other, and under `auto` the Pallas
+            kernel (`tpu_custom_call`) is in the compiled program.
+5. stop     the service stops; per-phase seconds are printed.
+
+`--chips 4` runs the mesh path instead (device, data, then Q3 and a
+grouped aggregate under `spark_tpu.sql.mesh.size=4` against
+single-device runs and the goldens, `meshFallback` off) and no other
+phase.
+
+`--queries` adds Q5 (`--queries Q1,Q6,Q3,Q5`). It is not in the
+default set because the whole script has 1200 seconds, compilation
+included, and may count on no compiled program from an earlier run:
+under the installed XLA:TPU a multi-key 64-bit `sort` takes minutes to
+compile, Q5's final stage holds 56 sorts, and its join capacities take
+four compiles to settle. On the chip Q5 had not answered when this
+script's 900 s HTTP timeout ended the run; the default set took 740 s,
+of which Q3's two submissions were 560 (PERF.md, PR 22).
+
+Any failure in any phase raises and the process exits non-zero at
+once: nothing here catches an error and carries on, and nothing falls
+back to another configuration. The last line of stdout is
+`{"ok": true, "device": {"platform", "kind", "count"}}` as JAX reports
+the device. Times printed on the way are information, not claims.
+
+`--sf` and `--agg-rows` shrink the data for a rehearsal that calls the
+phase functions on the CPU (tests/test_chip_smoke.py); run as a script
+the device phase refuses anything but a TPU whatever the size.
+"""
+
+from __future__ import annotations
+
+import argparse
+import collections
+import json
+import os
+import sys
+import time
+import urllib.request
+
+import pandas as pd
+
+CHECKOUT = os.path.dirname(os.path.abspath(__file__))
+
+KERNEL_KEY = "spark_tpu.sql.aggregate.kernelMode"
+MESH_KEY = "spark_tpu.sql.mesh.size"
+MESH_FALLBACK_KEY = "spark_tpu.execution.meshFallback.enabled"
+
+#: the TPC-H queries served by default, scan-aggregates first, the
+#: three-way join after; Q5 comes by --queries (see the docstring)
+SERVED = ("Q1", "Q6", "Q3")
+
+#: of --queries, what the mesh phase runs: the join queries (hash
+#: exchange, broadcast join, global sort)
+MESH_QUERIES = ("Q3", "Q5")
+
+#: reference AggregateBenchmark, "linear keys": range(20 << 22) into
+#: 65,536 groups (bench.py builds the same DataFrame)
+AGG_ROWS = 20 << 22
+AGG_GROUPS = 65536
+
+#: the mesh phase's grouped aggregate: partial per shard, hash exchange
+#: over all_to_all, final merge (10,000 supplier keys at SF1)
+MESH_AGG_SQL = ("select l_suppkey, sum(l_quantity) as qty, "
+                "count(*) as n from lineitem group by l_suppkey")
+
+#: recovery actions the engine records per query; the smoke accepts none
+FAULT_PREFIX = "spark_tpu_fault_"
+
+
+def log(msg: str) -> None:
+    print(msg, flush=True)
+
+
+# -- 1. device ---------------------------------------------------------------
+
+
+def phase_device(min_count: int) -> dict:
+    """Refuse anything but a TPU with at least `min_count` chips."""
+    import jax
+    import jaxlib
+
+    import spark_tpu  # noqa: F401 — places the compile cache
+    devs = jax.devices()
+    dev = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+           "count": len(devs)}
+    if dev["platform"] != "tpu":
+        sys.exit(f"chip_smoke: jax found no TPU (devices: {devs}); "
+                 f"this script has no CPU path")
+    if dev["count"] < min_count:
+        sys.exit(f"chip_smoke: {min_count} chips asked for, "
+                 f"{dev['count']} visible")
+    from importlib.metadata import version
+    libtpu = version("libtpu")
+    log(f"device: jax {jax.__version__} jaxlib {jaxlib.__version__} "
+        f"libtpu {libtpu} kind={dev['kind']!r} count={dev['count']} "
+        f"compile_cache={jax.config.jax_compilation_cache_dir} "
+        f"(JAX_COMPILATION_CACHE_DIR "
+        f"{'set' if os.environ.get('JAX_COMPILATION_CACHE_DIR') else 'unset'})")
+    return dev
+
+
+class CacheCounter:
+    """Counts JAX's own persistent-compilation-cache events, so a run
+    can say whether it compiled or loaded."""
+
+    def __init__(self):
+        import jax
+        self.events: collections.Counter = collections.Counter()
+        jax.monitoring.register_event_listener(self._on_event)
+
+    def _on_event(self, name: str, **_kw) -> None:
+        if name.startswith("/jax/compilation_cache/cache_"):
+            self.events[name.rsplit("/", 1)[-1]] += 1
+
+    def line(self) -> str:
+        return (f"compile cache: hits={self.events['cache_hits']} "
+                f"misses={self.events['cache_misses']}")
+
+
+# -- 2. data -----------------------------------------------------------------
+
+
+def phase_data(sf: float, seed: int) -> str:
+    from spark_tpu.tpch.datagen import write_parquet
+    path = os.path.join(CHECKOUT, "data", "tpch", f"sf{sf:g}")
+    write_parquet(path, sf, seed)
+    import pyarrow.parquet as pq
+    rows = pq.read_metadata(
+        os.path.join(path, "lineitem.parquet")).num_rows
+    log(f"data: TPC-H sf={sf:g} seed={seed} lineitem_rows={rows} "
+        f"at {path}")
+    return path
+
+
+# -- 3. serve ----------------------------------------------------------------
+
+
+def _http_json(url: str, body: dict = None, timeout: float = 900):
+    data = None if body is None else json.dumps(body).encode()
+    req = urllib.request.Request(
+        url, data=data, headers={"Content-Type": "application/json"})
+    with urllib.request.urlopen(req, timeout=timeout) as resp:
+        return json.load(resp)
+
+
+def _check_golden(got: pd.DataFrame, path: str, qname: str) -> None:
+    from spark_tpu.tpch import golden as G
+    want = G.GOLDEN[qname](path)
+    G.compare(G.normalize_decimals(got)[list(want.columns)]
+              .reset_index(drop=True), want.reset_index(drop=True))
+
+
+def start_service(path: str):
+    from spark_tpu import Conf
+    from spark_tpu.service.server import SqlService
+    from spark_tpu.tpch import queries as Q
+    conf = Conf()
+    conf.set("spark_tpu.service.port", 0)
+    return SqlService(
+        conf, init_session=lambda s: Q.register_tables(s, path)).start()
+
+
+def phase_serve(svc, path: str, queries=SERVED) -> None:
+    """`queries` over HTTP, cold then warm: golden parity, clean
+    status records, clean /metrics."""
+    from spark_tpu.observability.metrics import parse_prometheus_text
+    from spark_tpu.tpch import sql_queries as SQLQ
+    base = f"http://127.0.0.1:{svc.port}"
+    for name in queries:
+        ms = []
+        for _run in ("cold", "warm"):
+            t0 = time.perf_counter()
+            resp = _http_json(f"{base}/sql",
+                              {"sql": getattr(SQLQ, name)})
+            ms.append((time.perf_counter() - t0) * 1e3)
+            assert resp["status"] == "ok", resp
+            got = pd.DataFrame(resp["rows"], columns=resp["columns"])
+            _check_golden(got, path, name.lower())
+            rec = _http_json(f"{base}/queries/{resp['query_id']}")
+            assert rec["status"] == "ok", rec
+            assert not rec.get("fault_events"), rec
+            assert not rec.get("fault_summary"), rec
+        log(f"serve: {name} rows={resp['row_count']} golden=ok "
+            f"cold_ms={ms[0]:.1f} warm_ms={ms[1]:.1f}")
+    with urllib.request.urlopen(f"{base}/metrics", timeout=30) as resp:
+        prom = parse_prometheus_text(resp.read().decode())
+    assert prom.get("spark_tpu_service_completed", 0) >= 2 * len(queries), \
+        prom
+    assert not prom.get("spark_tpu_queries_failed"), prom
+    recovered = {k: v for k, v in prom.items()
+                 if k.startswith(FAULT_PREFIX) and v}
+    assert not recovered, f"recovery actions ran: {recovered}"
+    log(f"serve: /metrics completed="
+        f"{int(prom['spark_tpu_service_completed'])} retries=0 "
+        f"oom_rungs=0 mesh_fallback=0")
+
+
+# -- 4. aggregate at full width ----------------------------------------------
+
+
+def _linear_keys(spark, n_rows: int):
+    from spark_tpu import functions as F
+    from spark_tpu.functions import col
+    return (spark.range(n_rows)
+            .select(F.pmod(col("id"), AGG_GROUPS).alias("k"))
+            .group_by(col("k")).agg(F.sum(col("k")).alias("sum(k)")))
+
+
+def programs_with_kernel(spark) -> list:
+    """Stage-cache keys of the streamed range aggregate whose compiled
+    program holds the Pallas kernel. The session's stage cache keeps
+    the jitted chunk loop the query ran (`stream_range_aggregate`);
+    compiling it again is a cache hit."""
+    streamed = {k: fn for k, fn in spark._stage_cache.items()
+                if k.startswith("stream_range:")}
+    assert streamed, (f"the aggregate did not take the streamed range "
+                      f"path: {[k[:60] for k in spark._stage_cache]}")
+    return [k for k, fn in streamed.items()
+            if "tpu_custom_call" in fn.lower().compile().as_text()]
+
+
+def phase_aggregate(spark, n_rows: int) -> None:
+    """Linear keys under kernelMode auto and scatter: closed form, each
+    other, and last the proof of which kernel each program holds."""
+    assert n_rows % AGG_GROUPS == 0, n_rows
+    per_key = n_rows // AGG_GROUPS
+    results, with_kernel = {}, {}
+    for mode in ("auto", "scatter"):
+        spark.conf.set(KERNEL_KEY, mode)
+        spark._stage_cache.clear()
+        ms = []
+        for _run in ("cold", "warm"):
+            t0 = time.perf_counter()
+            qe = _linear_keys(spark, n_rows)._qe()
+            got = qe.collect().to_pandas()
+            ms.append((time.perf_counter() - t0) * 1e3)
+            assert not qe.fault_summary, qe.fault_summary
+        got = got.sort_values("k").reset_index(drop=True)
+        assert got["k"].tolist() == list(range(AGG_GROUPS)), got["k"][:5]
+        assert (got["sum(k)"] == got["k"] * per_key).all(), got.head()
+        results[mode] = got
+        with_kernel[mode] = programs_with_kernel(spark)
+        log(f"aggregate: kernelMode={mode} rows={n_rows} "
+            f"groups={AGG_GROUPS} closed_form=ok "
+            f"pallas_kernel_in_program={bool(with_kernel[mode])} "
+            f"cold_ms={ms[0]:.1f} warm_ms={ms[1]:.1f}")
+    spark.conf.set(KERNEL_KEY, "auto")
+    pd.testing.assert_frame_equal(results["auto"], results["scatter"])
+    assert not with_kernel["scatter"], with_kernel["scatter"]
+    assert with_kernel["auto"], (
+        "kernelMode=auto on a TPU, yet no compiled program of the "
+        "aggregate holds a tpu_custom_call: the Pallas kernel did not "
+        "run")
+
+
+# -- the mesh, behind --chips 4 ----------------------------------------------
+
+
+def _mesh_agg_golden(path: str) -> pd.DataFrame:
+    from spark_tpu.tpch import golden as G
+    li = G.normalize_decimals(pd.read_parquet(
+        os.path.join(path, "lineitem.parquet"),
+        columns=["l_suppkey", "l_quantity"]))
+    return (li.groupby("l_suppkey", as_index=False)
+            .agg(qty=("l_quantity", "sum"), n=("l_quantity", "size")))
+
+
+def phase_mesh(spark, path: str, n: int, queries=("Q3",)) -> None:
+    """`queries` and a grouped aggregate under mesh.size=n against
+    single-device runs and the goldens; no single-device fallback, and
+    the mesh run must really lay its batches over n devices."""
+    from spark_tpu.tpch import golden as G
+    from spark_tpu.tpch import queries as Q
+    from spark_tpu.tpch import sql_queries as SQLQ
+    Q.register_tables(spark, path)
+    spark.conf.set(MESH_FALLBACK_KEY, False)
+    # the cheapest program to compile first; each run meets its golden
+    # as soon as it ends, so a run cut short still says what passed
+    cases = [("grouped_agg", MESH_AGG_SQL, "l_suppkey")]
+    cases += [(q, getattr(SQLQ, q), None) for q in queries]
+    for name, sql, sort_key in cases:
+        runs = {}
+        for size in (n, 0):
+            spark.conf.set(MESH_KEY, size)
+            t0 = time.perf_counter()
+            qe = spark.sql(sql)._qe()
+            batch, _, _ = qe.execute_batch()
+            got = G.normalize_decimals(batch.to_arrow().to_pandas())
+            ms = (time.perf_counter() - t0) * 1e3
+            assert not qe.fault_summary, qe.fault_summary
+            assert "mesh_fallback" not in qe.last_metrics, qe.last_metrics
+            spread = {d.id for c in batch.columns.values()
+                      for d in c.data.sharding.device_set}
+            assert len(spread) == max(size, 1), (
+                f"{name}: mesh.size={size} but the stage's batch lies "
+                f"on devices {sorted(spread)}")
+            exchanged = sum(int(v) for k, v in qe.last_metrics.items()
+                            if k.startswith("exch_rows_"))
+            assert bool(exchanged) == bool(size), qe.last_metrics
+            # row counts per operator repeat exactly on the CPU's
+            # virtual mesh: a wrong answer here is found by diffing them
+            log(f"mesh: {name} mesh.size={size} counters=" + json.dumps(
+                {k: v for k, v in sorted(qe.last_metrics.items())
+                 if "_ms_" not in k}))
+            if sort_key:
+                got = got.sort_values(sort_key).reset_index(drop=True)
+                G.compare(got, _mesh_agg_golden(path))
+            else:
+                _check_golden(got, path, name.lower())
+            runs[size] = got
+            log(f"mesh: {name} mesh.size={size} rows={len(got)} "
+                f"golden=ok devices={len(spread)} "
+                f"exchanged_rows={exchanged} ms={ms:.1f}")
+        G.compare(runs[n], runs[0])
+        log(f"mesh: {name} mesh == single-device ok")
+    spark.conf.set(MESH_KEY, 0)
+
+
+def assert_devices_held_data(n: int) -> None:
+    """Scans land on device 0 and the mesh program reshards them, so
+    the other chips show memory in use only if shards really went
+    there. (TPU only: the CPU backend reports no memory statistics.)"""
+    import jax
+    peaks = {d.id: d.memory_stats()["peak_bytes_in_use"]
+             for d in jax.devices()[:n]}
+    assert all(v > 0 for v in peaks.values()), peaks
+    log(f"mesh: peak bytes in use per device {peaks}")
+
+
+# -- main --------------------------------------------------------------------
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--seed", type=int, default=42)
+    ap.add_argument("--chips", type=int, default=1, choices=(1, 4),
+                    help="4: run the mesh phase and nothing else")
+    ap.add_argument("--queries", default=",".join(SERVED),
+                    help="TPC-H queries to serve, e.g. Q1,Q6,Q3,Q5")
+    ap.add_argument("--sf", type=float, default=1.0,
+                    help="TPC-H scale factor (rehearsals shrink it)")
+    ap.add_argument("--agg-rows", type=int, default=AGG_ROWS,
+                    help="rows of the linear-keys aggregate")
+    args = ap.parse_args(argv)
+    queries = tuple(q.strip().upper() for q in args.queries.split(","))
+
+    seconds = {}
+
+    def timed(name, fn, *a):
+        t0 = time.perf_counter()
+        out = fn(*a)
+        seconds[name] = round(time.perf_counter() - t0, 1)
+        return out
+
+    dev = timed("device", phase_device, args.chips)
+    cache = CacheCounter()
+    path = timed("data", phase_data, args.sf, args.seed)
+
+    from spark_tpu import SparkTpuSession
+    spark = SparkTpuSession.builder().get_or_create()
+    if args.chips == 4:
+        timed("mesh", phase_mesh, spark, path, 4,
+              tuple(q for q in queries if q in MESH_QUERIES))
+        assert_devices_held_data(4)
+    else:
+        svc = timed("start", start_service, path)
+        try:
+            timed("serve", phase_serve, svc, path, queries)
+            timed("aggregate", phase_aggregate, spark, args.agg_rows)
+        finally:
+            timed("stop", svc.stop)
+    log(cache.line())
+    log("seconds: " + json.dumps(seconds))
+    print(json.dumps({"ok": True, "device": dev}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -1,18 +1,13 @@
 #!/usr/bin/env python
-"""Perf-regression gate: bench smoke vs the last good BENCH round.
+"""Perf-regression gate: TPC smoke against a self-calibrated baseline.
 
-The round-5 failure mode was a perf trajectory going dark (BENCH_r05:
-rc 124, parsed null) with nothing in CI noticing. This gate runs the
-TPC-H smoke (Q1 + Q3, small scale factor, current backend) and fails
-preflight when `tpch_*_ms` regresses more than the threshold against
-the recorded baseline:
+This gate runs the TPC-H/TPC-DS smoke (small scale factor, current
+backend) and fails preflight when a `tpc*_ms` reading regresses more
+than the threshold against the recorded baseline:
 
 - The baseline lives in PERF_BASELINE.json, keyed by platform+scale
-  (CPU preflight numbers must never be compared against TPU BENCH
-  rounds). A missing entry self-calibrates: on a TPU backend at the
-  BENCH scale factor it seeds from the newest BENCH_*.json that
-  actually parsed tpch metrics (the "last good" round); otherwise from
-  the current measurement — then passes with a note.
+  and a coarse machine fingerprint. A missing entry self-calibrates
+  from the current measurement, then passes with a note.
 - Regression = current > baseline * (1 + threshold) AND current >
   baseline + abs_floor_ms (small queries jitter; a 25% blowup of 80ms
   is noise, of 800ms is a regression).
@@ -39,52 +34,9 @@ BASELINE_PATH = os.path.join(REPO, "PERF_BASELINE.json")
 sys.path.insert(0, REPO)
 
 
-#: baseline metric families merged independently from BENCH rounds:
-#: name -> key regex. `compile` carries the compile-cache section's
-#: cold/warm-process compile rows (bench_compile_cache), so warm
-#: compile-time regressions enter the gated baseline like wall-clock.
-FAMILIES = (
-    ("tpch", r"tpch_q\d+_sf[\d.]+_ms$"),
-    ("tpcds", r"tpcds_q\d+_sf[\d.]+_ms$"),
-    ("compile", r"tpch_q\d+_compile_(?:cold|warm)_ms$"),
-)
-
-
-def last_good_bench() -> tuple:
-    """(name, {metric: ms}) merged PER FAMILY from the newest
-    BENCH_*.json rounds: tpch_*_ms from the newest round that carries
-    any, tpcds_*_ms likewise, tpch_*_compile_*_ms likewise — a round
-    whose tpch section timed out but whose tpcds section parsed must
-    not shadow an older round's good tpch numbers (and vice versa).
-    `name` is the newest contributing round; (None, {}) when the
-    trajectory is dark."""
-    rounds = []
-    for name in os.listdir(REPO):
-        m = re.match(r"BENCH_r(\d+)\.json$", name)
-        if m:
-            rounds.append((int(m.group(1)), name))
-    merged: dict = {}
-    newest = None
-    seen_families = set()
-    for _, name in sorted(rounds, reverse=True):
-        try:
-            doc = json.load(open(os.path.join(REPO, name)))
-        except (OSError, ValueError):
-            continue
-        extra = ((doc.get("parsed") or {}).get("extra")) or {}
-        for fam, rx in FAMILIES:
-            if fam in seen_families:
-                continue
-            ms = {k: float(v) for k, v in extra.items()
-                  if re.match(rx, k)}
-            if ms:
-                seen_families.add(fam)
-                merged.update(ms)
-                if newest is None:
-                    newest = name
-        if len(seen_families) == len(FAMILIES):
-            break
-    return newest, merged
+#: the compile-cache section's cold/warm-process rows
+#: (bench_compile_cache), gated like wall-clock under PERF_GATE_COMPILE
+_COMPILE_RX = r"tpch_q\d+_compile_(?:cold|warm)_ms$"
 
 
 def _time3(run_once) -> float:
@@ -155,22 +107,6 @@ def platform_key(sf: float) -> str:
             f"-{platform.machine()}-c{os.cpu_count()}")
 
 
-def _default_sf(bench_ms: dict) -> float:
-    """Without an explicit PERF_GATE_SF: 0.01 on CPU (preflight smoke),
-    but on a TPU backend gate at the largest scale factor the last good
-    BENCH round actually measured — baseline ms only seed from BENCH
-    when the scale factors match, so gating at a different sf would
-    leave the documented seed path dead and self-calibrate against a
-    possibly-regressed current measurement."""
-    import jax
-    if jax.default_backend() != "tpu" or not bench_ms:
-        return 0.01
-    sfs = [float(m.group(1)) for m in
-           (re.match(r"tpc(?:h|ds)_q\d+_sf([\d.]+)_ms$", k)
-            for k in bench_ms) if m]
-    return max(sfs) if sfs else 0.01
-
-
 def main(argv) -> int:
     threshold = float(os.environ.get("PERF_GATE_THRESHOLD_PCT", "25"))
     floor_ms = float(os.environ.get("PERF_GATE_FLOOR_MS", "200"))
@@ -180,9 +116,7 @@ def main(argv) -> int:
         "PERF_GATE_TPCDS_QUERIES", "q3,q19").split(",") if q.strip()]
     update = "--update" in argv
 
-    bench_name, bench_ms = last_good_bench()
-    sf_env = os.environ.get("PERF_GATE_SF")
-    sf = float(sf_env) if sf_env else _default_sf(bench_ms)
+    sf = float(os.environ.get("PERF_GATE_SF", "0.01"))
     current = measure(sf, queries, tpcds_queries)
     if os.environ.get("PERF_GATE_COMPILE"):
         # opt-in (two fresh subprocesses, ~1min): the compile-cache
@@ -192,7 +126,7 @@ def main(argv) -> int:
         import bench
         cc = bench.bench_compile_cache(None)
         current.update({k: float(v) for k, v in cc.items()
-                        if re.match(FAMILIES[2][1], k)})
+                        if re.match(_COMPILE_RX, k)})
     key = platform_key(sf)
 
     baselines = {}
@@ -204,35 +138,15 @@ def main(argv) -> int:
     entry = baselines.get(key)
 
     if entry is None or update:
-        # calibrate: prefer the last good BENCH round when its numbers
-        # are same-platform/same-scale (the TPU driver path), else the
-        # current measurement (the CPU preflight path)
-        seeded = {}
-        if key.startswith("tpu"):  # key is platform_key(sf), computed once
-            for fam, names in (("tpch", queries),
-                               ("tpcds", tpcds_queries)):
-                for name in names:
-                    bkey = f"{fam}_{name}_sf{sf:g}_ms"
-                    if bkey in bench_ms:
-                        seeded[f"{fam}_{name}_ms"] = bench_ms[bkey]
-            # compile-cache rows are sf-less (bench emits them from a
-            # fixed-size subprocess pair): seed the ones we measure
-            for k, v in bench_ms.items():
-                if re.match(FAMILIES[2][1], k) and k in current:
-                    seeded[k] = v
-        source = bench_name if seeded else "self"
-        # per-family merge: bench-seeded keys win, the current
-        # measurement fills every family the bench round didn't carry
-        # (a partial seed must not leave the other family ungated)
-        entry = dict(current, **seeded)
-        entry.update(calibrated_against=source,
+        # calibrate from the current measurement
+        entry = dict(current, calibrated_against="self",
                      calibrated_ts=round(time.time(), 1))
         baselines[key] = entry
         with open(BASELINE_PATH, "w") as f:
             json.dump(baselines, f, indent=1, sort_keys=True)
             f.write("\n")
         print(json.dumps({"perf_gate": "calibrated", "platform": key,
-                          "source": source, "current": current}))
+                          "source": "self", "current": current}))
         return 0
 
     # metrics measured for the first time on an existing baseline (the
@@ -260,8 +174,7 @@ def main(argv) -> int:
     verdict = {"perf_gate": "fail" if failures else "ok",
                "platform": key, "current": current,
                "baseline": {k: v for k, v in entry.items()
-                            if k.startswith(("tpch_", "tpcds_"))},
-               "last_good_bench": bench_name}
+                            if k.startswith(("tpch_", "tpcds_"))}}
     if failures:
         verdict["regressions"] = failures
     print(json.dumps(verdict))

@@ -230,7 +230,7 @@ TASK_MAX_FAILURES = register(
 EXEC_MAX_RETRIES = register(
     "spark_tpu.execution.maxRetries", 3,
     doc="Retry budget per query execution for TRANSIENT failures "
-        "(remote-compile 500s, UNAVAILABLE, DEADLINE_EXCEEDED) and "
+        "(UNAVAILABLE, DEADLINE_EXCEEDED, channel resets) and "
         "stage wall-clock timeouts, with exponential backoff + jitter "
         "(execution/failures.py taxonomy). A transient retry drops the "
         "failed stage's compiled entry and recompiles; a timeout retry "
@@ -1161,9 +1161,10 @@ COMPILE_CACHE_DIR = register(
     "spark_tpu.sql.compileCache.dir", "spark-compile-cache",
     doc="Directory for the persistent compile cache: cc-<hash>.pkl "
         "serialized executables + manifest.jsonl (the warm-start "
-        "replay log) + xla/ (JAX's native compilation cache, wired as "
-        "the secondary seat when unset by the operator). Empty "
-        "disables the cache even when compileCache.enabled is true.")
+        "replay log). A relative path resolves against the checkout "
+        "(the directory holding the spark_tpu package), never the "
+        "working directory. Empty disables the cache even when "
+        "compileCache.enabled is true.")
 
 COMPILE_CACHE_MAX_BYTES = register(
     "spark_tpu.sql.compileCache.maxBytes", 1 << 30,

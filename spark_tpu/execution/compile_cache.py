@@ -46,12 +46,10 @@ LRU by mtime — loads touch their entry); `manifest.jsonl` records
 recently-seen stage keys for the warm-start replay
 (`session.warmup()` / `SqlService.start()`), compacted in place.
 
-**Secondary seat.** When the cache is enabled, JAX's native
-compilation cache (`jax_compilation_cache_dir`) is pointed at
-`<dir>/xla` if the operator hasn't configured it: a fingerprint or
-signature miss that still re-lowers an unchanged HLO can then skip the
-backend compile even though it re-paid the trace (best-effort;
-platform support varies).
+**JAX's own cache** (`jax_compilation_cache_dir`, keyed on HLO +
+compile options) is a separate thing placed once, at package import
+(`spark_tpu/__init__.py`): a fingerprint or signature miss here that
+re-lowers an unchanged HLO can still skip the backend compile there.
 
 Concurrency: `CompileCache._lock` (registered `execution.compile_cache`
 in the concurrency registry) serializes writes, eviction and manifest
@@ -160,10 +158,23 @@ def _sig_hash(sig: Tuple) -> str:
 
 def _deserialize(entry: Dict):
     """Backend-load a validated entry's executable (the shared tail of
-    the query-path load and the warm-start replay)."""
+    the query-path load and the warm-start replay) onto the devices it
+    was compiled for: its mesh's gang, or the default device for a
+    single-device stage. `deserialize_and_load` would otherwise bind it
+    to EVERY local device and dispatch fails with one argument list per
+    device on any host that has more than one."""
+    import jax
     from jax.experimental import serialize_executable as se
+
+    mesh_ids = (entry.get("fingerprint") or {}).get("mesh_devices")
+    if mesh_ids:
+        by_id = {int(d.id): d for d in jax.devices()}
+        devices = [by_id[i] for i in mesh_ids]
+    else:
+        devices = jax.devices()[:1]
     return se.deserialize_and_load(
-        entry["payload"], entry["in_tree"], entry["out_tree"])
+        entry["payload"], entry["in_tree"], entry["out_tree"],
+        execution_devices=devices)
 
 
 def entry_hash(stage_key: str, fingerprint: Dict, sig: Tuple) -> str:
@@ -419,11 +430,9 @@ class CompileCache:
     # -- bounds --------------------------------------------------------------
 
     def _entries_by_age(self) -> List[Tuple[float, int, str]]:
-        """[(mtime, size, path)] oldest first, covering the cc-*.pkl
-        entries AND the `xla/` secondary seat (JAX's persistent cache
-        has no eviction of its own — the operator bounded THIS
-        directory, so everything under it counts). Files vanishing
-        under a concurrent process's eviction are skipped."""
+        """[(mtime, size, path)] oldest first over the cc-*.pkl
+        entries. Files vanishing under a concurrent process's eviction
+        are skipped."""
         out = []
         try:
             names = os.listdir(self.dir)
@@ -431,9 +440,6 @@ class CompileCache:
             return out
         paths = [os.path.join(self.dir, n) for n in names
                  if n.startswith("cc-") and n.endswith(".pkl")]
-        for root, _dirs, files in os.walk(os.path.join(self.dir,
-                                                       "xla")):
-            paths.extend(os.path.join(root, f) for f in files)
         for path in paths:
             try:
                 st = os.stat(path)
@@ -625,30 +631,15 @@ def get_cache(conf) -> Optional[CompileCache]:
     d = str(conf.get(DIR_KEY) or "").strip()
     if not d:
         return None
-    key = (os.path.abspath(d), int(conf.get(MAX_BYTES_KEY)))
+    # a relative dir resolves against the checkout, not the working
+    # directory: every process of a deployment must find the same cache
+    from .. import CHECKOUT
+    key = (os.path.normpath(os.path.join(CHECKOUT, d)),
+           int(conf.get(MAX_BYTES_KEY)))
     cc = _CACHES.get(key)
     if cc is None:
         cc = _CACHES[key] = CompileCache(*key)
-        _wire_jax_cache(key[0])
     return cc
-
-
-def _wire_jax_cache(base_dir: str) -> None:
-    """Secondary seat: point JAX's native compilation cache at
-    `<dir>/xla` unless the operator already configured one. It keys on
-    HLO + compile options, so a fingerprint/signature miss that
-    re-lowers an unchanged program can still skip the backend compile
-    (trace + lower are still paid — the executable cache above is the
-    primary seat). Best-effort: support varies by platform/version."""
-    try:
-        import jax
-        if getattr(jax.config, "jax_compilation_cache_dir", None):
-            return
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.join(base_dir, "xla"))
-    except Exception as e:  # noqa: BLE001 — advisory only
-        warnings.warn(f"compile cache: could not wire "
-                      f"jax_compilation_cache_dir ({e})")
 
 
 def warm_start(stage_cache: Dict, conf, metrics=None) -> int:

@@ -461,6 +461,11 @@ class QueryExecution:
                                     try_stream_aggregate_spill)
         rec = self._recovery
         cache = self.session._stage_cache
+        if isinstance(node, P.HashAggregateExec):
+            # the chunk drivers jit on their own, outside
+            # _compile_stage: name the stage for _handle_failure's
+            # diagnostics (never a cache key, so evicting it is a no-op)
+            self._last_stage_key = f"stream:{node.simple_string()}"
         if mesh is None and isinstance(node, P.HashAggregateExec):
             memo_key = ("stream", id(node))
             if rec is not None:
@@ -663,7 +668,7 @@ class QueryExecution:
             from jax.sharding import PartitionSpec as Psp
             from ..parallel.mesh import shard_map
             from ..parallel import stripe_batch
-            from ..parallel.mesh import AXIS
+            from ..parallel.mesh import AXIS, pmax
 
             n = int(mesh.devices.size)
 
@@ -688,7 +693,7 @@ class QueryExecution:
                 for k, v in ctx.metrics.items():
                     # capacity-sizing stats take the worst shard (pmax);
                     # row counts sum across shards
-                    red = jax.lax.pmax if k.startswith(
+                    red = pmax if k.startswith(
                         ("join_rows_", "exch_max_", "agg_groups_",
                          "rtf_build_ms_", "join_build_ms_",
                          "join_probe_ms_", "join_table_slots_")) \
@@ -1269,8 +1274,9 @@ class QueryExecution:
         recovery action (caller re-executes); raises when the failure is
         fatal or every applicable budget is exhausted."""
         import warnings
-        from .failures import (FailureClass, StageOOMError,
-                               StageTimeoutError, classify, is_mesh_failure)
+        from .failures import (FailureClass, StageCompileError,
+                               StageOOMError, StageTimeoutError, classify,
+                               is_compile_refusal, is_mesh_failure)
         conf = self._conf
         cls = classify(e)
         msg = f"{type(e).__name__}: {e}"
@@ -1280,6 +1286,17 @@ class QueryExecution:
         # degraded re-plan, no gang restart (execution/lifecycle.py)
         if cls is FailureClass.CANCELLED:
             raise
+
+        # a program the device compiler refused fails as that, before
+        # any rung can read its text as something curable: a kernel
+        # over its VMEM limit says RESOURCE_EXHAUSTED (the OOM ladder's
+        # token) and, under a mesh, carries shard_map in its op name
+        # (the gang-restart ladder's)
+        if is_compile_refusal(e):
+            raise StageCompileError(
+                f"the device compiler refused a program of stage "
+                f"{(self._last_stage_key or '<uncompiled>')[:400]}: "
+                f"{msg}") from e
 
         # graceful decommission (parallel/elastic.py): a drain request
         # surfaced at a chunk boundary — a planned transition, not a
@@ -1616,9 +1633,9 @@ class QueryExecution:
                 faults.fire("stage_run")  # chaos seam: pre-dispatch
                 batch, flags, metrics = fn(*args)
                 # ONE batched host pull for the whole stats channel —
-                # per-scalar np.asarray costs an RPC round trip each on
-                # tunneled runtimes (it also syncs the attempt, making
-                # the wall-clock deadline check below honest). The pull
+                # per-scalar np.asarray is a host sync each (the pull
+                # also syncs the attempt, making the wall-clock
+                # deadline check below honest). The pull
                 # is cancellable (dispatchPollMs readiness polling):
                 # a cancel/deadline lands within ~one tick instead of
                 # at stage completion

@@ -7,8 +7,8 @@ spark.task.maxFailures), fetch failures resubmit the parent stage
 all of that into one opaque exception channel; this module restores the
 structure:
 
-- TRANSIENT: infra flakes (remote-compile 500s, UNAVAILABLE,
-  DEADLINE_EXCEEDED) — retried with exponential backoff + jitter
+- TRANSIENT: infra flakes (UNAVAILABLE, DEADLINE_EXCEEDED, channel
+  resets) — retried with exponential backoff + jitter
   (`spark_tpu.execution.{maxRetries,backoffMs}`).
 - TIMEOUT: a stage blew its wall-clock deadline
   (`spark_tpu.execution.stageTimeoutMs`) — retried like TRANSIENT
@@ -16,7 +16,10 @@ structure:
 - OOM: HBM RESOURCE_EXHAUSTED — handled by the executor's degradation
   ladder (evict device cache -> reroute through the host-spill chunked
   path -> diagnostic raise), the UnifiedMemoryManager
-  evict-then-spill discipline with host RAM as the spill tier.
+  evict-then-spill discipline with host RAM as the spill tier. HBM
+  only: the device compiler refusing a program (a kernel over its
+  scoped-VMEM limit also says RESOURCE_EXHAUSTED) is FATAL — no
+  eviction or re-plan changes what the compiler accepts.
 - OVERFLOW: static-capacity overflow. Never an exception — it flows as
   flags through the stats channel into the AQE re-jit loop; listed here
   so the taxonomy is total.
@@ -25,7 +28,9 @@ structure:
   never degraded: the recovery ladder re-raises immediately (a
   deadline blown mid-recovery must stop the ladder, not retry through
   it).
-- FATAL: everything else — surfaces immediately.
+- FATAL: everything else — surfaces immediately. A compiler refusal
+  surfaces as `StageCompileError`, naming the stage; the compiler's
+  own text names the kernel.
 
 Synthetic faults from `spark_tpu.testing.faults` carry their class on
 the exception; real errors classify by message tokens, so both flow
@@ -57,12 +62,28 @@ class StageOOMError(RuntimeError):
     message names the stage and its capacity stats."""
 
 
-#: message tokens marking retryable infra flakes (remote-compile 500s on
-#: tunneled runtimes, gRPC channel errors); DEADLINE_EXCEEDED is the
-#: runtime's own deadline, distinct from our stage wall-clock TIMEOUT
+class StageCompileError(RuntimeError):
+    """The device compiler refused a stage's program: a Pallas kernel
+    over its scoped-VMEM limit, an unaligned slice, an op Mosaic does
+    not lower. Deterministic, so never retried and never degraded; the
+    message names the stage and carries the compiler's text."""
+
+
+#: message tokens marking retryable infra flakes (gRPC channel errors);
+#: DEADLINE_EXCEEDED is the runtime's own deadline, distinct from our
+#: stage wall-clock TIMEOUT
 _TRANSIENT_TOKENS = (
-    "remote_compile", "UNAVAILABLE", "DEADLINE_EXCEEDED", "ABORTED",
+    "UNAVAILABLE", "DEADLINE_EXCEEDED", "ABORTED",
     "Connection reset", "Socket closed", "connection attempt",
+)
+
+#: tokens of a program the device COMPILER refused. Checked before the
+#: OOM tokens: a kernel over its VMEM limit reports RESOURCE_EXHAUSTED
+#: "in memory space vmem", which the HBM ladder cannot cure. Exhausted
+#: HBM ("memory space hbm", allocator messages) stays OOM.
+_COMPILE_REFUSAL_TOKENS = (
+    "memory space vmem", "memory space smem", "scoped vmem",
+    "Mosaic", "tpu_custom_call",
 )
 
 _OOM_TOKENS = (
@@ -82,6 +103,14 @@ _MESH_TOKENS = (
 )
 
 
+def is_compile_refusal(exc: BaseException) -> bool:
+    """True when the device compiler rejected the program itself (see
+    `_COMPILE_REFUSAL_TOKENS`) — as opposed to the device running out
+    of HBM, or the infrastructure flaking."""
+    msg = f"{type(exc).__name__}: {exc}"
+    return any(t in msg for t in _COMPILE_REFUSAL_TOKENS)
+
+
 def classify(exc: BaseException) -> FailureClass:
     """Map an exception to its failure class. Synthetic faults classify
     by their carried class; real errors by message tokens."""
@@ -99,6 +128,8 @@ def classify(exc: BaseException) -> FailureClass:
         return FailureClass.FATAL
     if isinstance(exc, MemoryError):
         return FailureClass.OOM
+    if is_compile_refusal(exc):
+        return FailureClass.FATAL
     msg = f"{type(exc).__name__}: {exc}"
     if any(t in msg for t in _OOM_TOKENS):
         return FailureClass.OOM

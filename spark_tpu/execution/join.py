@@ -265,11 +265,14 @@ def build_runtime_filter(build_batch: Batch, key_expr, ctx,
         lo = jnp.min(jnp.where(bmask, raw, pos))
         hi = jnp.max(jnp.where(bmask, raw, neg))
     if ctx.axis_name is not None and ctx.n_shards > 1:
-        bloom = BloomFilter(jax.lax.pmax(bloom.bits, ctx.axis_name),
+        # the engine's pmax/pmin, not lax's: see parallel/mesh.py for
+        # what XLA:TPU does to narrow and to 64-bit operands
+        from ..parallel.mesh import pmax, pmin
+        bloom = BloomFilter(pmax(bloom.bits, ctx.axis_name),
                             bloom.num_hashes)
         if lo is not None:
-            lo = jax.lax.pmin(lo, ctx.axis_name)
-            hi = jax.lax.pmax(hi, ctx.axis_name)
+            lo = pmin(lo, ctx.axis_name)
+            hi = pmax(hi, ctx.axis_name)
     return RuntimeFilter(bloom, lo, hi)
 
 

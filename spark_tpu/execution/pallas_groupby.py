@@ -50,8 +50,58 @@ SUPER = 8            # tiles per exact-f32 accumulation window
 D_BLOCK = 512        # small-domain kernel: columns per block
 FACTOR_B = 512       # factorized kernel: dB (lane dimension)
 PARTIAL_BUDGET = 256 * 1024 * 1024  # max bytes of per-call partial sums
+#: scoped-VMEM ceiling handed to Mosaic with every call, and the budget
+#: the block sizes below are fitted to. The compiler's 16 MiB default
+#: refuses a TILE-row one-hot beside a double-buffered output slab
+#: ("Scoped allocation with size 24.75M and limit 16.00M" for 9 limb
+#: rows at a_blk=128); blocks that fit 16 MiB would shrink a_blk to a
+#: few sublanes. A v5e core has 128 MiB of VMEM.
+VMEM_LIMIT = 64 * 1024 * 1024
 
 assert TILE * SUPER * 255 < (1 << 25)  # f32-exact window
+
+
+def _factored_vmem_bytes(a_blk: int, d_b: int, n_words: int,
+                         n_limb_rows: int) -> int:
+    """Upper bound on one grid step of `_factored_kernel`: pipelined
+    blocks count twice (double buffering), and each one-hot is charged
+    its 32-bit compare next to its bf16 form. Measured against the v5e
+    compiler (smallest limit that compiles) it overstates by 1.5-3.5x;
+    the compiler decides live ranges, so the bound has to be loose."""
+    ins = 2 * (2 + n_words) * TILE * 4
+    out = 2 * n_limb_rows * a_blk * d_b * 4
+    onehot_b = TILE * d_b * (4 + 2)
+    onehot_a = TILE * a_blk * (4 + 2 + 2)  # compare, one-hot, scaled
+    return ins + out + onehot_b + onehot_a + a_blk * d_b * 4
+
+
+def _small_vmem_bytes(d_block: int, n_words: int, n_limb_rows: int,
+                      n_float_rows: int) -> int:
+    """Upper bound on one grid step of `_small_kernel`, same rules as
+    `_factored_vmem_bytes`; the float path holds the [T, D] match mask
+    and ONE masked row at a time (rows accumulate in sequence)."""
+    ins = 2 * (1 + n_words + n_float_rows) * TILE * 4
+    out = 2 * (n_limb_rows + 2 * n_float_rows) * d_block * 4
+    ints = (TILE * d_block * (4 + 2)
+            + n_limb_rows * TILE * (4 + 4 + 2)) if n_limb_rows else 0
+    floats = TILE * d_block * (4 + 4) if n_float_rows else 0
+    return ins + out + ints + floats
+
+
+def _fit_block(cap: int, step: int, vmem_bytes) -> int:
+    """Largest multiple of `step` <= cap whose grid step fits
+    VMEM_LIMIT; a shape whose smallest block does not fit is refused
+    here, by name, before the compiler sees it."""
+    blk = max(step, (cap // step) * step)
+    while blk > step and vmem_bytes(blk) > VMEM_LIMIT:
+        blk -= step
+    if vmem_bytes(blk) > VMEM_LIMIT:
+        raise ValueError(
+            f"pallas dense_groupby: a {step}-wide block needs "
+            f"{vmem_bytes(blk)} bytes of VMEM, over the "
+            f"{VMEM_LIMIT}-byte limit (too many aggregate rows for "
+            f"the MXU kernel)")
+    return blk
 
 
 def _limb_layout(widths: Sequence[int]) -> List[Tuple[int, int, int]]:
@@ -138,25 +188,29 @@ def _small_kernel(*refs, n_words: int, limb_plan, n_float_rows: int,
         # floats avoid the MXU (f32 matmul decomposes into lossy bf16
         # passes): VPU masked reduce keeps true f32 adds, Kahan across t
         match = idx[:, None] == col  # [T, DB] bool
-        frows = []
+        # one row at a time, each folded into the output block before
+        # the next is built: stacking the rows first kept every
+        # [T, DB] f32 masked temporary alive at once (4 MiB a row)
         for r in range(n_float_rows):
             v = floats_ref[r, :]  # [T] f32
-            frows.append(jnp.sum(jnp.where(match, v[:, None], 0.0), axis=0))
-        fpart = jnp.stack(frows, axis=0)  # [RF, DB] f32
+            fpart = jnp.sum(jnp.where(match, v[:, None], 0.0), axis=0,
+                            keepdims=True)  # [1, DB] f32
+            sum_r = slice(r, r + 1)
+            comp_r = slice(n_float_rows + r, n_float_rows + r + 1)
 
-        @pl.when(t == 0)
-        def _():
-            fout_ref[0, :n_float_rows] = fpart
-            fout_ref[0, n_float_rows:] = jnp.zeros_like(fpart)
+            @pl.when(t == 0)
+            def _():
+                fout_ref[0, sum_r] = fpart
+                fout_ref[0, comp_r] = jnp.zeros_like(fpart)
 
-        @pl.when(t > 0)
-        def _():
-            s = fout_ref[0, :n_float_rows]
-            c = fout_ref[0, n_float_rows:]
-            y = fpart - c
-            tt = s + y
-            fout_ref[0, n_float_rows:] = (tt - s) - y
-            fout_ref[0, :n_float_rows] = tt
+            @pl.when(t > 0)
+            def _():
+                s = fout_ref[0, sum_r]
+                c = fout_ref[0, comp_r]
+                y = fpart - c
+                tt = s + y
+                fout_ref[0, comp_r] = (tt - s) - y
+                fout_ref[0, sum_r] = tt
 
 
 def _factored_kernel(ia_ref, ib_ref, words_ref, out_ref, *,
@@ -176,26 +230,21 @@ def _factored_kernel(ia_ref, ib_ref, words_ref, out_ref, *,
     onehot_b = (ib[:, None] == rows_b).astype(jnp.bfloat16)  # [T, dB]
     w = words_ref[:, :]
 
-    parts = []
-    for (word, s) in limb_plan:
+    @pl.when(t == 0)
+    def _():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    # each limb's [aB, dB] product goes straight into its output row:
+    # concatenating all R products first held a second output slab
+    for r, (word, s) in enumerate(limb_plan):
         # minor-dim insertion must happen on the 32-bit value (Mosaic
         # rejects it on bf16); cast after the [T] -> [T, 1] reshape
         limb2 = ((w[word][:, None] >> (8 * s)) & jnp.int32(0xFF)) \
             .astype(jnp.float32).astype(jnp.bfloat16)  # [T, 1]
-        scaled_a = onehot_a * limb2                     # [T, dA]
-        g = jax.lax.dot_general(
+        scaled_a = onehot_a * limb2                     # [T, aB]
+        out_ref[0, r] += jax.lax.dot_general(
             scaled_a, onehot_b, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)         # [dA, dB]
-        parts.append(g[None])
-    part = jnp.concatenate(parts, axis=0)  # [R, dA, dB]
-
-    @pl.when(t == 0)
-    def _():
-        out_ref[0] = part
-
-    @pl.when(t > 0)
-    def _():
-        out_ref[0] += part
+            preferred_element_type=jnp.float32)         # [aB, dB]
 
 
 def dense_groupby_sums(idx, int_rows: Sequence, float_rows: Sequence,
@@ -223,16 +272,29 @@ def dense_groupby_sums(idx, int_rows: Sequence, float_rows: Sequence,
         raise ValueError("float rows unsupported for large domains "
                          "(caller must fall back to scatter)")
 
+    layout = _limb_layout(widths)
+    n_limb_rows = len(layout)
+    n_words = sum(1 if w <= 32 else 2 for w in widths)  # _split_u32
+    n_float_rows = 2 * n_f
+
     if use_factored:
         d_b = FACTOR_B
         d_a = -(-domain // d_b)
         d_a = -(-d_a // 8) * 8  # sublane multiple
         d_pad = d_a * d_b
+        a_max = _fit_block(d_a, 8, lambda a: _factored_vmem_bytes(
+            a, d_b, n_words, n_limb_rows))
+        # even split of the a-axis: no block of pure padding
+        num_ablk = -(-d_a // a_max)
+        a_blk = -(-d_a // (8 * num_ablk)) * 8
     else:
         d_pad = -(-domain // 128) * 128
-        d_block = min(D_BLOCK, d_pad)
+        d_block = _fit_block(min(D_BLOCK, d_pad), 128,
+                             lambda d: _small_vmem_bytes(
+                                 d, n_words, n_limb_rows, n_float_rows))
         num_dblk = -(-d_pad // d_block)
         d_pad = num_dblk * d_block
+    params = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT)
 
     idx32 = idx.astype(jnp.int32)
     if n_pad != n:
@@ -242,15 +304,12 @@ def dense_groupby_sums(idx, int_rows: Sequence, float_rows: Sequence,
     def pad_rows(r):
         return jnp.pad(r, (0, n_pad - n)) if n_pad != n else r
 
-    layout = _limb_layout(widths)
     u32 = word_index = None
     if n_i:
         u32, word_index = _split_u32(int_rows, widths, pad_rows)
+        assert u32.shape[0] == n_words
     limb_plan = tuple((word_index[(k, h)], s) for (k, h, s) in layout) \
         if n_i else ()
-    n_words = 0 if u32 is None else u32.shape[0]
-    n_limb_rows = len(limb_plan)
-    n_float_rows = 2 * n_f
 
     f32 = None
     if n_f:
@@ -279,11 +338,6 @@ def dense_groupby_sums(idx, int_rows: Sequence, float_rows: Sequence,
             ia = jnp.minimum(idx_c // d_b, d_a)  # padding -> row d_a: none
             ib = idx_c % d_b
             u32_c = jax.lax.slice_in_dim(u32, r0, r1, axis=1)
-            # bound the VMEM output slab to ~4MB per grid step
-            a_blk = max(8, min(d_a, (4 << 20)
-                               // max(1, n_limb_rows * d_b * 4)))
-            a_blk = (a_blk // 8) * 8
-            num_ablk = -(-d_a // a_blk)
             out = pl.pallas_call(
                 functools.partial(_factored_kernel, limb_plan=limb_plan,
                                   a_blk=a_blk, d_b=d_b),
@@ -303,6 +357,8 @@ def dense_groupby_sums(idx, int_rows: Sequence, float_rows: Sequence,
                     memory_space=pltpu.VMEM),
                 out_shape=jax.ShapeDtypeStruct(
                     (cs, n_limb_rows, num_ablk * a_blk, d_b), jnp.float32),
+                compiler_params=params,
+                name="dense_groupby_factored",
                 interpret=interpret,
             )(ia, ib, u32_c)
             part = out.astype(jnp.int64).sum(axis=0) \
@@ -348,6 +404,8 @@ def dense_groupby_sums(idx, int_rows: Sequence, float_rows: Sequence,
                 in_specs=in_specs,
                 out_specs=out_specs,
                 out_shape=out_shapes,
+                compiler_params=params,
+                name="dense_groupby_small",
                 interpret=interpret,
             )(*operands)
             pos = 0

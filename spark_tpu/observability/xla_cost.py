@@ -30,21 +30,11 @@ _MEM_FIELDS = ("argument_size_in_bytes", "output_size_in_bytes",
                "generated_code_size_in_bytes")
 
 
-def _first_dict(obj):
-    """cost_analysis() returns a dict (new jax) or a list of per-
-    computation dicts (jax<=0.4.x) — normalize to one dict."""
-    if isinstance(obj, dict):
-        return obj
-    if isinstance(obj, (list, tuple)) and obj and isinstance(obj[0], dict):
-        return obj[0]
-    return None
-
-
 def analyze_compiled(compiled) -> Dict:
     """Flatten a Compiled's cost + memory analysis into event fields."""
     out: Dict = {}
     try:
-        cost = _first_dict(compiled.cost_analysis())
+        cost = compiled.cost_analysis()
         if cost:
             for key, name in _COST_FIELDS.items():
                 if key in cost:

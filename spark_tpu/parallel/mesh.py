@@ -10,31 +10,45 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
+import jax.numpy as jnp
+from jax import shard_map  # noqa: F401 — re-exported to the engine
 from jax.sharding import Mesh
-
-# jax moved shard_map from jax.experimental to the top level around
-# 0.5.x and renamed check_rep -> check_vma; import whichever this jax
-# ships (0.4.37 has only the experimental location) and normalize the
-# kwarg so call sites can always pass check_vma.
-try:
-    from jax.experimental.shard_map import shard_map as _shard_map
-except ImportError:  # newer jax: top-level export only
-    from jax import shard_map as _shard_map
-
-import inspect as _inspect
-
-_SHARD_MAP_PARAMS = frozenset(
-    _inspect.signature(_shard_map).parameters)
-
-
-def shard_map(f, *args, **kwargs):
-    if "check_vma" in kwargs and "check_vma" not in _SHARD_MAP_PARAMS:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    return _shard_map(f, *args, **kwargs)
 
 AXIS = "data"
 
 EXCLUDE_KEY = "spark_tpu.sql.mesh.excludeDevices"
+
+
+def _all_reduce(x, axis_name, reduce_all, reduce_local):
+    """Cross-shard max/min the way XLA:TPU gets right (libtpu 0.0.34,
+    found on four v5e chips in PR 22); only 32-bit operands take the
+    library's all-reduce as they are.
+
+    - 64-bit: refused at compile time ("UNIMPLEMENTED: Supported
+      lowering only of Sum all reduce" for an s64/f64 pmax/pmin), and
+      with x64 on every Python int in the stats channel is 64-bit.
+      Gather the per-shard values and reduce locally: exact, and the
+      operands are scalars and bounds, never tables.
+    - 8/16-bit: accepted, and WRONG element-wise on the chip (a uint8
+      pmax over Bloom bits lost set bits, the runtime filter pruned
+      matching probe rows, Q3 under mesh.size=4 answered wrongly).
+      Widen to 32 bits for the reduce and narrow the result."""
+    x = jnp.asarray(x)
+    if x.dtype.itemsize == 8:
+        return reduce_local(jax.lax.all_gather(x, axis_name), axis=0)
+    if x.dtype.itemsize < 4:
+        wide = jnp.float32 if jnp.issubdtype(x.dtype, jnp.floating) \
+            else jnp.int32
+        return reduce_all(x.astype(wide), axis_name).astype(x.dtype)
+    return reduce_all(x, axis_name)
+
+
+def pmax(x, axis_name):
+    return _all_reduce(x, axis_name, jax.lax.pmax, jnp.max)
+
+
+def pmin(x, axis_name):
+    return _all_reduce(x, axis_name, jax.lax.pmin, jnp.min)
 
 
 def mesh_size(conf) -> int:
@@ -120,10 +134,7 @@ def init_distributed(conf) -> int:
         return len(jax.devices())
     num = int(conf.get("spark_tpu.sql.cluster.numProcesses"))
     pid = int(conf.get("spark_tpu.sql.cluster.processId"))
-    state = getattr(jax.distributed, "global_state", None)
-    already = state is not None and \
-        getattr(state, "coordinator_address", None)
-    if not already:
+    if not jax.distributed.is_initialized():
         jax.distributed.initialize(coordinator_address=coord,
                                    num_processes=num, process_id=pid)
     return len(jax.devices())

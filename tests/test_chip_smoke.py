@@ -1,0 +1,80 @@
+"""Rehearsal of chip_smoke.py without the chip (on-chip-measurement
+guide, section 2): the script itself must refuse the CPU, so its
+phases are rehearsed here by calling their functions at a tiny size —
+the served path on the CPU backend, the mesh path on four of the
+virtual devices."""
+
+import os
+import shutil
+import subprocess
+import sys
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import chip_smoke  # noqa: E402
+
+SF = 0.002
+
+
+def _run_script(cwd, *argv):
+    env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
+    env["JAX_PLATFORMS"] = "cpu"
+    return subprocess.run(
+        [sys.executable, os.path.join(cwd, "chip_smoke.py"), *argv],
+        cwd=cwd, env=env, capture_output=True, text=True, timeout=300)
+
+
+@pytest.mark.parametrize("argv", [(), ("--chips", "4")])
+def test_script_refuses_the_cpu(argv):
+    """No accelerator: another exit code than 0, no result line, and
+    no query run (no data directory appears)."""
+    before = os.path.isdir(os.path.join(REPO, "data"))
+    proc = _run_script(REPO, *argv)
+    assert proc.returncode != 0
+    assert '"ok"' not in proc.stdout, proc.stdout
+    assert "no TPU" in proc.stderr, proc.stderr[-500:]
+    assert os.path.isdir(os.path.join(REPO, "data")) == before
+
+
+def test_script_fails_without_the_program(tmp_path):
+    shutil.copy(os.path.join(REPO, "chip_smoke.py"), tmp_path)
+    proc = _run_script(str(tmp_path))
+    assert proc.returncode != 0
+    assert '"ok"' not in proc.stdout, proc.stdout
+
+
+@pytest.fixture(scope="module")
+def tpch_path(tmp_path_factory):
+    from unittest import mock
+    root = str(tmp_path_factory.mktemp("chip_smoke"))
+    with mock.patch.object(chip_smoke, "CHECKOUT", root):
+        return chip_smoke.phase_data(SF, seed=42)
+
+
+def test_serve_phase(tpch_path):
+    svc = chip_smoke.start_service(tpch_path)
+    try:
+        # with Q5, which the script leaves to --queries on the chip
+        chip_smoke.phase_serve(svc, tpch_path,
+                               chip_smoke.SERVED + ("Q5",))
+    finally:
+        svc.stop()
+
+
+def test_aggregate_phase_demands_the_kernel(session):
+    """Closed form and auto == scatter hold on any backend; what only a
+    TPU can pass is the last assertion, that the Pallas kernel is in
+    the `auto` program. Off the chip it must fail, and as that."""
+    # four chunks, so the rows stream as the full width does
+    session.conf.set("spark_tpu.sql.execution.streamingChunkRows",
+                     chip_smoke.AGG_GROUPS)
+    with pytest.raises(AssertionError, match="tpu_custom_call"):
+        chip_smoke.phase_aggregate(session, 4 * chip_smoke.AGG_GROUPS)
+
+
+def test_mesh_phase_on_virtual_devices(session, tpch_path):
+    chip_smoke.phase_mesh(session, tpch_path, 4,
+                          chip_smoke.MESH_QUERIES)

@@ -114,6 +114,92 @@ def test_call_signature_distinguishes_dictionaries():
     assert same == sig1
 
 
+@pytest.mark.parametrize("n_mesh", [0, 4])
+def test_entry_loads_onto_the_devices_it_was_compiled_for(tmp_path,
+                                                          n_mesh):
+    """With 8 devices visible, a stored executable loads onto its own
+    devices — one for a single-device stage, the gang for a mesh stage
+    — and runs. Loaded onto every local device (the library's default)
+    it failed at dispatch with "8 argument lists when local device
+    count is 1"; a one-device host hides that."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    assert len(jax.devices()) == 8
+    x = jnp.arange(8, dtype=jnp.int64)
+    mesh = None
+    if n_mesh:
+        mesh = Mesh(np.array(jax.devices()[2:2 + n_mesh]), ("data",))
+        x = jax.device_put(x, NamedSharding(mesh, PartitionSpec("data")))
+    compiled = jax.jit(lambda a: a * 2 + 1).lower(x).compile()
+    cc = CC.CompileCache(str(tmp_path), 0)
+    assert cc.store("k", mesh, (x,), compiled)
+    loaded = cc.load("k", mesh, (x,))
+    assert loaded is not None
+    out = loaded(x)
+    assert np.array_equal(np.asarray(out), np.arange(8) * 2 + 1)
+    want = {d.id for d in mesh.devices.flat} if n_mesh \
+        else {jax.devices()[0].id}
+    assert {d.id for d in out.sharding.device_set} == want
+
+
+def test_jax_cache_is_placed_once_and_only_by_the_package(tmp_path):
+    """The cache rule, in fresh interpreters (the directory is decided
+    at package import). JAX_COMPILATION_CACHE_DIR set: JAX reads it and
+    no code path of the engine — import, a session, the AOT cache, a
+    query — changes `jax_compilation_cache_dir`. Unset: it is
+    <checkout>/.jax_cache whatever the working directory."""
+    probe = (
+        "import json, os, jax\n"
+        "seen = [jax.config.jax_compilation_cache_dir]\n"
+        "import spark_tpu\n"
+        "seen.append(jax.config.jax_compilation_cache_dir)\n"
+        "from spark_tpu.execution import compile_cache as CC\n"
+        "s = spark_tpu.SparkTpuSession.builder().get_or_create()\n"
+        "s.range(64).count()\n"
+        "s.conf.set(CC.ENABLED_KEY, True)\n"
+        "s.conf.set(CC.DIR_KEY, 'rel-cc')\n"
+        "cc = CC.get_cache(s.conf)  # resolves the dir, writes nothing\n"
+        "seen.append(jax.config.jax_compilation_cache_dir)\n"
+        "print(json.dumps({'seen': seen, 'aot': cc.dir,\n"
+        "                  'checkout': spark_tpu.CHECKOUT}))\n")
+
+    def run(cwd, **env_extra):
+        env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu")
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
+        env.update(env_extra)
+        proc = subprocess.run([sys.executable, "-c", probe], cwd=cwd,
+                              env=env, capture_output=True, text=True,
+                              timeout=300)
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        return json.loads(proc.stdout.strip().splitlines()[-1])
+
+    fixed = os.path.join(REPO, ".jax_cache")
+    for cwd in (str(tmp_path), REPO):
+        got = run(cwd)
+        assert got["checkout"] == REPO
+        assert got["seen"] == [None, fixed, fixed], got
+        # the engine's own AOT cache resolves against the checkout too
+        assert got["aot"] == os.path.join(REPO, "rel-cc"), got
+    placed = str(tmp_path / "operator-cache")
+    got = run(str(tmp_path), JAX_COMPILATION_CACHE_DIR=placed)
+    assert got["seen"] == [placed, placed, placed], got
+    # nothing in the package sets a directory except that one line
+    hits = []
+    for root, _dirs, files in os.walk(os.path.join(REPO, "spark_tpu")):
+        for f in files:
+            if f.endswith(".py"):
+                with open(os.path.join(root, f)) as fh:
+                    if '"jax_compilation_cache_dir"' in fh.read():
+                        hits.append(os.path.relpath(
+                            os.path.join(root, f), REPO))
+    assert hits == [os.path.join("spark_tpu", "__init__.py")], hits
+    # tests themselves run with the persistent cache off
+    import jax
+    assert jax.config.jax_enable_compilation_cache is False
+
+
 def test_cached_stage_fn_requires_builder_for_novel_sig():
     fn = CC.CachedStageFn()
     with pytest.raises(RuntimeError, match="no jit builder"):

@@ -169,6 +169,52 @@ def test_final_merge_kernel_full_width():
     assert np.asarray(accs[1][0]).tolist() == [(1 << 40) * 40] * 4
 
 
+#: (groups, int row widths, float rows): both kernels, one and several
+#: blocks of each gridded axis, raw-width and merge-mode limbs
+_KERNEL_SHAPES = {
+    "small_int_float": (6, [8, 64, 64], 2),
+    "small_three_blocks_floats": (300, [8], 3),
+    "factored_8_64": (65536, [8, 64], 0),
+    "factored_merge_null_slot": (65537, [8, 64, 64], 0),
+    "factored_split_a_axis": (1 << 20, [8], 0),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(_KERNEL_SHAPES))
+def test_dense_groupby_sums_exact(shape):
+    """The Pallas kernels (interpret mode) against numpy over several
+    row tiles and super-tiles: integer sums bit-exact mod 2^64 whatever
+    the limb widths, float sums to the f32-tile tolerance, out-of-range
+    rows dropped. PR 22 re-cut both kernels' accumulation and block
+    sizes to what the v5e compiler accepts (tests/test_chip_compile.py
+    compiles them); this holds the arithmetic still."""
+    import jax
+    from spark_tpu.execution.pallas_groupby import dense_groupby_sums
+    domain, widths, n_float = _KERNEL_SHAPES[shape]
+    n = 70_000  # 9 row tiles: two super-tiles, the second mostly padding
+    rs = np.random.RandomState(7)
+    idx = rs.randint(0, domain + 1, n).astype(np.int32)  # == domain: dropped
+    ints = [rs.randint(0, 1 << w, n, dtype=np.int64) if w < 64
+            else rs.randint(-(1 << 62), 1 << 62, n, dtype=np.int64)
+            for w in widths]
+    floats = [rs.randn(n) * 1e3 for _ in range(n_float)]
+    got_i, got_f = jax.jit(
+        lambda i, a, b: dense_groupby_sums(
+            i, list(a), list(b), domain, interpret=True,
+            int_widths=widths))(idx, tuple(ints), tuple(floats))
+
+    def want(v):
+        out = np.zeros(domain + 1, v.dtype)
+        np.add.at(out, idx, v)
+        return out[:domain]
+
+    for v, g in zip(ints, got_i):
+        assert np.array_equal(np.asarray(g), want(v)), shape
+    for v, g in zip(floats, got_f):
+        np.testing.assert_allclose(np.asarray(g), want(v), rtol=1e-5,
+                                   atol=1e-2)
+
+
 def test_two_phase_mesh_agg_forced_matmul(session):
     """End-to-end: a distributed two-phase aggregate with the Pallas
     kernel forced (interpret mode on CPU) must match the single-chip

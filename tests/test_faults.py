@@ -12,8 +12,9 @@ import pytest
 from spark_tpu import functions as F
 from spark_tpu.functions import col
 from spark_tpu.execution.failures import (FailureClass, RetryPolicy,
-                                          StageOOMError, StageTimeoutError,
-                                          classify, is_mesh_failure)
+                                          StageCompileError, StageOOMError,
+                                          StageTimeoutError, classify,
+                                          is_mesh_failure)
 from spark_tpu.testing import faults
 from spark_tpu.testing.faults import FaultInjected, FaultPlan
 from spark_tpu.tpch import golden as G
@@ -147,13 +148,70 @@ def test_classify_taxonomy():
         is FailureClass.OOM
     assert classify(RuntimeError("UNAVAILABLE: conn")) \
         is FailureClass.TRANSIENT
-    assert classify(RuntimeError("INTERNAL: remote_compile 500")) \
+    assert classify(RuntimeError("Connection reset by peer")) \
         is FailureClass.TRANSIENT
     assert classify(StageTimeoutError("slow")) is FailureClass.TIMEOUT
     assert classify(ValueError("bad plan")) is FailureClass.FATAL
     assert classify(MemoryError()) is FailureClass.OOM
     assert is_mesh_failure(RuntimeError("shard_map lowering failed"))
     assert not is_mesh_failure(RuntimeError("UNAVAILABLE: conn"))
+    # the device compiler refusing a kernel also says RESOURCE_EXHAUSTED:
+    # FATAL, while HBM running out at run time stays with the ladder
+    assert classify(RuntimeError(_VMEM_REFUSAL)) is FailureClass.FATAL
+    assert classify(RuntimeError(
+        "INTERNAL: Mosaic failed to compile TPU kernel: unaligned "
+        "slice")) is FailureClass.FATAL
+    assert classify(RuntimeError(
+        "RESOURCE_EXHAUSTED: Error allocating device buffer: Attempting "
+        "to allocate 8.00G. That was not possible. There are 3.12G "
+        "free.; (0x0x0_HBM0)")) is FailureClass.OOM
+    assert classify(RuntimeError(
+        "RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out "
+        "of memory in memory space hbm. Used 20.50G of 15.48G hbm.")) \
+        is FailureClass.OOM
+
+
+#: what the v5e compiler said of the factored kernel before its blocks
+#: were fitted to the VMEM limit (ISSUE 22, finding 1), word for word
+_VMEM_REFUSAL = (
+    "RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem while "
+    "allocating on stack for %dense_groupby_factored.1 = "
+    "f32[16,9,128,512]{3,2,1,0:T(8,128)S(1)} custom-call(...), "
+    "custom_call_target=\"tpu_custom_call\", metadata={op_name="
+    "\"jit(run)/shard_map/dense_groupby_factored/pallas_call\"}. Scoped "
+    "allocation with size 24.75M and limit 16.00M exceeded scoped vmem "
+    "limit by 8.75M.")
+
+
+@pytest.mark.parametrize("mesh_size", [0, 4])
+def test_compiler_refusal_is_fatal_and_names_stage(
+        tpch_session, monkeypatch, mesh_size):
+    """A kernel the device compiler refuses fails at once as a
+    StageCompileError naming the stage and the kernel. Its text holds
+    the OOM ladder's token and, under a mesh, the gang-restart
+    ladder's: neither may act on it."""
+    from spark_tpu.execution.executor import QueryExecution
+
+    def refuse(self, root, mesh=None, args=None):
+        self._last_stage_key = self._stage_key(root, mesh)
+        raise RuntimeError(_VMEM_REFUSAL)
+
+    monkeypatch.setattr(QueryExecution, "_compile_stage", refuse)
+    _cold(tpch_session)
+    old = tpch_session.conf.get(MESH_KEY)
+    tpch_session.conf.set(MESH_KEY, mesh_size)
+    try:
+        qe = Q.QUERIES["q1"](tpch_session)._qe()
+        with pytest.raises(StageCompileError) as err:
+            qe.collect()
+    finally:
+        tpch_session.conf.set(MESH_KEY, old)
+    text = str(err.value)
+    assert qe._last_stage_key[:80] in text
+    assert "dense_groupby_factored" in text and "scoped vmem" in text
+    assert classify(err.value) is FailureClass.FATAL
+    assert not qe.fault_summary, qe.fault_summary
+    assert qe._oom_rung == 0
 
 
 def test_retry_policy_backoff_exponential_jittered():

@@ -415,3 +415,41 @@ def test_distributed_streaming_aggregate(session):
                          prev_chunk)
         session.conf.set("spark_tpu.sql.io.deviceCacheBytes", prev_cache)
     assert any(calls), "mesh streaming path never engaged"
+
+
+@pytest.mark.parametrize("dtype,via", [
+    ("uint8", "convert_element_type"),   # Bloom bits: widened to int32
+    ("int32", None),                     # the library's all-reduce as is
+    ("int64", "all_gather"),             # stats, runtime-filter bounds
+    ("float64", "all_gather"),
+])
+def test_mesh_pmax_pmin_take_the_route_the_tpu_gets_right(dtype, via):
+    """`parallel.mesh.pmax/pmin`, element-wise over four shards: right
+    for every width, and by the route XLA:TPU handles — on the chip a
+    uint8 `lax.pmax` lost Bloom bits (wrong Q3 under mesh.size=4) and a
+    64-bit one does not compile (PR 22). The CPU computes all of them
+    correctly either way, so the route is asserted from the jaxpr."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, PartitionSpec
+
+    from spark_tpu.parallel.mesh import AXIS, pmax, pmin, shard_map
+    n = 4
+    mesh = Mesh(np.array(jax.devices()[:n]), (AXIS,))
+    rs = np.random.RandomState(3)
+    x = rs.randint(0, 2, n * 64).astype(dtype) if dtype == "uint8" \
+        else rs.randint(-(1 << 30), 1 << 30, n * 64).astype(dtype)
+    both = shard_map(lambda v: (pmax(v, AXIS), pmin(v, AXIS)), mesh=mesh,
+                     in_specs=PartitionSpec(AXIS),
+                     out_specs=PartitionSpec(), check_vma=False)
+    hi, lo = jax.jit(both)(jnp.asarray(x))
+    assert hi.dtype == lo.dtype == np.dtype(dtype)
+    assert np.array_equal(np.asarray(hi), x.reshape(n, -1).max(axis=0))
+    assert np.array_equal(np.asarray(lo), x.reshape(n, -1).min(axis=0))
+    text = str(jax.make_jaxpr(both)(jnp.asarray(x)))
+    if via is None:
+        assert "pmax" in text and "all_gather" not in text \
+            and "convert_element_type" not in text, text
+    else:
+        assert via in text, text
+        assert ("pmax" in text) == (via != "all_gather"), text

@@ -173,7 +173,9 @@ def test_init_distributed_mocked(session, monkeypatch):
     calls = []
 
     class FakeDistributed:
-        global_state = None
+        @staticmethod
+        def is_initialized():
+            return bool(calls)
 
         @staticmethod
         def initialize(coordinator_address=None, num_processes=None,
@@ -191,6 +193,7 @@ def test_init_distributed_mocked(session, monkeypatch):
         session.conf.set("spark_tpu.sql.cluster.numProcesses", 2)
         session.conf.set("spark_tpu.sql.cluster.processId", 1)
         n = M.init_distributed(session.conf)
+        assert M.init_distributed(session.conf) == n  # idempotent
         assert calls == [("host0:8476", 2, 1)]
         assert n == len(jax.devices())
     finally:

@@ -105,8 +105,8 @@ def test_user_accumulator_in_udf(session):
 
 
 def test_transient_failure_retries(session, monkeypatch):
-    """A transient (remote-compile-style) stage failure retries with a
-    fresh compile instead of surfacing (maxTaskFailures seat)."""
+    """A transient (channel-error) stage failure retries with a fresh
+    compile instead of surfacing (maxTaskFailures seat)."""
     from spark_tpu.execution.executor import QueryExecution
 
     calls = {"n": 0}
@@ -118,7 +118,7 @@ def test_transient_failure_retries(session, monkeypatch):
             if calls["n"] == 0:
                 calls["n"] += 1
                 raise RuntimeError(
-                    "INTERNAL: remote_compile: HTTP 500 (simulated)")
+                    "UNAVAILABLE: Socket closed (simulated)")
             return fn(*a, **k)
         return wrapper
 
