@@ -203,6 +203,12 @@ LOCKS: Tuple[LockDecl, ...] = (
              "_lock", "lock", 82,
              "per-histogram bucket counters (leaf; bucket index is "
              "computed before acquiring it)"),
+    LockDecl("obs.spans", _OBS + "spans.py", "SpanRecorder", "_lock",
+             "lock", 84,
+             "one query's span list, id counter and per-thread open "
+             "stacks, shared by the query's thread and its ingest "
+             "-prefetch worker (leaf: list/dict ops only inside; the "
+             "profiler annotation and the clock are read OUTSIDE it)"),
     LockDecl("testing.lockwatch", "spark_tpu/testing/lockwatch.py",
              "LockWatch", "_mu", "lock", 95,
              "lockwatch's own recorder lock: acquired inside every "

@@ -30,6 +30,7 @@ import numpy as np
 import pyarrow as pa
 
 from . import types as T
+from .observability.spans import span
 
 
 def bucket_capacity(n: int, growth: float = 2.0, floor: int = 8) -> int:
@@ -394,10 +395,29 @@ def _np_to_dtype(np_dtype) -> T.DataType:
 
 
 def _arrow_to_column(name: str, col: pa.ChunkedArray, n: int, cap: int) -> Column:
+    """One Arrow column to a device Column. Inside a query it leaves
+    two spans: `chunk.convert` (Arrow to the padded numpy buffer:
+    combine, decimal limb copy, cast, pad) and `chunk.put` (the
+    `jax.device_put` calls, i.e. staging: a put is not a sync)."""
+    if pa.types.is_list(col.type) or pa.types.is_large_list(col.type):
+        arr = col.combine_chunks() if isinstance(col, pa.ChunkedArray) \
+            else col
+        return _arrow_list_to_column(name, arr, n, cap)
+    with span("chunk.convert", column=name):
+        dt, padded, valid_np, dictionary = _arrow_to_padded(name, col, n, cap)
+    nbytes = padded.nbytes + (valid_np.nbytes if valid_np is not None else 0)
+    with span("chunk.put", column=name, bytes=nbytes):
+        validity = jax.device_put(valid_np) if valid_np is not None else None
+        # device_put is ~2x jnp.asarray for host->device of large buffers
+        data = jax.device_put(padded)
+    return Column(data, dt, validity, dictionary)
+
+
+def _arrow_to_padded(name: str, col, n: int, cap: int):
+    """(dtype, data padded to `cap`, validity padded to `cap` or None,
+    dictionary or None) of a non-list Arrow column: all host work."""
     arr = col.combine_chunks() if isinstance(col, pa.ChunkedArray) else col
     at = arr.type
-    if pa.types.is_list(at) or pa.types.is_large_list(at):
-        return _arrow_list_to_column(name, arr, n, cap)
     dictionary = None
     if pa.types.is_null(at):
         # an empty/all-None pandas object column infers arrow `null`
@@ -454,17 +474,15 @@ def _arrow_to_column(name: str, col: pa.ChunkedArray, n: int, cap: int) -> Colum
         np_data = arr.cast(pa.from_numpy_dtype(dt.np_dtype)).to_numpy(
             zero_copy_only=False)
 
-    validity = None
+    valid_np = None
     if arr.null_count > 0:
         valid_np = np.zeros(cap, dtype=np.bool_)
         valid_np[:n] = ~np.asarray(arr.is_null())
         np_data = np.where(valid_np[:n], np_data, np.zeros((), dtype=dt.np_dtype))
-        validity = jax.device_put(valid_np)
 
     padded = np.zeros(cap, dtype=dt.np_dtype)
     padded[:n] = np_data
-    # device_put is ~2x jnp.asarray for host->device of large buffers
-    return Column(jax.device_put(padded), dt, validity, dictionary)
+    return dt, padded, valid_np, dictionary
 
 
 def _arrow_list_to_column(name: str, arr, n: int, cap: int) -> Column:

@@ -196,7 +196,8 @@ INGEST_PREFETCH = register(
         "consumer thread, so HBM residency, arbiter leases and the "
         "per-chunk retry/checkpoint semantics are unchanged. Results "
         "are identical on/off; only ingest/compute overlap changes "
-        "(ingest_overlap_ms / ingest_stall_ms counters).")
+        "(the chunk.wait span and its sum, the ingest_stall_ms "
+        "counter, against the worker's chunk.decode / chunk.unify).")
 
 SHUFFLE_PARTITIONS = register(
     "spark_tpu.sql.shuffle.partitions", 8,

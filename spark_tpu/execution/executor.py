@@ -11,6 +11,7 @@ of `CodeGenerator.compile:1435`'s Janino cache.
 
 from __future__ import annotations
 
+import contextlib
 import time
 from typing import Dict, List, Optional, Tuple
 
@@ -1249,6 +1250,7 @@ class QueryExecution:
         """Run `_execute_batch_inner` under the failure taxonomy: each
         iteration either returns, re-raises (_ReplanRequest, FATAL,
         exhausted budgets), or applies one recovery action and loops."""
+        from ..observability.spans import use_recorder
         from . import lifecycle
         last: Optional[Exception] = None
         for _ in range(32):  # every action below consumes a bounded budget
@@ -1257,7 +1259,11 @@ class QueryExecution:
             # here instead of burning another recovery action
             lifecycle.checkpoint("stage_attempt")
             try:
-                return self._execute_batch_inner()
+                # code below that has no handle on the query (columnar
+                # ingest, the chunk drivers) opens its spans on this
+                # execution's recorder
+                with use_recorder(self.spans):
+                    return self._execute_batch_inner()
             except _ReplanRequest:
                 raise
             except Exception as e:  # noqa: BLE001
@@ -1509,10 +1515,7 @@ class QueryExecution:
             f"mesh.size={conf.get('spark_tpu.sql.mesh.size')}")
 
     def _execute_batch_inner(self) -> Tuple[Batch, Dict, Dict]:
-        from ..columnar import bucket_capacity
         from ..parallel.mesh import get_mesh
-        from ..testing import faults
-        from .failures import StageTimeoutError
         mesh = get_mesh(self._conf)
         if mesh is not None:
             # a drain request no gang this size can ever apply must
@@ -1556,7 +1559,23 @@ class QueryExecution:
             self.phase_times["python_udfs"] = time.perf_counter() - t0
         if mesh is not None:
             root0 = self._materialize_generates(root0)
-        t0 = time.perf_counter()
+        # spark_tpu.sql.profile.dir: one trace of everything from here
+        # on (streaming splice, ingest, dispatch), so a streamed
+        # query's chunks and their spark_tpu.* annotations stand in it;
+        # planning above may run nested executions, which have theirs
+        profile_dir = str(self._conf.get("spark_tpu.sql.profile.dir"))
+        with jax.profiler.trace(profile_dir) if profile_dir \
+                else contextlib.nullcontext():
+            return self._run_planned(root0, mesh, aqe_key)
+
+    def _run_planned(self, root0: P.PhysicalPlan, mesh, aqe_key
+                     ) -> Tuple[Batch, Dict, Dict]:
+        """The planned tree to its result batch: streaming splice,
+        ingest of what stays resident, the whole-stage dispatch with
+        its capacity re-plans, and the end event."""
+        from ..columnar import bucket_capacity
+        from ..testing import faults
+        from .failures import StageTimeoutError
         # per-shard flight recorder (observability/spans.py): the mesh
         # chunk drivers pick the telemetry up from the context var so
         # their signatures stay stable; records land on self.spans
@@ -1567,39 +1586,42 @@ class QueryExecution:
             telem = ShardStreamTelemetry(
                 recorder=self.spans, mesh=mesh, query_id=self.query_id,
                 bus=self.session.listeners)
-        with use_shard_telemetry(telem):
+        # the chunk pipeline's spans (chunk.*, stream.drain) nest
+        # under this one; a plan with nothing to stream leaves none
+        with self.spans.span("streaming") as sp, \
+                use_shard_telemetry(telem):
             root = self._materialize_streaming(root0, mesh)
-        dt = time.perf_counter() - t0
+            if root is root0:
+                self.spans.discard(sp)
         if root is not root0:
             # chunked ingest + chunk compute happen inside the splice
-            self.phase_times["streaming"] = dt
-            self.spans.record("streaming", t0, t0 + dt)
+            self.phase_times["streaming"] = sp.t1 - sp.t0
         scans: List[P.LeafExec] = []
         self._collect_scans(root, scans)
 
-        t0 = time.perf_counter()
         from . import lifecycle
-        # cooperative boundary before host ingest loads the scans
-        lifecycle.checkpoint("scan")
         from ..io.device_cache import load_scan
-        # dedupe by node identity: a runtime filter's creation chain
-        # shares its leaf with the join build side (the documented DAG),
-        # so the same scan appears twice in `scans` — load and pad it
-        # once, feed the same Batch to both input slots
-        loaded: Dict[int, Batch] = {}
-        for s in scans:
-            if id(s) in loaded:
-                continue
-            b = load_scan(s, self._conf) \
-                if isinstance(s, P.ScanExec) else s.load()
-            if mesh is not None:
-                from ..parallel import pad_batch_to_multiple
-                b = pad_batch_to_multiple(b, int(mesh.devices.size))
-            loaded[id(s)] = b
-        scan_batches = [loaded[id(s)] for s in scans]
-        t1 = time.perf_counter()
-        self.phase_times["ingest"] = t1 - t0
-        self.spans.record("ingest", t0, t1, scans=len(scans))
+        # a scan that loads (a cache miss, an in-memory table) leaves
+        # its columns' chunk.convert / chunk.put spans under this one
+        with self.spans.span("ingest", scans=len(scans)) as sp:
+            # cooperative boundary before host ingest loads the scans
+            lifecycle.checkpoint("scan")
+            # dedupe by node identity: a runtime filter's creation chain
+            # shares its leaf with the join build side (the documented
+            # DAG), so the same scan appears twice in `scans` — load and
+            # pad it once, feed the same Batch to both input slots
+            loaded: Dict[int, Batch] = {}
+            for s in scans:
+                if id(s) in loaded:
+                    continue
+                b = load_scan(s, self._conf) \
+                    if isinstance(s, P.ScanExec) else s.load()
+                if mesh is not None:
+                    from ..parallel import pad_batch_to_multiple
+                    b = pad_batch_to_multiple(b, int(mesh.devices.size))
+                loaded[id(s)] = b
+            scan_batches = [loaded[id(s)] for s in scans]
+        self.phase_times["ingest"] = sp.t1 - sp.t0
 
         t0 = time.perf_counter()
         token = None
@@ -1612,111 +1634,108 @@ class QueryExecution:
             root, mesh,
             (scan_batches,) if mesh is None else (scan_batches, token))
         adaptive = bool(self._conf.get("spark_tpu.sql.adaptive.enabled"))
-        profile_dir = str(self._conf.get("spark_tpu.sql.profile.dir"))
-        import contextlib
-        prof = jax.profiler.trace(profile_dir) if profile_dir else \
-            contextlib.nullcontext()
         timeout_ms = int(self._conf.get(
             "spark_tpu.execution.stageTimeoutMs"))
-        with prof:
-            overflow: List[str] = []
-            for _attempt in range(8):
-                # failures here (compile, dispatch, trace-time injected
-                # faults) propagate to _execute_recover, which classifies
-                # them (execution/failures.py) and retries/degrades —
-                # the unified spark.task.maxFailures seat
-                t_att = time.perf_counter()
-                args = (scan_batches,) if mesh is None \
-                    else (scan_batches, token)
-                fn = self._compile_stage(root, mesh, args)
-                t_disp = time.perf_counter()
-                faults.fire("stage_run")  # chaos seam: pre-dispatch
-                batch, flags, metrics = fn(*args)
-                # ONE batched host pull for the whole stats channel —
-                # per-scalar np.asarray is a host sync each (the pull
-                # also syncs the attempt, making the wall-clock
-                # deadline check below honest). The pull
-                # is cancellable (dispatchPollMs readiness polling):
-                # a cancel/deadline lands within ~one tick instead of
-                # at stage completion
-                flags, metrics = _sync_dispatched((flags, metrics),
-                                                  self._conf)
-                # jit compiles lazily: the first dispatch after a stage
-                # -cache miss pays trace + XLA compile in-line, so flag
-                # it — trace readers must not read that as execution
-                self.spans.record(
-                    "dispatch", t_disp, time.perf_counter(),
-                    attempt=_attempt,
+        overflow: List[str] = []
+        for _attempt in range(8):
+            # failures here (compile, dispatch, trace-time injected
+            # faults) propagate to _execute_recover, which classifies
+            # them (execution/failures.py) and retries/degrades —
+            # the unified spark.task.maxFailures seat
+            t_att = time.perf_counter()
+            args = (scan_batches,) if mesh is None \
+                else (scan_batches, token)
+            fn = self._compile_stage(root, mesh, args)
+            # jit compiles lazily: the first dispatch after a stage
+            # -cache miss pays trace + XLA compile in-line, so flag
+            # it — trace readers must not read that as execution
+            with self.spans.span(
+                    "dispatch", attempt=_attempt,
                     includes_jit_compile=getattr(
-                        self, "_last_compile_was_miss", False))
-                # deadline BEFORE the stage-timeout check: an attempt
-                # that outran the end-to-end budget raises the
-                # lifecycle error (ladder stops), never a retryable
-                # StageTimeoutError — queryDeadlineMs < stageTimeoutMs
-                # must not retry through the recovery ladder
-                lifecycle.checkpoint("post_dispatch")
-                if timeout_ms > 0:
-                    att_ms = (time.perf_counter() - t_att) * 1e3
-                    if att_ms > timeout_ms:
-                        raise StageTimeoutError(
-                            f"stage attempt took {att_ms:.0f}ms > "
-                            f"stageTimeoutMs={timeout_ms}: "
-                            f"{root.simple_string()}")
-                overflow = [k for k, v in flags.items()
-                            if k.startswith(("join_overflow_",
-                                             "join_nonunique_",
-                                             "join_hashsat_",
-                                             "exch_overflow_",
-                                             "agg_overflow_"))
-                            and bool(v)]
-                self._post_stage_completed(_attempt, t_att, metrics,
-                                           overflow)
-                if not overflow:
-                    break
-                self.spans.mark("aqe_overflow", flags=overflow[:8])
-                # unique-build / hash-saturation fallbacks are
-                # correctness re-plans, not capacity growth — never
-                # gated by the adaptive conf
-                if not adaptive and any(
-                        not k.startswith(("join_nonunique_",
-                                          "join_hashsat_"))
-                        for k in overflow):
-                    raise RuntimeError(
-                        f"capacity overflow in {overflow} with adaptive "
-                        f"re-planning disabled "
-                        f"(spark_tpu.sql.adaptive.enabled=false)")
-                for k in overflow:
-                    if k.startswith("join_nonunique_"):
-                        self._set_join_nonunique(
-                            root, k[len("join_nonunique_"):])
-                    elif k.startswith("join_hashsat_"):
-                        self._set_join_hash_fallback(
-                            root, k[len("join_hashsat_"):])
-                    elif k.startswith("join_overflow_"):
-                        tag = k[len("join_overflow_"):]
-                        total = int(metrics[f"join_rows_{tag}"])
-                        self._set_join_cap(root, tag,
-                                           bucket_capacity(max(total, 8)))
-                    elif k.startswith("exch_overflow_"):
-                        tag = k[len("exch_overflow_"):]
-                        mx = int(metrics[f"exch_max_{tag}"])
-                        if self._maybe_skew_replan(root, tag, metrics,
-                                                   mesh):
-                            raise _ReplanRequest()
-                        self._set_exchange_cap(root, tag,
-                                               bucket_capacity(max(mx, 8)))
-                    else:
-                        tag = k[len("agg_overflow_"):]
-                        total = int(metrics[f"agg_groups_{tag}"])
-                        # bucketed like every other learned capacity:
-                        # compute re-buckets before use, and a raw count
-                        # in the stage key recompiles per exact total
-                        self._set_agg_groups(root, tag,
-                                             bucket_capacity(max(total, 8)))
-            else:
+                        self, "_last_compile_was_miss", False)):
+                faults.fire("stage_run")  # chaos seam: pre-dispatch
+                # the call returning: trace + compile on a miss,
+                # else the enqueue of the stage's program
+                with self.spans.span("dispatch.launch"):
+                    batch, flags, metrics = fn(*args)
+                # ONE batched host pull for the whole stats channel
+                # — per-scalar np.asarray is a host sync each (the
+                # pull also syncs the attempt, making the wall-clock
+                # deadline check below honest). The pull is
+                # cancellable (dispatchPollMs readiness polling): a
+                # cancel/deadline lands within ~one tick instead of
+                # at stage completion
+                with self.spans.span("dispatch.sync"):
+                    flags, metrics = _sync_dispatched(
+                        (flags, metrics), self._conf)
+            # deadline BEFORE the stage-timeout check: an attempt
+            # that outran the end-to-end budget raises the
+            # lifecycle error (ladder stops), never a retryable
+            # StageTimeoutError — queryDeadlineMs < stageTimeoutMs
+            # must not retry through the recovery ladder
+            lifecycle.checkpoint("post_dispatch")
+            if timeout_ms > 0:
+                att_ms = (time.perf_counter() - t_att) * 1e3
+                if att_ms > timeout_ms:
+                    raise StageTimeoutError(
+                        f"stage attempt took {att_ms:.0f}ms > "
+                        f"stageTimeoutMs={timeout_ms}: "
+                        f"{root.simple_string()}")
+            overflow = [k for k, v in flags.items()
+                        if k.startswith(("join_overflow_",
+                                         "join_nonunique_",
+                                         "join_hashsat_",
+                                         "exch_overflow_",
+                                         "agg_overflow_"))
+                        and bool(v)]
+            self._post_stage_completed(_attempt, t_att, metrics,
+                                       overflow)
+            if not overflow:
+                break
+            self.spans.mark("aqe_overflow", flags=overflow[:8])
+            # unique-build / hash-saturation fallbacks are
+            # correctness re-plans, not capacity growth — never
+            # gated by the adaptive conf
+            if not adaptive and any(
+                    not k.startswith(("join_nonunique_",
+                                      "join_hashsat_"))
+                    for k in overflow):
                 raise RuntimeError(
-                    f"capacity retries did not converge; still "
-                    f"overflowing: {overflow}")
+                    f"capacity overflow in {overflow} with adaptive "
+                    f"re-planning disabled "
+                    f"(spark_tpu.sql.adaptive.enabled=false)")
+            for k in overflow:
+                if k.startswith("join_nonunique_"):
+                    self._set_join_nonunique(
+                        root, k[len("join_nonunique_"):])
+                elif k.startswith("join_hashsat_"):
+                    self._set_join_hash_fallback(
+                        root, k[len("join_hashsat_"):])
+                elif k.startswith("join_overflow_"):
+                    tag = k[len("join_overflow_"):]
+                    total = int(metrics[f"join_rows_{tag}"])
+                    self._set_join_cap(root, tag,
+                                       bucket_capacity(max(total, 8)))
+                elif k.startswith("exch_overflow_"):
+                    tag = k[len("exch_overflow_"):]
+                    mx = int(metrics[f"exch_max_{tag}"])
+                    if self._maybe_skew_replan(root, tag, metrics,
+                                               mesh):
+                        raise _ReplanRequest()
+                    self._set_exchange_cap(root, tag,
+                                           bucket_capacity(max(mx, 8)))
+                else:
+                    tag = k[len("agg_overflow_"):]
+                    total = int(metrics[f"agg_groups_{tag}"])
+                    # bucketed like every other learned capacity:
+                    # compute re-buckets before use, and a raw count
+                    # in the stage key recompiles per exact total
+                    self._set_agg_groups(root, tag,
+                                         bucket_capacity(max(total, 8)))
+        else:
+            raise RuntimeError(
+                f"capacity retries did not converge; still "
+                f"overflowing: {overflow}")
         batch = jax.block_until_ready(batch)
         self.phase_times["execution"] = time.perf_counter() - t0
         if adaptive:
@@ -1920,7 +1939,7 @@ class QueryExecution:
             "status": status,
             "plan": root.describe() if root is not None else
             self.logical.tree_string(),
-            "phase_times_s": {k: round(v, 4)
+            "phase_times_s": {k: round(v, 6)
                               for k, v in self.phase_times.items()},
             "metrics": self.last_metrics,
         }
@@ -2040,7 +2059,11 @@ class QueryExecution:
             if ext is not None:
                 return ext
             batch, _, _ = self.execute_batch()
-            return batch.to_arrow()
+            # after the end event: the device-to-host pull and the
+            # Arrow build stand in `self.spans` (and the service's
+            # timeline), not in the event log's record
+            with self.spans.span("egress"):
+                return batch.to_arrow()
         finally:
             lifecycle.exit_query_scope(lc_scope)
             res_arbiter.exit_query(arb_token)
@@ -2060,6 +2083,7 @@ class QueryExecution:
         from .failures import FailureClass, RetryPolicy, classify
         from .python_eval import plan_has_udfs
         from .recovery import RecoveryContext
+        from ..observability.spans import use_recorder
         self._activate_conf()
         if plan_has_udfs(self.executed_plan):
             return None  # UDF stages evaluate through execute_batch
@@ -2092,9 +2116,13 @@ class QueryExecution:
         try:
             while True:
                 try:
-                    out = try_external_collect(
-                        self.session, self.executed_plan, conf,
-                        self.session._stage_cache, self._recovery)
+                    with use_recorder(self.spans), \
+                            self.spans.span("external") as sp:
+                        out = try_external_collect(
+                            self.session, self.executed_plan, conf,
+                            self.session._stage_cache, self._recovery)
+                        if out is None:
+                            self.spans.discard(sp)
                     break
                 except Exception as e:  # noqa: BLE001 — classified below
                     if classify(e) not in (FailureClass.TRANSIENT,

@@ -43,6 +43,7 @@ import pyarrow as pa
 import pyarrow.compute as pc
 
 from ..columnar import Batch, bucket_capacity
+from ..observability.spans import span
 from ..plan import physical as P
 from .recovery import ChunkRetrier
 from .streaming_agg import (CHUNK_ROWS_KEY, _CHUNKABLE_JOINS,
@@ -188,8 +189,9 @@ def try_external_collect(session, plan: P.PhysicalPlan, conf,
     b = first
     try:
         while b is not None:
-            t = retrier.run(lambda bb=b: run_chunk(bb).to_arrow(),
-                            chunk=ci)
+            with span("chunk.launch", chunk=ci):  # the host pull included
+                t = retrier.run(lambda bb=b: run_chunk(bb).to_arrow(),
+                                chunk=ci)
             spilled.append(t)
             total_rows += t.num_rows
             if limit is not None and sort is None \
@@ -205,25 +207,25 @@ def try_external_collect(session, plan: P.PhysicalPlan, conf,
             # daemon may outlive its query
             chunks.close()
 
-    table = pa.concat_tables(spilled, promote_options="permissive")
-
-    if topn:
-        # tournament final: one small device sort+limit over the
-        # concatenated per-chunk top-n spills
-        ctx = P.ExecContext(conf)
-        b = Batch.from_arrow(table)
-        b = sort.compute(ctx, [b])
-        b = limit.compute(ctx, [b])
-        return b.to_arrow()
-    if sort is not None:
-        keys, placement = host_keys
-        idx = pc.sort_indices(
-            table, options=pc.SortOptions(sort_keys=keys,
-                                          null_placement=placement))
-        return table.take(idx)
-    if limit is not None:
-        return table.slice(0, limit.n)
-    return table
+    with span("stream.drain"):  # the host's merge of the spilled chunks
+        table = pa.concat_tables(spilled, promote_options="permissive")
+        if topn:
+            # tournament final: one small device sort+limit over the
+            # concatenated per-chunk top-n spills
+            ctx = P.ExecContext(conf)
+            b = Batch.from_arrow(table)
+            b = sort.compute(ctx, [b])
+            b = limit.compute(ctx, [b])
+            return b.to_arrow()
+        if sort is not None:
+            keys, placement = host_keys
+            idx = pc.sort_indices(
+                table, options=pc.SortOptions(sort_keys=keys,
+                                              null_placement=placement))
+            return table.take(idx)
+        if limit is not None:
+            return table.slice(0, limit.n)
+        return table
 
 
 # ---------------------------------------------------------------------------

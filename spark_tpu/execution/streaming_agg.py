@@ -26,6 +26,7 @@ import numpy as np
 
 from .. import types as T
 from ..columnar import Batch, Column, bucket_capacity
+from ..observability.spans import span
 from ..plan import physical as P
 from . import aggregate as agg_kernels
 from .recovery import CHECKPOINT_EVERY_KEY, ChunkRetrier
@@ -425,15 +426,24 @@ def _stream_scan_aggregate_inner(agg, chain, conf, cache, recovery,
     ci = 0
     b = first
     while b is not None:
-        check_dicts(b)
-        tables = retrier.run(lambda bb=b: run_chunk(tables, bb), chunk=ci)
+        # the launch is an enqueue: it returns once the chunk's program
+        # is dispatched, not when the device has run it
+        with span("chunk.launch", chunk=ci):
+            check_dicts(b)
+            tables = retrier.run(lambda bb=b: run_chunk(tables, bb),
+                                 chunk=ci)
         row_base += chunk_stride(b)
         ci += 1
         b = next(chunks, None)  # ingest un-retried: see ChunkRetrier
 
     dict_overrides = dict(chunks.dictionaries) if hasattr(
         chunks, "dictionaries") else {}
-    return agg.direct_finalize_tables(tables, prep, dict_overrides or None)
+    # the stream's one wait for the device: how far transfers and
+    # chunk programs lag the host once the last chunk is launched (the
+    # stage above would wait for the same arrays at its first sync)
+    with span("stream.drain"):
+        return jax.block_until_ready(agg.direct_finalize_tables(
+            tables, prep, dict_overrides or None))
 
 
 def stream_scan_aggregate_spill(agg: "P.HashAggregateExec", chain: List,
@@ -544,14 +554,16 @@ def _stream_scan_aggregate_spill_inner(agg, chain, conf, cache, recovery,
     ci = int(skip_chunks)
     b = first
     while b is not None:
-        spilled.append(retrier.run(
-            lambda bb=b: run_chunk(bb).to_arrow(), chunk=ci))
+        with span("chunk.launch", chunk=ci):  # the host pull included
+            spilled.append(retrier.run(
+                lambda bb=b: run_chunk(bb).to_arrow(), chunk=ci))
         ci += 1
         b = next(chunks, None)  # ingest un-retried: see ChunkRetrier
     for j in joins:
         j.out_cap = saved_caps[j.tag] if saved_caps[j.tag] is not None \
             else j.out_cap
-    table = pa.concat_tables(spilled, promote_options="permissive")
+    with span("stream.drain"):
+        table = pa.concat_tables(spilled, promote_options="permissive")
     return table, partial
 
 
@@ -736,7 +748,7 @@ def stream_scan_aggregate_mesh(agg: "P.HashAggregateExec", mesh, conf,
     est = leaf.source.estimated_rows()
     if est is not None and est <= chunk_rows:
         return None
-    if _prefer_resident(leaf, conf):
+    if _prefer_resident(leaf, conf, getattr(recovery, "metrics", None)):
         return None
 
     from ..io.sources import maybe_prefetch
@@ -937,9 +949,10 @@ def _stream_scan_aggregate_mesh_inner(agg, chain, mesh, conf, cache,
                 telem.chunk_ingested(ci, b.capacity,
                                      b.capacity * row_width(b),
                                      t_in0, t_in1)
-            check_dicts(b)
-            tables = retrier.run(lambda bb=b: step(tables, bb, ci),
-                                 chunk=ci)
+            with span("chunk.launch", chunk=ci):
+                check_dicts(b)
+                tables = retrier.run(lambda bb=b: step(tables, bb, ci),
+                                     chunk=ci)
             ci += 1
             if ck_key is not None:
                 # consumed-chunk watermark: bounds the replay a later
@@ -953,7 +966,9 @@ def _stream_scan_aggregate_mesh_inner(agg, chain, mesh, conf, cache,
 
     if telem is not None:
         telem.finish()  # flush the last chunk's buffered records
-    out = _with_dict_overrides(emit_step(tables), current_dicts())
+    with span("stream.drain"):
+        out = jax.block_until_ready(
+            _with_dict_overrides(emit_step(tables), current_dicts()))
     if ck is not None:
         # merge the seed checkpoint's partial rows with the resumed
         # tail's — the FINAL aggregate above re-reduces both
@@ -962,11 +977,22 @@ def _stream_scan_aggregate_mesh_inner(agg, chain, mesh, conf, cache,
     return out
 
 
-def _prefer_resident(leaf: "P.ScanExec", conf) -> bool:
+def _prefer_resident(leaf: "P.ScanExec", conf, metrics=None) -> bool:
     """True when the scan should load whole and ride the device-table
     cache instead of streaming: it's already cached, or its estimated
     footprint fits in half the cache budget (so repeated queries skip
-    host ingest entirely — the round-3 headline perf fix)."""
+    host ingest entirely — the round-3 headline perf fix). The verdict
+    counts into `scans_resident` / `scans_streamed` of `metrics`: a
+    streamed scan asks the cache only `contains()`, which counts
+    neither a hit nor a miss."""
+    resident = _resident_verdict(leaf, conf)
+    if metrics is not None:
+        metrics.counter("scans_resident" if resident
+                        else "scans_streamed").inc()
+    return resident
+
+
+def _resident_verdict(leaf: "P.ScanExec", conf) -> bool:
     from ..io.device_cache import (CACHE_BYTES_KEY, estimated_scan_bytes,
                                    is_cached, scan_cache_key)
     from ..service.arbiter import admit_scan_resident
@@ -1013,6 +1039,6 @@ def try_stream_aggregate(agg: "P.HashAggregateExec", conf,
         return None
     if not hasattr(leaf.source, "load_chunks"):
         return None
-    if _prefer_resident(leaf, conf):
+    if _prefer_resident(leaf, conf, getattr(recovery, "metrics", None)):
         return None
     return stream_scan_aggregate(agg, chain, leaf, conf, cache, recovery)

@@ -11,6 +11,7 @@ ingest is the only place bytes cross host->device (SURVEY.md section 2.4).
 
 from __future__ import annotations
 
+import contextlib
 import os
 from typing import Optional, Sequence, Tuple
 
@@ -21,6 +22,7 @@ import pyarrow.dataset as pa_dataset
 
 from .. import types as T
 from ..columnar import Batch
+from ..observability.spans import span
 from ..expr import (And, BinaryComparison, ColumnRef, EQ, Expression, GE, GT,
                     In, IsNull, LE, LT, Literal, NE, Not, Or)
 
@@ -201,7 +203,13 @@ class DictUnifier:
 
 class ChunkIterator:
     """Single-pass iterator of uniform-capacity Batches over a record
-    -batch stream; `.dictionaries` holds the final global dictionaries."""
+    -batch stream; `.dictionaries` holds the final global dictionaries.
+
+    Once `observe` has bound it to a query, every chunk leaves the
+    spans `chunk.decode` and `chunk.unify` (on whichever thread runs
+    `_host_next`: the prefetch worker, or the consumer with prefetch
+    off) and `chunk.to_device` (always the consumer), and counts into
+    `ingest_chunks` / `ingest_rows` / `ingest_put_bytes`."""
 
     def __init__(self, batches_iter, chunk_rows: int):
         self._batches = batches_iter
@@ -212,6 +220,27 @@ class ChunkIterator:
         self._done = False
         self._failed: Optional[BaseException] = None
         self._unifier = DictUnifier()
+        self._metrics = None
+        self._recorder = None
+        self._cause = None   # the consumer's open span, for the worker
+        self._chunk = 0      # ordinal of the next chunk taken
+
+    def observe(self, metrics, recorder) -> None:
+        """Bind the stream to its query's counters and spans (called
+        on the consumer thread, by `maybe_prefetch`)."""
+        self._metrics = metrics
+        self._recorder = recorder
+        self._cause = recorder.current() if recorder is not None else None
+
+    def _host_span(self, name: str, **attrs):
+        """A span of the host half (`_host_next`), which the prefetch
+        worker may run: a thread the query starts does not see the
+        query's context, so these go through the bound recorder, with
+        the consumer's open span as their cause. The consumer's own
+        spans (`chunk.wait`, `chunk.to_device`) open through `span`."""
+        if self._recorder is None:
+            return contextlib.nullcontext()
+        return self._recorder.span(name, parent=self._cause, **attrs)
 
     @property
     def dictionaries(self):
@@ -244,17 +273,27 @@ class ChunkIterator:
     def _take_chunk(self) -> Optional[pa.Table]:
         """One chunk's Arrow slice off the stream (the shared cursor
         advance of __next__ and skip_chunks, so both cut identical
-        chunk boundaries), or None at end of stream."""
-        self._fill()
-        if self._pending_rows == 0:
-            return None
-        table = pa.Table.from_batches(self._pending)
-        take = min(self._pending_rows, self._chunk_rows)
-        chunk = table.slice(0, take)
-        rest = table.slice(take)
-        self._pending = rest.to_batches() if rest.num_rows else []
-        self._pending_rows = rest.num_rows
-        return chunk
+        chunk boundaries), or None at end of stream. The `chunk.decode`
+        span: a Parquet scanner reads ahead on threads of its own, so
+        this is the wait for its batches and the slicing around it,
+        not the decode's CPU time."""
+        with self._host_span("chunk.decode", chunk=self._chunk) as sp:
+            self._fill()
+            if self._pending_rows == 0:
+                if sp is not None:
+                    self._recorder.discard(sp)  # end of stream
+                return None
+            table = pa.Table.from_batches(self._pending)
+            take = min(self._pending_rows, self._chunk_rows)
+            chunk = table.slice(0, take)
+            if sp is not None:  # what the chunk was cut from, and is
+                sp.attrs.update(rows=take, batches=len(self._pending),
+                                bytes=chunk.nbytes)
+            rest = table.slice(take)
+            self._pending = rest.to_batches() if rest.num_rows else []
+            self._pending_rows = rest.num_rows
+            self._chunk += 1
+            return chunk
 
     def skip_chunks(self, n: int) -> int:
         """Advance the cursor past the next `n` chunks without
@@ -280,10 +319,23 @@ class ChunkIterator:
         if self._capacity is None:
             from ..columnar import bucket_capacity
             self._capacity = bucket_capacity(self._chunk_rows)
-        return self._unifier.unify(chunk)
+        with self._host_span("chunk.unify", chunk=self._chunk - 1,
+                             rows=chunk.num_rows):
+            return self._unifier.unify(chunk)
 
     def _to_device(self, chunk: pa.Table) -> Batch:
-        return Batch.from_arrow(chunk, capacity=self._capacity)
+        with span("chunk.to_device", rows=chunk.num_rows):
+            batch = Batch.from_arrow(chunk, capacity=self._capacity)
+        if self._metrics is not None:
+            self._metrics.counter("ingest_chunks").inc()
+            self._metrics.counter("ingest_rows").inc(chunk.num_rows)
+            # what was put: the padded buffers (the selection mask is
+            # made on the device)
+            self._metrics.counter("ingest_put_bytes").inc(sum(
+                c.data.nbytes + (c.validity.nbytes
+                                 if c.validity is not None else 0)
+                for c in batch.columns.values()))
+        return batch
 
     def __next__(self) -> Batch:
         chunk = self._host_next()
@@ -548,11 +600,12 @@ class PrefetchChunkIterator:
     before and surfaces on the consumer thread for the whole-query
     ladder.
 
-    Observability: ``ingest_stall_ms`` counts time the consumer waited
-    for a chunk (the pipeline failing to hide host decode) and
-    ``ingest_overlap_ms`` counts decode time hidden behind compute —
-    both in the process metrics registry and the `tpch_*` bench
-    sidecars."""
+    Observability: the consumer's wait for a chunk (the pipeline
+    failing to hide the host's work) is the ``chunk.wait`` span and,
+    summed, the ``ingest_stall_ms`` counter of the process registry;
+    the worker's own time stands in the inner iterator's
+    ``chunk.decode`` / ``chunk.unify`` spans, on the worker's ``tid``
+    (the benchmark's ``ingest_*_ms_p50`` metrics read them)."""
 
     def __init__(self, inner: ChunkIterator, conf, recovery=None,
                  metrics=None):
@@ -607,14 +660,11 @@ class PrefetchChunkIterator:
         # ref to the iterator would keep it reachable forever and its
         # abandonment finalizer (see __init__) could never fire
         import queue as _queue
-        import time as _time
         while not stop.is_set():
-            t0 = _time.perf_counter()
             try:
-                item = ("ok", retrier.run(host_next, chunk=chunk),
-                        _time.perf_counter() - t0)
+                item = ("ok", retrier.run(host_next, chunk=chunk))
             except BaseException as e:  # noqa: BLE001 — relayed verbatim
-                item = ("err", e, 0.0)
+                item = ("err", e)
             # bounded put that notices close()/abandonment: the worker
             # must not strand blocked on a full size-1 queue forever
             while not stop.is_set():
@@ -640,20 +690,22 @@ class PrefetchChunkIterator:
                 args=(self._inner._host_next, self._retrier,
                       self._queue, self._stop, self._chunk))
             self._thread.start()
-        t0 = _time.perf_counter()
-        kind, payload, decode_s = self._queue.get()
-        stall_s = _time.perf_counter() - t0
+        # one interval, read twice: the `chunk.wait` span and the
+        # `ingest_stall_ms` counter (every wait counts, the last one
+        # for the end of the stream too)
+        with span("chunk.wait"):
+            t0 = _time.perf_counter()
+            kind, payload = self._queue.get()
+            stall_s = _time.perf_counter() - t0
+        if self._metrics is not None:
+            self._metrics.counter("ingest_stall_ms").inc(
+                round(stall_s * 1e3, 3))
         if kind == "err":
             self._closed = True
             raise payload
         if payload is None:
             self._closed = True
             raise StopIteration
-        if self._metrics is not None:
-            self._metrics.counter("ingest_stall_ms").inc(
-                round(stall_s * 1e3, 3))
-            self._metrics.counter("ingest_overlap_ms").inc(
-                round(max(0.0, decode_s - stall_s) * 1e3, 3))
         return self._inner._to_device(payload)
 
     def close(self, timeout_s: float = 5.0) -> None:
@@ -746,8 +798,10 @@ def maybe_prefetch(chunks, conf, recovery=None):
     on/off, only ingest/compute overlap changes."""
     if not isinstance(chunks, ChunkIterator):
         return chunks
+    from ..observability.spans import current_recorder
+    metrics = getattr(recovery, "metrics", None)
+    chunks.observe(metrics, current_recorder())
     if not bool(conf.get(INGEST_PREFETCH_KEY)):
         return chunks
-    metrics = getattr(recovery, "metrics", None)
     return PrefetchChunkIterator(chunks, conf, recovery=recovery,
                                  metrics=metrics)

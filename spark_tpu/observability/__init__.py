@@ -11,9 +11,18 @@ the engine-sized analog, organized the same way:
   on_stage_completed / on_fault / on_query_end); ``ListenerBus``
   delivers events so the event log, the Chrome-trace writer, the
   metrics sinks, and tests are all just subscribers.
-- ``spans``: per-stage spans (analysis -> optimize -> plan -> compile
-  -> ingest -> dispatch -> AQE-replan -> retry) with a wall-clock
-  anchor, exportable as Chrome trace-event JSON (Perfetto-loadable).
+- ``spans``: one query's span tree over both of its threads, each
+  span with the span that caused it (``parent``) and its thread
+  (``tid``): the lifecycle phases (analysis, optimize, plan, analyze,
+  compile, streaming / external, ingest, dispatch with
+  dispatch.launch / dispatch.sync, egress, the service's queue), the
+  chunk pipeline of a streamed scan (chunk.wait, chunk.decode,
+  chunk.unify, chunk.to_device with chunk.convert / chunk.put per
+  column, chunk.launch, stream.drain) and marks (aqe_replan,
+  aqe_overflow, retry:<action>, cancelled). On two clocks: a
+  wall-clock anchor for Chrome trace-event JSON (Perfetto-loadable),
+  and a ``spark_tpu.<name>`` annotation in the ``jax.profiler`` trace
+  whenever a profiler session is on.
 - ``xla_cost``: XLA cost/HBM accounting off the AOT API
   (``compiled.cost_analysis()`` / ``memory_analysis()``) — flops,
   bytes accessed, argument/output/temp sizes and the derived peak-HBM
@@ -44,8 +53,8 @@ from .flight_recorder import FlightRecorder
 from .metrics import (METRIC_PREFIXES, Histogram, MetricsRegistry,
                       is_registered_metric)
 from .spans import (ShardStreamTelemetry, Span, SpanRecorder,
-                    current_shard_telemetry, to_chrome_trace,
-                    use_shard_telemetry)
+                    current_recorder, current_shard_telemetry,
+                    to_chrome_trace, use_recorder, use_shard_telemetry)
 from .status_store import StatusStore
 from .straggler import StragglerMonitor
 
@@ -55,6 +64,7 @@ __all__ = [
     "QueryEndEvent", "QueryListener", "QueryStartEvent", "ServiceEvent",
     "ShardChunkEvent", "ShardStreamTelemetry", "Span", "SpanRecorder",
     "StageCompiledEvent", "StageCompletedEvent", "StatusStore",
-    "StragglerEvent", "StragglerMonitor", "current_shard_telemetry",
-    "is_registered_metric", "to_chrome_trace", "use_shard_telemetry",
+    "StragglerEvent", "StragglerMonitor", "current_recorder",
+    "current_shard_telemetry", "is_registered_metric", "to_chrome_trace",
+    "use_recorder", "use_shard_telemetry",
 ]

@@ -56,11 +56,17 @@ METRIC_PREFIXES = (
     # history.shard_summary / straggler_report)
     "shard_rows_",     # per-shard routed/processed live rows
     "shard_bytes_",    # per-shard routed payload bytes
-    # ingest pipeline (PrefetchChunkIterator): REGISTRY counters, not
-    # traced per-operator metrics — listed here so the namespace is
-    # closed in one place (consumers key on the prefixes)
-    "ingest_stall_",   # consumer time blocked waiting on host decode
-    "ingest_overlap_",  # host decode time hidden behind device compute
+    # ingest pipeline (io/sources.py chunk iterators,
+    # streaming_agg._prefer_resident): REGISTRY counters, not traced
+    # per-operator metrics — listed here so the namespace is closed in
+    # one place (consumers key on the prefixes)
+    "ingest_stall_",   # ingest_stall_ms: consumer waits for a chunk
+                       # (the sum of the chunk.wait spans)
+    "ingest_chunks",   # chunks of streamed scans placed on the device
+    "ingest_rows",     # their live rows
+    "ingest_put_",     # ingest_put_bytes: padded bytes they device_put
+    "scans_",          # scans_streamed / scans_resident: verdicts of
+                       # the residency decision on a streamable scan
     # straggler detection (observability/straggler.py): REGISTRY
     # counter, listed for namespace closure like the ingest pair
     "straggler_",      # straggler_flagged: shards flagged this process

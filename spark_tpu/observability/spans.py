@@ -1,21 +1,46 @@
-"""Per-stage spans with Chrome-trace export.
+"""Per-query spans: a tree over both threads, on two clocks.
 
-Each QueryExecution records named spans over its lifecycle phases
-(analysis -> optimize -> plan -> compile -> ingest -> dispatch ->
-AQE-replan -> retry). Spans use `time.perf_counter` internally (cheap,
-monotonic) with a wall-clock anchor captured at recorder creation, so
-export maps to epoch microseconds — the Chrome trace-event "X"
-(complete-event) format, loadable in Perfetto / chrome://tracing.
+Each QueryExecution owns one `SpanRecorder`. Its spans are the
+lifecycle phases (`analysis`, `optimize`, `plan`, `analyze`,
+`analyze_jaxpr`, `compile`, `deserialize`, `streaming`, `external`,
+`ingest`, `dispatch` with `dispatch.launch` / `dispatch.sync`,
+`egress`, the service's `queue`), the chunk pipeline of a streamed
+scan (`chunk.wait`, `chunk.decode`, `chunk.unify`, `chunk.to_device`
+with one `chunk.convert` / `chunk.put` per column, `chunk.launch`,
+`stream.drain`) and the marks (`aqe_replan`, `aqe_overflow`,
+`retry:<action>`, `cancelled`). Names are fixed and carry no ordinal:
+the benchmark's readers go by them (PERF.md section 3).
+
+A span knows the span that caused it (`parent`: the span open on the
+same thread when it started, else the one handed over from the thread
+that started the work) and its thread (`tid`), so a layer's self time
+is its length less the union of its children on its own thread.
+
+Clocks: `time.perf_counter` (cheap, monotonic) with a wall-clock
+anchor captured at recorder creation, so export maps to epoch
+microseconds: the Chrome trace-event "X" (complete-event) format,
+loadable in Perfetto / chrome://tracing. A span opened with `span()`
+also holds a `jax.profiler.TraceAnnotation("spark_tpu.<name>")` for
+its interval: whenever a profiler session is on (the benchmark's
+`--trace 1`, `spark_tpu.sql.profile.dir`) the same interval stands on
+the trace's host plane, on the device events' clock. With no session
+on the annotation is a flag test.
 """
 
 from __future__ import annotations
 
 import contextlib
 import os
+import threading
 import time
 from contextvars import ContextVar
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
+
+#: prefix of every engine span in the profiler's trace
+ANNOTATION_PREFIX = "spark_tpu."
 
 
 @dataclass
@@ -24,6 +49,9 @@ class Span:
     t0: float            # perf_counter seconds
     t1: float
     attrs: Dict = field(default_factory=dict)
+    id: int = 0          # unique within the recorder, in order of start
+    parent: Optional[int] = None  # id of the span that caused this one
+    tid: int = 0         # native id of the thread it ran on
 
     @property
     def dur_ms(self) -> float:
@@ -31,7 +59,9 @@ class Span:
 
 
 class SpanRecorder:
-    """Bounded span list for one QueryExecution (query_id = trace tid)."""
+    """Bounded span list for one QueryExecution. Spans are appended as
+    they END; `id` orders them by start. Safe to use from the query's
+    thread and its ingest-prefetch worker at once."""
 
     def __init__(self, query_id: int, max_spans: int = 1000,
                  max_shard_records: int = 4096):
@@ -49,6 +79,12 @@ class SpanRecorder:
         self.shard_dropped = 0
         self._anchor_wall = time.time()
         self._anchor_perf = time.perf_counter()
+        self._lock = threading.Lock()
+        self._ids = 0
+        #: native thread id -> ids of the spans open on that thread,
+        #: outermost first; a thread with none open has no entry
+        self._open: Dict[int, List[int]] = {}
+        self._discarded: set = set()
 
     def add_shard_records(self, records: List[Dict]) -> None:
         room = self.max_shard_records - len(self.shard_records)
@@ -62,21 +98,73 @@ class SpanRecorder:
         (the shared origin of span t0_ms and shard-record t0_ms)."""
         return round((t_perf - self._anchor_perf) * 1e3, 3)
 
-    def record(self, name: str, t0: float, t1: Optional[float] = None,
-               **attrs) -> None:
+    def current(self) -> Optional[int]:
+        """Id of the innermost span open on the calling thread: what a
+        thread hands to the worker it starts, as the worker's cause."""
+        stack = self._open.get(threading.get_native_id())
+        return stack[-1] if stack else None
+
+    def open_spans(self) -> Dict[int, List[int]]:
+        """{thread: ids of its open spans}; empty between queries."""
+        with self._lock:
+            return {tid: list(ids) for tid, ids in self._open.items()}
+
+    def _add_locked(self, span: Span) -> None:
         if len(self.spans) >= self.max_spans:
             self.dropped += 1
-            return
-        self.spans.append(Span(name, t0, t1 if t1 is not None else t0,
-                               attrs))
+        else:
+            self.spans.append(span)
+
+    def record(self, name: str, t0: float, t1: Optional[float] = None,
+               **attrs) -> None:
+        """An interval handed over after the fact (no annotation in
+        the profiler's trace); its parent is the span open on the
+        calling thread."""
+        with self._lock:
+            self._ids += 1
+            self._add_locked(Span(
+                name, t0, t1 if t1 is not None else t0, attrs, self._ids,
+                self.current(), threading.get_native_id()))
 
     @contextlib.contextmanager
-    def span(self, name: str, **attrs):
-        t0 = time.perf_counter()
+    def span(self, name: str, parent: Optional[int] = None, **attrs):
+        """Open a span on the calling thread for the body's interval;
+        yields it, so the body can add attributes known only at the
+        end. `parent` names the cause across threads and counts only
+        where the calling thread has no span open. The span is closed
+        and recorded however the body leaves (`error` names what it
+        raised)."""
+        tid = threading.get_native_id()
+        with self._lock:
+            self._ids += 1
+            stack = self._open.setdefault(tid, [])
+            sp = Span(name, 0.0, 0.0, attrs, self._ids,
+                      stack[-1] if stack else parent, tid)
+            stack.append(sp.id)
         try:
-            yield
+            with TraceAnnotation(ANNOTATION_PREFIX + name,
+                                 query_id=self.query_id):
+                sp.t0 = time.perf_counter()
+                try:
+                    yield sp
+                except BaseException as e:
+                    sp.attrs["error"] = type(e).__name__
+                    raise
+                finally:
+                    sp.t1 = time.perf_counter()
         finally:
-            self.record(name, t0, time.perf_counter(), **attrs)
+            with self._lock:
+                stack.remove(sp.id)
+                if not stack:
+                    self._open.pop(tid, None)
+                if sp.id not in self._discarded or any(
+                        s.parent == sp.id for s in self.spans):
+                    self._add_locked(sp)
+
+    def discard(self, span: Span) -> None:
+        """Leave an open span out when it ends, unless a child names
+        it (a phase that turned out to have nothing to do)."""
+        self._discarded.add(span.id)
 
     def mark(self, name: str, **attrs) -> None:
         """Zero-duration span (exported as a Chrome instant event)."""
@@ -90,10 +178,11 @@ class SpanRecorder:
     def to_dicts(self) -> List[Dict]:
         """Event-log form: relative start + duration in milliseconds."""
         out = []
-        for s in self.spans:
+        for s in list(self.spans):
             d = {"name": s.name,
                  "t0_ms": round((s.t0 - self._anchor_perf) * 1e3, 3),
-                 "dur_ms": round(s.dur_ms, 3)}
+                 "dur_ms": round(s.dur_ms, 3),
+                 "id": s.id, "parent": s.parent, "tid": s.tid}
             if s.attrs:
                 d["attrs"] = s.attrs
             out.append(d)
@@ -103,14 +192,15 @@ class SpanRecorder:
 def to_chrome_trace(recorder: SpanRecorder,
                     pid: Optional[int] = None) -> Dict:
     """Chrome trace-event JSON ({"traceEvents": [...]}) from a
-    recorder's spans. Zero-duration spans export as instant events
-    (ph "i"), the rest as complete events (ph "X")."""
+    recorder's spans, one row per thread (`tid`); `query_id`, `id` and
+    `parent` ride in `args`. Zero-duration spans export as instant
+    events (ph "i"), the rest as complete events (ph "X")."""
     pid = pid if pid is not None else os.getpid()
     events = []
-    for s in recorder.spans:
+    for s in list(recorder.spans):
         ts_us = recorder.wall(s.t0) * 1e6
         ev = {"name": s.name, "cat": "spark_tpu", "pid": pid,
-              "tid": recorder.query_id, "ts": ts_us}
+              "tid": s.tid, "ts": ts_us}
         dur_us = (s.t1 - s.t0) * 1e6
         if dur_us <= 0:
             ev["ph"] = "i"
@@ -118,10 +208,45 @@ def to_chrome_trace(recorder: SpanRecorder,
         else:
             ev["ph"] = "X"
             ev["dur"] = dur_us
-        if s.attrs:
-            ev["args"] = {k: v for k, v in s.attrs.items()}
+        ev["args"] = dict(s.attrs, query_id=recorder.query_id, id=s.id,
+                          parent=s.parent)
         events.append(ev)
     return {"traceEvents": events, "displayTimeUnit": "ms"}
+
+
+# ---------------------------------------------------------------------------
+# The current query's recorder (columnar ingest, chunk drivers)
+# ---------------------------------------------------------------------------
+
+#: the executor installs the running execution's recorder here; code
+#: below it that has no handle on the query (`columnar.py`, the chunk
+#: drivers) opens its spans through `span()`. A thread the query
+#: starts does not inherit it: the prefetch worker's spans go through
+#: the recorder its iterator was bound to (`io/sources.py`).
+_RECORDER: ContextVar[Optional[SpanRecorder]] = \
+    ContextVar("spark_tpu_span_recorder", default=None)
+
+
+def current_recorder() -> Optional[SpanRecorder]:
+    return _RECORDER.get()
+
+
+@contextlib.contextmanager
+def use_recorder(recorder: Optional[SpanRecorder]):
+    token = _RECORDER.set(recorder)
+    try:
+        yield recorder
+    finally:
+        _RECORDER.reset(token)
+
+
+def span(name: str, **attrs):
+    """A span on the current query's recorder; outside a query a
+    context that does nothing and yields None."""
+    rec = _RECORDER.get()
+    if rec is None:
+        return contextlib.nullcontext()
+    return rec.span(name, **attrs)
 
 
 # ---------------------------------------------------------------------------

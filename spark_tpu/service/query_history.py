@@ -38,6 +38,14 @@ class QueryHistoryStore:
             while len(self._entries) > self.max_entries:
                 self._entries.popitem(last=False)
 
+    def amend(self, query_id: str, **fields) -> None:
+        """Replace fields of a stored detail (what closed after the
+        engine's end event: the `egress` span); no entry, no effect."""
+        with self._lock:
+            detail = self._entries.get(query_id)
+            if detail is not None:
+                self._entries[query_id] = dict(detail, **fields)
+
     def get(self, query_id: str) -> Optional[Dict]:
         with self._lock:
             return self._entries.get(query_id)
@@ -60,6 +68,7 @@ def detail_from_event(event) -> Dict:
         "plan_tree": ev.get("plan_tree"),
         "phase_times_s": ev.get("phase_times_s"),
         "spans": ev.get("spans") or [],
+        "spans_dropped": ev.get("spans_dropped") or 0,
         "stages": ev.get("stages") or [],
         "shards": ev.get("shards") or [],
         "metrics": ev.get("metrics") or {},

@@ -402,6 +402,22 @@ class SqlService:
                 else "query_deadline_exceeded").inc()
         self._post(status, record["id"], session=session)
 
+    def _collect(self, entry, sql: str, rid: str, t_accept: float,
+                 t_started: float):
+        """Plan and collect `sql` on the leased session. The query's
+        recorder exists from here on, so the request's wait for the
+        session lock and the admission slot goes into it as `queue`;
+        `egress` closes after the engine's end event, so the history
+        store's copy of the spans is taken anew once the rows are
+        out."""
+        qe = entry.session.sql(sql)._qe()
+        qe.spans.record("queue", t_accept, t_started)
+        try:
+            return qe.collect()
+        finally:
+            self.history.amend(rid, spans=qe.spans.to_dicts(),
+                               spans_dropped=qe.spans.dropped)
+
     def submit(self, sql: str, session: str = "default",
                conf: Optional[Dict] = None):
         """Run `sql` on the named pooled session under admission
@@ -410,6 +426,7 @@ class SqlService:
         the engine raised; the record reflects the outcome either
         way."""
         self._check_draining()
+        t_accept = time.perf_counter()
         record = self._new_record(sql, session, conf)
         rid = record["id"]
         self._ensure_arbiter()
@@ -441,10 +458,11 @@ class SqlService:
                         entry.current_record = record
                         record["status"] = "running"
                         record["started_ts"] = time.time()
+                        t_started = time.perf_counter()
                         try:
                             with entry.session.as_active():
-                                qe = entry.session.sql(sql)._qe()
-                                table = qe.collect()
+                                table = self._collect(
+                                    entry, sql, rid, t_accept, t_started)
                         finally:
                             entry.current_record = None
                 finally:
@@ -568,6 +586,7 @@ class SqlService:
             raise err
 
         tok = self._get_token(record["id"])
+        t_accept = time.perf_counter()
 
         def run():
             # re-drive through submit's machinery minus re-registration
@@ -587,9 +606,11 @@ class SqlService:
                         entry.current_record = record
                         record["status"] = "running"
                         record["started_ts"] = time.time()
+                        t_started = time.perf_counter()
                         try:
                             with entry.session.as_active():
-                                t = entry.session.sql(sql)._qe().collect()
+                                t = self._collect(entry, sql, record["id"],
+                                                  t_accept, t_started)
                             record["row_count"] = int(t.num_rows)
                             record["status"] = "ok"
                             self.metrics.counter(
@@ -718,6 +739,7 @@ class SqlService:
                 "phase_times_s": detail.get("phase_times_s")
                 or rec.get("phase_times_s"),
                 "spans": detail.get("spans") or [],
+                "spans_dropped": detail.get("spans_dropped") or 0,
                 "stages": detail.get("stages") or [],
                 "shards": detail.get("shards") or [],
                 "metrics": detail.get("metrics") or {},

@@ -338,12 +338,10 @@ def _golden(session, qname, tpch_path):
 
 def test_prefetch_parity_on_off(tpch_session, tpch_path,
                                 streaming_conf):
-    stall0 = tpch_session.metrics.counter("ingest_stall_ms").value
+    chunks0 = tpch_session.metrics.counter("ingest_chunks").value
     on = _golden(tpch_session, "q1", tpch_path)
-    # the consumer measured the pipeline (stall or overlap advanced)
-    assert tpch_session.metrics.counter("ingest_stall_ms").value \
-        + tpch_session.metrics.counter("ingest_overlap_ms").value \
-        > stall0
+    # the pipeline ran and was counted (its waits: test_observability)
+    assert tpch_session.metrics.counter("ingest_chunks").value > chunks0
     streaming_conf.set(PREFETCH_KEY, False)
     off = _golden(tpch_session, "q1", tpch_path)
     pd.testing.assert_frame_equal(on, off)

@@ -723,3 +723,324 @@ def test_golden_parity_all_observability_on(session, obs_tpch_path,
     assert os.listdir(str(tmp_path / "ev"))
     assert os.listdir(str(tmp_path / "tr"))
     assert os.path.exists(str(tmp_path / "m" / "metrics.prom"))
+
+
+# -- the span tree of a streamed scan (both threads) --------------------------
+
+PREFETCH_KEY = "spark_tpu.sql.ingest.prefetch"
+PROFILE_KEY = "spark_tpu.sql.profile.dir"
+STREAM_ROWS, STREAM_CHUNK = 5000, 1024
+#: per chunk, on the consumer's thread and under `streaming`
+CONSUMER_CHUNK_SPANS = ("chunk.to_device", "chunk.launch")
+#: per chunk, on whichever thread decodes
+HOST_CHUNK_SPANS = ("chunk.decode", "chunk.unify")
+
+
+@pytest.fixture(scope="module")
+def stream_table(tmp_path_factory):
+    """A Parquet table of a string, an int64 and a decimal column, in
+    row groups that do not divide the chunk."""
+    import decimal
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+    rng = np.random.default_rng(7)
+    t = pa.table({
+        "k": pa.array(rng.choice(["A", "B", "C"], STREAM_ROWS)),
+        "v": pa.array(np.arange(STREAM_ROWS, dtype=np.int64)),
+        "d": pa.array([decimal.Decimal(int(x)) / 100 for x in
+                       rng.integers(0, 10000, STREAM_ROWS)],
+                      pa.decimal128(12, 2))})
+    path = str(tmp_path_factory.mktemp("stream_spans"))
+    pq.write_table(t, os.path.join(path, "t.parquet"), row_group_size=700)
+    return path
+
+
+def _stream_qe(session, path, prefetch=True, name="stream_spans_t"):
+    from spark_tpu.io.sources import ParquetSource
+    session.register_table(name, ParquetSource(path, name))
+    session.conf.set(CHUNK_KEY, STREAM_CHUNK)
+    session.conf.set(CACHE_KEY, 0)
+    session.conf.set(PREFETCH_KEY, prefetch)
+    return (session.table(name).group_by(col("k"))
+            .agg(F.sum(col("v")).alias("s"),
+                 F.sum(col("d")).alias("d")))._qe()
+
+
+def _ingest_counters(session):
+    return {k: session.metrics.counter(k).value
+            for k in ("ingest_stall_ms", "ingest_chunks", "ingest_rows",
+                      "ingest_put_bytes", "scans_streamed",
+                      "scans_resident")}
+
+
+@pytest.mark.parametrize("prefetch", [True, False],
+                         ids=["prefetch_on", "prefetch_off"])
+def test_stream_span_tree(session, stream_table, prefetch):
+    before = _ingest_counters(session)
+    qe = _stream_qe(session, stream_table, prefetch)
+    out = qe.collect().to_pandas()
+    assert int(out["s"].sum()) == STREAM_ROWS * (STREAM_ROWS - 1) // 2
+    grew = {k: v - before[k] for k, v in _ingest_counters(session).items()}
+    spans = qe.spans.spans
+    by_id = {s.id: s for s in spans}
+    assert len(by_id) == len(spans) and qe.spans.dropped == 0
+    assert qe.spans.open_spans() == {}
+    n_chunks = -(-STREAM_ROWS // STREAM_CHUNK)
+    (streaming,) = [s for s in spans if s.name == "streaming"]
+    consumer = streaming.tid
+
+    def named(name):
+        return [s for s in spans if s.name == name]
+
+    # exactly the tree: per chunk one of each, a convert and a put per
+    # column under each to_device, one drain, and with prefetch a wait
+    # per chunk and one for the end of the stream
+    for name in CONSUMER_CHUNK_SPANS + HOST_CHUNK_SPANS:
+        assert len(named(name)) == n_chunks, (name, len(named(name)))
+        assert all(s.parent == streaming.id for s in named(name))
+    assert len(named("stream.drain")) == 1
+    assert len(named("chunk.wait")) == (n_chunks + 1 if prefetch else 0)
+    for s in named("chunk.convert") + named("chunk.put"):
+        assert by_id[s.parent].name == "chunk.to_device"
+    for td in named("chunk.to_device"):
+        kids = [s.name for s in spans if s.parent == td.id]
+        assert sorted(kids) == ["chunk.convert"] * 3 + ["chunk.put"] * 3
+    assert {s.name for s in spans if s.name.startswith(
+        ("chunk.", "stream."))} == {
+        "chunk.decode", "chunk.unify", "chunk.to_device", "chunk.convert",
+        "chunk.put", "chunk.launch", "stream.drain"} | (
+        {"chunk.wait"} if prefetch else set())
+    assert [s.attrs["chunk"] for s in sorted(
+        named("chunk.launch"), key=lambda s: s.id)] == list(range(n_chunks))
+
+    # threads: the consumer runs all but decode and unify, which the
+    # prefetch worker runs when there is one
+    host_tids = {s.tid for n in HOST_CHUNK_SPANS for s in named(n)}
+    for s in spans:
+        if s.name not in HOST_CHUNK_SPANS:
+            assert s.tid == consumer, s
+    if prefetch:
+        assert len(host_tids) == 1 and consumer not in host_tids
+    else:
+        assert host_tids == {consumer}
+
+    # every child lies inside its parent
+    for s in spans:
+        if s.parent is not None:
+            p = by_id[s.parent]
+            assert p.t0 <= s.t0 and s.t1 <= p.t1, (s, p)
+
+    # the counters at the same boundaries
+    assert sum(s.attrs["rows"] for s in named("chunk.decode")) == STREAM_ROWS
+    assert grew["ingest_chunks"] == n_chunks
+    assert grew["ingest_rows"] == STREAM_ROWS
+    assert grew["scans_streamed"] == 1 and grew["scans_resident"] == 0
+    # string code 4 B, int64 8 B, decimal's unscaled int64 8 B
+    assert grew["ingest_put_bytes"] == n_chunks * STREAM_CHUNK * (4 + 8 + 8)
+    assert grew["ingest_put_bytes"] == sum(
+        s.attrs["bytes"] for s in named("chunk.put"))
+    waited = sum(s.dur_ms for s in named("chunk.wait"))
+    # one interval read twice, the span a few clock readings wider
+    assert abs(waited - grew["ingest_stall_ms"]) <= 0.05 * (n_chunks + 1)
+    if not prefetch:
+        assert grew["ingest_stall_ms"] == 0
+
+    # the rest of the request
+    (dispatch,) = named("dispatch")
+    assert sorted(s.name for s in spans if s.parent == dispatch.id) == [
+        "dispatch.launch", "dispatch.sync"]
+    assert len(named("egress")) == 1 and named("egress")[0].parent is None
+    dicts = {d["id"]: d for d in qe.spans.to_dicts()}
+    assert all({"id", "parent", "tid"} <= set(d) for d in dicts.values())
+    assert dicts[dispatch.id]["parent"] is None
+
+
+@pytest.mark.parametrize("prefetch", [True, False],
+                         ids=["prefetch_on", "prefetch_off"])
+def test_stream_failure_leaves_no_span_open(session, stream_table, prefetch):
+    """A stream that raises mid-chunk closes every span on both
+    threads, and the next query's tree names none of its spans."""
+    qe = _stream_qe(session, stream_table, prefetch)
+    session.conf.set("spark_tpu.faults.inject", "stream_chunk:fatal:2")
+    faults.reset()
+    try:
+        with pytest.raises(Exception):
+            qe.collect()
+    finally:
+        session.conf.set("spark_tpu.faults.inject", "")
+        faults.reset()
+    assert qe.spans.open_spans() == {}
+    failed = [s for s in qe.spans.spans if "error" in s.attrs]
+    assert {"chunk.launch", "streaming"} <= {s.name for s in failed}
+    again = _stream_qe(session, stream_table, prefetch)
+    again.collect()
+    ids = {s.id for s in again.spans.spans}
+    assert all(s.parent is None or s.parent in ids
+               for s in again.spans.spans)
+    assert not any("error" in s.attrs for s in again.spans.spans)
+    assert again.spans.open_spans() == {}
+
+
+def _host_plane_names(trace_dir):
+    import glob
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    names = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        # a line is a host thread; threads may share a name
+        for i, line in enumerate(plane.lines):
+            for e in line.events:
+                if e.name.startswith("spark_tpu."):
+                    names.setdefault(e.name, set()).add(i)
+    return names
+
+
+def test_engine_spans_stand_in_profiler_trace(session, stream_table,
+                                              tmp_path):
+    """Under a profiler session the engine's spans are annotations on
+    the trace's host plane, the worker's on a line of its own."""
+    import jax
+    qe = _stream_qe(session, stream_table, prefetch=True)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        qe.collect()
+    finally:
+        jax.profiler.stop_trace()
+    names = _host_plane_names(str(tmp_path))
+    assert {"spark_tpu.streaming", "spark_tpu.chunk.decode",
+            "spark_tpu.chunk.put", "spark_tpu.egress"} <= set(names), names
+    assert names["spark_tpu.chunk.decode"].isdisjoint(
+        names["spark_tpu.streaming"])
+
+
+def test_profile_dir_holds_the_streamed_chunks(session, stream_table,
+                                               tmp_path):
+    """`spark_tpu.sql.profile.dir` traces the whole attempt, the
+    streaming splice with its chunks included."""
+    qe = _stream_qe(session, stream_table, prefetch=True)
+    session.conf.set(PROFILE_KEY, str(tmp_path))
+    try:
+        qe.collect()
+    finally:
+        session.conf.set(PROFILE_KEY, "")
+    names = _host_plane_names(str(tmp_path))
+    assert {"spark_tpu.streaming", "spark_tpu.chunk.decode",
+            "spark_tpu.chunk.launch", "spark_tpu.dispatch"} <= set(names)
+
+
+def test_resident_scan_ingest_names_its_columns(session, stream_table):
+    """A scan that loads whole leaves its columns' convert and put
+    under the `ingest` phase."""
+    from spark_tpu.io.sources import ParquetSource
+    before = _ingest_counters(session)
+    session.register_table("resident_spans_t",
+                           ParquetSource(stream_table, "resident_spans_t"))
+    session.conf.set(CHUNK_KEY, 1 << 20)
+    qe = (session.table("resident_spans_t").group_by(col("k"))
+          .agg(F.sum(col("v")).alias("s")))._qe()
+    qe.collect()
+    (ingest,) = [s for s in qe.spans.spans if s.name == "ingest"]
+    kids = [s.name for s in qe.spans.spans if s.parent == ingest.id]
+    assert sorted(kids) == ["chunk.convert"] * 2 + ["chunk.put"] * 2
+    assert not any(s.name == "streaming" for s in qe.spans.spans)
+    assert _ingest_counters(session)["ingest_chunks"] \
+        == before["ingest_chunks"]
+
+
+def test_span_recorder_parent_thread_and_discard():
+    import threading
+    from spark_tpu.observability import SpanRecorder, to_chrome_trace
+    rec = SpanRecorder(query_id=9, max_spans=8)
+    with rec.span("outer", x=1) as outer:
+        with rec.span("inner"):
+            rec.mark("note")
+        assert rec.current() == outer.id
+        rec_cause = rec.current()
+
+        def work():
+            with rec.span("worker", parent=rec_cause) as w:
+                with rec.span("worker.inner"):
+                    pass
+                w.attrs["rows"] = 3
+        t = threading.Thread(target=work)
+        t.start()
+        t.join(timeout=10)
+        assert not t.is_alive()
+        with rec.span("nothing") as nothing:
+            rec.discard(nothing)
+        with rec.span("kept") as kept:
+            rec.record("handed_over", 1.0, 2.0)
+            rec.discard(kept)  # a child names it: it stays
+    by_name = {s.name: s for s in rec.spans}
+    assert "nothing" not in by_name and rec.open_spans() == {}
+    assert by_name["inner"].parent == outer.id
+    assert by_name["note"].parent == by_name["inner"].id
+    assert by_name["worker"].parent == outer.id
+    assert by_name["worker"].tid != outer.tid
+    assert by_name["worker"].attrs == {"rows": 3}
+    assert by_name["worker.inner"].parent == by_name["worker"].id
+    assert by_name["handed_over"].parent == by_name["kept"].id
+    assert by_name["outer"].parent is None
+    events = {e["name"]: e for e in to_chrome_trace(rec)["traceEvents"]}
+    assert events["worker"]["tid"] == by_name["worker"].tid
+    assert events["inner"]["args"]["parent"] == outer.id
+    assert events["inner"]["args"]["query_id"] == 9
+    with pytest.raises(ValueError):
+        with rec.span("raises"):
+            raise ValueError("boom")
+    assert rec.spans[-1].attrs["error"] == "ValueError"
+    for _ in range(4):
+        rec.mark("overflow")
+    assert len(rec.spans) == 8 and rec.dropped > 0
+
+
+def test_span_recorder_many_threads_lose_nothing():
+    """More threads than cores on one recorder, switching often: every
+    span arrives once, with an id of its own and its own thread's
+    parent, and no stack is left behind."""
+    import sys
+    import threading
+    from spark_tpu.observability import SpanRecorder
+    n_threads, n_spans = 32, 200
+    rec = SpanRecorder(query_id=1, max_spans=10 ** 6)
+
+    def work(k):
+        for i in range(n_spans):
+            with rec.span("outer", k=k) as outer:
+                with rec.span("inner", k=k) as inner:
+                    assert inner.parent == outer.id
+                rec.record("handed", 0.0, 1.0, k=k)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=work, args=(k,))
+                   for k in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(old)
+    assert len(rec.spans) == 3 * n_threads * n_spans and rec.dropped == 0
+    assert len({s.id for s in rec.spans}) == len(rec.spans)
+    by_id = {s.id: s for s in rec.spans}
+    for s in rec.spans:
+        if s.name != "outer":
+            assert by_id[s.parent].attrs["k"] == s.attrs["k"]
+            assert by_id[s.parent].tid == s.tid
+    assert rec.open_spans() == {}
+
+
+def test_phase_times_reach_the_event_to_the_microsecond(session):
+    qe = _fresh_agg(session, 771)._qe()
+    qe.execute_batch()
+    event = qe._build_event(None)
+    for k, v in qe.phase_times.items():
+        assert abs(event["phase_times_s"][k] - v) <= 5e-7, (k, v)

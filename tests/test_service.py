@@ -489,6 +489,29 @@ def test_http_query_listing_timeline_and_plan(service, tpch_path):
         assert exc.value.code == 404
 
 
+def test_timeline_holds_queue_and_egress(service):
+    """The request's wait for its session and slot (`queue`, before
+    the recorder's own start) and the pull of the rows (`egress`,
+    after the engine's end event) both reach the timeline, with the
+    tree's ids; a query that fails is amended the same way."""
+    svc = service()
+    svc.start()
+    _, resp = _post_sql(svc.port, {"sql": SQLQ.Q1})
+    _, tl = _get_json(svc.port, f"/queries/{resp['query_id']}/timeline")
+    by_name = {s["name"]: s for s in tl["spans"]}
+    assert {"queue", "dispatch", "dispatch.launch", "dispatch.sync",
+            "egress"} <= set(by_name), sorted(by_name)
+    assert tl["spans_dropped"] == 0
+    assert by_name["queue"]["t0_ms"] < 0 <= by_name["queue"]["dur_ms"]
+    assert by_name["egress"]["t0_ms"] >= by_name["dispatch"]["t0_ms"] \
+        + by_name["dispatch"]["dur_ms"]
+    assert by_name["dispatch.sync"]["parent"] == by_name["dispatch"]["id"]
+    assert len({s["tid"] for s in tl["spans"]}) == 1  # resident: one thread
+    # a store that no longer holds the query is left alone
+    svc.history.amend("q-unknown", spans=[])
+    assert svc.history.get("q-unknown") is None
+
+
 def test_history_store_bounded(service):
     from spark_tpu.service.query_history import QueryHistoryStore
     store = QueryHistoryStore(max_entries=2)
