@@ -149,7 +149,7 @@ class Catalog:
                 raise AnalysisError(
                     f"INSERT INTO a temp view {name!r} is not supported")
             raise AnalysisError(f"table {name!r} not found")
-        target = self._persistent_source(name)._dataset.schema
+        target = self._persistent_source(name).file_schema
         if len(table.schema) != len(target):
             raise AnalysisError(
                 f"INSERT INTO {name}: {len(table.schema)} columns for "

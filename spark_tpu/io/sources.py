@@ -5,14 +5,18 @@ Plays the role of the reference's DataSource V2 read stack
 `SupportsPushDownFilters` / `SupportsPushDownRequiredColumns`) and of the
 vectorized Parquet reader (`VectorizedParquetRecordReader.java:54`): the
 C++ Arrow/Parquet reader does columnar decode + predicate/column pushdown
-on host, then columns are dictionary-encoded/padded and device_put —
-ingest is the only place bytes cross host->device (SURVEY.md section 2.4).
+on host (handing over low-cardinality string columns as the files' own
+dictionary codes), then string columns are brought onto one dictionary,
+columns are padded and device_put — ingest is the only place bytes cross
+host->device (SURVEY.md section 2.4).
 """
 
 from __future__ import annotations
 
 import contextlib
 import os
+import time
+from concurrent.futures import ThreadPoolExecutor
 from typing import Optional, Sequence, Tuple
 
 import numpy as np
@@ -164,41 +168,128 @@ def _arrow_schema_to_engine(schema: pa.Schema) -> T.Schema:
     return T.Schema(fields)
 
 
+#: rows from which a chunk's columns are unified side by side: below
+#: it a column is a few megabytes and starting threads costs more
+#: than they save
+_UNIFY_SIDE_BY_SIDE_ROWS = 1 << 20
+
+
 class DictUnifier:
     """Grows one global dictionary per string column across chunks so
     device codes are comparable between chunks (append-only: codes handed
     out earlier stay valid). The analog of the reference's per-column
-    dictionary pages being resolved to one dictionary at read time."""
+    dictionary pages being resolved to one dictionary at read time.
+
+    A column that arrives dictionary-typed (a Parquet column read with
+    its page dictionaries, `_dictionary_columns`; a dictionary column of
+    an in-memory table) is unified by dictionary: its batches'
+    dictionaries are merged, the merged one is mapped into the global
+    one, and the int32 codes are remapped only where the two differ.
+    Work on values is per dictionary entry, never per row. A column
+    that arrives as plain strings (in-memory tables, CSV, JSON, a
+    Parquet column whose footers did not qualify it) is hashed row by
+    row first (`dictionary_encode`). Every other column is
+    concatenated into one array, as `Batch.from_arrow` wants it. All of
+    it runs under the thread that runs `ChunkIterator._host_next` (the
+    prefetch worker, or the consumer with prefetch off), inside its
+    `chunk.unify` span; while the consumer is idle until the chunk is
+    ready, that thread does the columns side by side (`unify`)."""
 
     def __init__(self):
         self.dicts = {}
 
-    def unify(self, table: pa.Table) -> pa.Table:
-        cols = []
-        for name, col in zip(table.column_names, table.columns):
-            arr = col.combine_chunks() if isinstance(col, pa.ChunkedArray) else col
-            at = arr.type
-            if pa.types.is_string(at) or pa.types.is_large_string(at):
-                arr = arr.cast(pa.string()).dictionary_encode()
-                at = arr.type
-            if pa.types.is_dictionary(at):
-                chunk_dict = arr.dictionary.cast(pa.string())
-                glob = self.dicts.get(name)
-                if glob is None:
-                    glob = chunk_dict
-                else:
-                    present = pc.index_in(chunk_dict, value_set=glob)
-                    new_mask = pc.is_null(present)
-                    if pc.any(new_mask).as_py():
-                        new_vals = pc.filter(chunk_dict, new_mask)
-                        glob = pa.concat_arrays([glob, new_vals])
+    def _to_global(self, name: str, arr: pa.DictionaryArray,
+                   all_used: bool) -> Optional[pa.Array]:
+        """Append to the column's global dictionary the values of the
+        chunk's dictionary that it lacks and that a row of the chunk
+        carries. A page dictionary also holds the values of rows that
+        a pushed filter or the chunk's cut left out; those get no
+        code, as they got none when rows were hashed, so a dictionary
+        (and the direct aggregate's domain, sized from it) holds what
+        the scan returned and nothing else. `all_used` says that every
+        value is carried (the chunk was encoded from its rows).
+        Returns the int32 map from the chunk's codes to the global
+        ones, or None where the chunk's codes already are the global
+        ones."""
+        chunk_dict = arr.dictionary.cast(pa.string())
+        glob = self.dicts.setdefault(name, chunk_dict.slice(0, 0))
+        present = pc.index_in(chunk_dict, value_set=glob)
+        if present.null_count:
+            new = pc.is_null(present)
+            if not all_used:  # the one pass over rows, codes only
+                used = np.zeros(len(chunk_dict), dtype=bool)
+                used[pc.unique(arr.indices).drop_null().to_numpy()] = True
+                new = pc.and_(new, pa.array(used))
+            if pc.any(new).as_py():
+                glob = pa.concat_arrays([glob, chunk_dict.filter(new)])
                 self.dicts[name] = glob
-                mapping = pc.index_in(chunk_dict, value_set=glob) \
-                    .cast(pa.int32())
-                codes = mapping.take(arr.indices)
-                arr = pa.DictionaryArray.from_arrays(codes, glob)
-            cols.append(arr)
-        return pa.table(cols, names=table.column_names)
+                present = pc.index_in(chunk_dict, value_set=glob)
+        mapping = present.cast(pa.int32())
+        if not mapping.null_count and np.array_equal(
+                mapping.to_numpy(), np.arange(len(mapping))):
+            return None
+        return mapping
+
+    def _unify_column(self, name: str, col: pa.ChunkedArray):
+        """One column as one array: (array, what was done to it
+        (`read` / `encoded` / `concat`), seconds)."""
+        t0 = time.perf_counter()
+        at = col.type
+        if pa.types.is_dictionary(at):
+            kind = "read"
+            arr = col.unify_dictionaries().combine_chunks()
+        elif pa.types.is_string(at) or pa.types.is_large_string(at):
+            kind = "encoded"
+            arr = col.combine_chunks().cast(pa.string()).dictionary_encode()
+        else:
+            return col.combine_chunks(), "concat", time.perf_counter() - t0
+        mapping = self._to_global(name, arr, all_used=kind == "encoded")
+        codes = arr.indices.cast(pa.int32()) if mapping is None \
+            else mapping.take(arr.indices)
+        # the codes index the global dictionary by construction: no
+        # pass over them to check it
+        arr = pa.DictionaryArray.from_arrays(codes, self.dicts[name],
+                                             safe=False)
+        return arr, kind, time.perf_counter() - t0
+
+    def unify(self, table: pa.Table, side_by_side: bool = True
+              ) -> Tuple[pa.Table, dict]:
+        """The chunk with every column one array and every string
+        column coded in its global dictionary, and where the time went:
+        `dict_columns_read` / `dict_columns_encoded` (string columns
+        unified by dictionary / hashed row by row), `dict_ms` (both
+        kinds), `concat_ms` and `concat_bytes` (the other columns).
+
+        `side_by_side` does the columns of a large chunk on threads
+        of their own, which live for this call (pyarrow releases the
+        GIL): right while the consumer has nothing to do until this
+        chunk is ready, wrong while it is busy, because every column
+        here is new memory and the first touch of fresh pages is what
+        both threads spend their time on. The two times are sums over
+        columns, so side by side they can pass the length of the
+        `chunk.unify` span that holds them."""
+        names = table.column_names
+        workers = min(len(names), os.cpu_count() or 1)
+        if side_by_side and workers > 1 \
+                and table.num_rows >= _UNIFY_SIDE_BY_SIDE_ROWS:
+            with ThreadPoolExecutor(
+                    max_workers=workers,
+                    thread_name_prefix="spark-tpu-ingest-unify") as pool:
+                done = list(pool.map(self._unify_column, names,
+                                     table.columns))
+        else:
+            done = [self._unify_column(n, c)
+                    for n, c in zip(names, table.columns)]
+        split = {"dict_columns_read": 0, "dict_columns_encoded": 0,
+                 "dict_ms": 0.0, "concat_ms": 0.0, "concat_bytes": 0}
+        for arr, kind, seconds in done:
+            if kind == "concat":
+                split["concat_ms"] += seconds * 1e3
+                split["concat_bytes"] += arr.nbytes
+            else:
+                split["dict_columns_" + kind] += 1
+                split["dict_ms"] += seconds * 1e3
+        return pa.table([d[0] for d in done], names=names), split
 
 
 class ChunkIterator:
@@ -209,7 +300,11 @@ class ChunkIterator:
     spans `chunk.decode` and `chunk.unify` (on whichever thread runs
     `_host_next`: the prefetch worker, or the consumer with prefetch
     off) and `chunk.to_device` (always the consumer), and counts into
-    `ingest_chunks` / `ingest_rows` / `ingest_put_bytes`."""
+    `ingest_chunks` / `ingest_rows` / `ingest_put_bytes` and, by how
+    its string columns arrived, `ingest_dict_columns_read` /
+    `ingest_dict_columns_encoded` (`DictUnifier`). `chunk.unify`
+    carries the split of its time as attributes: `dict_ms`,
+    `concat_ms`, `concat_bytes`."""
 
     def __init__(self, batches_iter, chunk_rows: int):
         self._batches = batches_iter
@@ -224,6 +319,11 @@ class ChunkIterator:
         self._recorder = None
         self._cause = None   # the consumer's open span, for the worker
         self._chunk = 0      # ordinal of the next chunk taken
+        #: whether the consumer is idle until the next chunk is ready:
+        #: always, when it makes the chunk itself; under a prefetcher
+        #: only while it waits at the queue (PrefetchChunkIterator
+        #: keeps this; a hint, read once a chunk by `_host_next`)
+        self.consumer_waits = True
 
     def observe(self, metrics, recorder) -> None:
         """Bind the stream to its query's counters and spans (called
@@ -320,8 +420,19 @@ class ChunkIterator:
             from ..columnar import bucket_capacity
             self._capacity = bucket_capacity(self._chunk_rows)
         with self._host_span("chunk.unify", chunk=self._chunk - 1,
-                             rows=chunk.num_rows):
-            return self._unifier.unify(chunk)
+                             rows=chunk.num_rows) as sp:
+            chunk, split = self._unifier.unify(
+                chunk, side_by_side=self.consumer_waits)
+            if sp is not None:  # what the span's time went to
+                sp.attrs.update(
+                    dict_ms=round(split["dict_ms"], 3),
+                    concat_ms=round(split["concat_ms"], 3),
+                    concat_bytes=split["concat_bytes"])
+        if self._metrics is not None:
+            for how in ("read", "encoded"):
+                self._metrics.counter("ingest_dict_columns_" + how).inc(
+                    split["dict_columns_" + how])
+        return chunk
 
     def _to_device(self, chunk: pa.Table) -> Batch:
         with span("chunk.to_device", rows=chunk.num_rows):
@@ -459,15 +570,91 @@ class JsonSource(ArrowTableSource):
                          table)
 
 
+#: the largest dictionary page (bytes between a column chunk's
+#: dictionary page and its first data page, as stored) of a column
+#: that is read dictionary-typed. A writer gives up on a chunk's
+#: dictionary once it passes its page limit (1 MiB by default, for
+#: Arrow, parquet-mr and Spark alike) and writes the rest of the chunk
+#: plain; such a chunk keeps a dictionary page of about that limit,
+#: several times this bound even when compressed.
+_DICT_PAGE_MAX_BYTES = 64 << 10
+
+
+def _dictionary_columns(dataset) -> list:
+    """The string columns of a Parquet dataset to read dictionary-typed
+    (`ParquetReadOptions.dictionary_columns`), decided from the footers
+    alone: every row group holds the column with a dictionary page of
+    at most `_DICT_PAGE_MAX_BYTES`, and the dictionary pages together
+    are under a byte a row (an entry takes its length's 4 bytes and
+    its characters, so there are several rows to an entry). Such a
+    column reaches `DictUnifier` as the file's own codes and a handful
+    of values. Any other (a column whose writer fell back to plain
+    pages, as it does for comments and names; one written without
+    dictionaries; one of mostly distinct values) would have the reader
+    hash its rows into a dictionary per batch and the unifier hash
+    those again, so it is read plain and encoded once, as before.
+    The choice moves time only: either way the chunks decode to the
+    same strings."""
+    names = {f.name for f in dataset.schema
+             if pa.types.is_string(f.type)
+             or pa.types.is_large_string(f.type)}
+    if not names:
+        return []
+    pages: dict = {}   # name -> [dictionary page bytes, values]
+    try:
+        for frag in dataset.get_fragments():
+            md = frag.metadata
+            wanted = [i for i in range(md.num_columns)
+                      if md.schema.column(i).path in names]
+            for rg in range(md.num_row_groups):
+                rgm = md.row_group(rg)
+                for i in wanted:
+                    col = rgm.column(i)
+                    if not col.num_values:
+                        continue
+                    page = (col.data_page_offset
+                            - col.dictionary_page_offset
+                            if col.has_dictionary_page else 0)
+                    if not 0 < page <= _DICT_PAGE_MAX_BYTES:
+                        names.discard(col.path_in_schema)
+                        continue
+                    seen = pages.setdefault(col.path_in_schema, [0, 0])
+                    seen[0] += page
+                    seen[1] += col.num_values
+    except (OSError, pa.ArrowException):
+        return []   # an unreadable footer fails the scan, where it did
+    return [f.name for f in dataset.schema
+            if f.name in names and f.name in pages
+            and pages[f.name][0] <= pages[f.name][1]]
+
+
 class ParquetSource(TableSource):
     """Parquet directory/file via the C++ Arrow dataset reader: column
     pruning + row-group predicate skipping happen in native code before
-    any bytes reach the device."""
+    any bytes reach the device.
+
+    One dataset object serves `schema`, `can_push`, `column_stats`,
+    `estimated_rows`, `load` (resident) and `load_chunks` (streamed).
+    It is opened so that the string columns `_dictionary_columns`
+    names arrive as `dictionary<int32, string>`, the page
+    dictionaries' own codes, on the scanner's threads; what is left
+    for the thread that takes the batches is work on dictionaries
+    (`DictUnifier`, `columnar._arrow_to_padded`). Pushed filters
+    compare such a column with string literals as they do a plain
+    one. `file_schema` stays the files' own, for a writer that
+    appends to them."""
 
     def __init__(self, path: str, name: Optional[str] = None):
         self.path = path
         self.name = name or os.path.basename(path).split(".")[0]
         self._dataset = pa_dataset.dataset(path, format="parquet")
+        self.file_schema: pa.Schema = self._dataset.schema
+        dict_columns = _dictionary_columns(self._dataset)
+        if dict_columns:
+            self._dataset = pa_dataset.dataset(
+                path, format=pa_dataset.ParquetFileFormat(
+                    read_options=pa_dataset.ParquetReadOptions(
+                        dictionary_columns=dict_columns)))
         self._column_stats: Optional[dict] = None
 
     def column_stats(self) -> Optional[dict]:
@@ -584,12 +771,20 @@ INGEST_PREFETCH_KEY = "spark_tpu.sql.ingest.prefetch"
 
 class PrefetchChunkIterator:
     """Double-buffered wrapper over a ChunkIterator: a background thread
-    decodes + dictionary-unifies Parquet chunk N+1 into HOST buffers
-    (``ChunkIterator._host_next`` — pyarrow releases the GIL, so the
-    decode genuinely overlaps the consumer's device compute) while the
-    consumer computes chunk N. Bounded to ONE in-flight chunk (a
-    size-1 queue), and device placement stays on the CONSUMER thread,
-    so HBM residency, arbiter leases and the per-chunk retry/checkpoint
+    makes chunk N+1 ready in HOST buffers (``ChunkIterator._host_next``
+    — pyarrow releases the GIL, so this genuinely overlaps the
+    consumer's work) while the consumer converts, places and launches
+    chunk N. Who does what: the Arrow scanner's own threads read and
+    decode Parquet pages (string columns that qualify come as the
+    file's dictionary codes: ``ParquetSource``); the WORKER takes the
+    chunk's record batches off the scanner (``chunk.decode``: a wait)
+    and makes one array of each column (``chunk.unify``: dictionaries
+    unified and codes remapped for string columns, the other columns
+    concatenated: ``DictUnifier``); the CONSUMER pads each column into
+    its numpy buffer and places it (``chunk.to_device``) and launches
+    the chunk program. Bounded to ONE in-flight chunk (a size-1
+    queue), and device placement stays on the CONSUMER thread, so HBM
+    residency, arbiter leases and the per-chunk retry/checkpoint
     semantics of the streaming drivers are unchanged.
 
     Fault behavior: the worker runs each host decode under the SAME
@@ -695,7 +890,9 @@ class PrefetchChunkIterator:
         # for the end of the stream too)
         with span("chunk.wait"):
             t0 = _time.perf_counter()
+            self._inner.consumer_waits = True
             kind, payload = self._queue.get()
+            self._inner.consumer_waits = False
             stall_s = _time.perf_counter() - t0
         if self._metrics is not None:
             self._metrics.counter("ingest_stall_ms").inc(

@@ -65,6 +65,9 @@ METRIC_PREFIXES = (
     "ingest_chunks",   # chunks of streamed scans placed on the device
     "ingest_rows",     # their live rows
     "ingest_put_",     # ingest_put_bytes: padded bytes they device_put
+    "ingest_dict_",    # ingest_dict_columns_read / _encoded: string
+                       # columns of a chunk unified by dictionary /
+                       # hashed row by row (io/sources.py DictUnifier)
     "scans_",          # scans_streamed / scans_resident: verdicts of
                        # the residency decision on a streamable scan
     # straggler detection (observability/straggler.py): REGISTRY

@@ -857,6 +857,58 @@ def test_stream_span_tree(session, stream_table, prefetch):
 
 @pytest.mark.parametrize("prefetch", [True, False],
                          ids=["prefetch_on", "prefetch_off"])
+def test_stream_counts_how_string_columns_arrived(session, stream_table,
+                                                  prefetch):
+    """A string column the Parquet reader hands over as the file's
+    codes is unified by dictionary, an in-memory table's strings are
+    hashed row by row: one counter each, on `/metrics`, and the
+    `chunk.unify` span says what its time went to."""
+    import pyarrow as pa
+    from spark_tpu.io.sources import ArrowTableSource
+    from spark_tpu.observability.metrics import (parse_prometheus_text,
+                                                 prometheus_text)
+    keys = ("ingest_dict_columns_read", "ingest_dict_columns_encoded")
+
+    def counters():
+        return [session.metrics.counter(k).value for k in keys]
+
+    n_chunks = -(-STREAM_ROWS // STREAM_CHUNK)
+    before = counters()
+    qe = _stream_qe(session, stream_table, prefetch)
+    qe.collect()
+    assert [a - b for a, b in zip(counters(), before)] == [n_chunks, 0]
+    unify = [s for s in qe.spans.spans if s.name == "chunk.unify"]
+    assert len(unify) == n_chunks
+    for s in unify:
+        assert {"chunk", "rows", "dict_ms", "concat_ms",
+                "concat_bytes"} <= set(s.attrs)
+        # an int64 and a decimal128 column were concatenated
+        assert s.attrs["concat_bytes"] == s.attrs["rows"] * (8 + 16)
+        assert 0 < s.attrs["dict_ms"] + s.attrs["concat_ms"] <= s.dur_ms
+    assert any(d["name"] == "chunk.unify" and "dict_ms" in d["attrs"]
+               for d in qe.spans.to_dicts())
+
+    rng = np.random.default_rng(11)
+    session.register_table("stream_plain_strings_t", ArrowTableSource(
+        "stream_plain_strings_t", pa.table({
+            "k": pa.array(rng.choice(["A", "B", "C"], STREAM_ROWS)),
+            "v": pa.array(np.arange(STREAM_ROWS, dtype=np.int64))})))
+    before = counters()
+    qe = (session.table("stream_plain_strings_t").group_by(col("k"))
+          .agg(F.sum(col("v")).alias("s")))._qe()
+    out = qe.collect().to_pandas()
+    assert int(out["s"].sum()) == STREAM_ROWS * (STREAM_ROWS - 1) // 2
+    assert any(s.name == "streaming" for s in qe.spans.spans)
+    assert [a - b for a, b in zip(counters(), before)] == [0, n_chunks]
+
+    # what `GET /metrics` renders (SqlService.metrics_text)
+    served = parse_prometheus_text(
+        prometheus_text(session.metrics.snapshot()))
+    assert [served["spark_tpu_" + k] for k in keys] == counters()
+
+
+@pytest.mark.parametrize("prefetch", [True, False],
+                         ids=["prefetch_on", "prefetch_off"])
 def test_stream_failure_leaves_no_span_open(session, stream_table, prefetch):
     """A stream that raises mid-chunk closes every span on both
     threads, and the next query's tree names none of its spans."""
