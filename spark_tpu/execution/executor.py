@@ -35,7 +35,7 @@ class _ReplanRequest(Exception):
 DISPATCH_POLL_KEY = "spark_tpu.execution.dispatchPollMs"
 
 
-def _sync_dispatched(outs, conf):
+def _sync_dispatched(outs, conf, span=None):
     """Host-sync a dispatched stage's stats channel, cancellably.
 
     `jax.device_get` blocks until the device computation completes, so
@@ -54,10 +54,16 @@ def _sync_dispatched(outs, conf):
     The tick ramps 1ms -> dispatchPollMs (doubling): short stages —
     the overwhelmingly common case on a serving path — pay ~1ms of
     added sync latency instead of a full poll interval, while the
-    cancel-latency bound for long stages stays ~dispatchPollMs."""
+    cancel-latency bound for long stages stays ~dispatchPollMs.
+
+    `span` (the caller's `dispatch.sync`) gets the attribute `ticks`:
+    the polls slept through, 0 where the sync blocked straight
+    through. A stage is found ready up to the last tick late."""
     from . import lifecycle
     tok = lifecycle.current_token()
     poll_ms = float(conf.get(DISPATCH_POLL_KEY) or 0)
+    if span is not None:
+        span.attrs["ticks"] = 0
     if tok is not None and poll_ms > 0:
         leaves = [a for a in jax.tree_util.tree_leaves(outs)
                   if hasattr(a, "is_ready")]
@@ -65,6 +71,8 @@ def _sync_dispatched(outs, conf):
         while not all(a.is_ready() for a in leaves):
             tok.check("dispatch_wait")
             tok.wait(tick_s)
+            if span is not None:
+                span.attrs["ticks"] += 1
             tick_s = min(tick_s * 2, poll_ms / 1e3)
     return jax.device_get(outs)
 
@@ -1646,6 +1654,8 @@ class QueryExecution:
             args = (scan_batches,) if mesh is None \
                 else (scan_batches, token)
             fn = self._compile_stage(root, mesh, args)
+            # one per dispatch span, capacity re-plans' attempts included
+            self.session.metrics.counter("stage_dispatches").inc()
             # jit compiles lazily: the first dispatch after a stage
             # -cache miss pays trace + XLA compile in-line, so flag
             # it — trace readers must not read that as execution
@@ -1665,9 +1675,14 @@ class QueryExecution:
                 # cancellable (dispatchPollMs readiness polling): a
                 # cancel/deadline lands within ~one tick instead of
                 # at stage completion
-                with self.spans.span("dispatch.sync"):
-                    flags, metrics = _sync_dispatched(
-                        (flags, metrics), self._conf)
+                with self.spans.span("dispatch.sync") as sync:
+                    try:
+                        flags, metrics = _sync_dispatched(
+                            (flags, metrics), self._conf, sync)
+                    finally:  # a cancel mid-poll counts its ticks too
+                        self.session.metrics.counter(
+                            "dispatch_sync_ticks").inc(
+                                sync.attrs.get("ticks", 0))
             # deadline BEFORE the stage-timeout check: an attempt
             # that outran the end-to-end budget raises the
             # lifecycle error (ladder stops), never a retryable

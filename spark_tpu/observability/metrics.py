@@ -70,6 +70,13 @@ METRIC_PREFIXES = (
                        # hashed row by row (io/sources.py DictUnifier)
     "scans_",          # scans_streamed / scans_resident: verdicts of
                        # the residency decision on a streamable scan
+    # the resident dispatch path (executor._run_planned): REGISTRY
+    # counters, listed for namespace closure
+    "stage_dispatches",  # whole-stage programs dispatched: one per
+                       # `dispatch` span, capacity re-plans included
+    "dispatch_sync_",  # dispatch_sync_ticks: readiness polls the
+                       # cancellable sync slept through (the sum of
+                       # the dispatch.sync spans' `ticks`)
     # straggler detection (observability/straggler.py): REGISTRY
     # counter, listed for namespace closure like the ingest pair
     "straggler_",      # straggler_flagged: shards flagged this process
