@@ -13,7 +13,9 @@ Thread roots (what makes state here "shared"):
 - per-session execution serialized under the session lease
   (`service.session` — the outermost lock, rank 10);
 - the ingest-prefetch daemon (`io/sources.py` PrefetchChunkIterator
-  worker), which fires fault seams and counts registry metrics;
+  worker), which fires fault seams and counts registry metrics, and
+  the threads it starts for a large chunk's columns, which draw from
+  the process's `io/host_buffers.py` pool and count too;
 - the listener bus delivering to the event-log / metrics / straggler /
   rebalancer subscribers (synchronously, on whichever thread posts).
 
@@ -203,6 +205,13 @@ LOCKS: Tuple[LockDecl, ...] = (
              "_lock", "lock", 82,
              "per-histogram bucket counters (leaf; bucket index is "
              "computed before acquiring it)"),
+    LockDecl("io.host_buffers", "spark_tpu/io/host_buffers.py",
+             "HostBufferPool", "_lock", "lock", 83,
+             "the process's pool of padded host buffers, shared by "
+             "every streamed scan's consumer, prefetch worker and "
+             "column threads (leaf: list/dict ops and the arrays' "
+             "non-blocking is_ready() inside; the wait for an array in "
+             "flight, the counters and the spans are all OUTSIDE it)"),
     LockDecl("obs.spans", _OBS + "spans.py", "SpanRecorder", "_lock",
              "lock", 84,
              "one query's span list, id counter and per-thread open "
@@ -302,6 +311,11 @@ GUARDED_BY: Tuple[GuardDecl, ...] = (
     GuardDecl(_SVC + "fleet.py", "_Worker", "crash_times", "_lock"),
     GuardDecl(_SVC + "query_history.py", "QueryHistoryStore",
               "_entries", "_lock"),
+    # host buffer pool
+    GuardDecl("spark_tpu/io/host_buffers.py", "HostBufferPool", "_free",
+              "_lock"),
+    GuardDecl("spark_tpu/io/host_buffers.py", "HostBufferPool",
+              "_in_flight", "_lock"),
     # observability
     GuardDecl(_OBS + "straggler.py", "StragglerMonitor", "_waits",
               "_lock"),

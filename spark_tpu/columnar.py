@@ -394,95 +394,247 @@ def _np_to_dtype(np_dtype) -> T.DataType:
     return m[np_dtype]
 
 
-def _arrow_to_column(name: str, col: pa.ChunkedArray, n: int, cap: int) -> Column:
+def _arrow_to_column(name: str, col, n: int, cap: int) -> Column:
     """One Arrow column to a device Column. Inside a query it leaves
     two spans: `chunk.convert` (Arrow to the padded numpy buffer:
-    combine, decimal limb copy, cast, pad) and `chunk.put` (the
+    decimal limb copy, cast, code remap, pad) and `chunk.put` (the
     `jax.device_put` calls, i.e. staging: a put is not a sync)."""
     if pa.types.is_list(col.type) or pa.types.is_large_list(col.type):
         arr = col.combine_chunks() if isinstance(col, pa.ChunkedArray) \
             else col
         return _arrow_list_to_column(name, arr, n, cap)
     with span("chunk.convert", column=name):
-        dt, padded, valid_np, dictionary = _arrow_to_padded(name, col, n, cap)
-    nbytes = padded.nbytes + (valid_np.nbytes if valid_np is not None else 0)
-    with span("chunk.put", column=name, bytes=nbytes):
-        validity = jax.device_put(valid_np) if valid_np is not None else None
-        # device_put is ~2x jnp.asarray for host->device of large buffers
-        data = jax.device_put(padded)
-    return Column(data, dt, validity, dictionary)
+        host = _arrow_to_padded(name, col, n, cap)
+    return host.put()
 
 
-def _arrow_to_padded(name: str, col, n: int, cap: int):
-    """(dtype, data padded to `cap`, validity padded to `cap` or None,
-    dictionary or None) of a non-list Arrow column: all host work."""
-    arr = col.combine_chunks() if isinstance(col, pa.ChunkedArray) else col
-    at = arr.type
-    dictionary = None
-    if pa.types.is_null(at):
+def device_dtype(name: str, at: pa.DataType) -> T.DataType:
+    """The engine type a non-list Arrow column is held in on the
+    device (its `np_dtype` is the padded buffer's)."""
+    if pa.types.is_null(at) or pa.types.is_string(at) \
+            or pa.types.is_large_string(at) or pa.types.is_dictionary(at):
         # an empty/all-None pandas object column infers arrow `null`
         # (e.g. a streaming schema df with pd.Series([], dtype=str)):
-        # treat it as an all-NULL string column, the dtype the object
-        # column would carry with any value present
-        arr = arr.cast(pa.string())
-        at = arr.type
-    if pa.types.is_string(at) or pa.types.is_large_string(at):
-        arr = arr.dictionary_encode()
-        at = arr.type
+        # an all-NULL string column, the dtype the object column
+        # would carry with any value present
+        return T.STRING
+    if pa.types.is_decimal(at):
+        return T.DecimalType(at.precision, at.scale)
+    if pa.types.is_timestamp(at):
+        return T.TIMESTAMP
+    dt = _ARROW_TO_DTYPE.get(at)
+    if dt is None:
+        raise TypeError(f"unsupported arrow type {at} for column {name}")
+    return dt
+
+
+def _values_view(arr: pa.Array, np_dtype, width: int = 1) -> np.ndarray:
+    """The fixed-width values of `arr` where they lie in its buffer,
+    `width` items of `np_dtype` a value: no copy, and under a null
+    whatever the writer left there."""
+    buf = arr.buffers()[1]
+    if buf is None:  # an all-null array may come without values
+        return np.zeros(width * len(arr), dtype=np_dtype)
+    return np.frombuffer(buf, dtype=np_dtype,
+                         count=width * (arr.offset + len(arr))
+                         )[width * arr.offset:]
+
+
+def fill_padded(name: str, piece: pa.Array, out: np.ndarray, pos: int,
+                code_map: Optional[np.ndarray] = None
+                ) -> Optional[np.ndarray]:
+    """Write one piece of a non-list Arrow column into
+    `out[pos:pos + len(piece)]`, a buffer of the column's device
+    dtype: the one copy a row makes on its way from the reader's
+    record batch to the buffer `jax.device_put` is handed. A string
+    piece comes dictionary-typed; `code_map` (int32) takes its codes
+    to the column's dictionary, None where they are its codes.
+    Returns the piece's validity, or None where it has no null; the
+    rows under a null are written as zero."""
+    m = len(piece)
+    if m == 0:
+        return None
+    dst = out[pos:pos + m]
+    at = piece.type
+    if pa.types.is_null(at):
+        dst[:] = 0
+        return np.zeros(m, dtype=np.bool_)
     if pa.types.is_dictionary(at):
-        dictionary = arr.dictionary
-        codes = arr.indices.cast(pa.int32())
-        np_data = codes.to_numpy(zero_copy_only=False)
-        dt: T.DataType = T.STRING
+        codes = piece.indices
+        src = _values_view(codes, codes.type.to_pandas_dtype())
+        if code_map is None:
+            dst[:] = src
+        elif len(code_map):
+            # clipped, not checked: a code under a null may be anything
+            np.take(code_map, src, out=dst, mode="clip")
+        else:
+            dst[:] = 0  # no value at all: every row is null
     elif pa.types.is_decimal(at):
-        dt = T.DecimalType(at.precision, at.scale)
-        # exact unscaled int64: read the low 64-bit limb of the 128-bit
+        # exact unscaled int64: the low 64-bit limb of the 128-bit
         # little-endian decimal buffer (two's complement reinterpret is
         # exact for values in int64 range, which our repr requires).
-        # decimal128 shares one buffer layout for every precision, so no
-        # cast is needed (the cast materialized a full copy — a third of
-        # decimal ingest time at TPC-H scale)
-        if arr.type.bit_width != 128:
-            arr = arr.cast(pa.decimal128(38, at.scale))
-        buf = arr.buffers()[1]
-        raw = np.frombuffer(buf, dtype=np.int64,
-                            count=2 * (arr.offset + len(arr)))
-        lo = raw[2 * arr.offset::2]          # strided view, copied once
+        # decimal128 shares one buffer layout for every precision, so
+        # no cast is needed (the cast materialized a full copy: a third
+        # of decimal ingest time at TPC-H scale)
+        if at.bit_width != 128:
+            piece = piece.cast(pa.decimal128(38, at.scale))
+        raw = _values_view(piece, np.int64, width=2)
+        lo = raw[::2]                        # strided view, copied once
         if at.precision > 18:
             # only precision > 18 can exceed int64; cheaper columns
             # (TPC-H's (12,2)/(15,2)) skip the check entirely
-            hi = raw[2 * arr.offset + 1::2]
-            expect_hi = lo >> 63  # sign extension when value fits int64
-            mism = hi != expect_hi
-            if arr.null_count:
-                mism = mism & ~np.asarray(arr.is_null()).astype(bool)
+            mism = raw[1::2] != lo >> 63  # sign extension when it fits
+            if piece.null_count:
+                mism = mism & np.asarray(piece.is_valid())
             if mism.any():
                 raise OverflowError(
                     f"decimal column {name} exceeds int64 unscaled range")
-        np_data = lo
+        dst[:] = lo
     elif at == pa.date32():
-        dt = T.DATE
-        np_data = arr.cast(pa.int32()).to_numpy(zero_copy_only=False)
+        dst[:] = _values_view(piece, np.int32)
     elif pa.types.is_timestamp(at):
-        dt = T.TIMESTAMP
-        np_data = arr.cast(pa.timestamp("us")).cast(pa.int64()).to_numpy(
-            zero_copy_only=False)
+        if at != pa.timestamp("us"):
+            piece = piece.cast(pa.timestamp("us"))
+        dst[:] = _values_view(piece, np.int64)
+    elif pa.types.is_boolean(at):  # bit-packed: through Arrow
+        import pyarrow.compute as pc
+        dst[:] = (pc.fill_null(piece, False) if piece.null_count
+                  else piece).to_numpy(zero_copy_only=False)
     else:
-        dt = _ARROW_TO_DTYPE.get(at)
-        if dt is None:
-            raise TypeError(f"unsupported arrow type {at} for column {name}")
-        np_data = arr.cast(pa.from_numpy_dtype(dt.np_dtype)).to_numpy(
-            zero_copy_only=False)
+        dst[:] = _values_view(piece, at.to_pandas_dtype())
+    if not piece.null_count:
+        return None
+    valid = np.asarray(piece.is_valid())
+    dst[~valid] = 0
+    return valid
 
-    valid_np = None
-    if arr.null_count > 0:
-        valid_np = np.zeros(cap, dtype=np.bool_)
-        valid_np[:n] = ~np.asarray(arr.is_null())
-        np_data = np.where(valid_np[:n], np_data, np.zeros((), dtype=dt.np_dtype))
 
-    padded = np.zeros(cap, dtype=dt.np_dtype)
-    padded[:n] = np_data
-    return dt, padded, valid_np, dictionary
+def _same_dictionary(a: pa.Array, b: pa.Array) -> bool:
+    """Whether two dictionaries hold the same values in the same
+    order; batches cut from one row group share the very buffers."""
+    if len(a) != len(b) or a.type != b.type:
+        return False
+    if a.offset == b.offset and [x and x.address for x in a.buffers()] \
+            == [x and x.address for x in b.buffers()]:
+        return True
+    return a.equals(b)
+
+
+def merge_dictionaries(dicts: Sequence[pa.Array]
+                       ) -> Tuple[Optional[pa.Array], list]:
+    """One dictionary for pieces that each came with their own:
+    (merged, maps). `merged` holds every value once, in the order in
+    which the dictionaries bring them; `maps[i]` is the int32 table
+    from the codes of `dicts[i]` to the merged ones, or None where
+    they are the merged ones. Work is per dictionary entry, never per
+    row, and a map is made once while consecutive pieces share a
+    dictionary."""
+    import pyarrow.compute as pc
+    merged, maps, last = None, [], None
+    for d in dicts:
+        if last is not None and _same_dictionary(d, last):
+            maps.append(maps[-1])
+            continue
+        last = d
+        if merged is None:
+            merged = d
+            maps.append(None)
+            continue
+        if d.type != merged.type:
+            d = d.cast(merged.type)
+        present = pc.index_in(d, value_set=merged)
+        if present.null_count:
+            merged = pa.concat_arrays([merged,
+                                       d.filter(pc.is_null(present))])
+            present = pc.index_in(d, value_set=merged)
+        to_merged = present.to_numpy(zero_copy_only=False).astype(
+            np.int32, copy=False)
+        maps.append(None if np.array_equal(
+            to_merged, np.arange(len(to_merged))) else to_merged)
+    return merged, maps
+
+
+class HostColumn:
+    """The host half of a non-list Column while it is filled: `data`
+    padded to the capacity in the device dtype, `validity` made when
+    the first null shows (`new_mask`), `dictionary` for a string
+    column, `rows` filled so far. `data` and what `new_mask` returns
+    are zero past `rows`, or the caller's to zero there: `put` hands
+    both to `jax.device_put` as they are."""
+
+    __slots__ = ("name", "dtype", "data", "validity", "dictionary",
+                 "rows", "_new_mask")
+
+    def __init__(self, name: str, dtype: T.DataType, data: np.ndarray,
+                 new_mask, dictionary: Optional[pa.Array] = None):
+        self.name = name
+        self.dtype = dtype
+        self.data = data
+        self.validity: Optional[np.ndarray] = None
+        self.dictionary = dictionary
+        self.rows = 0
+        self._new_mask = new_mask
+
+    def append(self, piece: pa.Array,
+               code_map: Optional[np.ndarray] = None) -> None:
+        valid = fill_padded(self.name, piece, self.data, self.rows,
+                            code_map)
+        end = self.rows + len(piece)
+        if valid is not None and self.validity is None:
+            self.validity = self._new_mask()
+            self.validity[:self.rows] = True
+        if self.validity is not None:
+            self.validity[self.rows:end] = True if valid is None else valid
+        self.rows = end
+
+    @property
+    def nbytes(self) -> int:
+        return self.data.nbytes + (self.validity.nbytes
+                                   if self.validity is not None else 0)
+
+    def put(self) -> Column:
+        with span("chunk.put", column=self.name, bytes=self.nbytes):
+            validity = jax.device_put(self.validity) \
+                if self.validity is not None else None
+            # device_put is ~2x jnp.asarray for host->device of large
+            # buffers
+            data = jax.device_put(self.data)
+        dictionary = self.dictionary
+        if dictionary is None and isinstance(self.dtype, T.StringType):
+            # a `null` column, or one of no chunk: no value, no code
+            dictionary = pa.array([], type=pa.string())
+        return Column(data, self.dtype, validity, dictionary)
+
+
+def as_dictionary_pieces(col) -> list:
+    """The pieces of a string column as dictionary arrays: a plain
+    column is hashed row by row, once and into one dictionary for all
+    its pieces, in the order of first appearance."""
+    if isinstance(col, pa.Array):
+        col = pa.chunked_array([col])
+    if not pa.types.is_dictionary(col.type):
+        col = col.dictionary_encode()
+    return col.chunks
+
+
+def _arrow_to_padded(name: str, col, n: int, cap: int) -> HostColumn:
+    """A non-list Arrow column in new buffers padded to `cap`: all
+    host work. The column's chunks are filled in one after the other
+    (`fill_padded`), never made one array first."""
+    dt = device_dtype(name, col.type)
+    host = HostColumn(name, dt, np.zeros(cap, dtype=dt.np_dtype),
+                      lambda: np.zeros(cap, dtype=np.bool_))
+    if isinstance(dt, T.StringType) and not pa.types.is_null(col.type):
+        pieces = as_dictionary_pieces(col)
+        host.dictionary, maps = merge_dictionaries(
+            [p.dictionary for p in pieces])
+    else:
+        pieces = col.chunks if isinstance(col, pa.ChunkedArray) else [col]
+        maps = [None] * len(pieces)
+    for piece, code_map in zip(pieces, maps):
+        host.append(piece, code_map)
+    assert host.rows == n, (name, host.rows, n)
+    return host
 
 
 def _arrow_list_to_column(name: str, arr, n: int, cap: int) -> Column:

@@ -7,28 +7,33 @@ vectorized Parquet reader (`VectorizedParquetRecordReader.java:54`): the
 C++ Arrow/Parquet reader does columnar decode + predicate/column pushdown
 on host (handing over low-cardinality string columns as the files' own
 dictionary codes), then string columns are brought onto one dictionary,
-columns are padded and device_put — ingest is the only place bytes cross
-host->device (SURVEY.md section 2.4).
+columns are filled into padded, pooled buffers and device_put — ingest is
+the only place bytes cross host->device (SURVEY.md section 2.4).
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import os
 import time
 from concurrent.futures import ThreadPoolExecutor
-from typing import Optional, Sequence, Tuple
+from typing import Optional, Sequence
 
+import jax.numpy as jnp
 import numpy as np
 import pyarrow as pa
 import pyarrow.compute as pc
 import pyarrow.dataset as pa_dataset
 
 from .. import types as T
-from ..columnar import Batch
+from ..columnar import (Batch, HostColumn, _arrow_to_column,
+                        as_dictionary_pieces, bucket_capacity, device_dtype,
+                        merge_dictionaries)
 from ..observability.spans import span
 from ..expr import (And, BinaryComparison, ColumnRef, EQ, Expression, GE, GT,
                     In, IsNull, LE, LT, Literal, NE, Not, Or)
+from .host_buffers import POOL, SETS_PER_STREAM
 
 
 def _decimal_literal_scalar(col_field: pa.Field, value):
@@ -140,38 +145,45 @@ class TableSource:
 
 
 def _arrow_schema_to_engine(schema: pa.Schema) -> T.Schema:
-    from ..columnar import _ARROW_TO_DTYPE
     fields = []
     for f in schema:
-        at = f.type
-        if pa.types.is_string(at) or pa.types.is_large_string(at) or \
-                pa.types.is_dictionary(at) or pa.types.is_null(at):
-            # arrow `null` = an empty/all-None object column (e.g. a
-            # streaming schema df): STRING is the dtype it would carry
-            # with any value present (columnar casts it the same way)
-            dt: T.DataType = T.STRING
-        elif pa.types.is_decimal(at):
-            dt = T.DecimalType(at.precision, at.scale)
-        elif pa.types.is_timestamp(at):
-            dt = T.TIMESTAMP
-        elif at == pa.date32():
-            dt = T.DATE
-        elif pa.types.is_list(at) or pa.types.is_large_list(at):
+        if pa.types.is_list(f.type) or pa.types.is_large_list(f.type):
             elem = _arrow_schema_to_engine(
-                pa.schema([pa.field("e", at.value_type)])).fields[0]
-            dt = T.ArrayType(elem.dtype)
+                pa.schema([pa.field("e", f.type.value_type)])).fields[0]
+            dt: T.DataType = T.ArrayType(elem.dtype)
         else:
-            dt = _ARROW_TO_DTYPE.get(at)
-            if dt is None:
-                raise TypeError(f"unsupported arrow type {at} ({f.name})")
+            dt = device_dtype(f.name, f.type)
         fields.append(T.Field(f.name, dt, f.nullable))
     return T.Schema(fields)
 
 
-#: rows from which a chunk's columns are unified side by side: below
-#: it a column is a few megabytes and starting threads costs more
-#: than they save
-_UNIFY_SIDE_BY_SIDE_ROWS = 1 << 20
+#: rows from which a chunk's columns are done side by side: below it
+#: a column is a few megabytes and starting threads costs more than
+#: they save
+_SIDE_BY_SIDE_ROWS = 1 << 20
+
+
+def _side_by_side(fn, items: list, rows: int) -> list:
+    """`fn` over the columns of a chunk of `rows` rows: a large
+    chunk's on threads of their own, which live for this call (pyarrow
+    and numpy release the GIL over a column). Every result is read, so
+    what a column raised is raised here."""
+    workers = min(len(items), os.cpu_count() or 1)
+    if workers < 2 or rows < _SIDE_BY_SIDE_ROWS:
+        return [fn(item) for item in items]
+    with ThreadPoolExecutor(
+            max_workers=workers,
+            thread_name_prefix="spark-tpu-ingest-column") as pool:
+        return list(pool.map(fn, items))
+
+
+#: a string column of a chunk as `DictUnifier` hands it to the fill:
+#: its `pieces`, dictionary-typed; `maps`, a piece's int32 table from
+#: its codes to the column's global ones (None: they are the global
+#: ones); `kind`, how the column arrived (`read` dictionary-typed,
+#: `encoded` from plain strings); the `seconds` it took
+CodedColumn = collections.namedtuple("CodedColumn",
+                                     "pieces maps kind seconds")
 
 
 class DictUnifier:
@@ -180,133 +192,139 @@ class DictUnifier:
     out earlier stay valid). The analog of the reference's per-column
     dictionary pages being resolved to one dictionary at read time.
 
-    A column that arrives dictionary-typed (a Parquet column read with
-    its page dictionaries, `_dictionary_columns`; a dictionary column of
-    an in-memory table) is unified by dictionary: its batches'
-    dictionaries are merged, the merged one is mapped into the global
-    one, and the int32 codes are remapped only where the two differ.
-    Work on values is per dictionary entry, never per row. A column
-    that arrives as plain strings (in-memory tables, CSV, JSON, a
-    Parquet column whose footers did not qualify it) is hashed row by
-    row first (`dictionary_encode`). Every other column is
-    concatenated into one array, as `Batch.from_arrow` wants it. All of
-    it runs under the thread that runs `ChunkIterator._host_next` (the
-    prefetch worker, or the consumer with prefetch off), inside its
-    `chunk.unify` span; while the consumer is idle until the chunk is
-    ready, that thread does the columns side by side (`unify`)."""
+    What it does is work on dictionaries, never on rows: a chunk's
+    pieces of a column (the scanner's record batches, as they were
+    cut) keep their own codes, and the unifier answers with one int32
+    map a piece from those codes to the global ones, which the fill
+    (`columnar.fill_padded`) applies on its one pass over the rows. A
+    column that arrives dictionary-typed (a Parquet column read with
+    its page dictionaries, `_dictionary_columns`; a dictionary column
+    of an in-memory table) has its pieces' dictionaries merged, a map
+    made once while consecutive pieces share a dictionary, and the
+    merged one mapped into the global one. A column that arrives as
+    plain strings (in-memory tables, CSV, JSON, a Parquet column whose
+    footers did not qualify it) is hashed row by row first, into one
+    dictionary for the chunk (`dictionary_encode`). It runs under the
+    thread that runs `ChunkIterator._host_next` (the prefetch worker,
+    or the consumer with prefetch off), inside its `chunk.unify` span,
+    the string columns of a large chunk side by side."""
 
     def __init__(self):
         self.dicts = {}
 
-    def _to_global(self, name: str, arr: pa.DictionaryArray,
-                   all_used: bool) -> Optional[pa.Array]:
+    def _to_global(self, name: str, chunk_dict: pa.Array,
+                   used) -> Optional[np.ndarray]:
         """Append to the column's global dictionary the values of the
         chunk's dictionary that it lacks and that a row of the chunk
         carries. A page dictionary also holds the values of rows that
         a pushed filter or the chunk's cut left out; those get no
         code, as they got none when rows were hashed, so a dictionary
         (and the direct aggregate's domain, sized from it) holds what
-        the scan returned and nothing else. `all_used` says that every
-        value is carried (the chunk was encoded from its rows).
-        Returns the int32 map from the chunk's codes to the global
-        ones, or None where the chunk's codes already are the global
-        ones."""
-        chunk_dict = arr.dictionary.cast(pa.string())
+        the scan returned and nothing else. `used(new)` gives the mask
+        of the values a row carries, at least among the `new` ones
+        (the one pass over rows, codes only, made only where a value
+        is new and only until each new one was seen), None where every
+        value is carried (the chunk was encoded from its rows). Returns the
+        int32 map from the chunk's codes to the global ones, or None
+        where the chunk's codes already are the global ones."""
         glob = self.dicts.setdefault(name, chunk_dict.slice(0, 0))
         present = pc.index_in(chunk_dict, value_set=glob)
         if present.null_count:
             new = pc.is_null(present)
-            if not all_used:  # the one pass over rows, codes only
-                used = np.zeros(len(chunk_dict), dtype=bool)
-                used[pc.unique(arr.indices).drop_null().to_numpy()] = True
-                new = pc.and_(new, pa.array(used))
+            if used is not None:
+                new = pc.and_(new, pa.array(used(new.to_numpy(
+                    zero_copy_only=False))))
             if pc.any(new).as_py():
                 glob = pa.concat_arrays([glob, chunk_dict.filter(new)])
                 self.dicts[name] = glob
                 present = pc.index_in(chunk_dict, value_set=glob)
-        mapping = present.cast(pa.int32())
-        if not mapping.null_count and np.array_equal(
-                mapping.to_numpy(), np.arange(len(mapping))):
+        # a value no row carries has no code, and no row asks for one
+        mapping = present.fill_null(0).to_numpy().astype(
+            np.int32, copy=False)
+        if len(mapping) <= len(glob) and np.array_equal(
+                mapping, np.arange(len(mapping))):
             return None
         return mapping
 
-    def _unify_column(self, name: str, col: pa.ChunkedArray):
-        """One column as one array: (array, what was done to it
-        (`read` / `encoded` / `concat`), seconds)."""
+    def unify_column(self, name: str,
+                     col: pa.ChunkedArray) -> CodedColumn:
+        """A string column of a chunk, its values in the column's
+        global dictionary and its rows untouched."""
         t0 = time.perf_counter()
-        at = col.type
-        if pa.types.is_dictionary(at):
-            kind = "read"
-            arr = col.unify_dictionaries().combine_chunks()
-        elif pa.types.is_string(at) or pa.types.is_large_string(at):
-            kind = "encoded"
-            arr = col.combine_chunks().cast(pa.string()).dictionary_encode()
-        else:
-            return col.combine_chunks(), "concat", time.perf_counter() - t0
-        mapping = self._to_global(name, arr, all_used=kind == "encoded")
-        codes = arr.indices.cast(pa.int32()) if mapping is None \
-            else mapping.take(arr.indices)
-        # the codes index the global dictionary by construction: no
-        # pass over them to check it
-        arr = pa.DictionaryArray.from_arrays(codes, self.dicts[name],
-                                             safe=False)
-        return arr, kind, time.perf_counter() - t0
+        kind = "read" if pa.types.is_dictionary(col.type) else "encoded"
+        if pa.types.is_large_string(col.type):
+            col = col.cast(pa.string())
+        pieces = as_dictionary_pieces(col)
+        merged, local = merge_dictionaries([p.dictionary for p in pieces])
 
-    def unify(self, table: pa.Table, side_by_side: bool = True
-              ) -> Tuple[pa.Table, dict]:
-        """The chunk with every column one array and every string
-        column coded in its global dictionary, and where the time went:
-        `dict_columns_read` / `dict_columns_encoded` (string columns
-        unified by dictionary / hashed row by row), `dict_ms` (both
-        kinds), `concat_ms` and `concat_bytes` (the other columns).
+        def used(new: np.ndarray) -> np.ndarray:
+            # piece by piece, until every new value was seen in a row:
+            # as a rule in the chunk's first record batch
+            mask = np.zeros(len(merged), dtype=bool)
+            for piece, to_merged in zip(pieces, local):
+                codes = pc.unique(piece.indices).drop_null().to_numpy()
+                mask[codes if to_merged is None else to_merged[codes]] = True
+                if mask[new].all():
+                    break
+            return mask
 
-        `side_by_side` does the columns of a large chunk on threads
-        of their own, which live for this call (pyarrow releases the
-        GIL): right while the consumer has nothing to do until this
-        chunk is ready, wrong while it is busy, because every column
-        here is new memory and the first touch of fresh pages is what
-        both threads spend their time on. The two times are sums over
-        columns, so side by side they can pass the length of the
-        `chunk.unify` span that holds them."""
-        names = table.column_names
-        workers = min(len(names), os.cpu_count() or 1)
-        if side_by_side and workers > 1 \
-                and table.num_rows >= _UNIFY_SIDE_BY_SIDE_ROWS:
-            with ThreadPoolExecutor(
-                    max_workers=workers,
-                    thread_name_prefix="spark-tpu-ingest-unify") as pool:
-                done = list(pool.map(self._unify_column, names,
-                                     table.columns))
-        else:
-            done = [self._unify_column(n, c)
-                    for n, c in zip(names, table.columns)]
-        split = {"dict_columns_read": 0, "dict_columns_encoded": 0,
-                 "dict_ms": 0.0, "concat_ms": 0.0, "concat_bytes": 0}
-        for arr, kind, seconds in done:
-            if kind == "concat":
-                split["concat_ms"] += seconds * 1e3
-                split["concat_bytes"] += arr.nbytes
-            else:
-                split["dict_columns_" + kind] += 1
-                split["dict_ms"] += seconds * 1e3
-        return pa.table([d[0] for d in done], names=names), split
+        to_global = self._to_global(
+            name, merged.cast(pa.string()),
+            used if kind == "read" else None)
+        maps, made = [], {}
+        for to_merged in local:
+            if to_global is None or to_merged is None:
+                maps.append(to_merged if to_global is None else to_global)
+                continue
+            if id(to_merged) not in made:
+                made[id(to_merged)] = to_global[to_merged]
+            maps.append(made[id(to_merged)])
+        return CodedColumn(pieces, maps, kind, time.perf_counter() - t0)
+
+
+class HostChunk:
+    """A chunk's host half, as `ChunkIterator._host_next` hands it to
+    `_to_device`: `columns` by name, a `columnar.HostColumn` filled and
+    padded (a list column: its Arrow pieces, converted at the put),
+    and the pooled `buffers` they stand in."""
+
+    __slots__ = ("rows", "columns", "buffers")
+
+    def __init__(self, rows: int):
+        self.rows = rows
+        self.columns = {}
+        self.buffers = []
 
 
 class ChunkIterator:
     """Single-pass iterator of uniform-capacity Batches over a record
     -batch stream; `.dictionaries` holds the final global dictionaries.
 
-    Once `observe` has bound it to a query, every chunk leaves the
-    spans `chunk.decode` and `chunk.unify` (on whichever thread runs
-    `_host_next`: the prefetch worker, or the consumer with prefetch
-    off) and `chunk.to_device` (always the consumer), and counts into
-    `ingest_chunks` / `ingest_rows` / `ingest_put_bytes` and, by how
-    its string columns arrived, `ingest_dict_columns_read` /
-    `ingest_dict_columns_encoded` (`DictUnifier`). `chunk.unify`
-    carries the split of its time as attributes: `dict_ms`,
-    `concat_ms`, `concat_bytes`."""
+    Who does what: `_host_next` makes a chunk's host half on whichever
+    thread runs it (the prefetch worker, or the consumer with prefetch
+    off): it cuts the chunk as slices of the reader's record batches
+    (`chunk.decode`), has `DictUnifier` map the string columns' codes
+    (`chunk.unify`) and fills every column's padded buffer straight
+    from those slices (`chunk.convert`, one a column, a large chunk's
+    columns side by side): a row is copied once, and into a buffer of
+    the process's `HostBufferPool` that an earlier chunk or request
+    has touched. `_to_device`, always on the consumer, only puts
+    (`chunk.to_device` with one `chunk.put` a column) and notes each
+    buffer with the array made from it; the set goes back to the pool
+    when the stream's fourth chunk wants one (a stream has at most
+    `SETS_PER_STREAM` out), and the pool hands a buffer out again
+    once its array is ready. `close` (the drivers call it on every
+    exit) gives back every set, put or only filled.
 
-    def __init__(self, batches_iter, chunk_rows: int):
+    Once `observe` has bound it to a query, the spans above are
+    recorded and every chunk counts into `ingest_chunks` /
+    `ingest_rows` / `ingest_put_bytes`, by where its buffers came
+    from `ingest_buffers_reused` / `ingest_buffers_allocated` and, by
+    how its string columns arrived, `ingest_dict_columns_read` /
+    `ingest_dict_columns_encoded` (`DictUnifier`). `chunk.unify`
+    carries `dict_ms`, a `chunk.convert` the `bytes` it filled."""
+
+    def __init__(self, batches_iter, chunk_rows: int, pool=None):
         self._batches = batches_iter
         self._chunk_rows = chunk_rows
         self._capacity = None
@@ -319,11 +337,18 @@ class ChunkIterator:
         self._recorder = None
         self._cause = None   # the consumer's open span, for the worker
         self._chunk = 0      # ordinal of the next chunk taken
-        #: whether the consumer is idle until the next chunk is ready:
-        #: always, when it makes the chunk itself; under a prefetcher
-        #: only while it waits at the queue (PrefetchChunkIterator
-        #: keeps this; a hint, read once a chunk by `_host_next`)
-        self.consumer_waits = True
+        self._pool = pool if pool is not None else POOL
+        #: chunks filled and not yet put: the worker's and the queue's
+        self._filled = []
+        #: sets that were put, oldest first, as (buffer, array made
+        #: from it): kept until this stream wants a set again, so that
+        #: its first SETS_PER_STREAM chunks draw as many sets whatever
+        #: the two threads' timing, and a later stream finds them all
+        self._put = collections.deque()
+        self._fills = 0      # chunks filled so far
+        #: {pool key: buffers a chunk} of the widest chunk so far
+        self._shape = collections.Counter()
+        self._closed = False
 
     def observe(self, metrics, recorder) -> None:
         """Bind the stream to its query's counters and spans (called
@@ -334,10 +359,11 @@ class ChunkIterator:
 
     def _host_span(self, name: str, **attrs):
         """A span of the host half (`_host_next`), which the prefetch
-        worker may run: a thread the query starts does not see the
-        query's context, so these go through the bound recorder, with
-        the consumer's open span as their cause. The consumer's own
-        spans (`chunk.wait`, `chunk.to_device`) open through `span`."""
+        worker may run, a column's on a thread of its own: a thread
+        the query starts does not see the query's context, so these go
+        through the bound recorder, with the consumer's open span as
+        their cause. The consumer's own spans (`chunk.wait`,
+        `chunk.to_device`) open through `span`."""
         if self._recorder is None:
             return contextlib.nullcontext()
         return self._recorder.span(name, parent=self._cause, **attrs)
@@ -373,7 +399,8 @@ class ChunkIterator:
     def _take_chunk(self) -> Optional[pa.Table]:
         """One chunk's Arrow slice off the stream (the shared cursor
         advance of __next__ and skip_chunks, so both cut identical
-        chunk boundaries), or None at end of stream. The `chunk.decode`
+        chunk boundaries), or None at end of stream: slices of the
+        pending record batches, no row copied. The `chunk.decode`
         span: a Parquet scanner reads ahead on threads of its own, so
         this is the wait for its batches and the slicing around it,
         not the decode's CPU time."""
@@ -397,10 +424,10 @@ class ChunkIterator:
 
     def skip_chunks(self, n: int) -> int:
         """Advance the cursor past the next `n` chunks without
-        dictionary-unifying or moving bytes to the device — the
-        checkpoint-restore path resumes a stream at a chunk cursor.
-        Returns how many chunks were actually skipped (fewer when the
-        stream ends first)."""
+        dictionary-unifying, filling a buffer or moving bytes to the
+        device — the checkpoint-restore path resumes a stream at a
+        chunk cursor. Returns how many chunks were actually skipped
+        (fewer when the stream ends first)."""
         skipped = 0
         while skipped < int(n):
             if self._take_chunk() is None:
@@ -408,8 +435,39 @@ class ChunkIterator:
             skipped += 1
         return skipped
 
-    def _host_next(self) -> Optional[pa.Table]:
-        """One decoded + dictionary-unified HOST chunk (pa.Table), or
+    def _draw(self, buffers: list, np_dtype) -> np.ndarray:
+        """A pooled buffer of the chunk's capacity, noted in the
+        chunk's `buffers`."""
+        buf, reused = self._pool.take(np_dtype, self._capacity)
+        buffers.append(buf)
+        if self._metrics is not None:
+            self._metrics.counter(
+                "ingest_buffers_reused" if reused
+                else "ingest_buffers_allocated").inc()
+        return buf
+
+    def _fill_column(self, buffers: list, name: str, pieces: list,
+                     maps: Optional[list]) -> HostColumn:
+        """One column of the chunk in a pooled buffer: every piece
+        written where it belongs, the rest zeroed (a buffer that was
+        used holds an older chunk's rows there)."""
+        with self._host_span("chunk.convert", column=name) as sp:
+            dt = device_dtype(name, pieces[0].type)
+            col = HostColumn(
+                name, dt, self._draw(buffers, dt.np_dtype),
+                lambda: self._draw(buffers, np.bool_),
+                self._unifier.dicts.get(name) if maps is not None else None)
+            for piece, code_map in zip(pieces, maps or [None] * len(pieces)):
+                col.append(piece, code_map)
+            col.data[col.rows:] = 0
+            if col.validity is not None:
+                col.validity[col.rows:] = False
+            if sp is not None:
+                sp.attrs["bytes"] = col.nbytes
+        return col
+
+    def _host_next(self) -> Optional[HostChunk]:
+        """One chunk's host half, every column filled and padded, or
         None at end of stream. All the per-chunk host work lives here;
         device placement stays in __next__ — the split the prefetcher
         (PrefetchChunkIterator) overlaps with device compute."""
@@ -417,29 +475,82 @@ class ChunkIterator:
         if chunk is None:
             return None
         if self._capacity is None:
-            from ..columnar import bucket_capacity
             self._capacity = bucket_capacity(self._chunk_rows)
+        rows = chunk.num_rows
+        strings = [(n, c) for n, c in zip(chunk.column_names, chunk.columns)
+                   if pa.types.is_string(c.type)
+                   or pa.types.is_large_string(c.type)
+                   or pa.types.is_dictionary(c.type)]
         with self._host_span("chunk.unify", chunk=self._chunk - 1,
-                             rows=chunk.num_rows) as sp:
-            chunk, split = self._unifier.unify(
-                chunk, side_by_side=self.consumer_waits)
-            if sp is not None:  # what the span's time went to
-                sp.attrs.update(
-                    dict_ms=round(split["dict_ms"], 3),
-                    concat_ms=round(split["concat_ms"], 3),
-                    concat_bytes=split["concat_bytes"])
+                             rows=rows) as sp:
+            coded = dict(zip((n for n, _ in strings), _side_by_side(
+                lambda item: self._unifier.unify_column(*item),
+                strings, rows)))
+            if sp is not None:  # thread time, summed over the columns
+                sp.attrs["dict_ms"] = round(
+                    sum(c.seconds for c in coded.values()) * 1e3, 3)
         if self._metrics is not None:
             for how in ("read", "encoded"):
                 self._metrics.counter("ingest_dict_columns_" + how).inc(
-                    split["dict_columns_" + how])
-        return chunk
+                    sum(1 for c in coded.values() if c.kind == how))
+        host = HostChunk(rows)
+        if self._fills >= SETS_PER_STREAM and self._put:
+            # the set of the chunk three before: long put, and the
+            # pool waits for its transfers if they are not over
+            for buf, arr in self._put.popleft():
+                self._pool.give(buf, arr)
+        self._fills += 1
 
-    def _to_device(self, chunk: pa.Table) -> Batch:
-        with span("chunk.to_device", rows=chunk.num_rows):
-            batch = Batch.from_arrow(chunk, capacity=self._capacity)
+        def fill(item):
+            name, col = item
+            if pa.types.is_list(col.type) or pa.types.is_large_list(col.type):
+                return col  # offsets are not fixed-width: at the put
+            if name in coded:
+                return self._fill_column(host.buffers, name,
+                                         coded[name].pieces,
+                                         coded[name].maps)
+            return self._fill_column(host.buffers, name, col.chunks, None)
+
+        try:
+            host.columns = dict(zip(chunk.column_names, _side_by_side(
+                fill, list(zip(chunk.column_names, chunk.columns)), rows)))
+        except BaseException as e:
+            for buf in host.buffers:
+                self._pool.give(buf)
+            if isinstance(e, Exception):
+                # the chunk's rows are off the stream: a replay would
+                # skip them, so it raises this again instead
+                self._failed = e
+            raise
+        self._shape |= collections.Counter(  # the most of each key
+            self._pool.key_of(buf) for buf in host.buffers)
+        self._filled.append(host)
+        return host
+
+    def _to_device(self, host: HostChunk) -> Batch:
+        self._filled.remove(host)
+        cols = {}
+        try:
+            with span("chunk.to_device", rows=host.rows):
+                for name, col in host.columns.items():
+                    cols[name] = col.put() if isinstance(col, HostColumn) \
+                        else _arrow_to_column(name, col, host.rows,
+                                              self._capacity)
+                batch = Batch(cols, jnp.arange(self._capacity) < host.rows)
+        finally:
+            # a buffer goes back with the array that was made from it:
+            # the pool hands it out again once that one is ready
+            made = {}
+            for name, col in host.columns.items():
+                if isinstance(col, HostColumn) and name in cols:
+                    made[id(col.data)] = cols[name].data
+                    if col.validity is not None:
+                        made[id(col.validity)] = cols[name].validity
+            self._put.append([(buf, made.get(id(buf)))
+                              for buf in host.buffers])
         if self._metrics is not None:
             self._metrics.counter("ingest_chunks").inc()
-            self._metrics.counter("ingest_rows").inc(chunk.num_rows)
+            self._metrics.counter("ingest_rows").inc(host.rows)
             # what was put: the padded buffers (the selection mask is
             # made on the device)
             self._metrics.counter("ingest_put_bytes").inc(sum(
@@ -448,11 +559,31 @@ class ChunkIterator:
                 for c in batch.columns.values()))
         return batch
 
+    def close(self) -> None:
+        """Give back the buffers of chunks that were filled and never
+        put, and leave the pool with what a stream of this shape
+        needs. On every exit: the end of the stream, a driver's
+        `finally`, `PrefetchChunkIterator.close` once its worker is
+        joined."""
+        if self._closed:
+            return
+        self._closed = True
+        for host in self._filled:
+            for buf in host.buffers:
+                self._pool.give(buf)
+        self._filled = []
+        while self._put:
+            for buf, arr in self._put.popleft():
+                self._pool.give(buf, arr)
+        if self._shape:
+            self._pool.trim(self._shape)
+
     def __next__(self) -> Batch:
-        chunk = self._host_next()
-        if chunk is None:
+        host = None if self._closed else self._host_next()
+        if host is None:
+            self.close()
             raise StopIteration
-        return self._to_device(chunk)
+        return self._to_device(host)
 
 
 import itertools
@@ -639,7 +770,8 @@ class ParquetSource(TableSource):
     names arrive as `dictionary<int32, string>`, the page
     dictionaries' own codes, on the scanner's threads; what is left
     for the thread that takes the batches is work on dictionaries
-    (`DictUnifier`, `columnar._arrow_to_padded`). Pushed filters
+    (`DictUnifier`, `columnar.merge_dictionaries`) and the one copy
+    of the codes (`columnar.fill_padded`). Pushed filters
     compare such a column with string literals as they do a plain
     one. `file_schema` stays the files' own, for a writer that
     appends to them."""
@@ -772,18 +904,21 @@ INGEST_PREFETCH_KEY = "spark_tpu.sql.ingest.prefetch"
 class PrefetchChunkIterator:
     """Double-buffered wrapper over a ChunkIterator: a background thread
     makes chunk N+1 ready in HOST buffers (``ChunkIterator._host_next``
-    — pyarrow releases the GIL, so this genuinely overlaps the
-    consumer's work) while the consumer converts, places and launches
+    — pyarrow and numpy release the GIL, so this genuinely overlaps
+    the consumer's work) while the consumer places and launches
     chunk N. Who does what: the Arrow scanner's own threads read and
     decode Parquet pages (string columns that qualify come as the
     file's dictionary codes: ``ParquetSource``); the WORKER takes the
-    chunk's record batches off the scanner (``chunk.decode``: a wait)
-    and makes one array of each column (``chunk.unify``: dictionaries
-    unified and codes remapped for string columns, the other columns
-    concatenated: ``DictUnifier``); the CONSUMER pads each column into
-    its numpy buffer and places it (``chunk.to_device``) and launches
-    the chunk program. Bounded to ONE in-flight chunk (a size-1
-    queue), and device placement stays on the CONSUMER thread, so HBM
+    chunk's record batches off the scanner (``chunk.decode``: a wait),
+    has the string columns' codes mapped to their global dictionaries
+    (``chunk.unify``: work on dictionaries, ``DictUnifier``) and fills
+    every column's padded numpy buffer straight from the batches
+    (``chunk.convert``, a large chunk's columns on threads of their
+    own), in pooled memory that was touched before
+    (``io/host_buffers.py``); the CONSUMER only places the buffers
+    (``chunk.to_device``: one ``chunk.put`` a column) and launches the
+    chunk program. Bounded to ONE in-flight chunk (a size-1 queue),
+    and device placement stays on the CONSUMER thread, so HBM
     residency, arbiter leases and the per-chunk retry/checkpoint
     semantics of the streaming drivers are unchanged.
 
@@ -799,8 +934,9 @@ class PrefetchChunkIterator:
     failing to hide the host's work) is the ``chunk.wait`` span and,
     summed, the ``ingest_stall_ms`` counter of the process registry;
     the worker's own time stands in the inner iterator's
-    ``chunk.decode`` / ``chunk.unify`` spans, on the worker's ``tid``
-    (the benchmark's ``ingest_*_ms_p50`` metrics read them)."""
+    ``chunk.decode`` / ``chunk.unify`` / ``chunk.convert`` spans, on
+    the worker's and its column threads' ``tid`` (the benchmark's
+    ``ingest_*_ms_p50`` metrics read them)."""
 
     def __init__(self, inner: ChunkIterator, conf, recovery=None,
                  metrics=None):
@@ -890,9 +1026,7 @@ class PrefetchChunkIterator:
         # for the end of the stream too)
         with span("chunk.wait"):
             t0 = _time.perf_counter()
-            self._inner.consumer_waits = True
             kind, payload = self._queue.get()
-            self._inner.consumer_waits = False
             stall_s = _time.perf_counter() - t0
         if self._metrics is not None:
             self._metrics.counter("ingest_stall_ms").inc(
@@ -902,6 +1036,7 @@ class PrefetchChunkIterator:
             raise payload
         if payload is None:
             self._closed = True
+            self._inner.close()  # every chunk was put: nothing is out
             raise StopIteration
         return self._inner._to_device(payload)
 
@@ -931,6 +1066,10 @@ class PrefetchChunkIterator:
                     f"ingest-prefetch worker failed to exit within "
                     f"{timeout_s}s of close()")
         self._thread = None
+        # with the worker joined, what it filled and nobody put (the
+        # drained queue's chunk, the one it was blocked on) goes back
+        # to the buffer pool
+        self._inner.close()
 
 
 # ---------------------------------------------------------------------------

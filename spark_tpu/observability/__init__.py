@@ -17,8 +17,8 @@ the engine-sized analog, organized the same way:
   compile, streaming / external, ingest, dispatch with
   dispatch.launch / dispatch.sync, egress, the service's queue), the
   chunk pipeline of a streamed scan (chunk.wait, chunk.decode,
-  chunk.unify, chunk.to_device with chunk.convert / chunk.put per
-  column, chunk.launch, stream.drain) and marks (aqe_replan,
+  chunk.unify, chunk.convert per column, chunk.to_device with
+  chunk.put per column, chunk.launch, stream.drain) and marks (aqe_replan,
   aqe_overflow, retry:<action>, cancelled). On two clocks: a
   wall-clock anchor for Chrome trace-event JSON (Perfetto-loadable),
   and a ``spark_tpu.<name>`` annotation in the ``jax.profiler`` trace

@@ -65,6 +65,10 @@ METRIC_PREFIXES = (
     "ingest_chunks",   # chunks of streamed scans placed on the device
     "ingest_rows",     # their live rows
     "ingest_put_",     # ingest_put_bytes: padded bytes they device_put
+    "ingest_buffers_",  # ingest_buffers_reused / _allocated: padded
+                       # host buffers a chunk's columns were filled
+                       # into, drawn used from the process's pool /
+                       # made new (io/host_buffers.py)
     "ingest_dict_",    # ingest_dict_columns_read / _encoded: string
                        # columns of a chunk unified by dictionary /
                        # hashed row by row (io/sources.py DictUnifier)

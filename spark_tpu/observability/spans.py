@@ -5,9 +5,12 @@ lifecycle phases (`analysis`, `optimize`, `plan`, `analyze`,
 `analyze_jaxpr`, `compile`, `deserialize`, `streaming`, `external`,
 `ingest`, `dispatch` with `dispatch.launch` / `dispatch.sync`,
 `egress`, the service's `queue`), the chunk pipeline of a streamed
-scan (`chunk.wait`, `chunk.decode`, `chunk.unify`, `chunk.to_device`
-with one `chunk.convert` / `chunk.put` per column, `chunk.launch`,
-`stream.drain`) and the marks (`aqe_replan`, `aqe_overflow`,
+scan (`chunk.wait`; `chunk.decode`, `chunk.unify` and one
+`chunk.convert` per column on the thread that makes the chunk's host
+half, a large chunk's columns on threads of their own;
+`chunk.to_device` with one `chunk.put` per column; `chunk.launch`,
+`stream.drain`; a resident load leaves `chunk.convert` / `chunk.put`
+per column under `ingest`) and the marks (`aqe_replan`, `aqe_overflow`,
 `retry:<action>`, `cancelled`). Names are fixed and carry no ordinal:
 the benchmark's readers go by them (PERF.md section 3).
 

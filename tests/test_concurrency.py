@@ -707,6 +707,9 @@ class _FakeChunkSource:
     def skip_chunks(self, n):
         return 0
 
+    def close(self):
+        pass
+
 
 def test_prefetch_close_joins_worker(session):
     from spark_tpu.io.sources import PrefetchChunkIterator

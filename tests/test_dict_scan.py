@@ -295,12 +295,13 @@ def test_pushdown_on_dictionary_column(pushdown_table, op):
         sum(v for v, _ in streamed) if streamed else None)
 
 
-def test_unify_side_by_side_is_the_serial_result(monkeypatch):
-    """A chunk large enough has its columns unified on threads of
-    their own for the call: same table, same counts, none left."""
+def test_columns_side_by_side_give_the_serial_result(monkeypatch):
+    """A chunk large enough has its columns unified and filled on
+    threads of their own for the call: the same buffers as one after
+    the other, the same dictionaries, and no thread left."""
     import threading
     from spark_tpu.io import sources
-    n = sources._UNIFY_SIDE_BY_SIDE_ROWS + 5
+    n = sources._SIDE_BY_SIDE_ROWS + 5
     rng = np.random.default_rng(3)
     half = n // 2
     typed = pa.chunked_array([
@@ -316,18 +317,36 @@ def test_unify_side_by_side_is_the_serial_result(monkeypatch):
         "v": pa.chunked_array([pa.array(np.arange(half)),
                                pa.array(np.arange(half, n))]),
         "w": pa.array(rng.random(n))})
-    side, split = sources.DictUnifier().unify(table)
-    assert not [t for t in threading.enumerate()
-                if t.name.startswith("spark-tpu-ingest-unify")]
-    monkeypatch.setattr(sources, "_UNIFY_SIDE_BY_SIDE_ROWS", 1 << 62)
-    serial, split_s = sources.DictUnifier().unify(table)
-    assert side.equals(serial)
-    assert all(c.num_chunks == 1 for c in side.columns)
-    assert side.column("typed").to_pylist() == typed.to_pylist()
-    assert side.column("typed").chunk(0).dictionary.to_pylist() == [
-        "A", "N", "R", "late"]
-    for key in ("dict_columns_read", "dict_columns_encoded",
-                "concat_bytes"):
-        assert split[key] == split_s[key]
-    assert (split["dict_columns_read"], split["dict_columns_encoded"],
-            split["concat_bytes"]) == (1, 1, n * 16)
+
+    def one_chunk():
+        seen = set()
+        fill_column = ChunkIterator._fill_column
+
+        def spy(self, *args):
+            seen.add(threading.current_thread().name.rsplit("_", 1)[0])
+            return fill_column(self, *args)
+
+        monkeypatch.setattr(ChunkIterator, "_fill_column", spy)
+        it = ChunkIterator(iter(table.to_batches()), n)
+        (batch,) = list(it)
+        assert not [t for t in threading.enumerate()
+                    if t.name.startswith("spark-tpu-ingest-column")]
+        return batch, it.dictionaries, seen
+
+    side, side_dicts, threads = one_chunk()
+    assert threads == {"spark-tpu-ingest-column"}
+    monkeypatch.setattr(sources, "_SIDE_BY_SIDE_ROWS", 1 << 62)
+    serial, serial_dicts, threads = one_chunk()
+    assert threads == {threading.current_thread().name.rsplit("_", 1)[0]}
+    for name in table.column_names:
+        assert np.array_equal(np.asarray(side.columns[name].data),
+                              np.asarray(serial.columns[name].data)), name
+        assert side.columns[name].validity is None
+    assert side_dicts["typed"].to_pylist() == ["A", "N", "R", "late"]
+    assert {k: v.to_pylist() for k, v in side_dicts.items()} \
+        == {k: v.to_pylist() for k, v in serial_dicts.items()}
+    out = side.to_arrow()
+    assert out.column("typed").to_pylist() == typed.to_pylist()
+    assert out.column("plain").to_pylist() == \
+        table.column("plain").to_pylist()
+    assert out.column("v").to_pylist() == list(range(n))

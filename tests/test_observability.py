@@ -732,8 +732,9 @@ PROFILE_KEY = "spark_tpu.sql.profile.dir"
 STREAM_ROWS, STREAM_CHUNK = 5000, 1024
 #: per chunk, on the consumer's thread and under `streaming`
 CONSUMER_CHUNK_SPANS = ("chunk.to_device", "chunk.launch")
-#: per chunk, on whichever thread decodes
-HOST_CHUNK_SPANS = ("chunk.decode", "chunk.unify")
+#: per chunk, on whichever thread makes the chunk's host half (the
+#: chunks here are too small for a thread a column)
+HOST_CHUNK_SPANS = ("chunk.decode", "chunk.unify", "chunk.convert")
 
 
 @pytest.fixture(scope="module")
@@ -792,19 +793,21 @@ def test_stream_span_tree(session, stream_table, prefetch):
     def named(name):
         return [s for s in spans if s.name == name]
 
-    # exactly the tree: per chunk one of each, a convert and a put per
-    # column under each to_device, one drain, and with prefetch a wait
-    # per chunk and one for the end of the stream
+    # exactly the tree: per chunk one of each and a convert per column
+    # beside decode and unify, a put per column under each to_device,
+    # one drain, and with prefetch a wait per chunk and one for the
+    # end of the stream
     for name in CONSUMER_CHUNK_SPANS + HOST_CHUNK_SPANS:
-        assert len(named(name)) == n_chunks, (name, len(named(name)))
+        per_chunk = 3 if name == "chunk.convert" else 1
+        assert len(named(name)) == per_chunk * n_chunks, name
         assert all(s.parent == streaming.id for s in named(name))
     assert len(named("stream.drain")) == 1
     assert len(named("chunk.wait")) == (n_chunks + 1 if prefetch else 0)
-    for s in named("chunk.convert") + named("chunk.put"):
-        assert by_id[s.parent].name == "chunk.to_device"
     for td in named("chunk.to_device"):
         kids = [s.name for s in spans if s.parent == td.id]
-        assert sorted(kids) == ["chunk.convert"] * 3 + ["chunk.put"] * 3
+        assert kids == ["chunk.put"] * 3
+    assert sorted(s.attrs["column"] for s in named("chunk.convert")) \
+        == sorted(s.attrs["column"] for s in named("chunk.put"))
     assert {s.name for s in spans if s.name.startswith(
         ("chunk.", "stream."))} == {
         "chunk.decode", "chunk.unify", "chunk.to_device", "chunk.convert",
@@ -813,8 +816,8 @@ def test_stream_span_tree(session, stream_table, prefetch):
     assert [s.attrs["chunk"] for s in sorted(
         named("chunk.launch"), key=lambda s: s.id)] == list(range(n_chunks))
 
-    # threads: the consumer runs all but decode and unify, which the
-    # prefetch worker runs when there is one
+    # threads: the consumer runs all but decode, unify and convert,
+    # which the prefetch worker runs when there is one
     host_tids = {s.tid for n in HOST_CHUNK_SPANS for s in named(n)}
     for s in spans:
         if s.name not in HOST_CHUNK_SPANS:
@@ -839,6 +842,9 @@ def test_stream_span_tree(session, stream_table, prefetch):
     assert grew["ingest_put_bytes"] == n_chunks * STREAM_CHUNK * (4 + 8 + 8)
     assert grew["ingest_put_bytes"] == sum(
         s.attrs["bytes"] for s in named("chunk.put"))
+    # what was filled is what was put
+    assert grew["ingest_put_bytes"] == sum(
+        s.attrs["bytes"] for s in named("chunk.convert"))
     waited = sum(s.dur_ms for s in named("chunk.wait"))
     # one interval read twice, the span a few clock readings wider
     assert abs(waited - grew["ingest_stall_ms"]) <= 0.05 * (n_chunks + 1)
@@ -862,7 +868,8 @@ def test_stream_counts_how_string_columns_arrived(session, stream_table,
     """A string column the Parquet reader hands over as the file's
     codes is unified by dictionary, an in-memory table's strings are
     hashed row by row: one counter each, on `/metrics`, and the
-    `chunk.unify` span says what its time went to."""
+    `chunk.unify` span says what its time went to, a `chunk.convert`
+    what it filled."""
     import pyarrow as pa
     from spark_tpu.io.sources import ArrowTableSource
     from spark_tpu.observability.metrics import (parse_prometheus_text,
@@ -880,11 +887,16 @@ def test_stream_counts_how_string_columns_arrived(session, stream_table,
     unify = [s for s in qe.spans.spans if s.name == "chunk.unify"]
     assert len(unify) == n_chunks
     for s in unify:
-        assert {"chunk", "rows", "dict_ms", "concat_ms",
-                "concat_bytes"} <= set(s.attrs)
-        # an int64 and a decimal128 column were concatenated
-        assert s.attrs["concat_bytes"] == s.attrs["rows"] * (8 + 16)
-        assert 0 < s.attrs["dict_ms"] + s.attrs["concat_ms"] <= s.dur_ms
+        assert {"chunk", "rows", "dict_ms"} <= set(s.attrs)
+        assert 0 < s.attrs["dict_ms"] <= s.dur_ms
+    # no column is concatenated any more: each is filled where it is
+    # put, a code 4 B, an int64 and a decimal's unscaled int64 8 B
+    filled = {}
+    for s in qe.spans.spans:
+        if s.name == "chunk.convert":
+            filled.setdefault(s.attrs["column"], set()).add(s.attrs["bytes"])
+    assert filled == {"k": {4 * STREAM_CHUNK}, "v": {8 * STREAM_CHUNK},
+                      "d": {8 * STREAM_CHUNK}}
     assert any(d["name"] == "chunk.unify" and "dict_ms" in d["attrs"]
                for d in qe.spans.to_dicts())
 
