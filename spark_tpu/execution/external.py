@@ -37,18 +37,13 @@ from __future__ import annotations
 import os
 from typing import List, Optional, Tuple
 
-import jax
 import numpy as np
 import pyarrow as pa
 import pyarrow.compute as pc
 
-from ..columnar import Batch, bucket_capacity
-from ..observability.spans import span
+from ..columnar import Batch
 from ..plan import physical as P
-from .recovery import ChunkRetrier
-from .streaming_agg import (CHUNK_ROWS_KEY, _CHUNKABLE_JOINS,
-                            _replay_chain, apply_join_overflow,
-                            prepare_chunk_joins)
+from .streaming_agg import drive_host_partials, walk_chain
 
 
 def _match_shape(plan: P.PhysicalPlan):
@@ -62,20 +57,7 @@ def _match_shape(plan: P.PhysicalPlan):
     if isinstance(node, P.SortExec):
         sort = node
         node = node.child
-    chain: List[P.PhysicalPlan] = []
-    while True:
-        if isinstance(node, (P.ProjectExec, P.FilterExec)):
-            chain.append(node)
-            node = node.children[0]
-        elif isinstance(node, P.RuntimeFilterExec):
-            # pure pruning optimization: safe to drop in the chunked
-            # replay (the join re-checks every key)
-            node = node.children[0]
-        elif isinstance(node, P.JoinExec) and node.how in _CHUNKABLE_JOINS:
-            chain.append(node)
-            node = node.children[0]
-        else:
-            break
+    chain, node = walk_chain(node)
     if not isinstance(node, P.ScanExec):
         return None
     return limit, sort, chain, node
@@ -130,93 +112,22 @@ def try_external_collect(session, plan: P.PhysicalPlan, conf,
         if host_keys is None:
             return None
 
-    from ..io.sources import maybe_prefetch
-    chunk_rows = int(conf.get(CHUNK_ROWS_KEY))
-    chunks = maybe_prefetch(
-        leaf.source.load_chunks(leaf.required_columns,
-                                leaf.pushed_filters, chunk_rows),
-        conf, recovery)
-    first = next(iter(chunks), None)
-    if first is None:
-        return None
-
-    joins, builds, _saved = prepare_chunk_joins(
-        chain, conf, first.capacity, recovery)
-
     topn = sort is not None and limit is not None
 
-    def make_update():
-        from .streaming_agg import conf_compile_suffix
-        key = (f"ext_collect:{plan.describe()}:{chunk_rows}"
-               + conf_compile_suffix(conf))
-        fn = cache.get(key) if cache is not None else None
-        if fn is None:
-            def update(b, bb):
-                ctx = P.ExecContext(conf)
-                b = _replay_chain(chain, ctx, b, bb)
-                if topn:
-                    # fuse the chunk's top-n into the device program:
-                    # sorting compacts the selection, limit masks to n
-                    b = sort.compute(ctx, [b])
-                    b = limit.compute(ctx, [b])
-                return b, ctx.flags, ctx.metrics
+    def tail(ctx, b):
+        if topn:
+            # fuse the chunk's top-n into the device program:
+            # sorting compacts the selection, limit masks to n
+            b = sort.compute(ctx, [b])
+            b = limit.compute(ctx, [b])
+        return b
 
-            fn = jax.jit(update)
-            if cache is not None:
-                cache[key] = fn
-        return fn
-
-    update_fn = make_update()
-
-    def run_chunk(b):
-        nonlocal update_fn
-        for _attempt in range(8):
-            out, flags, metrics = update_fn(b, builds)
-            flags, metrics = jax.device_get((flags, metrics))
-            if not apply_join_overflow(flags, metrics, joins):
-                return out
-            # describe() changed with the grown caps: re-jit, retry
-            update_fn = make_update()
-        raise RuntimeError("external-collect join capacity did not "
-                           "converge")
-
-    # chunk-granular retry (execution/recovery.py): a transient fault
-    # replays only the failed chunk — nothing already spilled re-runs
-    retrier = ChunkRetrier(conf, recovery)
-    spilled: List[pa.Table] = []
-    total_rows = 0
-    ci = 0
-    b = first
-    try:
-        while b is not None:
-            with span("chunk.launch", chunk=ci):  # the host pull included
-                t = retrier.run(lambda bb=b: run_chunk(bb).to_arrow(),
-                                chunk=ci)
-            spilled.append(t)
-            total_rows += t.num_rows
-            if limit is not None and sort is None \
-                    and total_rows >= limit.n:
-                break  # plain LIMIT: enough live rows spilled
-            ci += 1
-            b = next(chunks, None)  # ingest un-retried: see ChunkRetrier
-    finally:
-        if hasattr(chunks, "close"):
-            # early LIMIT break, a fault, or a cancellation unwinding
-            # mid-stream: release + JOIN the prefetch worker (it may
-            # hold one decoded chunk against a full queue) — no ingest
-            # daemon may outlive its query
-            chunks.close()
-
-    with span("stream.drain"):  # the host's merge of the spilled chunks
-        table = pa.concat_tables(spilled, promote_options="permissive")
+    def merge(table):  # the host's merge of the spilled chunks
         if topn:
             # tournament final: one small device sort+limit over the
             # concatenated per-chunk top-n spills
-            ctx = P.ExecContext(conf)
-            b = Batch.from_arrow(table)
-            b = sort.compute(ctx, [b])
-            b = limit.compute(ctx, [b])
-            return b.to_arrow()
+            return tail(P.ExecContext(conf), Batch.from_arrow(table)) \
+                .to_arrow()
         if sort is not None:
             keys, placement = host_keys
             idx = pc.sort_indices(
@@ -226,6 +137,12 @@ def try_external_collect(session, plan: P.PhysicalPlan, conf,
         if limit is not None:
             return table.slice(0, limit.n)
         return table
+
+    return drive_host_partials(
+        leaf, conf, cache, recovery, "ext_collect", plan, chain, tail,
+        merge,
+        # plain LIMIT: stop once enough live rows have spilled
+        stop_rows=limit.n if limit is not None and sort is None else None)
 
 
 # ---------------------------------------------------------------------------

@@ -10,8 +10,9 @@ completed shuffle files survive downstream failures (the RDD lineage
 model of Zaharia et al., NSDI'12). This module restores that
 granularity at the three seams this engine has:
 
-- **ChunkRetrier** — per-chunk retry inside the streaming drivers
-  (`streaming_agg.py` scan/spill/mesh variants and `external.py`).
+- **ChunkRetrier** — per-chunk retry inside the one chunk driver
+  (`chunk_stream.drive`, under the `streaming_agg.py` scan/spill/mesh
+  variants and `external.py`).
   The carry state (accumulator tables, chunk cursor) is only advanced
   after a chunk succeeds, so a TRANSIENT/UNAVAILABLE fault replays
   exactly the failed chunk —

@@ -18,18 +18,16 @@ to whole-input execution.
 
 from __future__ import annotations
 
-from typing import List, Optional, Sequence, Tuple
+from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from .. import types as T
 from ..columnar import Batch, Column, bucket_capacity
-from ..observability.spans import span
 from ..plan import physical as P
-from . import aggregate as agg_kernels
-from .recovery import CHECKPOINT_EVERY_KEY, ChunkRetrier
+from .chunk_stream import Carry, drive, run_through_joins
+from .recovery import CHECKPOINT_EVERY_KEY
 
 CHUNK_ROWS_KEY = "spark_tpu.sql.execution.streamingChunkRows"
 
@@ -66,14 +64,13 @@ def conf_compile_suffix(conf) -> str:
 _CHUNKABLE_JOINS = ("inner", "left", "left_semi", "left_anti")
 
 
-def find_streamable_chain(agg: "P.HashAggregateExec",
-                          allow_joins: bool = True
-                          ) -> Optional[Tuple[List, P.LeafExec]]:
-    """agg.child must be a chain of Project/Filter — and, when
-    `allow_joins`, probe-side-chunkable joins (the build side is an
-    independent subtree, materialized once) — over a single leaf."""
+def walk_chain(node: P.PhysicalPlan, allow_joins: bool = True
+               ) -> Tuple[List, P.PhysicalPlan]:
+    """The chain of Project/Filter — and, when `allow_joins`,
+    probe-side-chunkable joins (the build side is an independent
+    subtree, materialized once) — from `node` down, and the node under
+    it."""
     chain = []
-    node = agg.child
     while True:
         if isinstance(node, (P.ProjectExec, P.FilterExec)):
             chain.append(node)
@@ -88,9 +85,16 @@ def find_streamable_chain(agg: "P.HashAggregateExec",
             chain.append(node)
             node = node.children[0]  # continue down the probe side
         else:
-            break
-    if isinstance(node, (P.RangeExec, P.ScanExec)):
-        return chain, node
+            return chain, node
+
+
+def find_streamable_chain(agg: "P.HashAggregateExec",
+                          allow_joins: bool = True
+                          ) -> Optional[Tuple[List, P.LeafExec]]:
+    """agg.child must be a `walk_chain` over a single leaf."""
+    chain, leaf = walk_chain(agg.child, allow_joins)
+    if isinstance(leaf, (P.RangeExec, P.ScanExec)):
+        return chain, leaf
     return None
 
 
@@ -102,41 +106,6 @@ def _replay_chain(chain: List, ctx, batch: Batch,
         else:
             batch = op.compute(ctx, [batch])
     return batch
-
-
-def apply_join_overflow(flags, metrics, joins) -> bool:
-    """Parse one chunk update's `join_overflow_`/`join_nonunique_`/
-    `join_hashsat_` flag families and apply capacity growth /
-    unique-build / hash-kernel fallbacks to `joins`. Returns True when
-    anything changed — the caller must re-jit and retry the SAME chunk
-    against the pre-update state. The ONE copy of the chunked-join AQE
-    protocol, shared by every chunk driver (direct stream, partial
-    spill, external collect)."""
-    overflow = [k for k, v in flags.items()
-                if k.startswith(("join_overflow_", "join_nonunique_",
-                                 "join_hashsat_"))
-                and bool(v)]
-    if not overflow:
-        return False
-    for k in overflow:
-        if k.startswith("join_nonunique_"):
-            tag = k[len("join_nonunique_"):]
-            for j in joins:
-                if j.tag == tag:
-                    j.unique_build = False
-            continue
-        if k.startswith("join_hashsat_"):
-            tag = k[len("join_hashsat_"):]
-            for j in joins:
-                if j.tag == tag:
-                    j.hash_fallback = False
-            continue
-        tag = k[len("join_overflow_"):]
-        total = int(metrics[f"join_rows_{tag}"])
-        for j in joins:
-            if j.tag == tag:
-                j.out_cap = bucket_capacity(max(total, 8))
-    return True
 
 
 def prepare_chunk_joins(chain: List, conf, first_cap: int, recovery=None):
@@ -311,139 +280,178 @@ def stream_scan_aggregate(agg: "P.HashAggregateExec", chain: List,
     (uniform bucketed capacity so the update step compiles once) while the
     device reduces — the double-buffered host->HBM pipeline of SURVEY.md
     section 2.5 'Async/overlap' (io/sources.py PrefetchChunkIterator
-    decodes chunk N+1 on a background thread while chunk N computes)."""
-    from ..io.sources import maybe_prefetch
+    decodes chunk N+1 on a background thread while chunk N computes).
+    The carry: accumulator tables on the device."""
     chunk_rows = int(conf.get(CHUNK_ROWS_KEY))
-    chunks = maybe_prefetch(
-        leaf.source.load_chunks(leaf.required_columns,
-                                leaf.pushed_filters, chunk_rows),
-        conf, recovery)
-    try:
-        return _stream_scan_aggregate_inner(agg, chain, conf, cache,
-                                            recovery, chunks,
-                                            chunk_rows)
-    finally:
-        # deterministic worker shutdown on EVERY exit — normal
-        # exhaustion, fallback `return None`, or an exception (fault,
-        # cancellation) unwinding mid-stream: no prefetch daemon may
-        # outlive its query (lockwatch assert_no_thread_leak)
-        if hasattr(chunks, "close"):
-            chunks.close()
 
+    def begin(first, dictionaries):
+        joins, builds, saved_caps = prepare_chunk_joins(
+            chain, conf, first.capacity, recovery)
 
-def _stream_scan_aggregate_inner(agg, chain, conf, cache, recovery,
-                                 chunks, chunk_rows):
-    first = next(iter(chunks), None)
-    if first is None:
-        return None
+        def make_update():
+            key = (f"stream_scan:{agg.describe()}:{chunk_rows}"
+                   + conf_compile_suffix(conf))
+            bundle = cache.get(key) if cache is not None else None
+            if bundle is None:
+                ctx = P.ExecContext(conf)
+                probe = _replay_chain(chain, ctx, first, builds)
+                prep0 = agg.prepare_direct(probe, conf)
+                if prep0 is None:
+                    return None
 
-    joins, builds, saved_caps = prepare_chunk_joins(
-        chain, conf, first.capacity, recovery)
+                if joins:
+                    def update(tables, b, bb, row_base):
+                        ctx = P.ExecContext(conf)
+                        b = _replay_chain(chain, ctx, b, bb)
+                        new = agg.direct_update_tables(
+                            tables, b, prep0, conf, row_base=row_base)
+                        return new, ctx.flags, ctx.metrics
 
-    def make_update():
-        key = (f"stream_scan:{agg.describe()}:{chunk_rows}"
-               + conf_compile_suffix(conf))
-        bundle = cache.get(key) if cache is not None else None
+                    # no donation: a join-capacity overflow must re-run
+                    # the SAME chunk against the pre-update tables
+                    bundle = (prep0, jax.jit(update))
+                else:
+                    def update(tables, b, row_base):
+                        ctx = P.ExecContext(conf)
+                        b = _replay_chain(chain, ctx, b)
+                        return agg.direct_update_tables(
+                            tables, b, prep0, conf, row_base=row_base)
+
+                    # join-free hot path: donate tables, no per-chunk
+                    # host sync — the double-buffered host->HBM overlap
+                    bundle = (prep0, jax.jit(update, donate_argnums=(0,)))
+                if cache is not None:
+                    cache[key] = bundle
+            return bundle
+
+        bundle = make_update()
         if bundle is None:
-            ctx = P.ExecContext(conf)
-            probe = _replay_chain(chain, ctx, first, builds)
-            prep0 = agg.prepare_direct(probe, conf)
-            if prep0 is None:
-                return None
+            for j in joins:  # leave the whole-input fallback's caps alone
+                j.out_cap = saved_caps[j.tag]
+            return None
+        prep, update_fn = bundle
+        tables = agg.direct_init_tables(prep)
 
-            if joins:
-                def update(tables, b, bb, row_base):
-                    ctx = P.ExecContext(conf)
-                    b = _replay_chain(chain, ctx, b, bb)
-                    new = agg.direct_update_tables(tables, b, prep0, conf,
-                                                   row_base=row_base)
-                    return new, ctx.flags, ctx.metrics
+        # running row base for position-packed aggregates: each chunk's
+        # stride covers the largest post-replay capacity (join out_caps
+        # only grow, so bases stay collision-free even across mid-run
+        # re-jits)
+        row_base = 0
 
-                # no donation: a join-capacity overflow must re-run the
-                # SAME chunk against the pre-update tables
-                bundle = (prep0, jax.jit(update))
-            else:
-                def update(tables, b, row_base):
-                    ctx = P.ExecContext(conf)
-                    b = _replay_chain(chain, ctx, b)
-                    return agg.direct_update_tables(tables, b, prep0, conf,
-                                                    row_base=row_base)
+        def chunk_stride(b):
+            return max([b.capacity] + [j.out_cap or 0 for j in joins])
 
-                # join-free hot path: donate tables, no per-chunk host
-                # sync — the double-buffered host->HBM overlap
-                bundle = (prep0, jax.jit(update, donate_argnums=(0,)))
-            if cache is not None:
-                cache[key] = bundle
-        return bundle
+        def check_bound(b):
+            if row_base + chunk_stride(b) >= (1 << 30) and \
+                    any(a.func.uses_row_base for a in agg.agg_exprs):
+                raise RuntimeError(
+                    "first/last over a streamed scan exceeds the 2^30 "
+                    "packed-position bound")
 
-    bundle = make_update()
-    if bundle is None:
-        for j in joins:  # leave the whole-input fallback's caps alone
-            j.out_cap = saved_caps[j.tag]
-        return None
-    prep, update_fn = bundle
-
-    check_dicts = _dict_growth_guard(agg, prep)
-    tables = agg.direct_init_tables(prep)
-
-    # running row base for position-packed aggregates: each chunk's
-    # stride covers the largest post-replay capacity (join out_caps only
-    # grow, so bases stay collision-free even across mid-run re-jits)
-    row_base = 0
-
-    def chunk_stride(b):
-        return max([b.capacity] + [j.out_cap or 0 for j in joins])
-
-    def check_bound(b):
-        if row_base + chunk_stride(b) >= (1 << 30) and \
-                any(a.func.uses_row_base for a in agg.agg_exprs):
-            raise RuntimeError(
-                "first/last over a streamed scan exceeds the 2^30 "
-                "packed-position bound")
-
-    def run_chunk(tables, b):
-        nonlocal update_fn
-        check_bound(b)
-        base = jnp.asarray(row_base, jnp.int64)
-        if not joins:
-            return update_fn(tables, b, base)
-        for _attempt in range(8):
-            new, flags, metrics = update_fn(tables, b, builds, base)
-            flags, metrics = jax.device_get((flags, metrics))
-            if not apply_join_overflow(flags, metrics, joins):
-                return new
-            # out_cap is part of describe(): re-jit under the new key,
-            # then retry the SAME chunk against the pre-update tables
+        def regrow(b):
+            # out_cap is part of describe(): re-jit under the new key
             # (the grown out_cap widens the position stride — re-check)
+            nonlocal update_fn
             _prep2, update_fn = make_update()
             check_bound(b)
-        raise RuntimeError("streamed join capacity did not converge")
 
-    # chunk-granular retry (execution/recovery.py): carry state only
-    # advances after a chunk succeeds, so a TRANSIENT fault replays
-    # exactly the failed chunk against the pre-chunk tables
-    retrier = ChunkRetrier(conf, recovery)
-    ci = 0
-    b = first
-    while b is not None:
-        # the launch is an enqueue: it returns once the chunk's program
-        # is dispatched, not when the device has run it
-        with span("chunk.launch", chunk=ci):
-            check_dicts(b)
-            tables = retrier.run(lambda bb=b: run_chunk(tables, bb),
-                                 chunk=ci)
-        row_base += chunk_stride(b)
-        ci += 1
-        b = next(chunks, None)  # ingest un-retried: see ChunkRetrier
+        def fold(b, ci):
+            nonlocal tables
+            check_bound(b)
+            base = jnp.asarray(row_base, jnp.int64)
+            if not joins:
+                new = update_fn(tables, b, base)
+            else:
+                new = run_through_joins(
+                    lambda: update_fn(tables, b, builds, base),
+                    lambda: regrow(b), joins, "stream_scan")
+            # the step's last act, so still inside chunk.launch: letting
+            # go of the donated tables gives up the interpreter lock, and
+            # beside the filling threads it is a while in coming back
+            tables = new
 
-    dict_overrides = dict(chunks.dictionaries) if hasattr(
-        chunks, "dictionaries") else {}
-    # the stream's one wait for the device: how far transfers and
-    # chunk programs lag the host once the last chunk is launched (the
-    # stage above would wait for the same arrays at its first sync)
-    with span("stream.drain"):
-        return jax.block_until_ready(agg.direct_finalize_tables(
-            tables, prep, dict_overrides or None))
+        def took(_new, b, ci):
+            nonlocal row_base
+            row_base += chunk_stride(b)
+
+        def finish(drain):
+            with drain:
+                return jax.block_until_ready(agg.direct_finalize_tables(
+                    tables, prep, dictionaries() or None))
+
+        return Carry(fold, took, finish,
+                     check=_dict_growth_guard(agg, prep))
+
+    return drive(leaf, chunk_rows, conf, recovery, begin)
+
+
+def drive_host_partials(leaf, conf, cache, recovery, driver: str, node,
+                        chain: List, tail, finish, *, seed=(), start=0,
+                        stop_rows=None):
+    """`drive` with the carry of the spill aggregate and of external
+    collect: each chunk's output pulled to host Arrow buffers (host RAM
+    as the spill tier), concatenated at the end. They differ by what the
+    chunk program does after the chain, `tail(ctx, b)`; by `finish`,
+    what is made of the concatenated table under the drain; by the
+    `seed` partials a resume from `start` prepends; and by the plain
+    LIMIT's `stop_rows`. The program stands in the stage cache under
+    `driver` and `node.describe()`, which moves with grown join caps."""
+    import pyarrow as pa
+    chunk_rows = int(conf.get(CHUNK_ROWS_KEY))
+    spilled: List = list(seed)
+
+    def concat():
+        return pa.concat_tables(spilled, promote_options="permissive")
+
+    def begin(first, dictionaries):
+        joins, builds, _saved = prepare_chunk_joins(
+            chain, conf, first.capacity, recovery)
+        update_fn = None
+        rows = 0
+
+        def make_update():
+            nonlocal update_fn
+            key = (f"{driver}:{node.describe()}:{chunk_rows}"
+                   + conf_compile_suffix(conf))
+            update_fn = cache.get(key) if cache is not None else None
+            if update_fn is None:
+                # a NEW function for each program: jax.jit keeps its
+                # trace by the function's identity, and a join's grown
+                # out_cap is read only while tracing
+                def update(b, bb):
+                    ctx = P.ExecContext(conf)
+                    out = tail(ctx, _replay_chain(chain, ctx, b, bb))
+                    return out, ctx.flags, ctx.metrics
+
+                update_fn = jax.jit(update)
+                if cache is not None:
+                    cache[key] = update_fn
+
+        def fold(b, ci):
+            # dictionary-encoded columns decode to strings in to_arrow, so
+            # per-chunk dictionaries unify value-wise in the concat. The
+            # host pull rides inside the retried step: a flake during
+            # to_arrow replays only this chunk (not yet spilled)
+            return run_through_joins(lambda: update_fn(b, builds),
+                                     make_update, joins, driver).to_arrow()
+
+        def took(table, b, ci):
+            nonlocal rows
+            spilled.append(table)
+            rows += table.num_rows
+            return stop_rows is not None and rows >= stop_rows
+
+        def drained(drain):
+            with drain:
+                return finish(concat())
+
+        make_update()
+        return Carry(fold, took, drained)
+
+    # empty: a resume that landed exactly at end-of-stream — the seed
+    # checkpoint already covers every chunk
+    return drive(leaf, chunk_rows, conf, recovery, begin,
+                 lambda: concat() if spilled else None, start=start)
 
 
 def stream_scan_aggregate_spill(agg: "P.HashAggregateExec", chain: List,
@@ -466,105 +474,44 @@ def stream_scan_aggregate_spill(agg: "P.HashAggregateExec", chain: List,
     mesh stream single-device: `skip_chunks` advances the chunk cursor
     past what the checkpoint already covers, and `seed_partials`
     prepends the checkpointed partial tables to the spill list."""
-    from ..io.sources import maybe_prefetch
-
-    chunk_rows = int(conf.get(CHUNK_ROWS_KEY))
-    chunks = maybe_prefetch(
-        leaf.source.load_chunks(leaf.required_columns,
-                                leaf.pushed_filters, chunk_rows),
-        conf, recovery)
-    try:
-        return _stream_scan_aggregate_spill_inner(
-            agg, chain, conf, cache, recovery, skip_chunks,
-            seed_partials, chunks, chunk_rows)
-    finally:
-        # join the prefetch worker on every exit (see
-        # stream_scan_aggregate): a cancelled/deadlined query must not
-        # leak its ingest daemon
-        if hasattr(chunks, "close"):
-            chunks.close()
-
-
-def _stream_scan_aggregate_spill_inner(agg, chain, conf, cache, recovery,
-                                       skip_chunks, seed_partials,
-                                       chunks, chunk_rows):
     import copy
-    import pyarrow as pa
-    if skip_chunks:
-        if not hasattr(chunks, "skip_chunks") or \
-                chunks.skip_chunks(skip_chunks) < skip_chunks:
-            return None  # stream shorter than the checkpoint cursor
-    first = next(iter(chunks), None)
-
     partial = copy.copy(agg)
     partial.mode = "partial"
     # num_segments falls back to the post-replay batch capacity: a chunk
     # can never have more groups than rows, so the per-chunk partial
     # needs no overflow retry of its own
     partial.est_groups = None
+    # the join capacities the plan came with go back on at the end
+    planned = [(j, j.out_cap) for j in chain
+               if isinstance(j, P.JoinExec) and j.out_cap is not None]
 
-    if first is None:
-        if seed_partials:
-            # resume landed exactly at end-of-stream: the checkpoint
-            # already covers every chunk
-            return pa.concat_tables(list(seed_partials),
-                                    promote_options="permissive"), partial
+    def finish(table):
+        for j, cap in planned:
+            j.out_cap = cap
+        return table
+
+    table = drive_host_partials(
+        leaf, conf, cache, recovery, "stream_spill", agg, chain,
+        lambda ctx, b: partial.compute(ctx, [b]), finish,
+        seed=seed_partials or (), start=int(skip_chunks))
+    return None if table is None else (table, partial)
+
+
+def _spillable_scan(agg: "P.HashAggregateExec"):
+    """(chain, leaf) when `agg` can stream through the partial-spill
+    driver: a complete-mode aggregate whose accumulators decompose, over
+    a chain down to a chunkable scan; else None."""
+    if agg.mode != "complete":
         return None
-
-    joins, builds, saved_caps = prepare_chunk_joins(
-        chain, conf, first.capacity, recovery)
-
-    def make_update():
-        key = (f"stream_spill:{agg.describe()}:{chunk_rows}"
-               + conf_compile_suffix(conf))
-        fn = cache.get(key) if cache is not None else None
-        if fn is None:
-            def update(b, bb):
-                ctx = P.ExecContext(conf)
-                b = _replay_chain(chain, ctx, b, bb)
-                out = partial.compute(ctx, [b])
-                return out, ctx.flags, ctx.metrics
-
-            fn = jax.jit(update)
-            if cache is not None:
-                cache[key] = fn
-        return fn
-
-    update_fn = make_update()
-
-    def run_chunk(b):
-        nonlocal update_fn
-        for _attempt in range(8):
-            out, flags, metrics = update_fn(b, builds)
-            flags, metrics = jax.device_get((flags, metrics))
-            if not apply_join_overflow(flags, metrics, joins):
-                return out
-            # describe() changed with the grown caps: re-jit and retry
-            # the SAME chunk (partials for it were not yet spilled)
-            update_fn = make_update()
-        raise RuntimeError("spilled join capacity did not converge")
-
-    # spill each chunk's compacted partial to host; dictionary-encoded
-    # group keys decode to strings here, so per-chunk dictionaries unify
-    # value-wise in the concat (no shared-encoding requirement). The
-    # host pull rides inside the retried step: a flake during to_arrow
-    # replays only this chunk (its partial was not yet spilled).
-    retrier = ChunkRetrier(conf, recovery)
-    spilled: List = list(seed_partials or [])
-    ci = int(skip_chunks)
-    b = first
-    while b is not None:
-        with span("chunk.launch", chunk=ci):  # the host pull included
-            spilled.append(retrier.run(
-                lambda bb=b: run_chunk(bb).to_arrow(), chunk=ci))
-        ci += 1
-        b = next(chunks, None)  # ingest un-retried: see ChunkRetrier
-    for j in joins:
-        j.out_cap = saved_caps[j.tag] if saved_caps[j.tag] is not None \
-            else j.out_cap
-    with span("stream.drain"):
-        table = pa.concat_tables(spilled, promote_options="permissive")
-    return table, partial
+    if any(a.func.uses_row_base for a in agg.agg_exprs):
+        return None  # packed-position aggs need whole-input row order
+    if any(getattr(a.func, "positional", False) for a in agg.agg_exprs):
+        return None  # no accumulator decomposition: whole-input only
+    found = find_streamable_chain(agg)
+    if found is None or not isinstance(found[1], P.ScanExec) or \
+            not hasattr(found[1].source, "load_chunks"):
+        return None
+    return found
 
 
 def try_stream_aggregate_spill(agg: "P.HashAggregateExec", conf,
@@ -578,23 +525,12 @@ def try_stream_aggregate_spill(agg: "P.HashAggregateExec", conf,
     HBM pool (UnifiedMemoryManager.scala:49's execution-pool analog,
     now genuinely shared across concurrent queries)."""
     from ..service.arbiter import admit_scan_resident, out_of_core_active
-    if not out_of_core_active(conf) or agg.mode != "complete":
+    if not out_of_core_active(conf):
         return None
-    if any(a.func.uses_row_base for a in agg.agg_exprs):
-        return None  # packed-position aggs need whole-input row order
-    if any(getattr(a.func, "positional", False) for a in agg.agg_exprs):
-        return None  # no accumulator decomposition: whole-input only
-    found = find_streamable_chain(agg)
-    if found is None:
+    found = _spillable_scan(agg)
+    if found is None or admit_scan_resident(conf, found[1]):
         return None
-    chain, leaf = found
-    if not isinstance(leaf, P.ScanExec) or \
-            not hasattr(leaf.source, "load_chunks"):
-        return None
-    if admit_scan_resident(conf, leaf):
-        return None
-    return stream_scan_aggregate_spill(agg, chain, leaf, conf, cache,
-                                       recovery)
+    return stream_scan_aggregate_spill(agg, *found, conf, cache, recovery)
 
 
 def _dict_growth_guard(agg: "P.HashAggregateExec", prep):
@@ -659,6 +595,15 @@ def _with_dict_overrides(batch: Batch, dict_overrides: dict) -> Batch:
     return Batch(cols, batch.selection)
 
 
+def _record_restore(recovery, ck, **extra) -> None:
+    """A resume from checkpoint `ck` is running: record it, with the
+    chunks it replays."""
+    replayed = recovery.restore_replayed(ck.key, ck.cursor)
+    recovery.record("checkpoint_restore", None, cursor=int(ck.cursor),
+                    ckpt_rows=int(ck.table.num_rows),
+                    chunks_replayed=replayed, **extra)
+
+
 def resume_from_mesh_checkpoint(agg: "P.HashAggregateExec", conf,
                                 cache: Optional[dict] = None,
                                 recovery=None):
@@ -671,33 +616,19 @@ def resume_from_mesh_checkpoint(agg: "P.HashAggregateExec", conf,
     stream_scan_aggregate_spill, or None when no checkpoint applies."""
     if recovery is None or not recovery.checkpoints:
         return None
-    if agg.mode != "complete":
-        return None
-    if any(a.func.uses_row_base for a in agg.agg_exprs):
-        return None  # never checkpointed (position packing is per-run)
-    if any(getattr(a.func, "positional", False) for a in agg.agg_exprs):
-        return None
-    found = find_streamable_chain(agg)
+    found = _spillable_scan(agg)
     if found is None:
         return None
-    chain, leaf = found
-    if not isinstance(leaf, P.ScanExec) or \
-            not hasattr(leaf.source, "load_chunks"):
-        return None
     chunk_rows = int(conf.get(CHUNK_ROWS_KEY))
-    ck = recovery.get_checkpoint(checkpoint_key(agg, leaf, chunk_rows))
+    ck = recovery.get_checkpoint(checkpoint_key(agg, found[1], chunk_rows))
     if ck is None:
         return None
-    out = stream_scan_aggregate_spill(agg, chain, leaf, conf, cache,
+    out = stream_scan_aggregate_spill(agg, *found, conf, cache,
                                       recovery=recovery,
                                       skip_chunks=ck.cursor,
                                       seed_partials=[ck.table])
-    if out is None:
-        return None
-    replayed = recovery.restore_replayed(ck.key, ck.cursor)
-    recovery.record("checkpoint_restore", None, cursor=int(ck.cursor),
-                    ckpt_rows=int(ck.table.num_rows),
-                    chunks_replayed=replayed)
+    if out is not None:
+        _record_restore(recovery, ck)
     return out
 
 
@@ -725,9 +656,9 @@ def stream_scan_aggregate_mesh(agg: "P.HashAggregateExec", mesh, conf,
     the (already planned) exchange + final aggregate run unchanged.
 
     This is the round-2 gap VERDICT weak #7: distributed runs used to
-    materialize entire scans. The partial tables are [n, total]-shaped
+    materialize entire scans. The carry: partial tables, [n, total]-shaped
     arrays sharded on dim 0 — only accumulator-table bytes stay resident
-    between chunks."""
+    between chunks — with what a gang needs at the chunk boundary."""
     if agg.mode != "partial":
         return None
     if any(getattr(a.func, "positional", False) for a in agg.agg_exprs):
@@ -751,8 +682,13 @@ def stream_scan_aggregate_mesh(agg: "P.HashAggregateExec", mesh, conf,
     if _prefer_resident(leaf, conf, getattr(recovery, "metrics", None)):
         return None
 
-    from ..io.sources import maybe_prefetch
+    import contextlib
+    import time as _time
+    import pyarrow as pa
+    from jax.sharding import PartitionSpec as Psp
     from ..observability.spans import current_shard_telemetry
+    from ..parallel import elastic as EL
+    from ..parallel.mesh import AXIS, shard_map
     n = int(mesh.devices.size)
     telem = current_shard_telemetry()
     needs_base = any(a.func.uses_row_base for a in agg.agg_exprs)
@@ -768,169 +704,103 @@ def stream_scan_aggregate_mesh(agg: "P.HashAggregateExec", mesh, conf,
     # rows at emit, so the recovery replays at most everyChunks chunks
     # ON the mesh (the mesh-side analog of resume_from_mesh_checkpoint)
     ck = recovery.get_checkpoint(ck_key) if ck_key is not None else None
-    chunks = maybe_prefetch(
-        leaf.source.load_chunks(leaf.required_columns,
-                                leaf.pushed_filters, chunk_rows),
-        conf, recovery)
-    try:
-        return _stream_scan_aggregate_mesh_inner(
-            agg, chain, mesh, conf, cache, recovery, chunks,
-            chunk_rows, n, telem, needs_base, every, ck_key,
-            save_key, ck)
-    finally:
-        # join the prefetch worker on every exit (see
-        # stream_scan_aggregate): a mesh fault or a cancellation
-        # unwinding mid-stream must not leak its ingest daemon
-        if hasattr(chunks, "close"):
-            chunks.close()
-
-
-def _stream_scan_aggregate_mesh_inner(agg, chain, mesh, conf, cache,
-                                      recovery, chunks, chunk_rows, n,
-                                      telem, needs_base, every, ck_key,
-                                      save_key, ck):
-    import jax
-    from jax.sharding import PartitionSpec as Psp
-    from ..parallel.mesh import shard_map
-    from ..parallel.mesh import AXIS
-    from ..parallel import elastic as EL
-    import pyarrow as pa
-    import time as _time
-    if ck is not None:
-        if not hasattr(chunks, "skip_chunks") or \
-                chunks.skip_chunks(ck.cursor) < ck.cursor:
-            return None  # stream shorter than the cursor: unmatchable
-
-    def record_restore():
-        replayed = recovery.restore_replayed(ck_key, ck.cursor)
-        recovery.record("checkpoint_restore", None,
-                        cursor=int(ck.cursor),
-                        ckpt_rows=int(ck.table.num_rows),
-                        chunks_replayed=replayed, driver="mesh")
-
-    t_in0 = _time.perf_counter()
-    first = next(iter(chunks), None)
-    t_in1 = _time.perf_counter()
-    if first is None:
-        if ck is not None:
-            # resume landed exactly at end-of-stream: the checkpoint
-            # already covers every chunk — its partial rows ARE the
-            # stream's result (the exchange + final above re-reduce)
-            record_restore()
-            return Batch.from_arrow(ck.table)
-        return None
-    key = (f"stream_mesh:{agg.describe()}:{chunk_rows}:{n}"
-           + conf_compile_suffix(conf))
-    bundle = cache.get(key) if cache is not None else None
-    if bundle is None:
-        ctx = P.ExecContext(conf)
-        probe = _replay_chain(chain, ctx, first)
-        prep = agg.prepare_direct(probe, conf)
-        if prep is None:
-            return None
-
-        def update(tables, b, chunk_base):
-            t = jax.tree_util.tree_map(lambda x: x[0], tables)
-            ctx = P.ExecContext(conf)
-            local = _replay_chain(chain, ctx, b)
-            # unique packed positions: chunks stride the full chunk
-            # capacity (host counter), shards stride the local capacity
-            base = chunk_base + jax.lax.axis_index(AXIS) \
-                .astype(jnp.int64) * local.capacity
-            new = agg.direct_update_tables(t, local, prep, conf,
-                                           row_base=base)
-            # per-shard telemetry channel: this shard's live rows this
-            # chunk, shape [1] so the sharded stack is [n] with one
-            # device-resident slot per shard (spans.ShardStreamTelemetry
-            # times per-shard readiness off exactly this array)
-            live = jnp.sum(local.selection_mask().astype(jnp.int64))[None]
-            return jax.tree_util.tree_map(lambda x: x[None], new), live
-
-        def emit(tables):
-            t = jax.tree_util.tree_map(lambda x: x[0], tables)
-            return agg.direct_partial_batch(t, prep)
-
-        update_step = jax.jit(shard_map(
-            update, mesh=mesh, in_specs=(Psp(AXIS), Psp(AXIS), Psp()),
-            out_specs=(Psp(AXIS), Psp(AXIS)), check_vma=False),
-            donate_argnums=(0,))
-        emit_step = jax.jit(shard_map(
-            emit, mesh=mesh, in_specs=(Psp(AXIS),),
-            out_specs=Psp(AXIS), check_vma=False))
-        # prep MUST live in the bundle: the jitted closures capture it,
-        # so a cache hit with a fresh prep would silently mix layouts
-        bundle = (prep, update_step, emit_step)
-        if cache is not None:
-            cache[key] = bundle
-    prep, update_step, emit_step = bundle
-
-    # per-shard neutral tables, [n, total] sharded on dim 0
-    cnt0, accs0 = agg.direct_init_tables(prep)
-    tables = (jnp.broadcast_to(cnt0, (n,) + cnt0.shape),
-              [[jnp.broadcast_to(a, (n,) + a.shape) for a in row]
-               for row in accs0])
-
-    check_dicts = _dict_growth_guard(agg, prep)
-    chunk_base = 0
-
-    def row_width(b):
-        return sum(c.data.dtype.itemsize
-                   + (1 if c.validity is not None else 0)
-                   for c in b.columns.values())
-
     # straggler rebalancing (parallel/elastic.py): inert until the
     # ElasticRebalancer flags a shard via on_straggler, then each
     # chunk's rows skew away from it. Position-packed aggregates keep
     # the even split (their packed bases encode assignment).
     rebal = EL.RebalanceState(n, conf, recovery=recovery) \
         if not needs_base else None
+    rebalancing = contextlib.ExitStack()  # open for the chunk loop alone
 
-    def step(tables, b, ci):
-        nonlocal chunk_base
-        padded = EL.pad_chunk_for_shards(b, n, rebal)
-        if needs_base and chunk_base + padded.capacity >= (1 << 30):
-            raise RuntimeError(
-                "first/last over a streamed mesh scan exceeds the 2^30 "
-                "packed-position bound")
-        t_disp = _time.perf_counter()
-        out, shard_rows = update_step(tables, padded,
-                                      jnp.asarray(chunk_base, jnp.int64))
-        if telem is not None:
-            # hot path stays sync-free: the device array is buffered;
-            # the PREVIOUS chunk's buffer flushes inside this call
-            telem.chunk_dispatched(ci, shard_rows, row_width(b), t_disp)
-        chunk_base += padded.capacity
-        return out
+    def empty():
+        if ck is None:
+            return None
+        # resume landed exactly at end-of-stream: the checkpoint
+        # already covers every chunk — its partial rows ARE the
+        # stream's result (the exchange + final above re-reduce)
+        _record_restore(recovery, ck, driver="mesh")
+        return Batch.from_arrow(ck.table)
 
-    def current_dicts() -> dict:
-        return dict(chunks.dictionaries) if hasattr(
-            chunks, "dictionaries") else {}
+    def begin(first, dictionaries):
+        key = (f"stream_mesh:{agg.describe()}:{chunk_rows}:{n}"
+               + conf_compile_suffix(conf))
+        bundle = cache.get(key) if cache is not None else None
+        if bundle is None:
+            ctx = P.ExecContext(conf)
+            probe = _replay_chain(chain, ctx, first)
+            prep = agg.prepare_direct(probe, conf)
+            if prep is None:
+                return None
 
-    def snapshot():
-        # device->host checkpoint of the accumulator state: emit the
-        # per-shard partial rows (the exact shape a FINAL aggregate
-        # consumes) and decode them against the dictionaries grown so
-        # far — every code folded so far is covered (append-only). A
-        # RESUMED stream's accumulators only cover the post-cursor
-        # chunks: prepend the seed checkpoint so a later restore never
-        # loses the head of the stream.
-        t = _with_dict_overrides(emit_step(tables),
-                                 current_dicts()).to_arrow()
-        if ck is not None:
-            t = pa.concat_tables([ck.table, t],
-                                 promote_options="permissive")
-        return t
+            def update(tables, b, chunk_base):
+                t = jax.tree_util.tree_map(lambda x: x[0], tables)
+                ctx = P.ExecContext(conf)
+                local = _replay_chain(chain, ctx, b)
+                # unique packed positions: chunks stride the full chunk
+                # capacity (host counter), shards stride the local
+                # capacity
+                base = chunk_base + jax.lax.axis_index(AXIS) \
+                    .astype(jnp.int64) * local.capacity
+                new = agg.direct_update_tables(t, local, prep, conf,
+                                               row_base=base)
+                # per-shard telemetry channel: this shard's live rows
+                # this chunk, shape [1] so the sharded stack is [n] with
+                # one device-resident slot per shard
+                # (spans.ShardStreamTelemetry times per-shard readiness
+                # off exactly this array)
+                live = jnp.sum(
+                    local.selection_mask().astype(jnp.int64))[None]
+                return jax.tree_util.tree_map(lambda x: x[None], new), live
 
-    # chunk-granular retry + periodic checkpoint (execution/recovery.py)
-    if ck is not None:
-        # the bundle exists and the cursor was skipped: the resume is
-        # definitely running — record it (with its bounded replay)
-        record_restore()
-    retrier = ChunkRetrier(conf, recovery)
-    ci = int(ck.cursor) if ck is not None else 0
-    b = first
-    with EL.use_rebalance(rebal):
-        while b is not None:
+            def emit(tables):
+                t = jax.tree_util.tree_map(lambda x: x[0], tables)
+                return agg.direct_partial_batch(t, prep)
+
+            update_step = jax.jit(shard_map(
+                update, mesh=mesh, in_specs=(Psp(AXIS), Psp(AXIS), Psp()),
+                out_specs=(Psp(AXIS), Psp(AXIS)), check_vma=False),
+                donate_argnums=(0,))
+            emit_step = jax.jit(shard_map(
+                emit, mesh=mesh, in_specs=(Psp(AXIS),),
+                out_specs=Psp(AXIS), check_vma=False))
+            # prep MUST live in the bundle: the jitted closures capture
+            # it, so a cache hit with a fresh prep would silently mix
+            # layouts
+            bundle = (prep, update_step, emit_step)
+            if cache is not None:
+                cache[key] = bundle
+        prep, update_step, emit_step = bundle
+
+        # per-shard neutral tables, [n, total] sharded on dim 0
+        cnt0, accs0 = agg.direct_init_tables(prep)
+        tables = (jnp.broadcast_to(cnt0, (n,) + cnt0.shape),
+                  [[jnp.broadcast_to(a, (n,) + a.shape) for a in row]
+                   for row in accs0])
+        chunk_base = 0
+
+        def row_width(b):
+            return sum(c.data.dtype.itemsize
+                       + (1 if c.validity is not None else 0)
+                       for c in b.columns.values())
+
+        def emit_rows():
+            # the per-shard partial rows (the exact shape a FINAL
+            # aggregate consumes) against the dictionaries grown so far —
+            # every code folded so far is covered (append-only)
+            return _with_dict_overrides(emit_step(tables), dictionaries())
+
+        def with_seed(t: pa.Table) -> pa.Table:
+            # a RESUMED stream's accumulators only cover the post-cursor
+            # chunks: the seed checkpoint's rows go first, so neither a
+            # later restore nor the FINAL aggregate above loses the head
+            # of the stream
+            return t if ck is None else pa.concat_tables(
+                [ck.table, t], promote_options="permissive")
+
+        def snapshot():  # device->host checkpoint of the accumulators
+            return with_seed(emit_rows().to_arrow())
+
+        def between(ci, b, t_in0, t_in1):
             # graceful decommission: a pending drain request applies at
             # the chunk boundary — checkpoint forced at the current
             # cursor so the reduced gang resumes here, then the request
@@ -949,32 +819,53 @@ def _stream_scan_aggregate_mesh_inner(agg, chain, mesh, conf, cache,
                 telem.chunk_ingested(ci, b.capacity,
                                      b.capacity * row_width(b),
                                      t_in0, t_in1)
-            with span("chunk.launch", chunk=ci):
-                check_dicts(b)
-                tables = retrier.run(lambda bb=b: step(tables, bb, ci),
-                                     chunk=ci)
-            ci += 1
+
+        def fold(b, ci):
+            nonlocal tables, chunk_base
+            padded = EL.pad_chunk_for_shards(b, n, rebal)
+            if needs_base and chunk_base + padded.capacity >= (1 << 30):
+                raise RuntimeError(
+                    "first/last over a streamed mesh scan exceeds the "
+                    "2^30 packed-position bound")
+            t_disp = _time.perf_counter()
+            out, shard_rows = update_step(
+                tables, padded, jnp.asarray(chunk_base, jnp.int64))
+            if telem is not None:
+                # hot path stays sync-free: the device array is buffered;
+                # the PREVIOUS chunk's buffer flushes inside this call
+                telem.chunk_dispatched(ci, shard_rows, row_width(b),
+                                       t_disp)
+            chunk_base += padded.capacity
+            tables = out  # the step's last act: see stream_scan_aggregate
+
+        def took(_out, b, ci):
             if ck_key is not None:
                 # consumed-chunk watermark: bounds the replay a later
                 # checkpoint restore reports (restore_replayed)
-                recovery.note_progress(ck_key, ci)
-            if save_key is not None and ci % every == 0:
-                recovery.save_checkpoint(save_key, ci, snapshot)
-            t_in0 = _time.perf_counter()
-            b = next(chunks, None)  # ingest un-retried: see ChunkRetrier
-            t_in1 = _time.perf_counter()
+                recovery.note_progress(ck_key, ci + 1)
+            if save_key is not None and (ci + 1) % every == 0:
+                recovery.save_checkpoint(save_key, ci + 1, snapshot)
 
-    if telem is not None:
-        telem.finish()  # flush the last chunk's buffered records
-    with span("stream.drain"):
-        out = jax.block_until_ready(
-            _with_dict_overrides(emit_step(tables), current_dicts()))
-    if ck is not None:
-        # merge the seed checkpoint's partial rows with the resumed
-        # tail's — the FINAL aggregate above re-reduces both
-        out = Batch.from_arrow(pa.concat_tables(
-            [ck.table, out.to_arrow()], promote_options="permissive"))
-    return out
+        def finish(drain):
+            rebalancing.close()
+            if telem is not None:
+                telem.finish()  # flush the last chunk's buffered records
+            with drain:
+                out = jax.block_until_ready(emit_rows())
+            return out if ck is None \
+                else Batch.from_arrow(with_seed(out.to_arrow()))
+
+        if ck is not None:
+            # the bundle exists and the cursor was skipped: the resume is
+            # definitely running — record it (with its bounded replay)
+            _record_restore(recovery, ck, driver="mesh")
+        rebalancing.enter_context(EL.use_rebalance(rebal))
+        return Carry(fold, took, finish, _dict_growth_guard(agg, prep),
+                     between)
+
+    with rebalancing:
+        return drive(leaf, chunk_rows, conf, recovery, begin, empty,
+                     start=ck.cursor if ck is not None else 0)
 
 
 def _prefer_resident(leaf: "P.ScanExec", conf, metrics=None) -> bool:

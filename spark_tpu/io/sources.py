@@ -922,13 +922,14 @@ class PrefetchChunkIterator:
     residency, arbiter leases and the per-chunk retry/checkpoint
     semantics of the streaming drivers are unchanged.
 
-    Fault behavior: the worker runs each host decode under the SAME
-    per-chunk retry path the compute steps use (``ChunkRetrier`` with
-    the ``ingest_prefetch`` chaos seam) — a transient fault fired at
-    the seam replays exactly one chunk's decode (`rec_chunks_replayed`
+    Fault behavior: the worker runs each host decode under `retry`,
+    which the chunk driver hands over (``execution/chunk_stream.py``:
+    the SAME per-chunk retry path its compute steps use, with the
+    ``ingest_prefetch`` chaos seam) — a transient fault fired at the
+    seam replays exactly one chunk's decode (`rec_chunks_replayed`
     counts it); a real reader failure poisons the inner iterator as
     before and surfaces on the consumer thread for the whole-query
-    ladder.
+    ladder. Without `retry` a decode runs once, plainly.
 
     Observability: the consumer's wait for a chunk (the pipeline
     failing to hide the host's work) is the ``chunk.wait`` span and,
@@ -938,12 +939,12 @@ class PrefetchChunkIterator:
     the worker's and its column threads' ``tid`` (the benchmark's
     ``ingest_*_ms_p50`` metrics read them)."""
 
-    def __init__(self, inner: ChunkIterator, conf, recovery=None,
+    def __init__(self, inner: ChunkIterator, conf, retry=None,
                  metrics=None):
-        from ..execution.recovery import ChunkRetrier
+        # `conf` is read no longer (the retry policy that read it
+        # arrives as `retry`); callers still pass it in second place
         self._inner = inner
-        self._retrier = ChunkRetrier(conf, recovery,
-                                     site="ingest_prefetch")
+        self._retry = retry or (lambda step, chunk: step())
         self._metrics = metrics
         self._started = False
         self._closed = False
@@ -986,14 +987,14 @@ class PrefetchChunkIterator:
     # -- pipeline -----------------------------------------------------------
 
     @staticmethod
-    def _worker(host_next, retrier, q, stop, chunk) -> None:
+    def _worker(host_next, retry, q, stop, chunk) -> None:
         # deliberately a staticmethod over plain arguments: holding a
         # ref to the iterator would keep it reachable forever and its
         # abandonment finalizer (see __init__) could never fire
         import queue as _queue
         while not stop.is_set():
             try:
-                item = ("ok", retrier.run(host_next, chunk=chunk))
+                item = ("ok", retry(host_next, chunk=chunk))
             except BaseException as e:  # noqa: BLE001 — relayed verbatim
                 item = ("err", e)
             # bounded put that notices close()/abandonment: the worker
@@ -1018,7 +1019,7 @@ class PrefetchChunkIterator:
             self._thread = threading.Thread(
                 target=self._worker, daemon=True,
                 name="spark-tpu-ingest-prefetch",
-                args=(self._inner._host_next, self._retrier,
+                args=(self._inner._host_next, self._retry,
                       self._queue, self._stop, self._chunk))
             self._thread.start()
         # one interval, read twice: the `chunk.wait` span and the
@@ -1126,12 +1127,13 @@ def decode_stream_file(path: str, fmt: str) -> pa.Table:
                      f"(parquet, csv, json)")
 
 
-def maybe_prefetch(chunks, conf, recovery=None):
+def maybe_prefetch(chunks, conf, recovery=None, retry=None):
     """Wrap a chunk stream in the double-buffered prefetcher when
-    ``spark_tpu.sql.ingest.prefetch`` is on. The one entry point every
-    chunk driver (streaming_agg direct/spill/mesh, external collect)
-    routes its `load_chunks` result through — results are identical
-    on/off, only ingest/compute overlap changes."""
+    ``spark_tpu.sql.ingest.prefetch`` is on. The chunk driver
+    (execution/chunk_stream.py) routes every `load_chunks` result
+    through here and hands the worker its per-chunk `retry(step,
+    chunk=)` — results are identical on/off, only ingest/compute
+    overlap changes."""
     if not isinstance(chunks, ChunkIterator):
         return chunks
     from ..observability.spans import current_recorder
@@ -1139,5 +1141,5 @@ def maybe_prefetch(chunks, conf, recovery=None):
     chunks.observe(metrics, current_recorder())
     if not bool(conf.get(INGEST_PREFETCH_KEY)):
         return chunks
-    return PrefetchChunkIterator(chunks, conf, recovery=recovery,
+    return PrefetchChunkIterator(chunks, conf, retry=retry,
                                  metrics=metrics)
