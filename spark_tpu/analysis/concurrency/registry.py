@@ -16,6 +16,14 @@ Thread roots (what makes state here "shared"):
   worker), which fires fault seams and counts registry metrics, and
   the threads it starts for a large chunk's columns, which draw from
   the process's `io/host_buffers.py` pool and count too;
+- the dispatch-sync waiter (`execution/executor.py` _await_ready,
+  named `spark-tpu-dispatch-sync`): one short-lived daemon a sync
+  that found its dispatched stage still running, which blocks on the
+  stage's output arrays and sets the one `threading.Event` the
+  query's thread waits on. It touches those arrays and that event and
+  nothing else: no lock of this registry, no counter, no span, no
+  fault seam (the counters and the span's attributes are written by
+  the query's thread);
 - the listener bus delivering to the event-log / metrics / straggler /
   rebalancer subscribers (synchronously, on whichever thread posts).
 

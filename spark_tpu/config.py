@@ -267,15 +267,18 @@ EXEC_QUERY_DEADLINE_MS = register(
 
 EXEC_DISPATCH_POLL_MS = register(
     "spark_tpu.execution.dispatchPollMs", 25,
-    doc="Cancellable host sync of a DISPATCHED stage: with a cancel "
-        "token installed, the post-dispatch stats pull polls the "
-        "output arrays' readiness instead of blocking in "
-        "jax.device_get — the tick ramps 1ms up to this cap, so a "
-        "cancel (DELETE /queries/<id>) or a blown queryDeadlineMs "
-        "lands within ~one capped tick while the device compute "
-        "proceeds in the background, and short stages pay ~1ms of "
-        "added sync latency. 0 restores the blocking sync "
-        "(cancellation then lands only when the stage completes).",
+    doc="Cancellable host sync of a DISPATCHED stage, and the bound "
+        "on how late a cancel lands in it: with a cancel token "
+        "installed, the post-dispatch stats pull is woken when the "
+        "device is done with the stage's output arrays (a waiter "
+        "thread blocks on them) and waits for that in slices of at "
+        "most this long instead of blocking in jax.device_get, so "
+        "a cancel (DELETE /queries/<id>) or a blown queryDeadlineMs "
+        "lands within one slice while the device compute proceeds "
+        "in the background. A finished stage is found when it "
+        "finishes, whatever this is set to. 0 restores the blocking "
+        "sync (cancellation then lands only when the stage "
+        "completes).",
     validator=lambda v: v >= 0)
 
 CHUNK_RETRY_ENABLED = register(

@@ -12,6 +12,7 @@ of `CodeGenerator.compile:1435`'s Janino cache.
 from __future__ import annotations
 
 import contextlib
+import threading
 import time
 from typing import Dict, List, Optional, Tuple
 
@@ -35,45 +36,75 @@ class _ReplanRequest(Exception):
 DISPATCH_POLL_KEY = "spark_tpu.execution.dispatchPollMs"
 
 
+#: name of the short-lived thread a sync that has to wait starts
+#: (`LockWatch.assert_no_thread_leak` finds a leak by this prefix)
+SYNC_WAITER_THREAD = "spark-tpu-dispatch-sync"
+
+
+def _await_ready(leaves, done):
+    """The waiter thread's whole life: block until the device is done
+    with `leaves`, then wake the query's thread. It only waits: a
+    device error is swallowed here and raised by the pull, on the
+    thread that runs the query."""
+    try:
+        jax.block_until_ready(leaves)
+    except BaseException:
+        pass
+    finally:
+        done.set()
+
+
 def _sync_dispatched(outs, conf, span=None):
     """Host-sync a dispatched stage's stats channel, cancellably.
 
     `jax.device_get` blocks until the device computation completes, so
     a cancel of a DISPATCHED stage used to land only when the stage
-    finished. With a cancel token installed and dispatchPollMs > 0,
-    poll the output arrays' readiness instead: each tick checks the
-    token, so a DELETE /queries/<id> or a blown queryDeadlineMs raises
-    the structured lifecycle error within ~one poll interval (the
-    device compute keeps running in the background — XLA offers no
-    kill — but the host thread, its leases and its session lease are
-    released promptly). Checks the token DIRECTLY rather than through
-    lifecycle.checkpoint: readiness polling is timing-dependent, and
-    routing it through the `cancel_point` chaos seam would make the
-    cancel matrix's nth-boundary targeting nondeterministic.
+    finished. With a cancel token installed and dispatchPollMs > 0 the
+    wait is for the device and not for a clock: a stage that is ready
+    already is pulled at once; otherwise one daemon thread
+    (`SYNC_WAITER_THREAD`, one a sync and never shared: a waiter
+    blocked on one session's stage would find another's late) blocks
+    on the output arrays and sets an event when they are ready, so the
+    query's thread wakes a thread's hand-over after the device ends,
+    however long the stage ran. It waits on that event in slices of at
+    most dispatchPollMs and at most the deadline's remaining budget,
+    and checks the token after each (`CancelToken.wait(on=)`): a
+    DELETE /queries/<id> (which sets the same event and so wakes the
+    wait at once) or a blown
+    queryDeadlineMs raises the structured lifecycle error within one
+    slice (the device compute keeps running in the background, XLA
+    offers no kill, and the waiter ends with it, holding nothing of the
+    query but the arrays; the host thread, its leases and its session
+    lease are released promptly). Checks the token DIRECTLY rather
+    than through lifecycle.checkpoint: how many slices a stage takes
+    is timing-dependent, and routing them through the `cancel_point`
+    chaos seam would make the cancel matrix's nth-boundary targeting
+    nondeterministic.
 
-    The tick ramps 1ms -> dispatchPollMs (doubling): short stages —
-    the overwhelmingly common case on a serving path — pay ~1ms of
-    added sync latency instead of a full poll interval, while the
-    cancel-latency bound for long stages stays ~dispatchPollMs.
+    The pull stays the last act on the query's thread, so a device
+    error surfaces there, in `dispatch.sync`, under _execute_recover.
 
-    `span` (the caller's `dispatch.sync`) gets the attribute `ticks`:
-    the polls slept through, 0 where the sync blocked straight
-    through. A stage is found ready up to the last tick late."""
+    `span` (the caller's `dispatch.sync`) gets the attributes `ticks`,
+    the slices slept through with the stage still running (0 for a
+    stage shorter than dispatchPollMs, and where the sync blocked
+    straight through), and `waited`, 1 where the stage was not ready
+    at the call and a waiter was started."""
     from . import lifecycle
     tok = lifecycle.current_token()
-    poll_ms = float(conf.get(DISPATCH_POLL_KEY) or 0)
-    if span is not None:
-        span.attrs["ticks"] = 0
-    if tok is not None and poll_ms > 0:
+    poll_s = float(conf.get(DISPATCH_POLL_KEY) or 0) / 1e3
+    attrs = {} if span is None else span.attrs
+    attrs["ticks"] = attrs["waited"] = 0
+    if tok is not None and poll_s > 0:
         leaves = [a for a in jax.tree_util.tree_leaves(outs)
                   if hasattr(a, "is_ready")]
-        tick_s = min(0.001, poll_ms / 1e3)
-        while not all(a.is_ready() for a in leaves):
-            tok.check("dispatch_wait")
-            tok.wait(tick_s)
-            if span is not None:
-                span.attrs["ticks"] += 1
-            tick_s = min(tick_s * 2, poll_ms / 1e3)
+        if not all(a.is_ready() for a in leaves):
+            attrs["waited"] = 1
+            done = threading.Event()
+            threading.Thread(
+                target=_await_ready, args=(leaves, done),
+                name=SYNC_WAITER_THREAD, daemon=True).start()
+            while not tok.wait(poll_s, on=done):
+                attrs["ticks"] += 1
     return jax.device_get(outs)
 
 
@@ -1672,17 +1703,20 @@ class QueryExecution:
                 # — per-scalar np.asarray is a host sync each (the
                 # pull also syncs the attempt, making the wall-clock
                 # deadline check below honest). The pull is
-                # cancellable (dispatchPollMs readiness polling): a
-                # cancel/deadline lands within ~one tick instead of
-                # at stage completion
+                # cancellable: it is woken when the device is done,
+                # and waits for that in slices of dispatchPollMs, so
+                # a cancel/deadline lands within one slice instead
+                # of at stage completion
                 with self.spans.span("dispatch.sync") as sync:
                     try:
                         flags, metrics = _sync_dispatched(
                             (flags, metrics), self._conf, sync)
-                    finally:  # a cancel mid-poll counts its ticks too
-                        self.session.metrics.counter(
-                            "dispatch_sync_ticks").inc(
-                                sync.attrs.get("ticks", 0))
+                    finally:  # a cancel mid-wait counts them too
+                        for attr, counter in (
+                                ("ticks", "dispatch_sync_ticks"),
+                                ("waited", "dispatch_sync_waits")):
+                            self.session.metrics.counter(counter).inc(
+                                sync.attrs.get(attr, 0))
             # deadline BEFORE the stage-timeout check: an attempt
             # that outran the end-to-end budget raises the
             # lifecycle error (ladder stops), never a retryable

@@ -89,14 +89,20 @@ class CancelToken:
 
     def __init__(self, deadline_ms: Optional[float] = None):
         self._event = threading.Event()
+        # the events that `wait(on=)` calls are parked on right now:
+        # cancel() sets them too. append / remove / the copy in
+        # cancel() are each atomic, so no lock
+        self._wakers: list = []
         self.deadline_ms = float(deadline_ms) if deadline_ms else None
         self.deadline = (time.monotonic() + self.deadline_ms / 1e3
                          if self.deadline_ms else None)
 
     def cancel(self) -> None:
         """Idempotent: the query stops at its next boundary; waiters
-        parked in `wait()` wake immediately."""
+        parked in `wait()` wake immediately, on whichever event."""
         self._event.set()
+        for ev in list(self._wakers):
+            ev.set()
 
     @property
     def cancelled(self) -> bool:
@@ -122,25 +128,43 @@ class CancelToken:
                 f"query exceeded queryDeadlineMs="
                 f"{self.deadline_ms:g}{at}")
 
-    def wait(self, seconds: float) -> None:
+    def wait(self, seconds: float,
+             on: Optional[threading.Event] = None) -> bool:
         """Interruptible bounded sleep: wakes on cancel, caps at the
         remaining deadline budget, raises on either. A capped wait
         raises QueryDeadlineError — the caller's full sleep would have
         outrun the budget, so sleeping the remainder then resuming
-        work would just blow the deadline one boundary later."""
+        work would just blow the deadline one boundary later.
+
+        With `on`, the sleep is parked on the caller's event, which
+        someone else sets (the device ending a dispatched stage) and a
+        cancel sets too: True as soon as it is set, False where the
+        slice passed and it is not: a wait for that event in slices of
+        at most `seconds`, with the token checked after each."""
         s = max(0.0, float(seconds))
         rem = self.remaining_s()
         capped = rem is not None and rem < s
         if capped:
             s = max(rem, 0.0)
-        if s > 0:
-            self._event.wait(s)
+        ev = self._event if on is None else on
+        if on is not None:
+            self._wakers.append(on)
+        try:
+            # cancelled before `on` was listed: cancel() did not set it
+            if s > 0 and not self._event.is_set():
+                ev.wait(s)
+        finally:
+            if on is not None:
+                self._wakers.remove(on)
         if self._event.is_set():
             raise QueryCancelledError("query cancelled during wait")
+        if on is not None and on.is_set():
+            return True
         if capped or self.expired():
             raise QueryDeadlineError(
                 f"query exceeded queryDeadlineMs={self.deadline_ms:g} "
                 f"during wait")
+        return False
 
 
 #: the token of the query execution running in the current context;

@@ -78,9 +78,12 @@ METRIC_PREFIXES = (
     # counters, listed for namespace closure
     "stage_dispatches",  # whole-stage programs dispatched: one per
                        # `dispatch` span, capacity re-plans included
-    "dispatch_sync_",  # dispatch_sync_ticks: readiness polls the
-                       # cancellable sync slept through (the sum of
-                       # the dispatch.sync spans' `ticks`)
+    "dispatch_sync_",  # dispatch_sync_waits: syncs that found their
+                       # stage still running and waited on it;
+                       # dispatch_sync_ticks: dispatchPollMs slices
+                       # those waits slept through with the stage
+                       # still running (the sums of the dispatch.sync
+                       # spans' `waited` and `ticks`)
     # straggler detection (observability/straggler.py): REGISTRY
     # counter, listed for namespace closure like the ingest pair
     "straggler_",      # straggler_flagged: shards flagged this process

@@ -14,6 +14,7 @@ cancel-after-finish 409, structured 404), and the per-session quotas
 import json
 import threading
 import time
+import types
 import urllib.error
 import urllib.request
 
@@ -654,67 +655,105 @@ def test_cancel_matrix_service_async(service):
 
 
 class _FakeDeviceArray:
-    """Stand-in for a dispatched jax.Array: is_ready() flips when the
-    'device' finishes; __array__ lets jax.device_get materialize it."""
+    """Stand-in for a dispatched jax.Array. The 'device' finishes
+    `ready_after_s` from now, or when the test sets `ends` (a stage
+    that never ends by itself: the test sets it in its `finally`, so
+    that no waiter thread outlives the test). is_ready() flips then
+    and block_until_ready() returns then; __array__ lets
+    jax.device_get materialize it. `fails` names the methods that
+    raise a device error once the stage has ended."""
 
-    def __init__(self, ready_after_s=0.0):
+    def __init__(self, ready_after_s=0.0, ends=None, fails=()):
         import numpy as np
         self._value = np.zeros(2, dtype=np.int64)
-        self._ready_ts = time.monotonic() + ready_after_s
+        self.ready_ts = time.monotonic() + ready_after_s
+        self._ends = ends
+        self._fails = fails
 
     def is_ready(self):
-        return time.monotonic() >= self._ready_ts
+        return time.monotonic() >= self.ready_ts or (
+            self._ends is not None and self._ends.is_set())
+
+    def block_until_ready(self):
+        left = self.ready_ts - time.monotonic()
+        if left > 0:
+            if self._ends is not None:
+                self._ends.wait(left)
+            else:
+                time.sleep(left)
+        if "block_until_ready" in self._fails:
+            raise RuntimeError("device error in block_until_ready")
+        return self
 
     def __array__(self, dtype=None):
+        if "__array__" in self._fails:
+            raise RuntimeError("device error in __array__")
         return self._value
+
+
+def _poll_conf(poll_ms):
+    from spark_tpu.execution.executor import DISPATCH_POLL_KEY
+    return Conf().set(DISPATCH_POLL_KEY, poll_ms)
+
+
+def _assert_no_sync_waiter_leak():
+    from spark_tpu.execution.executor import SYNC_WAITER_THREAD
+    LockWatch().assert_no_thread_leak(prefix=SYNC_WAITER_THREAD)
+
+
+def _SyncSpan():
+    """What `_sync_dispatched` needs of the caller's span."""
+    return types.SimpleNamespace(attrs={})
 
 
 def test_dispatch_poll_cancel_lands_mid_stage():
     """Regression for the dispatch gap: with a never-ready output, a
     cancel must land within ~one poll tick instead of blocking in
     jax.device_get until the device finishes the stage."""
-    from spark_tpu.execution.executor import (DISPATCH_POLL_KEY,
-                                              _sync_dispatched)
-    conf = Conf().set(DISPATCH_POLL_KEY, 20)
+    from spark_tpu.execution.executor import _sync_dispatched
+    conf = _poll_conf(20)
     tok = lifecycle.CancelToken()
     ctx = lifecycle.install(tok)
+    ends = threading.Event()
     try:
         timer = threading.Timer(0.15, tok.cancel)
         timer.start()
         t0 = time.monotonic()
         with pytest.raises(lifecycle.QueryCancelledError):
             _sync_dispatched(
-                {"flags": _FakeDeviceArray(ready_after_s=3600)}, conf)
+                {"flags": _FakeDeviceArray(3600, ends)}, conf)
         elapsed = time.monotonic() - t0
         assert elapsed < 2.0, f"cancel took {elapsed:.2f}s (gap back?)"
         timer.cancel()
     finally:
+        ends.set()
         lifecycle.uninstall(ctx)
+    _assert_no_sync_waiter_leak()
 
 
 def test_dispatch_poll_deadline_lands_mid_stage():
-    from spark_tpu.execution.executor import (DISPATCH_POLL_KEY,
-                                              _sync_dispatched)
-    conf = Conf().set(DISPATCH_POLL_KEY, 20)
+    from spark_tpu.execution.executor import _sync_dispatched
+    conf = _poll_conf(20)
     tok = lifecycle.CancelToken(deadline_ms=150)
     ctx = lifecycle.install(tok)
+    ends = threading.Event()
     try:
         t0 = time.monotonic()
         with pytest.raises(lifecycle.QueryDeadlineError):
-            _sync_dispatched(
-                [_FakeDeviceArray(ready_after_s=3600)], conf)
+            _sync_dispatched([_FakeDeviceArray(3600, ends)], conf)
         assert time.monotonic() - t0 < 2.0
     finally:
+        ends.set()
         lifecycle.uninstall(ctx)
+    _assert_no_sync_waiter_leak()
 
 
 def test_dispatch_poll_returns_when_ready():
-    """The poll loop exits on readiness and returns device_get's
-    result; arrays without is_ready (host values) never stall it."""
-    from spark_tpu.execution.executor import (DISPATCH_POLL_KEY,
-                                              _sync_dispatched)
+    """The wait ends on readiness and returns device_get's result;
+    arrays without is_ready (host values) never stall it."""
+    from spark_tpu.execution.executor import _sync_dispatched
     import numpy as np
-    conf = Conf().set(DISPATCH_POLL_KEY, 20)
+    conf = _poll_conf(20)
     tok = lifecycle.CancelToken()
     ctx = lifecycle.install(tok)
     try:
@@ -724,17 +763,156 @@ def test_dispatch_poll_returns_when_ready():
         assert out["b"] == 7
     finally:
         lifecycle.uninstall(ctx)
+    _assert_no_sync_waiter_leak()
 
 
 def test_dispatch_poll_disabled_blocks_straight_through():
     """dispatchPollMs=0 (and no token) short-circuits to the plain
     blocking device_get — the pre-existing fast path."""
-    from spark_tpu.execution.executor import (DISPATCH_POLL_KEY,
-                                              _sync_dispatched)
+    from spark_tpu.execution.executor import _sync_dispatched
     import numpy as np
-    conf = Conf().set(DISPATCH_POLL_KEY, 0)
-    out = _sync_dispatched([_FakeDeviceArray()], conf)
+    out = _sync_dispatched([_FakeDeviceArray()], _poll_conf(0))
     assert np.array_equal(out[0], np.zeros(2, dtype=np.int64))
+
+
+def _median_lateness_ms(stage_ms, conf, n=7):
+    """Median over `n` syncs of how long after its stage ended
+    `_sync_dispatched` returned."""
+    import statistics
+    from spark_tpu.execution.executor import _sync_dispatched
+    late = []
+    for _ in range(n):
+        arr = _FakeDeviceArray(ready_after_s=stage_ms / 1e3)
+        span = _SyncSpan()
+        _sync_dispatched([arr], conf, span)
+        late.append((time.monotonic() - arr.ready_ts) * 1e3)
+        assert span.attrs["waited"] == 1
+        # a slice is 25 ms: none passes in 10 ms, 2 in 60, 7-8 in 200
+        # (fewer where a loaded host wakes the slices late)
+        slices = stage_ms // 25
+        assert max(0, slices - 2) <= span.attrs["ticks"] <= slices, \
+            span.attrs
+    return statistics.median(late)
+
+
+@pytest.mark.parametrize("stage_ms,parent_tick_ms",
+                         [(10, 8), (60, 25), (200, 25)])
+def test_dispatch_sync_found_ready_when_ready(stage_ms, parent_tick_ms):
+    """The sync waits for the device and not for a clock: it returns a
+    thread's wake-up after the stage ends, and how late does not grow
+    with the stage. `parent_tick_ms` is the tick that the ramp this
+    replaced (1, 2, 4, 8, 16, 25, 25.. ms) was sleeping when a stage
+    of that length ended; it found the stage half of it late on
+    average and up to all of it."""
+    conf = _poll_conf(25)
+    ctx = lifecycle.install(lifecycle.CancelToken())
+    try:
+        late = _median_lateness_ms(stage_ms, conf)
+        short = late if stage_ms == 10 else _median_lateness_ms(10, conf)
+    finally:
+        lifecycle.uninstall(ctx)
+    assert late < parent_tick_ms / 3, (late, stage_ms)
+    assert late - short < 3.0, (late, short, stage_ms)
+    _assert_no_sync_waiter_leak()
+
+
+@pytest.mark.parametrize("how", ["cancel", "deadline"])
+def test_dispatch_sync_stop_lands_within_a_slice(how):
+    """A cancel and a blown deadline 50 ms into a stage that never
+    ends raise their structured errors from the sync within
+    dispatchPollMs (plus the host's slack), and `ticks` counted the
+    slices slept through until then."""
+    from spark_tpu.execution.executor import _sync_dispatched
+    poll_ms, at_ms, slack_ms = 25, 50, 100
+    tok = lifecycle.CancelToken(
+        deadline_ms=at_ms if how == "deadline" else None)
+    error = (lifecycle.QueryDeadlineError if how == "deadline"
+             else lifecycle.QueryCancelledError)
+    ctx = lifecycle.install(tok)
+    ends = threading.Event()
+    span = _SyncSpan()
+    timer = threading.Timer(at_ms / 1e3, tok.cancel)
+    try:
+        if how == "cancel":
+            timer.start()
+        t0 = time.monotonic()
+        with pytest.raises(error):
+            _sync_dispatched([_FakeDeviceArray(3600, ends)],
+                             _poll_conf(poll_ms), span)
+        late_ms = (time.monotonic() - t0) * 1e3 - at_ms
+    finally:
+        timer.cancel()
+        ends.set()
+        lifecycle.uninstall(ctx)
+    assert -1.0 <= late_ms < poll_ms + slack_ms, late_ms
+    # the slices before the stop: at 25 ms, and at 50 where the
+    # slice's end wins the race with the stop
+    assert span.attrs["waited"] == 1
+    assert 1 <= span.attrs["ticks"] <= 2 + slack_ms // poll_ms, span.attrs
+    _assert_no_sync_waiter_leak()
+
+
+@pytest.mark.parametrize("fails", [("block_until_ready", "__array__"),
+                                   ("__array__",)])
+def test_dispatch_sync_device_error_on_query_thread(fails):
+    """The waiter thread only waits: what its block_until_ready raises
+    is swallowed there, and the device's error reaches the caller from
+    `_sync_dispatched` itself, out of the pull on the query's thread
+    (where `dispatch.sync` and _execute_recover see it)."""
+    from spark_tpu.execution.executor import _sync_dispatched
+    ctx = lifecycle.install(lifecycle.CancelToken())
+    try:
+        with pytest.raises(RuntimeError, match="error in __array__"):
+            _sync_dispatched(
+                [_FakeDeviceArray(ready_after_s=0.03, fails=fails)],
+                _poll_conf(25))
+    finally:
+        lifecycle.uninstall(ctx)
+    _assert_no_sync_waiter_leak()
+
+
+def test_dispatch_sync_ready_stage_starts_nothing(monkeypatch):
+    """A stage that is ready at the call is pulled at once: no waiter
+    thread, no wait and no tick counted."""
+    from spark_tpu.execution import executor
+    import numpy as np
+
+    def no_thread(*a, **kw):
+        raise AssertionError("a ready stage started a waiter thread")
+    ctx = lifecycle.install(lifecycle.CancelToken())
+    monkeypatch.setattr(executor.threading, "Thread", no_thread)
+    span = _SyncSpan()
+    try:
+        out = executor._sync_dispatched(
+            {"a": _FakeDeviceArray(), "b": 7}, _poll_conf(25), span)
+    finally:
+        lifecycle.uninstall(ctx)
+    assert np.array_equal(out["a"], np.zeros(2, dtype=np.int64))
+    assert span.attrs == {"ticks": 0, "waited": 0}
+
+
+@pytest.mark.parametrize("first", ["cancel", "wait"])
+def test_cancel_token_wakes_a_wait_parked_elsewhere(first):
+    """`wait(on=event)`: a cancel ends a wait that is parked on the
+    caller's event, whichever of the two came first, and the wait
+    raises; it returns True where someone else set the event, False
+    where the slice passed."""
+    tok = lifecycle.CancelToken()
+    ev = threading.Event()
+    if first == "cancel":
+        tok.cancel()
+    else:
+        assert tok.wait(0.001, on=ev) is False
+        threading.Timer(0.02, tok.cancel).start()
+    t0 = time.monotonic()
+    with pytest.raises(lifecycle.QueryCancelledError):
+        tok.wait(5.0, on=ev)
+    assert time.monotonic() - t0 < 2.0
+    assert tok._wakers == []
+    other = lifecycle.CancelToken(deadline_ms=60_000)
+    done = threading.Event()
+    threading.Timer(0.02, done.set).start()
+    assert other.wait(5.0, on=done) is True
 
 
 def test_dispatch_gap_regression_slow_stage_cancel(service):

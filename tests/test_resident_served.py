@@ -2,9 +2,9 @@
 drives it: one Parquet `lineitem` registered with a `SqlService`, a
 request of Q1 then Q6 over `POST /sql`. On the CPU at SF0.01: the
 answers against the benchmark's plain references, what the device-table
-cache holds, and the two counters of the dispatch path
-(`stage_dispatches`, `dispatch_sync_ticks`), whose counts repeat
-exactly where nothing depends on timing."""
+cache holds, and the three counters of the dispatch path
+(`stage_dispatches`, `dispatch_sync_ticks`, `dispatch_sync_waits`),
+whose counts repeat exactly where nothing depends on timing."""
 
 import json
 import os
@@ -26,6 +26,7 @@ SF, PARTS, SEED = 0.01, 3, 2147483659
 
 DISPATCHES = "spark_tpu_stage_dispatches"
 TICKS = "spark_tpu_dispatch_sync_ticks"
+WAITS = "spark_tpu_dispatch_sync_waits"
 
 
 def _text(query):
@@ -73,12 +74,15 @@ class Served:
         token = self.source.cache_token()
         return [k for k in CACHE._entries if k[0] == token]
 
-    def sync_ticks(self, payload):
-        """The `ticks` attributes of a served query's `dispatch.sync`
+    def sync_attrs(self, payload, attr):
+        """The attribute `attr` of a served query's `dispatch.sync`
         spans, off its timeline."""
         tl = json.loads(self.get(f"/queries/{payload['query_id']}/timeline"))
-        return [s["attrs"]["ticks"] for s in tl["spans"]
+        return [s["attrs"][attr] for s in tl["spans"]
                 if s["name"] == "dispatch.sync"]
+
+    def sync_ticks(self, payload):
+        return self.sync_attrs(payload, "ticks")
 
 
 @pytest.fixture(scope="module")
@@ -175,6 +179,29 @@ def test_both_counters_are_on_metrics(served):
     from spark_tpu.observability.metrics import is_registered_metric
     assert is_registered_metric("stage_dispatches")
     assert is_registered_metric("dispatch_sync_ticks")
+
+
+def test_sync_waits_are_counted_and_never_outgrow_the_dispatches(served):
+    """`dispatch_sync_waits`: one for each sync that found its stage
+    still running and started a waiter. Whether it did is timing, so
+    the count is bounded and not fixed: at most one a dispatch, and
+    the sum of the spans' `waited`."""
+    from spark_tpu.observability.metrics import is_registered_metric
+    assert is_registered_metric("dispatch_sync_waits")
+    assert f"# TYPE {WAITS} counter" in served.get("/metrics").decode()
+    before = served.counters()
+    waited = []
+    for _ in range(3):
+        for answer in served.request():
+            waited.extend(served.sync_attrs(answer, "waited"))
+    after = served.counters()
+    grown = after[WAITS] - before[WAITS]
+    assert set(waited) <= {0, 1}, waited
+    assert grown == sum(waited)
+    assert 0 <= grown <= after[DISPATCHES] - before[DISPATCHES] == 6
+    from spark_tpu.execution.executor import SYNC_WAITER_THREAD
+    from spark_tpu.testing.lockwatch import LockWatch
+    LockWatch().assert_no_thread_leak(prefix=SYNC_WAITER_THREAD)
 
 
 def test_a_sync_that_never_polls_counts_no_tick(session, served):
