@@ -20,10 +20,12 @@ One process, one TPU chip, the entry points a user calls:
             kernel (`tpu_custom_call`) is in the compiled program.
 5. stop     the service stops; per-phase seconds are printed.
 
-`--chips 4` runs the mesh path instead (device, data, then Q3 and a
-grouped aggregate under `spark_tpu.sql.mesh.size=4` against
-single-device runs and the goldens, `meshFallback` off) and no other
-phase.
+`--chips 4` runs the mesh path instead (device, data, then under
+`spark_tpu.sql.mesh.size=4` with `meshFallback` off: a grouped
+aggregate and Q3 against single-device runs and the goldens, and
+between them the request of the benchmark's four-chip cell, Q1 then
+`q15max`, served over `POST /sql` with Q1 streaming over the mesh)
+and no other phase.
 
 `--queries` adds Q5 (`--queries Q1,Q6,Q3,Q5`). It is not in the
 default set because the whole script has 1200 seconds, compilation
@@ -80,6 +82,16 @@ AGG_GROUPS = 65536
 #: over all_to_all, final merge (10,000 supplier keys at SF1)
 MESH_AGG_SQL = ("select l_suppkey, sum(l_quantity) as qty, "
                 "count(*) as n from lineitem group by l_suppkey")
+
+#: the request of the benchmark's cell `tpch-sf10-mesh4.q1q15max`, by
+#: the cell's own texts (benchmark/queries/<name>.sql), and what makes
+#: SF1's Q1 stream over the mesh in three chunks as SF10's does in
+#: four: a chunk under the table's rows, a cache budget whose half is
+#: under the scan's estimate
+CELL_QUERIES = ("q1", "q15max")
+CELL_STREAM_CONF = {
+    "spark_tpu.sql.execution.streamingChunkRows": 1 << 21,
+    "spark_tpu.sql.io.deviceCacheBytes": 1 << 30}
 
 #: recovery actions the engine records per query; the smoke accepts none
 FAULT_PREFIX = "spark_tpu_fault_"
@@ -168,20 +180,42 @@ def _check_golden(got: pd.DataFrame, path: str, qname: str) -> None:
               .reset_index(drop=True), want.reset_index(drop=True))
 
 
-def start_service(path: str):
+def start_service(path: str, overrides: dict = None):
     from spark_tpu import Conf
     from spark_tpu.service.server import SqlService
     from spark_tpu.tpch import queries as Q
     conf = Conf()
     conf.set("spark_tpu.service.port", 0)
+    for key, value in (overrides or {}).items():
+        conf.set(key, value)
     return SqlService(
         conf, init_session=lambda s: Q.register_tables(s, path)).start()
+
+
+def _check_status_record(base: str, resp: dict) -> None:
+    rec = _http_json(f"{base}/queries/{resp['query_id']}")
+    assert rec["status"] == "ok", rec
+    assert not rec.get("fault_events"), rec
+    assert not rec.get("fault_summary"), rec
+
+
+def _clean_metrics(base: str, completed: int) -> dict:
+    """`/metrics`, after asserting that `completed` queries completed,
+    none failed and no recovery action ran."""
+    from spark_tpu.observability.metrics import parse_prometheus_text
+    with urllib.request.urlopen(f"{base}/metrics", timeout=30) as resp:
+        prom = parse_prometheus_text(resp.read().decode())
+    assert prom.get("spark_tpu_service_completed", 0) >= completed, prom
+    assert not prom.get("spark_tpu_queries_failed"), prom
+    recovered = {k: v for k, v in prom.items()
+                 if k.startswith(FAULT_PREFIX) and v}
+    assert not recovered, f"recovery actions ran: {recovered}"
+    return prom
 
 
 def phase_serve(svc, path: str, queries=SERVED) -> None:
     """`queries` over HTTP, cold then warm: golden parity, clean
     status records, clean /metrics."""
-    from spark_tpu.observability.metrics import parse_prometheus_text
     from spark_tpu.tpch import sql_queries as SQLQ
     base = f"http://127.0.0.1:{svc.port}"
     for name in queries:
@@ -194,20 +228,10 @@ def phase_serve(svc, path: str, queries=SERVED) -> None:
             assert resp["status"] == "ok", resp
             got = pd.DataFrame(resp["rows"], columns=resp["columns"])
             _check_golden(got, path, name.lower())
-            rec = _http_json(f"{base}/queries/{resp['query_id']}")
-            assert rec["status"] == "ok", rec
-            assert not rec.get("fault_events"), rec
-            assert not rec.get("fault_summary"), rec
+            _check_status_record(base, resp)
         log(f"serve: {name} rows={resp['row_count']} golden=ok "
             f"cold_ms={ms[0]:.1f} warm_ms={ms[1]:.1f}")
-    with urllib.request.urlopen(f"{base}/metrics", timeout=30) as resp:
-        prom = parse_prometheus_text(resp.read().decode())
-    assert prom.get("spark_tpu_service_completed", 0) >= 2 * len(queries), \
-        prom
-    assert not prom.get("spark_tpu_queries_failed"), prom
-    recovered = {k: v for k, v in prom.items()
-                 if k.startswith(FAULT_PREFIX) and v}
-    assert not recovered, f"recovery actions ran: {recovered}"
+    prom = _clean_metrics(base, 2 * len(queries))
     log(f"serve: /metrics completed="
         f"{int(prom['spark_tpu_service_completed'])} retries=0 "
         f"oom_rungs=0 mesh_fallback=0")
@@ -283,10 +307,69 @@ def _mesh_agg_golden(path: str) -> pd.DataFrame:
             .agg(qty=("l_quantity", "sum"), n=("l_quantity", "size")))
 
 
-def phase_mesh(spark, path: str, n: int, queries=("Q3",)) -> None:
-    """`queries` and a grouped aggregate under mesh.size=n against
-    single-device runs and the goldens; no single-device fallback, and
-    the mesh run must really lay its batches over n devices."""
+def phase_mesh_served(path: str, n: int,
+                      stream_conf: dict = CELL_STREAM_CONF) -> None:
+    """The four-chip cell's request, Q1 then `q15max`, over `POST /sql`
+    from a `SqlService` under mesh.size=n with no fallback, cold then
+    warm, Q1 streaming over the mesh: Q1 against the pandas golden,
+    `q15max` against the benchmark's exact reference, clean status
+    records, and a `/metrics` that counts the mesh's stages and
+    exchanges and no recovery. `stream_conf` is what makes Q1 stream
+    at the data's size (a rehearsal's is smaller)."""
+    from benchmark.reference import q15max as reference
+    want = float(reference.combine([reference.partial(
+        os.path.join(path, "lineitem.parquet"))])[0]["max_revenue"])
+    texts = {}
+    for name in CELL_QUERIES:
+        with open(os.path.join(CHECKOUT, "benchmark", "queries",
+                               name + ".sql")) as f:
+            texts[name] = f.read()
+    svc = start_service(path, {MESH_KEY: n, MESH_FALLBACK_KEY: False,
+                               **stream_conf})
+    base = f"http://127.0.0.1:{svc.port}"
+    try:
+        ms = {}
+        for _run in ("cold", "warm"):
+            for name in CELL_QUERIES:
+                t0 = time.perf_counter()
+                resp = _http_json(f"{base}/sql", {"sql": texts[name]})
+                ms.setdefault(name, []).append(
+                    (time.perf_counter() - t0) * 1e3)
+                assert resp["status"] == "ok", resp
+                if name == "q1":
+                    _check_golden(pd.DataFrame(
+                        resp["rows"], columns=resp["columns"]), path, name)
+                else:
+                    assert resp["rows"] == [
+                        {"one": 1, "max_revenue": want}], (resp["rows"], want)
+                _check_status_record(base, resp)
+        prom = _clean_metrics(base, 2 * len(CELL_QUERIES))
+        counted = {k: int(prom.get("spark_tpu_" + k, 0)) for k in (
+            "mesh_stage_dispatches", "stage_dispatches", "exchange_rows",
+            "exchange_bytes", "shard_rows_max", "shard_rows_total",
+            "scans_streamed", "ingest_chunks")}
+        assert counted["mesh_stage_dispatches"] \
+            == counted["stage_dispatches"] >= 2 * len(CELL_QUERIES), counted
+        assert counted["exchange_rows"] and counted["shard_rows_total"], \
+            counted
+        # Q1 streamed over the mesh on both passes, in more than a chunk
+        assert counted["scans_streamed"] >= 2, counted
+        assert counted["ingest_chunks"] >= 4, counted
+    finally:
+        svc.stop()
+    for name in CELL_QUERIES:
+        log(f"mesh: served {name} mesh.size={n} reference=ok "
+            f"cold_ms={ms[name][0]:.1f} warm_ms={ms[name][1]:.1f}")
+    log("mesh: served /metrics " + json.dumps(counted)
+        + " retries=0 mesh_fallback=0")
+
+
+def phase_mesh(spark, path: str, n: int, queries=("Q3",),
+               stream_conf: dict = CELL_STREAM_CONF) -> None:
+    """A grouped aggregate, the four-chip cell's served request and
+    `queries` under mesh.size=n against single-device runs and the
+    goldens; no single-device fallback, and the mesh run must really
+    lay its batches over n devices."""
     from spark_tpu.tpch import golden as G
     from spark_tpu.tpch import queries as Q
     from spark_tpu.tpch import sql_queries as SQLQ
@@ -331,6 +414,10 @@ def phase_mesh(spark, path: str, n: int, queries=("Q3",)) -> None:
                 f"exchanged_rows={exchanged} ms={ms:.1f}")
         G.compare(runs[n], runs[0])
         log(f"mesh: {name} mesh == single-device ok")
+        if name == "grouped_agg":
+            # before the join queries, whose compiles take minutes: a
+            # run cut short there still says whether the cell's path held
+            phase_mesh_served(path, n, stream_conf)
     spark.conf.set(MESH_KEY, 0)
 
 

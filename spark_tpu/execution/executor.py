@@ -1693,7 +1693,9 @@ class QueryExecution:
             with self.spans.span(
                     "dispatch", attempt=_attempt,
                     includes_jit_compile=getattr(
-                        self, "_last_compile_was_miss", False)):
+                        self, "_last_compile_was_miss", False)) as disp:
+                if mesh is not None:
+                    disp.attrs["mesh"] = int(mesh.devices.size)
                 faults.fire("stage_run")  # chaos seam: pre-dispatch
                 # the call returning: trace + compile on a miss,
                 # else the enqueue of the stage's program
@@ -1717,6 +1719,8 @@ class QueryExecution:
                                 ("waited", "dispatch_sync_waits")):
                             self.session.metrics.counter(counter).inc(
                                 sync.attrs.get(attr, 0))
+            if mesh is not None:
+                self._count_mesh_stage(metrics)
             # deadline BEFORE the stage-timeout check: an attempt
             # that outran the end-to-end budget raises the
             # lifecycle error (ladder stops), never a retryable
@@ -1936,6 +1940,23 @@ class QueryExecution:
                 break  # the guarded join (or an opaque op) ends the climb
 
         walk(root, ())
+
+    def _count_mesh_stage(self, metrics: Dict) -> None:
+        """What a dispatched mesh stage did, into the process counters
+        `/metrics` serves, from the stats channel `dispatch.sync` has
+        just pulled (no sync of its own), whatever the conf:
+        `mesh_stage_dispatches`, the exchanges' routed rows and bytes,
+        and of each exchange's per-shard row vector the fullest
+        shard's rows and all shards'."""
+        registry = self.session.metrics
+        registry.counter("mesh_stage_dispatches").inc()
+        for k, v in metrics.items():
+            if k.startswith("exch_rows_"):
+                registry.counter("exchange_rows").inc(int(v))
+            elif k.startswith("exch_bytes_"):
+                registry.counter("exchange_bytes").inc(int(v))
+            elif k.startswith("shard_rows_"):
+                registry.count_shard_rows(v.reshape(-1))
 
     def _record_exchange_shards(self, metrics: Dict, mesh) -> None:
         """Unpack the exchanges' per-shard row/byte vectors (emitted as

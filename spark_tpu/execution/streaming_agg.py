@@ -22,6 +22,7 @@ from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .. import types as T
 from ..columnar import Batch, Column, bucket_capacity
@@ -777,6 +778,9 @@ def stream_scan_aggregate_mesh(agg: "P.HashAggregateExec", mesh, conf,
                   [[jnp.broadcast_to(a, (n,) + a.shape) for a in row]
                    for row in accs0])
         chunk_base = 0
+        # each folded chunk's per-shard live rows, [n] on the device:
+        # pulled once, after the drain, into shard_rows_max / _total
+        folded = []
 
         def row_width(b):
             return sum(c.data.dtype.itemsize
@@ -836,6 +840,7 @@ def stream_scan_aggregate_mesh(agg: "P.HashAggregateExec", mesh, conf,
                 telem.chunk_dispatched(ci, shard_rows, row_width(b),
                                        t_disp)
             chunk_base += padded.capacity
+            folded.append(shard_rows)
             tables = out  # the step's last act: see stream_scan_aggregate
 
         def took(_out, b, ci):
@@ -852,6 +857,11 @@ def stream_scan_aggregate_mesh(agg: "P.HashAggregateExec", mesh, conf,
                 telem.finish()  # flush the last chunk's buffered records
             with drain:
                 out = jax.block_until_ready(emit_rows())
+            registry = getattr(recovery, "metrics", None)
+            if registry is not None:
+                # ready since the drain: a pull, not a wait
+                registry.count_shard_rows(
+                    np.sum(jax.device_get(folded), axis=0))
             return out if ck is None \
                 else Batch.from_arrow(with_seed(out.to_arrow()))
 

@@ -84,6 +84,18 @@ METRIC_PREFIXES = (
                        # those waits slept through with the stage
                        # still running (the sums of the dispatch.sync
                        # spans' `waited` and `ticks`)
+    # what a mesh adds (executor._run_planned after `dispatch.sync`,
+    # streaming_agg.stream_scan_aggregate_mesh at its drain): REGISTRY
+    # counters, listed for namespace closure. `shard_rows_max` /
+    # `shard_rows_total` fall under `shard_rows_` above: of every
+    # per-shard row vector a mesh stage's exchanges report, and of the
+    # rows each shard folded over a mesh stream, the fullest shard's
+    # rows and all shards' (max x shards / total - 1 is the skew)
+    "mesh_stage_",     # mesh_stage_dispatches: whole stages dispatched
+                       # under a mesh (over stage_dispatches: the share
+                       # of dispatches that cross chips)
+    "exchange_",       # exchange_rows / exchange_bytes: the sums of a
+                       # mesh stage's exch_rows_* / exch_bytes_*
     # straggler detection (observability/straggler.py): REGISTRY
     # counter, listed for namespace closure like the ingest pair
     "straggler_",      # straggler_flagged: shards flagged this process
@@ -318,6 +330,13 @@ class MetricsRegistry:
     def histogram_names(self) -> List[str]:
         with self._lock:
             return sorted(self._histograms)
+
+    def count_shard_rows(self, rows) -> None:
+        """One per-shard row vector ([n], one slot a mesh position)
+        into `shard_rows_max` / `shard_rows_total`: the fullest
+        shard's rows, and all shards'."""
+        self.counter("shard_rows_max").inc(int(max(rows)))
+        self.counter("shard_rows_total").inc(int(sum(rows)))
 
     def snapshot(self) -> Dict:
         with self._lock:

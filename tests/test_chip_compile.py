@@ -110,3 +110,47 @@ def test_mesh_pmax_pmin_compile_for_a_v5e_mesh(topo, dtype):
         sharding=NamedSharding(mesh, PartitionSpec(AXIS)))
     # the refusal came from lower/compile: returning is the proof
     assert jax.jit(bounds).lower(x).compile() is not None
+
+
+@pytest.mark.parametrize("sent", ["all_to_all", "all_gather", "psum",
+                                  "pmax_narrow"])
+def test_the_exchanges_collectives_keep_names_their_reader_knows(topo, sent):
+    """`benchmark/layer_metrics/exchange_ms.py` finds a mesh stage's
+    collectives in the device trace by the names of their HLO
+    instructions, and XLA:TPU names one after its opcode or after the
+    JAX primitive it came from (`all-gather.10`, `all_to_all.15`,
+    `pmax.6`). What `parallel/shuffle.py` and `parallel/mesh.py` send
+    (a hash exchange's `all_to_all` of 64-bit columns,
+    `all_gather_batch`, the statistics' `psum`, a widened `pmax`) is
+    compiled here for a four-chip v5e mesh, and every instruction whose
+    opcode is a collective must bear a name the reader counts."""
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from benchmark.layer_metrics.exchange_ms import is_collective
+    from spark_tpu.parallel.mesh import AXIS, pmax, shard_map
+    n, block = 4, 1 << 15
+    send = {
+        "all_to_all": lambda x: jax.lax.all_to_all(
+            x.reshape(n, block), AXIS, 0, 0).reshape(n * block),
+        "all_gather": lambda x: jax.lax.all_gather(x, AXIS).reshape(-1),
+        "psum": lambda x: jax.lax.psum(x, AXIS),
+        "pmax_narrow": lambda x: pmax(x.astype(jnp.uint8), AXIS)
+        .astype(jnp.int64),
+    }[sent]
+    mesh = Mesh(np.array(topo.devices[:n]), (AXIS,))
+    x = jax.ShapeDtypeStruct(
+        (n * n * block,), jnp.int64,
+        sharding=NamedSharding(mesh, PartitionSpec(AXIS)))
+    text = jax.jit(shard_map(
+        send, mesh=mesh, in_specs=PartitionSpec(AXIS),
+        out_specs=PartitionSpec(AXIS), check_vma=False)).lower(x) \
+        .compile().as_text()
+    sent_as = re.findall(
+        r"%([\w.-]+) = [^=]*? (?:all-to-all|all-reduce|all-gather|"
+        r"collective-permute|reduce-scatter)(?:-start|-done)?\(", text)
+    assert sent_as, text[:2000]
+    assert all(is_collective(name) for name in sent_as), sent_as
+    assert not is_collective("fusion.71") and not is_collective("copy.1")

@@ -76,5 +76,14 @@ def test_aggregate_phase_demands_the_kernel(session):
 
 
 def test_mesh_phase_on_virtual_devices(session, tpch_path):
+    # 12 k rows of lineitem: chunks of 4 Ki rows and a budget of 1 MiB
+    # make Q1 stream over the mesh in three chunks, as SF1's does on
+    # the chips under chip_smoke.CELL_STREAM_CONF
+    small = dict(zip(chip_smoke.CELL_STREAM_CONF, (1 << 12, 1 << 20)))
+    # a scan this process holds already is never streamed, and the
+    # serve phase's test may have run before this one; `--chips 4`
+    # runs the mesh phase alone
+    from spark_tpu.io.device_cache import CACHE
+    CACHE.clear()
     chip_smoke.phase_mesh(session, tpch_path, 4,
-                          chip_smoke.MESH_QUERIES)
+                          chip_smoke.MESH_QUERIES, stream_conf=small)

@@ -37,13 +37,18 @@ def _text(query):
 class Served:
     """A service over one table, and a client of it."""
 
-    def __init__(self, directory):
+    #: the request, as the cell's client sends it
+    QUERIES = ("q1", "q6")
+
+    def __init__(self, directory, **overrides):
         from spark_tpu import Conf
         from spark_tpu.io.sources import ParquetSource
         from spark_tpu.service.server import SqlService
         self.directory = directory
         self.source = ParquetSource(directory, "lineitem")
         conf = Conf().set("spark_tpu.service.port", 0)
+        for key, value in overrides.items():
+            conf.set(key, value)
         self.svc = SqlService(
             conf, init_session=lambda s: s.register_table(
                 "lineitem", self.source)).start()
@@ -63,8 +68,7 @@ class Served:
         return payload
 
     def request(self):
-        """Q1 then Q6, as the cell's client sends them."""
-        return [self.sql(_text("q1")), self.sql(_text("q6"))]
+        return [self.sql(_text(q)) for q in self.QUERIES]
 
     def counters(self):
         return parse_prometheus(self.get("/metrics").decode())
@@ -77,9 +81,12 @@ class Served:
     def sync_attrs(self, payload, attr):
         """The attribute `attr` of a served query's `dispatch.sync`
         spans, off its timeline."""
-        tl = json.loads(self.get(f"/queries/{payload['query_id']}/timeline"))
-        return [s["attrs"][attr] for s in tl["spans"]
+        return [s["attrs"][attr] for s in self.timeline(payload)["spans"]
                 if s["name"] == "dispatch.sync"]
+
+    def timeline(self, payload):
+        return json.loads(
+            self.get(f"/queries/{payload['query_id']}/timeline"))
 
     def sync_ticks(self, payload):
         return self.sync_attrs(payload, "ticks")
