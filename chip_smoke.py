@@ -24,8 +24,9 @@ One process, one TPU chip, the entry points a user calls:
 `spark_tpu.sql.mesh.size=4` with `meshFallback` off: a grouped
 aggregate and Q3 against single-device runs and the goldens, and
 between them the request of the benchmark's four-chip cell, Q1 then
-`q15max`, served over `POST /sql` with Q1 streaming over the mesh)
-and no other phase.
+`q15max`, served over `POST /sql` twice: with a cache budget cut so
+that Q1 streams over the mesh, then as the cell runs it, both scans
+held sharded over the chips' device-table caches) and no other phase.
 
 `--queries` adds Q5 (`--queries Q1,Q6,Q3,Q5`). It is not in the
 default set because the whole script has 1200 seconds, compilation
@@ -51,6 +52,7 @@ from __future__ import annotations
 
 import argparse
 import collections
+import gc
 import json
 import os
 import sys
@@ -84,14 +86,17 @@ MESH_AGG_SQL = ("select l_suppkey, sum(l_quantity) as qty, "
                 "count(*) as n from lineitem group by l_suppkey")
 
 #: the request of the benchmark's cell `tpch-sf10-mesh4.q1q15max`, by
-#: the cell's own texts (benchmark/queries/<name>.sql), and what makes
-#: SF1's Q1 stream over the mesh in three chunks as SF10's does in
-#: four: a chunk under the table's rows, a cache budget whose half is
-#: under the scan's estimate
+#: the cell's own texts (benchmark/queries/<name>.sql). A chunk under
+#: the table's rows has the residency verdict asked, as it is at SF10;
+#: with the engine's own cache budget both scans are then held, laid
+#: over the chips. What makes SF1's Q1 stream over the mesh in three
+#: chunks instead, as a scan does that exceeds the chips' caches: a
+#: chip's budget whose half is under a shard's part of the scan's
+#: estimate (996 MB over four shards: 249 MB)
 CELL_QUERIES = ("q1", "q15max")
-CELL_STREAM_CONF = {
-    "spark_tpu.sql.execution.streamingChunkRows": 1 << 21,
-    "spark_tpu.sql.io.deviceCacheBytes": 1 << 30}
+CELL_CONF = {"spark_tpu.sql.execution.streamingChunkRows": 1 << 21}
+CELL_STREAM_CONF = {**CELL_CONF,
+                    "spark_tpu.sql.io.deviceCacheBytes": 256 << 20}
 
 #: recovery actions the engine records per query; the smoke accepts none
 FAULT_PREFIX = "spark_tpu_fault_"
@@ -307,16 +312,28 @@ def _mesh_agg_golden(path: str) -> pd.DataFrame:
             .agg(qty=("l_quantity", "sum"), n=("l_quantity", "size")))
 
 
-def phase_mesh_served(path: str, n: int,
-                      stream_conf: dict = CELL_STREAM_CONF) -> None:
+def _bytes_in_use(n: int):
+    """Bytes in use by device, or None where the backend keeps no
+    memory statistics (the CPU's)."""
+    import jax
+    stats = [d.memory_stats() for d in jax.devices()[:n]]
+    if not all(stats):
+        return None
+    return [int(st["bytes_in_use"]) for st in stats]
+
+
+def phase_mesh_served(path: str, n: int, conf: dict, streams: bool) -> None:
     """The four-chip cell's request, Q1 then `q15max`, over `POST /sql`
     from a `SqlService` under mesh.size=n with no fallback, cold then
-    warm, Q1 streaming over the mesh: Q1 against the pandas golden,
-    `q15max` against the benchmark's exact reference, clean status
-    records, and a `/metrics` that counts the mesh's stages and
-    exchanges and no recovery. `stream_conf` is what makes Q1 stream
-    at the data's size (a rehearsal's is smaller)."""
+    warm: Q1 against the pandas golden, `q15max` against the
+    benchmark's exact reference, clean status records, and a
+    `/metrics` that counts the mesh's stages and exchanges and no
+    recovery. Under a `conf` that `streams`, Q1 streams over the mesh
+    on both passes; else both scans are loaded once, laid over the
+    devices, and found there: no chunk is ingested, and the devices'
+    bytes in use grow by the same."""
     from benchmark.reference import q15max as reference
+    from spark_tpu.io.device_cache import CACHE
     want = float(reference.combine([reference.partial(
         os.path.join(path, "lineitem.parquet"))])[0]["max_revenue"])
     texts = {}
@@ -324,10 +341,21 @@ def phase_mesh_served(path: str, n: int,
         with open(os.path.join(CHECKOUT, "benchmark", "queries",
                                name + ".sql")) as f:
             texts[name] = f.read()
+    # a scan this process holds already is never streamed, nor loaded;
+    # and what an earlier phase left for the collector goes now, not
+    # between this phase's two readings of the devices' memory
+    CACHE.clear()
+    gc.collect()
+    names = ("mesh_stage_dispatches", "stage_dispatches", "exchange_rows",
+             "exchange_bytes", "shard_rows_max", "shard_rows_total",
+             "scans_streamed", "scans_resident", "ingest_chunks")
     svc = start_service(path, {MESH_KEY: n, MESH_FALLBACK_KEY: False,
-                               **stream_conf})
+                               **conf})
     base = f"http://127.0.0.1:{svc.port}"
     try:
+        # the registry is the process's: what this service adds to it
+        before = _clean_metrics(base, 0)
+        loads, in_use = CACHE.sharded_loads, _bytes_in_use(n)
         ms = {}
         for _run in ("cold", "warm"):
             for name in CELL_QUERIES:
@@ -344,32 +372,47 @@ def phase_mesh_served(path: str, n: int,
                         {"one": 1, "max_revenue": want}], (resp["rows"], want)
                 _check_status_record(base, resp)
         prom = _clean_metrics(base, 2 * len(CELL_QUERIES))
-        counted = {k: int(prom.get("spark_tpu_" + k, 0)) for k in (
-            "mesh_stage_dispatches", "stage_dispatches", "exchange_rows",
-            "exchange_bytes", "shard_rows_max", "shard_rows_total",
-            "scans_streamed", "ingest_chunks")}
+        counted = {k: int(prom.get("spark_tpu_" + k, 0)
+                          - before.get("spark_tpu_" + k, 0)) for k in names}
+        counted["sharded_loads"] = CACHE.sharded_loads - loads
         assert counted["mesh_stage_dispatches"] \
             == counted["stage_dispatches"] >= 2 * len(CELL_QUERIES), counted
         assert counted["exchange_rows"] and counted["shard_rows_total"], \
             counted
-        # Q1 streamed over the mesh on both passes, in more than a chunk
-        assert counted["scans_streamed"] >= 2, counted
-        assert counted["ingest_chunks"] >= 4, counted
+        if streams:
+            # Q1 over the mesh on both passes, in more than a chunk
+            assert counted["scans_streamed"] >= 2, counted
+            assert counted["ingest_chunks"] >= 4, counted
+        else:
+            assert counted["scans_streamed"] == 0, counted
+            assert counted["ingest_chunks"] == 0, counted
+            assert counted["scans_resident"] == 2 * len(CELL_QUERIES), \
+                counted
+            assert counted["sharded_loads"] == len(CELL_QUERIES), counted
+            grown = None if in_use is None else [
+                b - a for a, b in zip(in_use, _bytes_in_use(n))]
+            # what the cache holds now was put where it is used: no
+            # device took more than a tenth above another
+            assert grown is None or 0 < max(grown) <= 1.1 * min(grown), grown
+            log(f"mesh: served bytes in use by device, grown by {grown}")
     finally:
         svc.stop()
+    how = "streamed" if streams else "held"
     for name in CELL_QUERIES:
-        log(f"mesh: served {name} mesh.size={n} reference=ok "
+        log(f"mesh: served ({how}) {name} mesh.size={n} reference=ok "
             f"cold_ms={ms[name][0]:.1f} warm_ms={ms[name][1]:.1f}")
-    log("mesh: served /metrics " + json.dumps(counted)
+    log(f"mesh: served ({how}) /metrics " + json.dumps(counted)
         + " retries=0 mesh_fallback=0")
 
 
 def phase_mesh(spark, path: str, n: int, queries=("Q3",),
-               stream_conf: dict = CELL_STREAM_CONF) -> None:
-    """A grouped aggregate, the four-chip cell's served request and
-    `queries` under mesh.size=n against single-device runs and the
-    goldens; no single-device fallback, and the mesh run must really
-    lay its batches over n devices."""
+               stream_conf: dict = CELL_STREAM_CONF,
+               cell_conf: dict = CELL_CONF) -> None:
+    """A grouped aggregate, the four-chip cell's served request
+    (streamed under `stream_conf`, then held under `cell_conf`; a
+    rehearsal's are smaller) and `queries` under mesh.size=n against
+    single-device runs and the goldens; no single-device fallback, and
+    the mesh run must really lay its batches over n devices."""
     from spark_tpu.tpch import golden as G
     from spark_tpu.tpch import queries as Q
     from spark_tpu.tpch import sql_queries as SQLQ
@@ -417,13 +460,13 @@ def phase_mesh(spark, path: str, n: int, queries=("Q3",),
         if name == "grouped_agg":
             # before the join queries, whose compiles take minutes: a
             # run cut short there still says whether the cell's path held
-            phase_mesh_served(path, n, stream_conf)
+            phase_mesh_served(path, n, stream_conf, streams=True)
+            phase_mesh_served(path, n, cell_conf, streams=False)
     spark.conf.set(MESH_KEY, 0)
 
 
 def assert_devices_held_data(n: int) -> None:
-    """Scans land on device 0 and the mesh program reshards them, so
-    the other chips show memory in use only if shards really went
+    """Every chip shows memory in use only if shards really went
     there. (TPU only: the CPU backend reports no memory statistics.)"""
     import jax
     peaks = {d.id: d.memory_stats()["peak_bytes_in_use"]

@@ -270,6 +270,10 @@ GUARDED_BY: Tuple[GuardDecl, ...] = (
               "misses", "_lock"),
     GuardDecl("spark_tpu/io/device_cache.py", "DeviceTableCache",
               "evictions", "_lock"),
+    GuardDecl("spark_tpu/io/device_cache.py", "DeviceTableCache",
+              "sharded_loads", "_lock"),
+    GuardDecl("spark_tpu/io/device_cache.py", "DeviceTableCache",
+              "_dealt", "_lock"),
     # arbiter + result cache
     GuardDecl(_SVC + "arbiter.py", "DeviceResourceArbiter", "_leases",
               "_cv"),
@@ -585,6 +589,10 @@ CALLED_WITH_LOCK_HELD: Dict[Tuple[str, str, str], str] = {
     # checkout; _reap_locked only mutates _idle/_live under it
     ("spark_tpu/udf_worker/pool.py", "UdfWorkerPool",
      "_reap_locked"): "_cv",
+    # the one place an entry is forgotten (its bytes, its deal): put,
+    # evict_bytes and invalidate_token call it under their `with`
+    ("spark_tpu/io/device_cache.py", "DeviceTableCache",
+     "_drop"): "_lock",
 }
 
 #: acquisition-order edges the lexical extractor cannot see (locks

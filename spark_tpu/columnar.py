@@ -156,21 +156,34 @@ class Batch:
 
     @staticmethod
     def from_arrow(table: pa.Table, growth: float = 2.0,
-                   capacity: Optional[int] = None) -> "Batch":
+                   capacity: Optional[int] = None,
+                   placement: Optional["ShardedPlacement"] = None
+                   ) -> "Batch":
         """Ingest a pyarrow table: dictionary-encode strings, pad to bucket.
 
         Replaces the reference's vectorized Parquet column readers
         (`VectorizedParquetRecordReader.java:54`) as the host->HBM edge.
         `capacity` forces a fixed padded size (chunked loads keep one
-        compiled shape across chunks)."""
+        compiled shape across chunks). Under a `placement` the rows
+        are dealt over a mesh's shards on the host and every array is
+        put sharded: no device ever holds a whole column, and
+        `placement.dealt` says what each shard got."""
         n = table.num_rows
         cap = capacity if capacity is not None else bucket_capacity(n, growth)
+        if placement is not None:
+            cap = placement.capacity(cap)
         assert cap >= n, (cap, n)
         cols: Dict[str, Column] = {}
         for name, col in zip(table.column_names, table.columns):
-            cols[name] = _arrow_to_column(name, col, n, cap)
-        sel = jnp.arange(cap) < n
-        return Batch(cols, sel)
+            cols[name] = _arrow_to_column(name, col, n, cap, placement)
+        if placement is None:
+            return Batch(cols, jnp.arange(cap) < n)
+        stripes = placement.stripes(n, cap)
+        mask = np.zeros(cap, dtype=np.bool_)
+        for _src, count, dst in stripes:
+            mask[dst:dst + count] = True
+        placement.dealt = tuple(count for _, count, _ in stripes)
+        return Batch(cols, placement.put(mask))
 
     # -- shape/meta ---------------------------------------------------------
 
@@ -394,18 +407,64 @@ def _np_to_dtype(np_dtype) -> T.DataType:
     return m[np_dtype]
 
 
-def _arrow_to_column(name: str, col, n: int, cap: int) -> Column:
+class ShardedPlacement:
+    """Where a loaded table's device copy lies under a mesh: every
+    array sharded on dim 0 over the mesh's data axis, the live rows
+    dealt over the shards in order and evenly. `stripes` is the deal,
+    made on the host before any put: shard i holds rows
+    [src, src + count) of the table at [i * local, i * local + count)
+    of the padded capacity, first rows on shard 0, counts a row apart
+    at most, so position-dependent aggregates see the table's order
+    and no shard waits for a fuller one. One is made for one load:
+    `dealt` is the live rows `Batch.from_arrow` gave each shard under
+    it (host knowledge, for the counters; None before)."""
+
+    __slots__ = ("shards", "sharding", "dealt")
+
+    def __init__(self, mesh, axis: str):
+        from jax.sharding import NamedSharding, PartitionSpec
+        self.shards = int(mesh.devices.size)
+        self.sharding = NamedSharding(mesh, PartitionSpec(axis))
+        self.dealt: Optional[Tuple[int, ...]] = None
+
+    def capacity(self, cap: int) -> int:
+        """`cap` made a multiple of the shards (a power-of-two bucket
+        over a gang of three is not)."""
+        return cap + (-cap) % self.shards
+
+    def stripes(self, rows: int, cap: int) -> List[Tuple[int, int, int]]:
+        """(src, count, dst) a shard."""
+        local = cap // self.shards
+        base, extra = divmod(rows, self.shards)
+        out, src = [], 0
+        for i in range(self.shards):
+            count = base + (i < extra)
+            out.append((src, count, i * local))
+            src += count
+        return out
+
+    def put(self, host: np.ndarray):
+        """Each device is sent its stripe of `host` from the host."""
+        return jax.device_put(host, self.sharding)
+
+
+def _arrow_to_column(name: str, col, n: int, cap: int,
+                     placement: Optional[ShardedPlacement] = None
+                     ) -> Column:
     """One Arrow column to a device Column. Inside a query it leaves
     two spans: `chunk.convert` (Arrow to the padded numpy buffer:
     decimal limb copy, cast, code remap, pad) and `chunk.put` (the
     `jax.device_put` calls, i.e. staging: a put is not a sync)."""
     if pa.types.is_list(col.type) or pa.types.is_large_list(col.type):
+        # offsets are absolute into the flattened values: a list
+        # column has no stripes (`io/device_cache.py::scan_mesh`)
+        assert placement is None, name
         arr = col.combine_chunks() if isinstance(col, pa.ChunkedArray) \
             else col
         return _arrow_list_to_column(name, arr, n, cap)
     with span("chunk.convert", column=name):
-        host = _arrow_to_padded(name, col, n, cap)
-    return host.put()
+        host = _arrow_to_padded(name, col, n, cap, placement)
+    return host.put(placement)
 
 
 def device_dtype(name: str, at: pa.DataType) -> T.DataType:
@@ -554,13 +613,39 @@ def merge_dictionaries(dicts: Sequence[pa.Array]
     return merged, maps
 
 
+def sort_dictionary(merged: pa.Array, maps: list) -> Tuple[pa.Array, list]:
+    """`merge_dictionaries`' result with the values in sorted order:
+    a table held whole over a mesh (`_arrow_to_padded` under a
+    placement; on one device a dictionary keeps the order of first
+    appearance, as it did) then carries the same dictionary whatever
+    order its rows brought the values in, so a stage over it (whose
+    program holds tables made from the dictionary: a sort's ranks) is
+    the same program for every data set of the same values, and the
+    compile caches find it again, as they found the streamed scan's
+    programs, which carry codes only. Work is per dictionary entry."""
+    import pyarrow.compute as pc
+    if merged is None or len(merged) < 2:
+        return merged, maps
+    order = pc.sort_indices(merged).to_numpy(zero_copy_only=False)
+    if np.array_equal(order, np.arange(len(order))):
+        return merged, maps
+    to_sorted = np.empty(len(order), dtype=np.int32)
+    to_sorted[order] = np.arange(len(order), dtype=np.int32)
+    made = {}   # consecutive pieces share one map
+    for m in maps:
+        if id(m) not in made:
+            made[id(m)] = to_sorted if m is None else to_sorted[m]
+    return merged.take(pa.array(order)), [made[id(m)] for m in maps]
+
+
 class HostColumn:
     """The host half of a non-list Column while it is filled: `data`
     padded to the capacity in the device dtype, `validity` made when
     the first null shows (`new_mask`), `dictionary` for a string
-    column, `rows` filled so far. `data` and what `new_mask` returns
-    are zero past `rows`, or the caller's to zero there: `put` hands
-    both to `jax.device_put` as they are."""
+    column, `rows` filled so far (`append` writes there: a caller
+    that deals rows over stripes moves it). `data` and what `new_mask`
+    returns are zero past `rows`, or the caller's to zero there: `put`
+    hands both to `jax.device_put` as they are."""
 
     __slots__ = ("name", "dtype", "data", "validity", "dictionary",
                  "rows", "_new_mask")
@@ -592,13 +677,16 @@ class HostColumn:
         return self.data.nbytes + (self.validity.nbytes
                                    if self.validity is not None else 0)
 
-    def put(self) -> Column:
+    def put(self, placement: Optional[ShardedPlacement] = None) -> Column:
+        """To the default device, or under a `placement` sharded over
+        its mesh."""
+        put = jax.device_put if placement is None else placement.put
         with span("chunk.put", column=self.name, bytes=self.nbytes):
-            validity = jax.device_put(self.validity) \
+            validity = put(self.validity) \
                 if self.validity is not None else None
             # device_put is ~2x jnp.asarray for host->device of large
             # buffers
-            data = jax.device_put(self.data)
+            data = put(self.data)
         dictionary = self.dictionary
         if dictionary is None and isinstance(self.dtype, T.StringType):
             # a `null` column, or one of no chunk: no value, no code
@@ -617,10 +705,14 @@ def as_dictionary_pieces(col) -> list:
     return col.chunks
 
 
-def _arrow_to_padded(name: str, col, n: int, cap: int) -> HostColumn:
+def _arrow_to_padded(name: str, col, n: int, cap: int,
+                     placement: Optional[ShardedPlacement] = None
+                     ) -> HostColumn:
     """A non-list Arrow column in new buffers padded to `cap`: all
     host work. The column's chunks are filled in one after the other
-    (`fill_padded`), never made one array first."""
+    (`fill_padded`), never made one array first; under a `placement`
+    each piece is cut where a shard's share ends and written where
+    that shard's rows lie, which is still the one copy a row makes."""
     dt = device_dtype(name, col.type)
     host = HostColumn(name, dt, np.zeros(cap, dtype=dt.np_dtype),
                       lambda: np.zeros(cap, dtype=np.bool_))
@@ -628,12 +720,35 @@ def _arrow_to_padded(name: str, col, n: int, cap: int) -> HostColumn:
         pieces = as_dictionary_pieces(col)
         host.dictionary, maps = merge_dictionaries(
             [p.dictionary for p in pieces])
+        if placement is not None:
+            # held whole over the mesh where it was streamed as codes:
+            # the stage's program must not hold this data set's order
+            host.dictionary, maps = sort_dictionary(host.dictionary, maps)
     else:
         pieces = col.chunks if isinstance(col, pa.ChunkedArray) else [col]
         maps = [None] * len(pieces)
+    # (src, count, dst) a shard; with no mesh the one stripe is the table
+    stripes = [(0, n, 0)] if placement is None \
+        else placement.stripes(n, cap)
+    row, shard = 0, 0   # the table's row the next piece starts at
     for piece, code_map in zip(pieces, maps):
-        host.append(piece, code_map)
-    assert host.rows == n, (name, host.rows, n)
+        done = 0
+        while done < len(piece):
+            src, count, dst = stripes[shard]
+            take = min(len(piece) - done, src + count - row)
+            if take == 0:
+                shard += 1
+                continue
+            host.rows = dst + row - src
+            host.append(piece.slice(done, take), code_map)
+            done += take
+            row += take
+    assert row == n, (name, row, n)
+    if host.validity is not None:
+        # `append` reads what lies before its cursor as valid rows
+        local = cap // len(stripes)
+        for _src, count, dst in stripes:
+            host.validity[dst + count:dst + local] = False
     return host
 
 

@@ -1639,7 +1639,7 @@ class QueryExecution:
         self._collect_scans(root, scans)
 
         from . import lifecycle
-        from ..io.device_cache import load_scan
+        from ..io.device_cache import load_scan, scan_mesh
         # a scan that loads (a cache miss, an in-memory table) leaves
         # its columns' chunk.convert / chunk.put spans under this one
         with self.spans.span("ingest", scans=len(scans)) as sp:
@@ -1653,10 +1653,16 @@ class QueryExecution:
             for s in scans:
                 if id(s) in loaded:
                     continue
-                b = load_scan(s, self._conf) \
-                    if isinstance(s, P.ScanExec) else s.load()
+                b, dealt = load_scan(s, self._conf, scan_mesh(s, mesh)) \
+                    if isinstance(s, P.ScanExec) else (s.load(), None)
                 if mesh is not None:
                     from ..parallel import pad_batch_to_multiple
+                    if dealt is not None:
+                        # a scan laid over the mesh at its load: the
+                        # stage finds its rows in place, and the host
+                        # knows how it dealt them
+                        sp.attrs["mesh"] = int(mesh.devices.size)
+                        self.session.metrics.count_shard_rows(dealt)
                     b = pad_batch_to_multiple(b, int(mesh.devices.size))
                 loaded[id(s)] = b
             scan_batches = [loaded[id(s)] for s in scans]
@@ -1665,7 +1671,8 @@ class QueryExecution:
         t0 = time.perf_counter()
         token = None
         if mesh is not None:
-            token = jnp.zeros((int(mesh.devices.size),), jnp.int32)
+            from ..parallel.mesh import stage_token
+            token = stage_token(mesh)
         # static analysis, jaxpr half: abstract-eval the exact stage
         # callable about to be jitted (gated; memoized per stage key),
         # then publish the combined findings on the bus

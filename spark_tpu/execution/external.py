@@ -101,7 +101,7 @@ def try_external_collect(session, plan: P.PhysicalPlan, conf,
     limit, sort, chain, leaf = m
     if not hasattr(leaf.source, "load_chunks"):
         return None
-    if admit_scan_resident(conf, leaf):
+    if admit_scan_resident(conf, leaf, None):
         return None  # fits resident (per-query budget or leased from
         # the shared arbiter pool): the normal path keeps it on device
 

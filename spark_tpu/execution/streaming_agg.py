@@ -148,8 +148,11 @@ def _materialize_subtree(root: P.PhysicalPlan, conf, recovery=None) -> Batch:
 
     collect(root)
     from ..io.device_cache import load_scan
-    inputs = [load_scan(s, conf) if isinstance(s, P.ScanExec) else s.load()
-              for s in scans]
+    # the subtree's program below is jitted for one device (no
+    # shard_map), so it asks for the one-device copy whatever mesh the
+    # query runs under, as it always has
+    inputs = [load_scan(s, conf, None)[0] if isinstance(s, P.ScanExec)
+              else s.load() for s in scans]
     # the executor's capacity setters, so every overflow family the main
     # AQE loop knows (join/exchange/aggregate) retries here too
     from .executor import QueryExecution
@@ -529,8 +532,8 @@ def try_stream_aggregate_spill(agg: "P.HashAggregateExec", conf,
     if not out_of_core_active(conf):
         return None
     found = _spillable_scan(agg)
-    if found is None or admit_scan_resident(conf, found[1]):
-        return None
+    if found is None or admit_scan_resident(conf, found[1], None):
+        return None  # (the executor comes here with no mesh only)
     return stream_scan_aggregate_spill(agg, *found, conf, cache, recovery)
 
 
@@ -680,7 +683,9 @@ def stream_scan_aggregate_mesh(agg: "P.HashAggregateExec", mesh, conf,
     est = leaf.source.estimated_rows()
     if est is not None and est <= chunk_rows:
         return None
-    if _prefer_resident(leaf, conf, getattr(recovery, "metrics", None)):
+    from ..io.device_cache import scan_mesh
+    if _prefer_resident(leaf, conf, scan_mesh(leaf, mesh),
+                        getattr(recovery, "metrics", None)):
         return None
 
     import contextlib
@@ -878,24 +883,28 @@ def stream_scan_aggregate_mesh(agg: "P.HashAggregateExec", mesh, conf,
                      start=ck.cursor if ck is not None else 0)
 
 
-def _prefer_resident(leaf: "P.ScanExec", conf, metrics=None) -> bool:
+def _prefer_resident(leaf: "P.ScanExec", conf, mesh,
+                     metrics=None) -> bool:
     """True when the scan should load whole and ride the device-table
     cache instead of streaming: it's already cached, or its estimated
     footprint fits in half the cache budget (so repeated queries skip
-    host ingest entirely — the round-3 headline perf fix). The verdict
-    counts into `scans_resident` / `scans_streamed` of `metrics`: a
-    streamed scan asks the cache only `contains()`, which counts
-    neither a hit nor a miss."""
-    resident = _resident_verdict(leaf, conf)
+    host ingest entirely — the round-3 headline perf fix). The budget
+    is a chip's: under a `mesh` (what `scan_mesh` gave for this scan;
+    None: one device) the scan is laid over its shards and what one
+    of them must hold is the estimate's share. The verdict counts into `scans_resident` /
+    `scans_streamed` of `metrics`: a streamed scan asks the cache only
+    `contains()`, which counts neither a hit nor a miss."""
+    resident = _resident_verdict(leaf, conf, mesh)
     if metrics is not None:
         metrics.counter("scans_resident" if resident
                         else "scans_streamed").inc()
     return resident
 
 
-def _resident_verdict(leaf: "P.ScanExec", conf) -> bool:
-    from ..io.device_cache import (CACHE_BYTES_KEY, estimated_scan_bytes,
-                                   is_cached, scan_cache_key)
+def _resident_verdict(leaf: "P.ScanExec", conf, mesh) -> bool:
+    from ..io.device_cache import (CACHE_BYTES_KEY, chip_share,
+                                   estimated_scan_bytes, is_cached,
+                                   scan_cache_key)
     from ..service.arbiter import admit_scan_resident
     # cheap disqualifiers FIRST: admit_scan_resident takes a
     # full-estimate lease from the shared pool, and leases are held to
@@ -904,13 +913,13 @@ def _resident_verdict(leaf: "P.ScanExec", conf) -> bool:
     budget = int(conf.get(CACHE_BYTES_KEY))
     if budget <= 0:
         return False
-    if scan_cache_key(leaf) is None:
+    if scan_cache_key(leaf, mesh) is None:
         return False  # uncacheable source: residency would re-ingest
-    if not is_cached(leaf):
-        est_b = estimated_scan_bytes(leaf)
+    if not is_cached(leaf, mesh):
+        est_b = chip_share(estimated_scan_bytes(leaf), mesh)
         if est_b is None or est_b > budget // 2:
             return False
-    return admit_scan_resident(conf, leaf)
+    return admit_scan_resident(conf, leaf, mesh)
     # False = over the per-query budget, or the shared-pool lease was
     # denied (arbiter): must stream
 
@@ -940,6 +949,7 @@ def try_stream_aggregate(agg: "P.HashAggregateExec", conf,
         return None
     if not hasattr(leaf.source, "load_chunks"):
         return None
-    if _prefer_resident(leaf, conf, getattr(recovery, "metrics", None)):
+    if _prefer_resident(leaf, conf, None,
+                        getattr(recovery, "metrics", None)):
         return None
     return stream_scan_aggregate(agg, chain, leaf, conf, cache, recovery)

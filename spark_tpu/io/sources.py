@@ -120,7 +120,10 @@ class TableSource:
         return False
 
     def load(self, required_columns: Optional[Sequence[str]],
-             pushed_filters: Sequence[Expression]) -> Batch:
+             pushed_filters: Sequence[Expression],
+             placement=None) -> Batch:
+        """The scan's rows as one device Batch; under a `placement`
+        (`columnar.ShardedPlacement`) dealt over a mesh's shards."""
         raise NotImplementedError
 
     def estimated_rows(self) -> Optional[int]:
@@ -648,7 +651,8 @@ class ArrowTableSource(TableSource):
         self._column_stats = stats
         return stats
 
-    def load(self, required_columns, pushed_filters) -> Batch:
+    def load(self, required_columns, pushed_filters,
+             placement=None) -> Batch:
         from ..testing import faults
         faults.fire("scan_load")  # chaos seam: host->HBM ingest edge
         t = self.table
@@ -658,7 +662,7 @@ class ArrowTableSource(TableSource):
                 t = t.filter(ae)
         if required_columns is not None:
             t = t.select(list(required_columns))
-        return Batch.from_arrow(t)
+        return Batch.from_arrow(t, placement=placement)
 
     def load_chunks(self, required_columns, pushed_filters,
                     chunk_rows: int) -> ChunkIterator:
@@ -867,7 +871,8 @@ class ParquetSource(TableSource):
         except Exception:
             return None
 
-    def load(self, required_columns, pushed_filters) -> Batch:
+    def load(self, required_columns, pushed_filters,
+             placement=None) -> Batch:
         from ..testing import faults
         faults.fire("scan_load")  # chaos seam: host->HBM ingest edge
         ae = None
@@ -878,7 +883,7 @@ class ParquetSource(TableSource):
         t = self._dataset.to_table(
             columns=list(required_columns) if required_columns is not None else None,
             filter=ae)
-        return Batch.from_arrow(t)
+        return Batch.from_arrow(t, placement=placement)
 
     def load_chunks(self, required_columns, pushed_filters,
                     chunk_rows: int) -> ChunkIterator:

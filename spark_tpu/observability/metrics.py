@@ -88,9 +88,11 @@ METRIC_PREFIXES = (
     # streaming_agg.stream_scan_aggregate_mesh at its drain): REGISTRY
     # counters, listed for namespace closure. `shard_rows_max` /
     # `shard_rows_total` fall under `shard_rows_` above: of every
-    # per-shard row vector a mesh stage's exchanges report, and of the
-    # rows each shard folded over a mesh stream, the fullest shard's
-    # rows and all shards' (max x shards / total - 1 is the skew)
+    # per-shard row vector a mesh stage's exchanges report, of the
+    # rows each shard folded over a mesh stream, and of the rows the
+    # host dealt each shard of a scan held over the mesh, the fullest
+    # shard's rows and all shards' (max x shards / total - 1 is the
+    # skew)
     "mesh_stage_",     # mesh_stage_dispatches: whole stages dispatched
                        # under a mesh (over stage_dispatches: the share
                        # of dispatches that cross chips)

@@ -101,6 +101,28 @@ def get_mesh(conf) -> Optional[Mesh]:
     return Mesh(np.array(devices[:n]), (AXIS,))
 
 
+#: (mesh, its stage token): the gang in use only, so a gang that was
+#: shrunk or restarted leaves no buffer behind on the devices it had
+_TOKEN: tuple = (None, None)
+
+
+def stage_token(mesh: Mesh):
+    """The [n] int32 argument that gives a mesh stage its shard axis,
+    made once for the mesh in use and laid over it: a dispatch that
+    made its own on the default device would have it moved to the
+    shards every time."""
+    global _TOKEN
+    held, token = _TOKEN
+    if held != mesh:
+        import numpy as np
+        from jax.sharding import NamedSharding, PartitionSpec
+        token = jax.device_put(
+            np.zeros((int(mesh.devices.size),), np.int32),
+            NamedSharding(mesh, PartitionSpec(AXIS)))
+        _TOKEN = (mesh, token)
+    return token
+
+
 def shard_hosts(mesh: Mesh) -> list:
     """Per-shard host identity for telemetry records: the JAX process
     index owning each data-axis position's device (0 for every shard on

@@ -288,8 +288,12 @@ class ScanExec(LeafExec):
             return full
         return T.Schema([full.field(n) for n in self.required_columns])
 
-    def load(self) -> Batch:
-        return self.source.load(self.required_columns, self.pushed_filters)
+    def load(self, placement=None) -> Batch:
+        if placement is None:  # a source written before placements
+            return self.source.load(self.required_columns,
+                                    self.pushed_filters)
+        return self.source.load(self.required_columns, self.pushed_filters,
+                                placement)
 
     def compute(self, ctx, inputs):
         # the executor substitutes the loaded batch

@@ -332,10 +332,14 @@ def release_current() -> None:
 # ---------------------------------------------------------------------------
 
 
-def admit_scan_resident(conf, leaf) -> bool:
+def admit_scan_resident(conf, leaf, mesh) -> bool:
     """May this scan's working set stay device-resident? The ONE
     residency verdict consulted by every out-of-core gate (external
-    collect, streaming partial spill, resident-preference):
+    collect, streaming partial spill, resident-preference). `mesh` is
+    the one the scan would be laid over (`device_cache.scan_mesh`;
+    None: one device), which its cache key names. Budget, pool and the
+    cache's count are a chip's, so what is weighed and leased is what
+    one chip must hold of the estimate (`device_cache.chip_share`):
 
     - explicit per-query deviceBudget (a test conf or the OOM ladder's
       rung-2 overlay) keeps legacy semantics: est <= budget, unknown
@@ -345,27 +349,27 @@ def admit_scan_resident(conf, leaf) -> bool:
       spill/stream re-plan);
     - otherwise legacy: no budget configured = always resident.
     """
-    from ..io.device_cache import (estimated_scan_bytes, is_cached,
-                                   scan_cache_key)
+    from ..io.device_cache import (chip_share, estimated_scan_bytes,
+                                   is_cached, scan_cache_key)
     budget = int(conf.get(DEVICE_BUDGET_KEY))
     arb = _ARBITER
     if budget > 0:
-        est = estimated_scan_bytes(leaf)
+        est = chip_share(estimated_scan_bytes(leaf), mesh)
         return est is not None and est <= budget
     if arb is None:
         return True
-    if is_cached(leaf):
+    if is_cached(leaf, mesh):
         # already device-resident: its bytes count against the pool as
         # STORAGE (headroom subtracts CACHE.nbytes), so taking a lease
         # too would double-count — and evict the very table the query
         # is about to reuse. Pin it instead: lease pressure must not
         # evict bytes this execution still references.
-        arb.pin_storage(_OWNER.get(), scan_cache_key(leaf))
+        arb.pin_storage(_OWNER.get(), scan_cache_key(leaf, mesh))
         return True
-    est = estimated_scan_bytes(leaf)
+    est = chip_share(estimated_scan_bytes(leaf), mesh)
     if est is None:
         return False  # unsizeable lease: stream it
-    key = scan_cache_key(leaf) or ("scan", id(leaf))
+    key = scan_cache_key(leaf, mesh) or ("scan", id(leaf))
     # per-session share quota: one session's leases are capped at
     # hbmShare * pool — over-share scans stream instead of pinning HBM
     share = float(conf.get(SESSION_HBM_SHARE_KEY))

@@ -76,14 +76,15 @@ def test_aggregate_phase_demands_the_kernel(session):
 
 
 def test_mesh_phase_on_virtual_devices(session, tpch_path):
-    # 12 k rows of lineitem: chunks of 4 Ki rows and a budget of 1 MiB
-    # make Q1 stream over the mesh in three chunks, as SF1's does on
-    # the chips under chip_smoke.CELL_STREAM_CONF
-    small = dict(zip(chip_smoke.CELL_STREAM_CONF, (1 << 12, 1 << 20)))
-    # a scan this process holds already is never streamed, and the
-    # serve phase's test may have run before this one; `--chips 4`
-    # runs the mesh phase alone
-    from spark_tpu.io.device_cache import CACHE
-    CACHE.clear()
+    # 12 k rows of lineitem: chunks of 4 Ki rows have the residency
+    # verdict asked, and a chip's budget of 512 KiB (a shard of four
+    # would hold 0.5 MB of Q1's estimate) makes Q1 stream over the
+    # mesh in three chunks, as SF1's does on the chips under
+    # chip_smoke.CELL_STREAM_CONF; with the engine's own budget both
+    # scans are held over the four devices
+    cell = dict(zip(chip_smoke.CELL_CONF, (1 << 12,)))
+    small = dict(zip(chip_smoke.CELL_STREAM_CONF, (1 << 12, 1 << 19)))
+    assert set(small) - set(cell) == {"spark_tpu.sql.io.deviceCacheBytes"}
     chip_smoke.phase_mesh(session, tpch_path, 4,
-                          chip_smoke.MESH_QUERIES, stream_conf=small)
+                          chip_smoke.MESH_QUERIES, stream_conf=small,
+                          cell_conf=cell)

@@ -808,7 +808,7 @@ def test_prefer_resident_takes_no_lease_for_streaming_scan():
         conf.set(CACHE_BYTES_KEY, 1 << 30)
         token = A.enter_query("stream-test")
         try:
-            assert _prefer_resident(_Leaf(), conf) is False
+            assert _prefer_resident(_Leaf(), conf, None) is False
             assert arb.leased_bytes == 0  # no est-sized lease parked
         finally:
             A.exit_query(token)
