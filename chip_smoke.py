@@ -9,9 +9,11 @@ One process, one TPU chip, the entry points a user calls:
 2. data     TPC-H at SF1 (the specification's smallest scale), made
             from --seed under <checkout>/data/tpch (git-ignored).
 3. serve    `SqlService` on an ephemeral port; Q1, Q6 and Q3 over HTTP
-            `POST /sql`, cold then warm, each compared with the
-            independent pandas golden; every status record must be
-            `ok` with no fault events, and `/metrics` must count no
+            `POST /sql`, each three times, cold then warm twice, each
+            compared with the independent pandas golden; the second
+            and third submission must compile no stage
+            (`compile_cache_misses` stands); every status record must
+            be `ok` with no fault events, and `/metrics` must count no
             retry, no OOM-ladder rung and no mesh fallback.
 4. aggregate  the reference AggregateBenchmark's "linear keys" shape at
             full width (83,886,080 rows into 65,536 groups) under
@@ -204,12 +206,16 @@ def _check_status_record(base: str, resp: dict) -> None:
     assert not rec.get("fault_summary"), rec
 
 
+def _metrics(base: str) -> dict:
+    from spark_tpu.observability.metrics import parse_prometheus_text
+    with urllib.request.urlopen(f"{base}/metrics", timeout=30) as resp:
+        return parse_prometheus_text(resp.read().decode())
+
+
 def _clean_metrics(base: str, completed: int) -> dict:
     """`/metrics`, after asserting that `completed` queries completed,
     none failed and no recovery action ran."""
-    from spark_tpu.observability.metrics import parse_prometheus_text
-    with urllib.request.urlopen(f"{base}/metrics", timeout=30) as resp:
-        prom = parse_prometheus_text(resp.read().decode())
+    prom = _metrics(base)
     assert prom.get("spark_tpu_service_completed", 0) >= completed, prom
     assert not prom.get("spark_tpu_queries_failed"), prom
     recovered = {k: v for k, v in prom.items()
@@ -218,14 +224,24 @@ def _clean_metrics(base: str, completed: int) -> dict:
     return prom
 
 
+MISSES = "spark_tpu_compile_cache_misses"
+
+
+def _stage_compiles(base: str) -> int:
+    """The stage cache's misses so far: the count of stage compiles."""
+    return int(_metrics(base).get(MISSES, 0))
+
+
 def phase_serve(svc, path: str, queries=SERVED) -> None:
-    """`queries` over HTTP, cold then warm: golden parity, clean
-    status records, clean /metrics."""
+    """`queries` over HTTP, each three times, cold then warm twice:
+    golden parity, clean status records, clean /metrics, and a stage
+    compiled by the first submission alone (until PR 37 a join's second
+    submission compiled again, and its "warm" time was a compile)."""
     from spark_tpu.tpch import sql_queries as SQLQ
     base = f"http://127.0.0.1:{svc.port}"
     for name in queries:
-        ms = []
-        for _run in ("cold", "warm"):
+        ms, compiles = [], [_stage_compiles(base)]
+        for _run in ("cold", "warm", "warm again"):
             t0 = time.perf_counter()
             resp = _http_json(f"{base}/sql",
                               {"sql": getattr(SQLQ, name)})
@@ -234,9 +250,15 @@ def phase_serve(svc, path: str, queries=SERVED) -> None:
             got = pd.DataFrame(resp["rows"], columns=resp["columns"])
             _check_golden(got, path, name.lower())
             _check_status_record(base, resp)
+            compiles.append(_stage_compiles(base))
+        grown = [b - a for a, b in zip(compiles, compiles[1:])]
+        assert grown[0] >= 1 and grown[1:] == [0, 0], \
+            f"{name}: stage compiles by submission {grown}: a warm " \
+            f"submission compiled"
         log(f"serve: {name} rows={resp['row_count']} golden=ok "
-            f"cold_ms={ms[0]:.1f} warm_ms={ms[1]:.1f}")
-    prom = _clean_metrics(base, 2 * len(queries))
+            f"cold_ms={ms[0]:.1f} warm_ms={ms[1]:.1f} "
+            f"warm_again_ms={ms[2]:.1f} compiles={grown}")
+    prom = _clean_metrics(base, 3 * len(queries))
     log(f"serve: /metrics completed="
         f"{int(prom['spark_tpu_service_completed'])} retries=0 "
         f"oom_rungs=0 mesh_fallback=0")

@@ -615,14 +615,14 @@ def merge_dictionaries(dicts: Sequence[pa.Array]
 
 def sort_dictionary(merged: pa.Array, maps: list) -> Tuple[pa.Array, list]:
     """`merge_dictionaries`' result with the values in sorted order:
-    a table held whole over a mesh (`_arrow_to_padded` under a
-    placement; on one device a dictionary keeps the order of first
-    appearance, as it did) then carries the same dictionary whatever
-    order its rows brought the values in, so a stage over it (whose
-    program holds tables made from the dictionary: a sort's ranks) is
-    the same program for every data set of the same values, and the
-    compile caches find it again, as they found the streamed scan's
-    programs, which carry codes only. Work is per dictionary entry."""
+    a table held whole (`_arrow_to_padded`; over a mesh since PR 36,
+    on one device since PR 37) then carries the same dictionary
+    whatever order its rows brought the values in, so a stage over it
+    (whose program holds tables made from the dictionary: a sort's
+    ranks, an equality's table by code) is the same program for every
+    data set of the same values, and the compile caches find it again,
+    as they found the streamed scan's programs, which carry codes
+    only. Work is per dictionary entry."""
     import pyarrow.compute as pc
     if merged is None or len(merged) < 2:
         return merged, maps
@@ -720,10 +720,9 @@ def _arrow_to_padded(name: str, col, n: int, cap: int,
         pieces = as_dictionary_pieces(col)
         host.dictionary, maps = merge_dictionaries(
             [p.dictionary for p in pieces])
-        if placement is not None:
-            # held whole over the mesh where it was streamed as codes:
-            # the stage's program must not hold this data set's order
-            host.dictionary, maps = sort_dictionary(host.dictionary, maps)
+        # a table held whole: the stage's program must not hold the
+        # order this data set's rows brought the values in
+        host.dictionary, maps = sort_dictionary(host.dictionary, maps)
     else:
         pieces = col.chunks if isinstance(col, pa.ChunkedArray) else [col]
         maps = [None] * len(pieces)

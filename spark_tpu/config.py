@@ -140,7 +140,10 @@ JOIN_KERNEL_MODE = register(
         "profile; 'sort' keeps the binary-search path; 'auto' picks "
         "hash only for large probes over comparatively small builds "
         "(join.hashMinProbeRows / hashProbeBuildRatio), so small joins "
-        "and CPU test runs keep the sort path. Results are "
+        "and CPU test runs keep the sort path, and never on a TPU, "
+        "where a gather costs several times a sort's share of a row "
+        "and the probe loop runs as far as the data's longest cluster "
+        "of keys (hash_join._auto_keeps_sort). Results are "
         "byte-identical across modes (both kernels emit matches in the "
         "same sorted-build order).",
     validator=lambda v: v in ("auto", "hash", "sort"))

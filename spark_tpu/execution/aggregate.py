@@ -34,6 +34,7 @@ from .. import types as T
 from ..columnar import Batch, Column, bucket_capacity
 from ..expr import Alias, Expression, Literal, Mod, Pmod, Vec
 from ..expr_agg import AccSpec, AggExpr
+from .sort import sort_carrying_positions
 
 
 def key_domain(expr: Expression, vec: Vec) -> Optional[Tuple[int, int]]:
@@ -332,8 +333,7 @@ def sort_aggregate(key_vecs: Sequence[Vec],
                              jnp.zeros((), data.dtype))
         operands.append(data)
     num_keys = len(operands)
-    operands.append(jnp.arange(capacity, dtype=jnp.int32))  # permutation payload
-    sorted_ops = jax.lax.sort(tuple(operands), num_keys=num_keys)
+    sorted_ops = sort_carrying_positions(operands)  # + the permutation
     perm = sorted_ops[-1]
     inv_sorted = sorted_ops[0].astype(jnp.bool_)
     valid_sorted = ~inv_sorted
@@ -440,8 +440,7 @@ def positional_sort(key_vecs: Sequence[Vec], value_vec: Vec, sel,
     operands.append(vinvalid)  # null values sort to the group tail
     operands.append(value_vec.data)
     num_keys = len(operands)
-    operands.append(jnp.arange(capacity, dtype=jnp.int32))
-    sorted_ops = jax.lax.sort(tuple(operands), num_keys=num_keys)
+    sorted_ops = sort_carrying_positions(operands)
     valid_sorted = sorted_ops[0] == 0
     values_sorted = sorted_ops[-2]
     vvalid_sorted = (sorted_ops[-3] == 0) & valid_sorted

@@ -117,7 +117,7 @@ class QueryExecution:
         self._optimized: Optional[L.LogicalPlan] = None
         self._executed: Optional[P.PhysicalPlan] = None
         self.phase_times: Dict[str, float] = {}
-        self.last_metrics: Dict[str, float] = {}  # ints except rtf_build_ms_*
+        self.last_metrics: Dict[str, float] = {}  # ints except the *_ms_* keys
         # observability: lifecycle identity + per-phase spans (Chrome
         # -trace exportable) + the XLA cost/memory analysis of every
         # stage this execution compiled or reused (observability/)
@@ -675,6 +675,11 @@ class QueryExecution:
         compiled program."""
         conf = self._conf
         per_op = bool(conf.get("spark_tpu.sql.metrics.enabled"))
+        # what the operators learn while this stage is traced and keep
+        # off the program (ExecContext.host): beside the stage-cache
+        # entry, under its key, so a later hit still finds it
+        host = self.session._stage_host.setdefault(
+            self._stage_key(root, mesh), {})
 
         def replay_root(ctx, inputs):
             counter = [0]
@@ -699,7 +704,7 @@ class QueryExecution:
 
         if mesh is None:
             def run(inputs):
-                ctx = P.ExecContext(conf)
+                ctx = P.ExecContext(conf, host=host)
                 out = replay_root(ctx, inputs)
                 return out, ctx.flags, ctx.metrics
 
@@ -720,7 +725,8 @@ class QueryExecution:
                 (P.SinglePartition, P.Replicated))
 
             def run_shard(inputs, _token):
-                ctx = P.ExecContext(conf, axis_name=AXIS, n_shards=n)
+                ctx = P.ExecContext(conf, axis_name=AXIS, n_shards=n,
+                                    host=host)
                 out = replay_root(ctx, inputs)
                 if replicated_out:
                     out = stripe_batch(out, ctx)
@@ -735,8 +741,7 @@ class QueryExecution:
                     # row counts sum across shards
                     red = pmax if k.startswith(
                         ("join_rows_", "exch_max_", "agg_groups_",
-                         "rtf_build_ms_", "join_build_ms_",
-                         "join_probe_ms_", "join_table_slots_")) \
+                         "join_table_slots_")) \
                         else jax.lax.psum
                     metrics[k] = red(jnp.asarray(v), AXIS)
                 return out, flags, metrics
@@ -1726,6 +1731,7 @@ class QueryExecution:
                                 ("waited", "dispatch_sync_waits")):
                             self.session.metrics.counter(counter).inc(
                                 sync.attrs.get(attr, 0))
+                self._note_joins(disp, metrics)
             if mesh is not None:
                 self._count_mesh_stage(metrics)
             # deadline BEFORE the stage-timeout check: an attempt
@@ -1798,10 +1804,6 @@ class QueryExecution:
                 f"overflowing: {overflow}")
         batch = jax.block_until_ready(batch)
         self.phase_times["execution"] = time.perf_counter() - t0
-        if adaptive:
-            # ROADMAP item (c): runtime-filter pruning shrinks the static
-            # capacities above the filter for the NEXT execution/compile
-            self._shrink_caps_from_rtf(root, metrics, mesh)
         if aqe_key is not None:
             # harvest from the UNSPLICED plan: streamed-aggregate joins
             # mutated their caps on the original nodes, which the
@@ -1821,15 +1823,14 @@ class QueryExecution:
         # they never enter last_metrics (scalar columns only)
         if mesh is not None and self._shard_obs_on():
             self._record_exchange_shards(metrics, mesh)
-        # *_ms metrics are floats (sub-ms filter/table builds are the
-        # common case) — int() would floor them to a useless 0
-        self.last_metrics = {
-            k: (round(float(v), 3)
-                if k.startswith(("rtf_build_ms_", "join_build_ms_",
-                                 "join_probe_ms_"))
-                else int(v))
-            for k, v in metrics.items()
-            if not k.startswith("shard_")}
+        self.last_metrics = {k: int(v) for k, v in metrics.items()
+                             if not k.startswith("shard_")}
+        # the *_ms_* keys (rtf_build_ms_*, join_build_ms_*,
+        # join_probe_ms_*): trace-time costs from the host's record of
+        # the stage, floats (sub-ms builds are the common case)
+        self.last_metrics.update(
+            (k, round(v, 3)) for k, v in self._stage_host().items()
+            if isinstance(v, float))
         if self._mesh_fallback:
             # degraded single-device result of a mesh-planned query:
             # visible next to the device metrics and in the event log
@@ -1896,57 +1897,35 @@ class QueryExecution:
         self._join_overrides[join.tag] = "broadcast"
         return True
 
-    def _shrink_caps_from_rtf(self, root: P.PhysicalPlan, metrics: Dict,
-                              mesh) -> None:
-        """Shrink post-filter static capacities using runtime-filter
-        pruned-row counts (ROADMAP runtime-filter item (c)): the probe
-        exchange's receive blocks and the guarded join's output were
-        seeded from the UNPRUNED probe capacity; after a converged run,
-        the survivors (rtf_tested - rtf_pruned) bound what those buffers
-        ever hold, so re-seed them down for the next compile — a
-        single-chip HBM/kernel-size win, not just ICI traffic. The
-        measured actuals (exch_max/join_rows) floor the new value, so a
-        shrunk cap never overflows on identical data; on grown data the
-        AQE overflow loop corrects upward as usual. Mutates `root`, whose
-        caps the AQE harvest persists."""
-        from ..columnar import bucket_capacity
-        n = int(mesh.devices.size) if mesh is not None else 1
+    def _note_joins(self, disp, metrics: Dict) -> None:
+        """What a dispatched stage's joins did, into the process
+        counter `/metrics` serves, from the stats channel
+        `dispatch.sync` has just pulled (no sync of its own), whatever
+        the conf: `join_output_rows`, the sum of the stage's
+        `join_rows_*` (the filters' `rtf_tested` / `rtf_pruned` are the
+        metrics sink's, folded at a query's end). The `dispatch` span
+        gets `joins=<n>` and the kernel each resolved to while the
+        stage was traced (`join_kernels`, `<tag>=sort|hash`). A stage
+        without a join touches nothing; one
+        deserialized from the engine's own compile cache
+        (`compileCache.enabled`) was not traced in this process and has
+        no host record, so its span and `last_metrics` go without."""
+        joined = [int(v) for k, v in metrics.items()
+                  if k.startswith("join_rows_")]
+        if joined:
+            self.session.metrics.counter("join_output_rows").inc(
+                sum(joined))
+        kernels = sorted(
+            (k[len("join_kernel_"):], v) for k, v in self._stage_host().items()
+            if k.startswith("join_kernel_"))
+        if kernels:
+            disp.attrs["joins"] = len(kernels)
+            disp.attrs["join_kernels"] = ",".join(
+                f"{tag}={kernel}" for tag, kernel in kernels)
 
-        def walk(node, ancestors):
-            for c in node.children:
-                walk(c, ancestors + (node,))
-            if not isinstance(node, P.RuntimeFilterExec):
-                return
-            tested = metrics.get(f"rtf_tested_{node.tag}")
-            pruned = metrics.get(f"rtf_pruned_{node.tag}")
-            if tested is None or pruned is None:
-                return
-            surv = int(tested) - int(pruned)
-            if int(tested) <= 0 or int(pruned) <= 0 or surv < 0:
-                return  # filter never pruned: nothing to shrink from
-            # climb from the filter to the join it guards, shrinking the
-            # exchange blocks on the way (narrow ops pass through)
-            for anc in reversed(ancestors):
-                if isinstance(anc, (P.ProjectExec, P.FilterExec,
-                                    P.RuntimeFilterExec)):
-                    continue
-                if isinstance(anc, P.ExchangeExec):
-                    if mesh is None:
-                        continue  # identity on a single chip
-                    actual = int(metrics.get(f"exch_max_{anc.tag}", 0))
-                    new = bucket_capacity(
-                        max(2 * (-(-surv // n)), actual, 8))
-                    if anc.block_cap is None or new < anc.block_cap:
-                        anc.block_cap = new
-                    continue
-                if isinstance(anc, P.JoinExec):
-                    actual = int(metrics.get(f"join_rows_{anc.tag}", 0))
-                    new = bucket_capacity(max(2 * surv, actual, 8))
-                    if anc.out_cap is None or new < anc.out_cap:
-                        anc.out_cap = new
-                break  # the guarded join (or an opaque op) ends the climb
-
-        walk(root, ())
+    def _stage_host(self) -> Dict[str, object]:
+        """The host's record of the stage last compiled or found."""
+        return self.session._stage_host.get(self._last_stage_key or "", {})
 
     def _count_mesh_stage(self, metrics: Dict) -> None:
         """What a dispatched mesh stage did, into the process counters

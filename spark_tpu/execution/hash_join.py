@@ -97,7 +97,8 @@ def kernel_choice(conf, probe_cap: int, build_cap: int,
     that outcome) — stay on sort.
 
     Reasons: 'pinned' (AQE saturation pin), 'forced' (kernelMode said
-    so), 'small-probe'/'ratio' (auto heuristics keep sort), 'clamp'
+    so), 'tpu' (auto never picks hash on a TPU: `_auto_keeps_sort`),
+    'small-probe'/'ratio' (auto heuristics keep sort), 'clamp'
     (the mode WANTED hash but the maxTableSlots clamp pushes the load
     factor past the fallback bound — the degraded case the analyzer
     reports), 'auto' (auto picked hash)."""
@@ -107,6 +108,8 @@ def kernel_choice(conf, probe_cap: int, build_cap: int,
     if mode == "sort":
         return "sort", "forced"
     if mode == "auto":
+        if _auto_keeps_sort():
+            return "sort", "tpu"
         # the table build amortizes only over large, probe-heavy joins
         if int(probe_cap) < int(conf.get(MIN_PROBE_ROWS_KEY)):
             return "sort", "small-probe"
@@ -124,6 +127,20 @@ def kernel_choice(conf, probe_cap: int, build_cap: int,
             and int(build_cap) > _FALLBACK_LOAD_FACTOR * slots:
         return "sort", "clamp"  # maxTableSlots: load factor too high
     return "hash", ("forced" if mode == "hash" else "auto")
+
+
+def _auto_keeps_sort() -> bool:
+    """True where `auto` must not pick the hash kernel whatever the
+    capacities: on a TPU. Each step of the probe loop is three gathers
+    over EVERY probe row, some 10 ns a row each on a v5e, and the loop
+    runs as far as the data's longest cluster of keys; the sort
+    kernel's whole search is one sort of probe and build together at
+    under 3 ns a row. TPC-H Q3 at SF1 on one v5e: 3.9-4.8 s a request
+    on the hash kernel, moving with the seed's values, 1.4 s on the
+    sort kernel whatever they are (PERF.md, PR 37). `hashMinProbeRows`
+    and `hashProbeBuildRatio` were set on CPU counts and decide there.
+    `kernelMode=hash` still forces the kernel on any backend."""
+    return jax.default_backend() == "tpu"
 
 
 def resolve_kernel(conf, probe_cap: int, build_cap: int,

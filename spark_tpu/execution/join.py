@@ -32,6 +32,7 @@ import numpy as np
 from .. import types as T
 from ..columnar import Batch, Column
 from ..expr import Vec
+from .sort import sort_carrying_positions
 
 
 def canon_key_data(data):
@@ -60,9 +61,8 @@ def build_sorted(key: Vec, sel) -> Tuple:
         invalid = (~sel).astype(jnp.int8)
     if key.validity is not None:
         invalid = invalid | (~key.validity).astype(jnp.int8)
-    perm0 = jnp.arange(cap, dtype=jnp.int32)
-    inv_s, keys_s, perm = jax.lax.sort(
-        (invalid, canon_key_data(key.data), perm0), num_keys=2)
+    inv_s, keys_s, perm = sort_carrying_positions(
+        (invalid, canon_key_data(key.data)))
     valid_s = inv_s == 0
     n_valid = jnp.sum(valid_s.astype(jnp.int32))
     # invalid slots carry arbitrary keys after the valid prefix;
@@ -96,6 +96,40 @@ def build_has_duplicates(sorted_keys, valid_sorted):
     return jnp.any(same & both)
 
 
+def search_sorted(sorted_keys, pk, side: str = "left"):
+    """`jnp.searchsorted(sorted_keys, pk, side)` by ONE sort of the two
+    arrays laid end to end (`sort_carrying_positions`: ties keep their
+    order): a query's insertion point is the number of build keys that
+    sort before it, which a running count over the sorted order gives,
+    scattered back to the query's place by the position the sort
+    carried along. `method="sort"` of
+    `jnp.searchsorted` ranks the concatenation AND the queries, two
+    64-bit argsorts and two scatters a call; XLA:TPU takes minutes to
+    compile each such sort, and a join's stage is made of them (Q3's
+    first request did not answer within 300 s on the chip; PERF.md,
+    PR 37). Ties: for `left` the queries stand first, so a query
+    sorts before the build keys equal to it; for `right` after them.
+    NaN sorts last and equal to itself, as in `lax.sort`'s total
+    order, which is the order `build_sorted` left the keys in."""
+    n, m = sorted_keys.shape[0], pk.shape[0]
+    dtype = jnp.promote_types(sorted_keys.dtype, pk.dtype)
+    build, query = sorted_keys.astype(dtype), pk.astype(dtype)
+    # where the queries stand in the concatenation: first or last
+    first = 0 if side == "left" else n
+    both = jnp.concatenate([query, build] if side == "left"
+                           else [build, query])
+    _, src = sort_carrying_positions((both,))
+    query_here = (src >= first) & (src < first + m)
+    builds_before = jnp.cumsum((~query_here).astype(jnp.int32))
+    # a build key's count goes nowhere: past the end, each to a place
+    # of its own, so that the indices are unique as declared (the
+    # scatter then needs no sort of its own)
+    return jnp.zeros((m,), jnp.int32).at[
+        jnp.where(query_here, src - first,
+                  m + jnp.arange(n + m, dtype=jnp.int32))].set(
+            builds_before, mode="drop", unique_indices=True)
+
+
 def match_unique(sorted_keys, n_valid, perm, probe_key: Vec, probe_sel):
     """Unique-build match: each probe row matches at most one build row
     (the FK->PK shape; reference: HashedRelation.scala keyIsUnique).
@@ -104,7 +138,7 @@ def match_unique(sorted_keys, n_valid, perm, probe_key: Vec, probe_sel):
 
     Returns (build_idx, found)."""
     pk = canon_key_data(probe_key.data)
-    lo = jnp.searchsorted(sorted_keys, pk, side="left", method="sort")
+    lo = search_sorted(sorted_keys, pk, side="left")
     lo = jnp.minimum(lo, sorted_keys.shape[0] - 1).astype(jnp.int32)
     hit = jnp.take(sorted_keys, lo)
     eq = hit == pk
@@ -128,12 +162,12 @@ def match_ranges(sorted_keys, n_valid, probe_key: Vec, probe_sel):
     Returns (lo, cnt): build rows [lo, lo+cnt) in sorted order match.
     cnt is 0 for unmatched/invalid/unselected probe rows.
 
-    method='sort' matters on TPU: the default 'scan' binary search is
+    By sort, which matters on TPU: the default 'scan' binary search is
     log2(build) SEQUENTIAL whole-probe gathers (~1.4s for 8M probes,
     measured), while one extra lax.sort is ~100ms."""
     pk = canon_key_data(probe_key.data)
-    lo = jnp.searchsorted(sorted_keys, pk, side="left", method="sort")
-    hi = jnp.searchsorted(sorted_keys, pk, side="right", method="sort")
+    lo = search_sorted(sorted_keys, pk, side="left")
+    hi = search_sorted(sorted_keys, pk, side="right")
     lo = jnp.minimum(lo, n_valid).astype(jnp.int32)
     hi = jnp.minimum(hi, n_valid).astype(jnp.int32)
     found = hi > lo

@@ -47,8 +47,11 @@ METRIC_PREFIXES = (
     "gen_rows_",       # generate/explode output rows
     "rtf_tested_",     # runtime-filter probe rows tested
     "rtf_pruned_",     # runtime-filter probe rows pruned
+    # the three *_ms_* names are HOST-side (ExecContext.add_host_ms):
+    # milliseconds of trace time kept beside the stage-cache entry and
+    # merged into last_metrics; no program holds a clock's reading
     "rtf_build_ms_",   # runtime-filter trace-time build cost
-    "join_build_ms_",  # hash-join table build cost (trace-time, pmax)
+    "join_build_ms_",  # hash-join table build cost (trace-time)
     "join_probe_ms_",  # hash-join probe-program build cost
     "join_table_slots_",  # hash-join open-addressing table capacity
     # per-shard telemetry ([n] arrays: one slot per mesh position, the
@@ -98,6 +101,14 @@ METRIC_PREFIXES = (
                        # of dispatches that cross chips)
     "exchange_",       # exchange_rows / exchange_bytes: the sums of a
                        # mesh stage's exch_rows_* / exch_bytes_*
+    # what a stage's joins did (executor._note_joins after
+    # `dispatch.sync`, from the stats channel it pulled, whatever the
+    # conf): REGISTRY counter, listed for namespace closure. The
+    # filters' process counters are the metrics sink's `rtf_tested` /
+    # `rtf_pruned` (observability/sinks.py), folded at a query's end
+    "join_output_",    # join_output_rows: the sum of a dispatched
+                       # stage's join_rows_* (under a mesh each is the
+                       # fullest shard's)
     # straggler detection (observability/straggler.py): REGISTRY
     # counter, listed for namespace closure like the ingest pair
     "straggler_",      # straggler_flagged: shards flagged this process

@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import copy
 import dataclasses
+import time
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -139,15 +140,24 @@ class ExecContext:
 
     When running inside `shard_map` over a mesh, `axis_name`/`n_shards`
     identify the data axis: leaves synthesize only their stripe and
-    ExchangeExec lowers to collectives (parallel/shuffle.py)."""
+    ExchangeExec lowers to collectives (parallel/shuffle.py).
+
+    `host` is what an operator learns while it is TRACED and the host
+    alone may know (the milliseconds a join's or a runtime filter's
+    construction took, the kernel a join resolved to). It never enters
+    the program: a clock's reading as a constant would make the lowered
+    text differ in every process. The executor keeps the record beside
+    the stage-cache entry, so a stage-cache hit still reports the
+    build cost of the trace that made the stage."""
 
     def __init__(self, conf: Conf, axis_name: Optional[str] = None,
-                 n_shards: int = 1):
+                 n_shards: int = 1, host: Optional[Dict[str, object]] = None):
         self.conf = conf
         self.axis_name = axis_name
         self.n_shards = n_shards
         self.flags: Dict[str, object] = {}
         self.metrics: Dict[str, object] = {}
+        self.host: Dict[str, object] = {} if host is None else host
 
     def add_flag(self, name: str, value) -> None:
         if name in self.flags:
@@ -167,6 +177,15 @@ class ExecContext:
                 f"observability.metrics.METRIC_PREFIXES and a history "
                 f"summary consumer")
         self.metrics[name] = value
+
+    def add_host_ms(self, name: str, t0: float) -> None:
+        """Milliseconds since `t0` (`time.perf_counter`) under a
+        registered metric name, into the host's record and no
+        program."""
+        from ..observability.metrics import is_registered_metric
+        if not is_registered_metric(name):
+            raise ValueError(f"unregistered metric name {name!r}")
+        self.host[name] = (time.perf_counter() - t0) * 1e3
 
 
 # ---------------------------------------------------------------------------
@@ -911,8 +930,7 @@ class WindowExec(UnaryExec):
 
         operands = [(~sel).astype(jnp.int8)] + p_ops + o_ops
         num_keys = len(operands)
-        operands.append(jnp.arange(cap, dtype=jnp.int32))
-        sorted_ops = jax.lax.sort(tuple(operands), num_keys=num_keys)
+        sorted_ops = sort_kernels.sort_carrying_positions(operands)
         perm = sorted_ops[-1]
         valid_sorted = sorted_ops[0] == 0
         sp_ops = list(sorted_ops[1:1 + len(p_ops)])
@@ -1460,11 +1478,10 @@ class JoinExec(PhysicalPlan):
         return mask
 
     def compute(self, ctx, inputs):
-        import time as _time
         from ..execution import hash_join as hash_kernels
         probe_batch, build_batch = inputs
         lvecs, rvecs, lk, rk, exact = self._eval_keys(probe_batch, build_batch)
-        t_build = _time.perf_counter()
+        t_build = time.perf_counter()
         keys_s, perm, n_valid, _valid_s = join_kernels.build_sorted(
             rk, build_batch.selection)
         # kernel choice (join.kernelMode): hash builds an open-
@@ -1476,6 +1493,9 @@ class JoinExec(PhysicalPlan):
         kernel = hash_kernels.resolve_kernel(
             ctx.conf, probe_batch.capacity, build_batch.capacity,
             self.hash_fallback)
+        # which kernel this join's program holds: the `dispatch` span's
+        # `join_kernels` attribute
+        ctx.host[f"join_kernel_{self.tag}"] = kernel
         hash_lc = None
         if kernel == "hash":
             slots = hash_kernels.table_slots(build_batch.capacity,
@@ -1493,15 +1513,13 @@ class JoinExec(PhysicalPlan):
                            jnp.asarray(slots, jnp.int64))
             # trace-time program-construction cost, the rtf_build_ms
             # convention: the kernels fuse into the stage, so this is
-            # the honest per-join observable (pmax'd across shards)
-            ctx.add_metric(f"join_build_ms_{self.tag}", jnp.float32(
-                (_time.perf_counter() - t_build) * 1e3))
-            t_probe = _time.perf_counter()
+            # the honest per-join observable; kept on the host
+            ctx.add_host_ms(f"join_build_ms_{self.tag}", t_build)
+            t_probe = time.perf_counter()
             hash_lc = hash_kernels.probe_table(
                 t_pos, cnt_all, keys_s, lk, probe_batch.selection,
                 slots, max_probe, hash_dtype=hash_dt)
-            ctx.add_metric(f"join_probe_ms_{self.tag}", jnp.float32(
-                (_time.perf_counter() - t_probe) * 1e3))
+            ctx.add_host_ms(f"join_probe_ms_{self.tag}", t_probe)
         if (self.unique_build is not False
                 and self.how in ("inner", "left", "left_semi",
                                  "left_anti")):
@@ -1712,7 +1730,6 @@ class RuntimeFilterExec(PhysicalPlan):
         return self.children[0].output_partitioning()
 
     def compute(self, ctx, inputs):
-        import time as _time
         probe, build = inputs
         n_items = self.est_items
         global_cap = build.capacity * max(1, ctx.n_shards)
@@ -1722,11 +1739,14 @@ class RuntimeFilterExec(PhysicalPlan):
         # tighter static bound on insertable rows — don't size the
         # (replicated) bit array past it
         n_items = min(n_items, global_cap)
-        t0 = _time.perf_counter()
+        t0 = time.perf_counter()
         filt = join_kernels.build_runtime_filter(
             build, self.build_key, ctx, expected_items=max(int(n_items), 8),
             fpp=self.fpp)
-        build_ms = (_time.perf_counter() - t0) * 1e3
+        # host time spent CONSTRUCTING the filter program (trace time):
+        # the build itself fuses into the stage, so this is the honest
+        # per-filter build-cost observable; kept on the host
+        ctx.add_host_ms(f"rtf_build_ms_{self.tag}", t0)
         keep = join_kernels.apply_runtime_filter(filt, probe,
                                                  self.probe_key)
         psel = probe.selection_mask()
@@ -1734,12 +1754,6 @@ class RuntimeFilterExec(PhysicalPlan):
                        jnp.sum(psel.astype(jnp.int64)))
         ctx.add_metric(f"rtf_pruned_{self.tag}",
                        jnp.sum((psel & ~keep).astype(jnp.int64)))
-        # host time spent CONSTRUCTING the filter program (trace time):
-        # the build itself fuses into the stage, so this is the honest
-        # per-filter build-cost observable — a static metric, pmax'd
-        # across shards
-        ctx.add_metric(f"rtf_build_ms_{self.tag}",
-                       jnp.float32(build_ms))
         return probe.with_selection(psel & keep)
 
     def simple_string(self):

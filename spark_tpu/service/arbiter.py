@@ -92,6 +92,10 @@ class DeviceResourceArbiter:
         #: the worst concurrent-fill race is a duplicate compile whose
         #: last write wins with an equivalent value.
         self.stage_cache: Dict[str, object] = {}
+        #: beside it, under the same keys and the same waiver: each
+        #: stage's host record (ExecContext.host), written while the
+        #: stage is traced and read after every dispatch
+        self.stage_host: Dict[str, Dict[str, object]] = {}
         #: arbiter-owned plan-fingerprint result cache (pooled sessions
         #: all point their _data_cache here)
         self.result_cache = ResultCache(max_bytes=result_cache_bytes,

@@ -77,6 +77,7 @@ class SessionPool:
         # swap in the shared process resources (see module docstring)
         s.metrics = self._metrics
         s._stage_cache = self._arbiter.stage_cache
+        s._stage_host = self._arbiter.stage_host
         s._data_cache = self._arbiter.result_cache
         entry = _Entry(s, name)
         if self._make_listener is not None:

@@ -54,6 +54,9 @@ class SparkTpuSession(metaclass=_ActiveSessionMeta):
         from .catalog import Catalog
         self.catalog: Catalog = Catalog(self)
         self._stage_cache: Dict[str, object] = {}
+        # beside it, under the same keys: what a stage's operators
+        # learned on the host while it was traced (ExecContext.host)
+        self._stage_host: Dict[str, Dict[str, object]] = {}
         # observability spine (observability/): the listener bus every
         # event-log line / trace file / metrics flush hangs off, the
         # process metrics registry, XLA stage-cost memo, and the

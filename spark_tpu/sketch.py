@@ -18,6 +18,10 @@ import numpy as np
 
 _MIX_MUL = np.uint64(0xBF58476D1CE4E5B9)
 _MIX_MUL2 = np.uint64(0x94D049BB133111EB)
+#: the most indices one scatter of `BloomFilter.build` takes (256 MiB of
+#: int32): up to it the k hashes share a scatter, beyond it they go in
+#: turns, one hash a scatter for a side of more rows than this
+_SCATTER_INDICES = 1 << 26
 
 
 def _mix64(x, seed: int):
@@ -50,12 +54,27 @@ class BloomFilter:
               fpp: float = 0.03, mask=None) -> "BloomFilter":
         n = int(values.shape[0])
         m, k = cls.sizing(expected_items or n, fpp)
-        bits = jnp.zeros((m,), jnp.uint8)
         x = values.astype(jnp.int64)
-        for s in range(k):
-            idx = (_mix64(x, s) % np.uint64(m)).astype(jnp.int32)
+        # the k hashes' indices laid end to end and set by ONE scatter,
+        # not k in a row: XLA:TPU sorts a scatter's indices, and each
+        # such sort is compiled by itself (Q3's two filters were 10 of
+        # its stage's 24 sorts; PERF.md, PR 37). The bits set are the
+        # same. A scatter takes as many hashes as keep its index array
+        # within _SCATTER_INDICES, so a large creation side (its
+        # capacity, not its estimate, is `n`) holds no more at once
+        # than it did hash by hash
+        per = max(1, _SCATTER_INDICES // max(n, 1))
+        groups = []
+        for first in range(0, k, per):
+            hashes = range(first, min(k, first + per))
+            idx = jnp.concatenate([
+                (_mix64(x, s) % np.uint64(m)).astype(jnp.int32)
+                for s in hashes])
             if mask is not None:
-                idx = jnp.where(mask, idx, m)
+                idx = jnp.where(jnp.tile(mask, len(hashes)), idx, m)
+            groups.append(idx)
+        bits = jnp.zeros((m,), jnp.uint8)
+        for idx in groups:
             bits = bits.at[idx].max(jnp.ones_like(idx, jnp.uint8),
                                     mode="drop")
         return cls(bits, k)

@@ -154,3 +154,81 @@ def test_the_exchanges_collectives_keep_names_their_reader_knows(topo, sent):
     assert sent_as, text[:2000]
     assert all(is_collective(name) for name in sent_as), sent_as
     assert not is_collective("fusion.71") and not is_collective("copy.1")
+
+
+def _sorts_of(compiled_text):
+    """The names of the instructions whose opcode is `sort`."""
+    import re
+    return re.findall(r"%([\w.-]+) = [^=]*? sort\(", compiled_text)
+
+
+@pytest.mark.parametrize("kernel", ["join_build", "order_by_limit"])
+def test_the_engines_sorts_keep_a_name_their_reader_knows(one_chip, kernel):
+    """`benchmark/layer_metrics/sort_ms.py` finds a stage's sorts in
+    the device trace by the names of their HLO instructions (`sort.12`).
+    A join's build side (`execution/join.py::build_sorted`: validity,
+    then a 64-bit key, the permutation carried) and Q3's `ORDER BY
+    revenue DESC, o_orderdate` (`execution/sort.py::sort_permutation`:
+    a 64-bit and a 32-bit key) are compiled here for a described v5e,
+    and every instruction whose opcode is `sort` must bear a name the
+    reader counts. At 1,024 rows, because XLA:TPU takes 75-100 s for
+    each of them at 32,768 (sandbox, PR 37), and Q3's whole stage four to
+    five minutes: `test_q3s_stage_compiles_for_v5e` below, marked slow."""
+    from benchmark.layer_metrics.sort_ms import is_sort
+    from spark_tpu import types as T
+    from spark_tpu.columnar import Batch, Column
+    from spark_tpu.execution import join as join_kernels
+    from spark_tpu.execution import sort as sort_kernels
+    from spark_tpu.expr import Vec
+    from spark_tpu.functions import col
+    n = 1 << 10
+
+    def spec(dtype):
+        return jax.ShapeDtypeStruct((n,), jnp.dtype(dtype), sharding=one_chip)
+
+    if kernel == "join_build":
+        def program(keys, sel):
+            return join_kernels.build_sorted(Vec(keys, T.LONG), sel)
+
+        args = (spec("int64"), spec("bool"))
+    else:
+        def program(revenue, date, sel):
+            batch = Batch({"revenue": Column(revenue, T.LONG),
+                           "o_orderdate": Column(date, T.DATE)}, sel)
+            return sort_kernels.sort_permutation(
+                batch, [col("revenue").desc(), col("o_orderdate").asc()])
+
+        args = (spec("int64"), spec("int32"), spec("bool"))
+    sorts = _sorts_of(jax.jit(program).lower(*args).compile().as_text())
+    assert sorts
+    assert all(is_sort(name) for name in sorts), sorts
+    assert not is_sort("fusion.71") and not is_sort("copy.1")
+
+
+@pytest.mark.slow
+def test_q3s_stage_compiles_for_v5e(one_chip, tmp_path):
+    """TPC-H Q3's whole stage at SF0.01, as the served path traces it
+    (`spark_tpu/testing/stage_lowering.py`), through the TPU compiler
+    for a described v5e: 24 instructions named `sort.<n>` in 321 s as
+    the tree stood, 4 minutes since the sorts carry their positions
+    (sandbox, PR 37), every one a name `sort_ms` counts."""
+    import os
+
+    from benchmark.datagen import customer, lineitem, orders
+    from benchmark.layer_metrics.sort_ms import is_sort
+    from spark_tpu import Conf
+    from spark_tpu.io.sources import ParquetSource
+    from spark_tpu.session import SparkTpuSession
+    from spark_tpu.testing.stage_lowering import lower_stage
+    from spark_tpu.tpch.sql_queries import Q3
+    session = SparkTpuSession(conf=Conf(), register_active=False)
+    for name, gen, parts in (("lineitem", lineitem, 2), ("orders", orders, 2),
+                             ("customer", customer, 1)):
+        d = str(tmp_path / name)
+        os.makedirs(d)
+        for part in range(parts):
+            gen.write_part(0.01, 2147483659, parts, part, d)
+        session.register_table(name, ParquetSource(d, name))
+    text = lower_stage(session.sql(Q3)._qe(), one_chip).compile().as_text()
+    sorts = _sorts_of(text)
+    assert len(sorts) >= 5 and all(is_sort(name) for name in sorts), sorts

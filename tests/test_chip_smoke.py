@@ -64,6 +64,26 @@ def test_serve_phase(tpch_path):
         svc.stop()
 
 
+def test_serve_phase_fails_when_a_warm_submission_compiles(tpch_path):
+    """Q3 goes three times, and the second and third may compile no
+    stage: with a count of stage compiles that grows at every look
+    (what a capacity re-seeded after the run used to amount to) the
+    phase must fail, and as that."""
+    import itertools
+    from unittest import mock
+    svc = chip_smoke.start_service(tpch_path)
+    count = itertools.count()
+    try:
+        # one more compile at every look, as if each submission compiled
+        with mock.patch.object(chip_smoke, "_stage_compiles",
+                               lambda base: next(count)):
+            with pytest.raises(AssertionError,
+                               match="a warm submission compiled"):
+                chip_smoke.phase_serve(svc, tpch_path, ("Q3",))
+    finally:
+        svc.stop()
+
+
 def test_aggregate_phase_demands_the_kernel(session):
     """Closed form and auto == scatter hold on any backend; what only a
     TPU can pass is the last assertion, that the Pallas kernel is in

@@ -118,6 +118,26 @@ def test_resolve_kernel_modes(session):
     assert HJ.resolve_kernel(conf, big, 1 << 12, None) == "sort"
 
 
+def test_auto_keeps_the_sort_kernel_on_a_tpu(session, monkeypatch):
+    """On a TPU a gather costs three times a sort's share of a row and
+    the hash probe's loop runs as far as the data's longest cluster:
+    `auto` keeps every join on the sort kernel there, whatever the
+    capacities (Q3 at SF1 on a v5e: 1.4 s a request against 3.9-4.8 s,
+    PERF.md, PR 37). A forced `hash` is still a forced hash."""
+    import jax
+    from spark_tpu.execution import hash_join as HJ
+    conf = session.conf
+    big, small = 1 << 22, 1 << 10
+    assert HJ.kernel_choice(conf, big, small) == ("hash", "auto")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert HJ.kernel_choice(conf, big, small) == ("sort", "tpu")
+    assert HJ.kernel_choice(conf, small, small) == ("sort", "tpu")
+    conf.set(MODE_KEY, "hash")
+    assert HJ.kernel_choice(conf, big, small) == ("hash", "forced")
+    conf.set(MODE_KEY, "sort")
+    assert HJ.kernel_choice(conf, big, small) == ("sort", "forced")
+
+
 def test_auto_keeps_sort_on_small_joins(tables):
     """Default auto mode on test-sized joins stays on the sort kernel
     (tier-1 CPU runs never trace the hash path unasked)."""
@@ -239,10 +259,15 @@ def test_hash_metrics_emitted(tables):
     slots = [v for k, v in qe.last_metrics.items()
              if k.startswith("join_table_slots_")]
     assert slots and all(s >= 16 and (s & (s - 1)) == 0 for s in slots)
-    assert any(k.startswith("join_build_ms_")
-               for k in qe.last_metrics), qe.last_metrics
-    assert any(k.startswith("join_probe_ms_")
-               for k in qe.last_metrics), qe.last_metrics
+    # host milliseconds of trace time, from the record kept beside the
+    # stage-cache entry (ExecContext.host): no program holds them
+    ms = {k: v for k, v in qe.last_metrics.items()
+          if k.startswith(("join_build_ms_", "join_probe_ms_"))}
+    assert {k.rsplit("_", 1)[0] for k in ms} == {"join_build_ms",
+                                                 "join_probe_ms"}, ms
+    assert all(isinstance(v, float) and v > 0 for v in ms.values()), ms
+    host = qe.session._stage_host[qe._last_stage_key]
+    assert all(round(host[k], 3) == v for k, v in ms.items())
 
 
 # -- mesh --------------------------------------------------------------------

@@ -50,8 +50,18 @@ def _reference_padded(name, col, n, cap):
         arr = arr.dictionary_encode()
         at = arr.type
     if pa.types.is_dictionary(at):
-        dictionary = arr.dictionary
-        np_data = arr.indices.cast(pa.int32()).to_numpy(zero_copy_only=False)
+        # since PR 37 a held table's dictionary is sorted on one device
+        # too (over a mesh since PR 36): the old codes, by rank
+        import pyarrow.compute as pc
+        order = pc.sort_indices(arr.dictionary).to_numpy(
+            zero_copy_only=False)
+        rank = np.empty(len(order), dtype=np.int32)
+        rank[order] = np.arange(len(order), dtype=np.int32)
+        dictionary = arr.dictionary.take(pa.array(order))
+        np_data = pc.fill_null(arr.indices, 0).cast(pa.int32()).to_numpy(
+            zero_copy_only=False)
+        if len(rank):    # a column of nulls alone has no entry
+            np_data = rank[np_data]
         dt = T.STRING
     elif pa.types.is_decimal(at):
         dt = T.DecimalType(at.precision, at.scale)
