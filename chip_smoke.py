@@ -12,7 +12,10 @@ One process, one TPU chip, the entry points a user calls:
             `POST /sql`, each three times, cold then warm twice, each
             compared with the independent pandas golden; the second
             and third submission must compile no stage
-            (`compile_cache_misses` stands); every status record must
+            (`compile_cache_misses` stands), but that a join query's
+            second may compile one, the stage whose runtime filters
+            hand on their survivors compacted, and then goes a fourth
+            time; every status record must
             be `ok` with no fault events, and `/metrics` must count no
             retry, no OOM-ladder rung and no mesh fallback.
 4. aggregate  the reference AggregateBenchmark's "linear keys" shape at
@@ -236,12 +239,22 @@ def phase_serve(svc, path: str, queries=SERVED) -> None:
     """`queries` over HTTP, each three times, cold then warm twice:
     golden parity, clean status records, clean /metrics, and a stage
     compiled by the first submission alone (until PR 37 a join's second
-    submission compiled again, and its "warm" time was a compile)."""
+    submission compiled again, and its "warm" time was a compile:
+    PR 37's fault was a compile on every submission). A join query
+    goes a fourth time, and its compiles by submission may read
+    [>=1, <=1, 0, 0]: the first execution of a text whose runtime
+    filter prunes hard learns the capacity its survivors fit, and the
+    second compiles the stage that hands them on compacted, once, by
+    design (PR 38); the third and the fourth must compile nothing."""
     from spark_tpu.tpch import sql_queries as SQLQ
     base = f"http://127.0.0.1:{svc.port}"
+    sent = 0
     for name in queries:
         ms, compiles = [], [_stage_compiles(base)]
-        for _run in ("cold", "warm", "warm again"):
+        joins = name in MESH_QUERIES
+        runs = ("cold", "warm", "warm again") \
+            + (("and again",) if joins else ())
+        for _run in runs:
             t0 = time.perf_counter()
             resp = _http_json(f"{base}/sql",
                               {"sql": getattr(SQLQ, name)})
@@ -251,14 +264,17 @@ def phase_serve(svc, path: str, queries=SERVED) -> None:
             _check_golden(got, path, name.lower())
             _check_status_record(base, resp)
             compiles.append(_stage_compiles(base))
+        sent += len(runs)
         grown = [b - a for a, b in zip(compiles, compiles[1:])]
-        assert grown[0] >= 1 and grown[1:] == [0, 0], \
+        assert grown[0] >= 1 and grown[1] <= int(joins) \
+            and not any(grown[2:]), \
             f"{name}: stage compiles by submission {grown}: a warm " \
             f"submission compiled"
         log(f"serve: {name} rows={resp['row_count']} golden=ok "
             f"cold_ms={ms[0]:.1f} warm_ms={ms[1]:.1f} "
-            f"warm_again_ms={ms[2]:.1f} compiles={grown}")
-    prom = _clean_metrics(base, 3 * len(queries))
+            + " ".join(f"warm_again_ms={t:.1f}" for t in ms[2:])
+            + f" compiles={grown}")
+    prom = _clean_metrics(base, sent)
     log(f"serve: /metrics completed="
         f"{int(prom['spark_tpu_service_completed'])} retries=0 "
         f"oom_rungs=0 mesh_fallback=0")

@@ -395,6 +395,7 @@ def _check_recompile(root: P.PhysicalPlan, conf,
             flag(node, "aggregate.est_groups", node.est_groups)
         elif isinstance(node, P.RuntimeFilterExec):
             flag(node, "runtime_filter.est_items", node.est_items)
+            flag(node, "runtime_filter.out_cap", node.out_cap)
 
     walk(root)
 

@@ -36,6 +36,31 @@ class _ReplanRequest(Exception):
 DISPATCH_POLL_KEY = "spark_tpu.execution.dispatchPollMs"
 
 
+#: When a runtime filter's output is worth compacting
+#: (`QueryExecution._learn_filter_caps`): the probe has at least
+#: MIN_SLOTS slots, and the bucket of the rows the filter kept, times
+#: SHRINK, fits them. Constants, set by a sweep on one TPU v5e (my chip
+#: run, PR 38, call 2: a probe of N slots compacted to N/4, four
+#: columns, against one N-row gather above the filter run at N/4
+#: instead): at a quarter, the compaction costs 17.3 ms at 1 Mi slots
+#: and one gather saved gives back 12.5, so two of the eight gathers
+#: and nine scatters a join and an aggregate hold above a filter pay
+#: for it (at half, a compaction gathers twice the rows to save a
+#: third less); at 65,536 slots it costs 1.86 ms and a gather saved
+#: gives 0.73, so all seventeen passes together save some 10 ms an
+#: execution, under which a second compile of the stage (minutes cold,
+#: seconds from the compile cache) is not paid back in a process's
+#: life; at 4,096 a gather saved gives nothing
+FILTER_COMPACT_MIN_SLOTS = 1 << 16
+FILTER_COMPACT_SHRINK = 4
+
+
+def _filter_kept(metrics: Dict, tag: str) -> int:
+    """The rows runtime filter `tag` kept, from its two counts."""
+    return int(metrics[f"rtf_tested_{tag}"]) \
+        - int(metrics[f"rtf_pruned_{tag}"])
+
+
 #: name of the short-lived thread a sync that has to wait starts
 #: (`LockWatch.assert_no_thread_leak` finds a leak by this prefix)
 SYNC_WAITER_THREAD = "spark-tpu-dispatch-sync"
@@ -435,6 +460,9 @@ class QueryExecution:
             pruned = m.get(f"rtf_pruned_{tag}")
             if tested is not None and pruned is not None:
                 notes.append(f"rtf pruned: {pruned:,}/{tested:,}")
+            slots = m.get(f"rtf_slots_{tag}")
+            if slots is not None:
+                notes.append(f"slots out: {slots:,}")
         note = f"   [{'; '.join(notes)}]" if notes else ""
         line = "  " * depth + node.simple_string() + note
         return "\n".join([line] + [self._runtime_tree(c, depth + 1)
@@ -1015,6 +1043,9 @@ class QueryExecution:
             out[f"exch:{root.tag}"] = root.block_cap
         elif isinstance(root, P.HashAggregateExec) and root.est_groups:
             out[f"agg:{root.tag}"] = root.est_groups
+        elif isinstance(root, P.RuntimeFilterExec) \
+                and root.out_cap is not None:
+            out[f"rtf:{root.tag}"] = root.out_cap
 
     def _apply_saved_caps(self, root: P.PhysicalPlan, caps: Dict[str, int]
                           ) -> None:
@@ -1028,6 +1059,8 @@ class QueryExecution:
                 self._set_join_hash_fallback(root, tag)
             elif kind == "exch":
                 self._set_exchange_cap(root, tag, cap)
+            elif kind == "rtf":
+                self._set_filter_cap(root, tag, cap)
             else:
                 self._set_agg_groups(root, tag, cap)
 
@@ -1069,6 +1102,13 @@ class QueryExecution:
             QueryExecution._set_agg_groups(c, tag, est)
         if isinstance(root, P.HashAggregateExec) and root.tag == tag:
             root.est_groups = est
+
+    @staticmethod
+    def _set_filter_cap(root: P.PhysicalPlan, tag: str, cap: int) -> None:
+        for c in root.children:
+            QueryExecution._set_filter_cap(c, tag, cap)
+        if isinstance(root, P.RuntimeFilterExec) and root.tag == tag:
+            root.out_cap = cap
 
     def execute_batch(self) -> Tuple[Batch, Dict, Dict]:
         """Run the query, returning (device Batch, flags, metrics).
@@ -1731,7 +1771,7 @@ class QueryExecution:
                                 ("waited", "dispatch_sync_waits")):
                             self.session.metrics.counter(counter).inc(
                                 sync.attrs.get(attr, 0))
-                self._note_joins(disp, metrics)
+                self._note_joins(disp, root, metrics)
             if mesh is not None:
                 self._count_mesh_stage(metrics)
             # deadline BEFORE the stage-timeout check: an attempt
@@ -1752,7 +1792,8 @@ class QueryExecution:
                                          "join_nonunique_",
                                          "join_hashsat_",
                                          "exch_overflow_",
-                                         "agg_overflow_"))
+                                         "agg_overflow_",
+                                         "rtf_overflow_"))
                         and bool(v)]
             self._post_stage_completed(_attempt, t_att, metrics,
                                        overflow)
@@ -1790,6 +1831,13 @@ class QueryExecution:
                         raise _ReplanRequest()
                     self._set_exchange_cap(root, tag,
                                            bucket_capacity(max(mx, 8)))
+                elif k.startswith("rtf_overflow_"):
+                    # a compacted filter kept more rows than its
+                    # capacity holds: grow it to what it kept
+                    tag = k[len("rtf_overflow_"):]
+                    self._set_filter_cap(
+                        root, tag,
+                        bucket_capacity(_filter_kept(metrics, tag)))
                 else:
                     tag = k[len("agg_overflow_"):]
                     total = int(metrics[f"agg_groups_{tag}"])
@@ -1813,6 +1861,8 @@ class QueryExecution:
             converged: Dict[str, int] = {}
             self._collect_caps(self.executed_plan, converged)
             self._collect_caps(root, converged)
+            if mesh is None and adaptive:
+                self._learn_filter_caps(root, metrics, converged)
             if converged:
                 store = self.session._aqe_caps
                 store.setdefault(aqe_key, {}).update(converged)
@@ -1827,10 +1877,12 @@ class QueryExecution:
                              if not k.startswith("shard_")}
         # the *_ms_* keys (rtf_build_ms_*, join_build_ms_*,
         # join_probe_ms_*): trace-time costs from the host's record of
-        # the stage, floats (sub-ms builds are the common case)
+        # the stage, floats (sub-ms builds are the common case); and
+        # its shapes (rtf_slots_*: what each filter handed on)
         self.last_metrics.update(
-            (k, round(v, 3)) for k, v in self._stage_host().items()
-            if isinstance(v, float))
+            (k, round(v, 3) if isinstance(v, float) else v)
+            for k, v in self._stage_host().items()
+            if isinstance(v, float) or k.startswith("rtf_slots_"))
         if self._mesh_fallback:
             # degraded single-device result of a mesh-planned query:
             # visible next to the device metrics and in the event log
@@ -1897,7 +1949,8 @@ class QueryExecution:
         self._join_overrides[join.tag] = "broadcast"
         return True
 
-    def _note_joins(self, disp, metrics: Dict) -> None:
+    def _note_joins(self, disp, root: P.PhysicalPlan,
+                    metrics: Dict) -> None:
         """What a dispatched stage's joins did, into the process
         counter `/metrics` serves, from the stats channel
         `dispatch.sync` has just pulled (no sync of its own), whatever
@@ -1905,7 +1958,9 @@ class QueryExecution:
         `join_rows_*` (the filters' `rtf_tested` / `rtf_pruned` are the
         metrics sink's, folded at a query's end). The `dispatch` span
         gets `joins=<n>` and the kernel each resolved to while the
-        stage was traced (`join_kernels`, `<tag>=sort|hash`). A stage
+        stage was traced (`join_kernels`, `<tag>=sort|hash`), and
+        `rtf_caps=<tag>=<K>,...` where a runtime filter of `root`
+        hands on a compacted batch of K slots. A stage
         without a join touches nothing; one
         deserialized from the engine's own compile cache
         (`compileCache.enabled`) was not traced in this process and has
@@ -1922,6 +1977,57 @@ class QueryExecution:
             disp.attrs["joins"] = len(kernels)
             disp.attrs["join_kernels"] = ",".join(
                 f"{tag}={kernel}" for tag, kernel in kernels)
+        rtf_caps = sorted((f.tag, f.out_cap)
+                          for f in self._runtime_filters(root)
+                          if f.out_cap is not None)
+        if rtf_caps:
+            disp.attrs["rtf_caps"] = ",".join(
+                f"{tag}={cap}" for tag, cap in rtf_caps)
+
+    @staticmethod
+    def _runtime_filters(root: P.PhysicalPlan
+                         ) -> List[P.RuntimeFilterExec]:
+        """`root`'s runtime filters, each once (creation chains are
+        shared under them: the tree is a DAG)."""
+        found: Dict[int, P.RuntimeFilterExec] = {}
+        seen = set()
+
+        def walk(node):
+            if id(node) in seen:
+                return
+            seen.add(id(node))
+            for c in node.children:
+                walk(c)
+            if isinstance(node, P.RuntimeFilterExec):
+                found[id(node)] = node
+
+        walk(root)
+        return list(found.values())
+
+    def _learn_filter_caps(self, root: P.PhysicalPlan, metrics: Dict,
+                           converged: Dict[str, int]) -> None:
+        """After a converged attempt on one device: a runtime filter
+        that handed on its probe's slots and kept few of them gets a
+        capacity among the converged ones, `rtf:<tag>`, from its own
+        counts in the stats channel `dispatch.sync` has pulled. This
+        execution's result stands; the next execution of the same text
+        on the same data applies the capacity and compiles the
+        compacted stage once (a first submission's compile is most of
+        what a request may take, PERF.md, and two do not fit), and
+        after that the capacity only grows (`rtf_overflow_<tag>`)."""
+        from ..columnar import bucket_capacity
+        host = self._stage_host()
+        for node in self._runtime_filters(root):
+            # the probe's slots, which a masked filter hands on: from
+            # the host's record of the trace (none: nothing is learned)
+            slots = host.get(f"rtf_slots_{node.tag}")
+            if node.out_cap is not None or slots is None \
+                    or f"rtf_tested_{node.tag}" not in metrics:
+                continue
+            cap = bucket_capacity(_filter_kept(metrics, node.tag))
+            if slots >= FILTER_COMPACT_MIN_SLOTS \
+                    and cap * FILTER_COMPACT_SHRINK <= slots:
+                converged[f"rtf:{node.tag}"] = cap
 
     def _stage_host(self) -> Dict[str, object]:
         """The host's record of the stage last compiled or found."""

@@ -103,6 +103,9 @@ def sort_permutation(batch: Batch, orders: Sequence[SortOrder]):
 
 
 def apply_permutation(batch: Batch, perm, n_valid) -> Batch:
+    """`batch`'s rows at the positions `perm`, the first `n_valid` of
+    them live. A `perm` shorter than the batch cuts it to that many
+    slots (a compacted runtime filter's output)."""
     cols = {}
     for name, col in batch.columns.items():
         if col.offsets is not None:
@@ -111,7 +114,7 @@ def apply_permutation(batch: Batch, perm, n_valid) -> Batch:
         data = jnp.take(col.data, perm)
         validity = None if col.validity is None else jnp.take(col.validity, perm)
         cols[name] = Column(data, col.dtype, validity, col.dictionary)
-    sel = jnp.arange(batch.capacity) < n_valid
+    sel = jnp.arange(perm.shape[0]) < n_valid
     return Batch(cols, sel)
 
 

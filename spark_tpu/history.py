@@ -552,10 +552,11 @@ def compare_runs(base: pd.DataFrame, other: pd.DataFrame,
 
 def runtime_filter_summary(events: pd.DataFrame) -> pd.DataFrame:
     """Per-(execution, filter) runtime-filter pruning summary from a
-    read_event_log frame: tag, rows tested, rows pruned, pruning ratio
-    and the trace-time build cost — the observability surface of the
-    runtime-filter subsystem (rtf_* metrics emitted by
-    RuntimeFilterExec)."""
+    read_event_log frame: tag, rows tested, rows pruned, pruning ratio,
+    the slots the filter handed on (its probe's, or a compacted
+    filter's learned capacity) and the trace-time build cost — the
+    observability surface of the runtime-filter subsystem (rtf_*
+    metrics emitted by RuntimeFilterExec)."""
     rows: List[dict] = []
     tested_cols = [c for c in events.columns
                    if c.startswith("rtf_tested_")]
@@ -566,6 +567,7 @@ def runtime_filter_summary(events: pd.DataFrame) -> pd.DataFrame:
             if pd.isna(tested):
                 continue
             pruned = r.get(f"rtf_pruned_{tag}")
+            slots = r.get(f"rtf_slots_{tag}")
             rows.append({
                 "ts": r.get("ts"),
                 "app": r.get("app"),
@@ -576,6 +578,7 @@ def runtime_filter_summary(events: pd.DataFrame) -> pd.DataFrame:
                 # "unknown" must not read as "pruned nothing"
                 "ratio": (float(pruned) / float(tested)
                           if not pd.isna(pruned) and tested else None),
+                "slots": None if pd.isna(slots) else int(slots),
                 "build_ms": r.get(f"rtf_build_ms_{tag}"),
             })
     return pd.DataFrame(rows)

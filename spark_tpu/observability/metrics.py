@@ -51,6 +51,9 @@ METRIC_PREFIXES = (
     # milliseconds of trace time kept beside the stage-cache entry and
     # merged into last_metrics; no program holds a clock's reading
     "rtf_build_ms_",   # runtime-filter trace-time build cost
+    "rtf_slots_",      # HOST-side too: the slots a runtime filter hands
+                       # on (its probe's, or the learned capacity of a
+                       # compacted one): a shape, not a traced value
     "join_build_ms_",  # hash-join table build cost (trace-time)
     "join_probe_ms_",  # hash-join probe-program build cost
     "join_table_slots_",  # hash-join open-addressing table capacity

@@ -223,7 +223,8 @@ class MetricsSinkListener(QueryListener):
         for prefix, counter in (("exch_bytes_", "shuffle_bytes"),
                                 ("exch_rows_", "shuffle_rows"),
                                 ("rtf_tested_", "rtf_tested"),
-                                ("rtf_pruned_", "rtf_pruned")):
+                                ("rtf_pruned_", "rtf_pruned"),
+                                ("rtf_slots_", "rtf_slots")):
             total = sum(int(v) for k, v in metrics.items()
                         if k.startswith(prefix))
             if total:

@@ -441,10 +441,14 @@ class CostBasedJoinReorder(Rule):
         shape-signature change test.
 
         Orientation follows BASE capacities, not post-filter estimates:
-        the engine masks filtered rows rather than compacting them, so
-        the side with more physical rows (the fact) must stay on the
-        probe/left regardless of how selective its filters are — a
-        build side is sorted at its full static capacity."""
+        a filter masks the rows it drops and hands on its input's
+        slots, so the side with more physical rows (the fact) must
+        stay on the probe/left regardless of how selective its filters
+        are — a build side is sorted at its full static capacity. (The
+        one operator that compacts is a runtime filter whose capacity
+        the executor has learned, `RuntimeFilterExec.out_cap`: it is
+        set after planning, from counts, and no order is chosen by
+        it.)"""
         leaf_index = {id(rels[i]): i for i in range(len(rels))}
         bound = {order[0]}
         acc = rels[order[0]]

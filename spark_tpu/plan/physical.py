@@ -1707,16 +1707,28 @@ class RuntimeFilterExec(PhysicalPlan):
     pruned rows never radix-partition or cross ICI.
 
     Dropping this node never changes results (the join re-checks every
-    key): streamed/out-of-core chain matchers skip it."""
+    key): streamed/out-of-core chain matchers skip it.
+
+    `out_cap` None hands on the probe's own slots with a narrower
+    selection. With a capacity K (learned by the executor's capacity
+    loop from this filter's own counts, `_learn_filter_caps`) the kept
+    rows are moved to the front in their order and the batch is cut to
+    K slots, so every operator above runs over K slots and not the
+    probe's; `rtf_overflow_<tag>` says that more than K rows were kept,
+    and the capacity loop then grows K and runs the stage again. Under
+    a mesh the node keeps the masked output (its counts are sums over
+    the shards, and a shard's capacity wants a shard's maximum)."""
 
     def __init__(self, child: PhysicalPlan, creation: PhysicalPlan,
                  probe_key: Expression, build_key: Expression,
-                 est_items: Optional[int] = None, fpp: float = 0.03):
+                 est_items: Optional[int] = None, fpp: float = 0.03,
+                 out_cap: Optional[int] = None):
         self.children = (child, creation)
         self.probe_key = probe_key
         self.build_key = build_key
         self.est_items = est_items
         self.fpp = fpp
+        self.out_cap = out_cap
         self.tag = "rf0"
 
     @property
@@ -1754,12 +1766,37 @@ class RuntimeFilterExec(PhysicalPlan):
                        jnp.sum(psel.astype(jnp.int64)))
         ctx.add_metric(f"rtf_pruned_{self.tag}",
                        jnp.sum((psel & ~keep).astype(jnp.int64)))
-        return probe.with_selection(psel & keep)
+        sel = psel & keep
+        cap = self.out_cap
+        if cap is None or ctx.n_shards > 1 or cap >= probe.capacity:
+            out = probe.with_selection(sel)
+        else:
+            # the kept rows' positions to the front, in their order
+            # (a sort whose one key is "not kept" and whose ties the
+            # position breaks), then every column gathered at the
+            # first K of them: on its live rows every array above is
+            # the array it was
+            _, perm = sort_kernels.sort_carrying_positions(
+                ((~sel).astype(jnp.int8),))
+            kept = jnp.sum(sel.astype(jnp.int32))
+            ctx.add_flag(f"rtf_overflow_{self.tag}", kept > cap)
+            out = sort_kernels.apply_permutation(probe, perm[:cap], kept)
+        # the slots handed on, beside rtf_tested / rtf_pruned: a shape,
+        # known while the stage is traced, so the host's record has it
+        # and the program does not
+        ctx.host[f"rtf_slots_{self.tag}"] = \
+            out.capacity * max(1, ctx.n_shards)
+        return out
 
     def simple_string(self):
+        # `cap` only when set, as JoinExec's hash_fallback: a filter
+        # that hands on its probe's slots keeps the text, and so the
+        # stage key and the compiled program, it always had
         return (f"RuntimeFilterExec({self.probe_key!r} IN "
                 f"bloom({self.build_key!r}), est={self.est_items}, "
-                f"fpp={self.fpp})")
+                + (f"cap={self.out_cap}, " if self.out_cap is not None
+                   else "")
+                + f"fpp={self.fpp})")
 
 
 def _unify_key_dictionaries(lvecs: List[Vec], rvecs: List[Vec]

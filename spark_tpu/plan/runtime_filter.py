@@ -57,6 +57,11 @@ def estimate_rows_physical(node: P.PhysicalPlan) -> Optional[int]:
         return node.num_rows()
     if isinstance(node, P.InputExec):
         return node.load().capacity
+    if isinstance(node, P.RuntimeFilterExec) and node.out_cap is not None:
+        # a compacted filter hands on at most its learned capacity
+        # (set after planning, so no planning decision sees this)
+        child = estimate_rows_physical(node.children[0])
+        return node.out_cap if child is None else min(node.out_cap, child)
     if isinstance(node, (P.ProjectExec, P.FilterExec, P.SortExec,
                          P.ExchangeExec, P.WindowExec,
                          P.HashAggregateExec, P.RuntimeFilterExec)):

@@ -148,6 +148,40 @@ class _StatusListener(QueryListener):
                 self._history.put(r["id"], detail_from_event(event))
 
 
+#: `mallopt`'s parameters (glibc's malloc.h) and what a started
+#: service sets them to: a thread arena's heap size on a 64-bit glibc
+#: (HEAP_MAX_SIZE) and, since setting any of the three ends glibc's
+#: own adjusting of the other two, the values that adjusting ends at
+#: (the mmap threshold's maximum and twice it)
+_ARENA_HEAP_BYTES = 64 << 20
+_MALLOPT = ((-2, _ARENA_HEAP_BYTES),  # M_TOP_PAD
+            (-3, 32 << 20),           # M_MMAP_THRESHOLD
+            (-1, 64 << 20))           # M_TRIM_THRESHOLD
+
+
+def _open_thread_arenas_whole() -> None:
+    """A served query runs on an HTTP handler's thread, and glibc gives
+    every thread but the main one an arena of its own, made of 64 MiB
+    heaps that it opens to reading and writing as they fill, one
+    `mprotect` a few pages. Where that call is dear (the benchmark's
+    machine runs a sandboxed kernel) those steps are most of what a
+    stage's executable takes to come out of the compile cache: Q3's
+    stage 9.1-10.0 s on a handler's thread and 1.7-2.6 s on the main
+    one, or on a handler's thread of a process padded so (my chip
+    runs, PR 38, calls 5-6, PERF.md). Padding every growth by a heap's
+    size opens a new heap whole, once; it touches no page and keeps no
+    more memory resident. Without a glibc there is nothing to set."""
+    import ctypes
+    try:
+        mallopt = ctypes.CDLL(None).mallopt
+    except (OSError, AttributeError):
+        return
+    mallopt.argtypes, mallopt.restype = [ctypes.c_int, ctypes.c_int], \
+        ctypes.c_int
+    for param, value in _MALLOPT:
+        mallopt(param, value)
+
+
 class SqlService:
     """Session pool + admission + arbiter + HTTP front end. Usable
     embedded (`submit()`) or served (`start()`/`stop()`)."""
@@ -862,6 +896,7 @@ class SqlService:
         connection-refused. Queries racing the replay just compile as
         usual (the stage cache fills under them either way)."""
         self._ensure_arbiter()
+        _open_thread_arenas_whole()
         self.status_store.start()
         handler = _make_handler(self)
         self._httpd = ThreadingHTTPServer(

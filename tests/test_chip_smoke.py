@@ -65,10 +65,11 @@ def test_serve_phase(tpch_path):
 
 
 def test_serve_phase_fails_when_a_warm_submission_compiles(tpch_path):
-    """Q3 goes three times, and the second and third may compile no
-    stage: with a count of stage compiles that grows at every look
-    (what a capacity re-seeded after the run used to amount to) the
-    phase must fail, and as that."""
+    """Q3 goes four times; the second may compile the stage whose
+    filters compact, the third and fourth no stage: with a count of
+    stage compiles that grows at every look (what a capacity re-seeded
+    after the run used to amount to) the phase must fail, and as
+    that."""
     import itertools
     from unittest import mock
     svc = chip_smoke.start_service(tpch_path)

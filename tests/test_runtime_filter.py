@@ -31,8 +31,8 @@ def tables(session):
     return session
 
 
-def _selective_join(session):
-    d = session.table("rtf_dim").filter(col("flag") == lit(0))
+def _selective_join(session, flag=0):
+    d = session.table("rtf_dim").filter(col("flag") == lit(flag))
     return session.table("rtf_fact").join(
         d, left_on=col("k"), right_on=col("k2"))
 
@@ -411,3 +411,256 @@ def test_semi_aware_skips_shadowing_project(semi_tables):
         .sort_values("v").reset_index(drop=True)
     pd.testing.assert_frame_equal(on, off)
     assert len(on) == 1, on  # probe k=1 matches build x=1/tag=a
+
+
+# -- a filter that hands on its survivors compacted (PR 38) -------------------
+
+def _rf_nodes(plan):
+    from spark_tpu.plan import physical as P
+    found, seen = [], set()
+
+    def walk(n):
+        if id(n) in seen:
+            return
+        seen.add(id(n))
+        if isinstance(n, P.RuntimeFilterExec):
+            found.append(n)
+        for c in n.children:
+            walk(c)
+
+    walk(plan)
+    return found
+
+
+def _set_cap(plan, cap):
+    nodes = _rf_nodes(plan)
+    assert len(nodes) == 1, plan.tree_string()
+    nodes[0].out_cap = cap
+    return nodes[0]
+
+
+@pytest.fixture
+def engaged(monkeypatch):
+    """The capacity loop's rule for compacting a filter engages on a
+    probe of the tests' size (the engine's own floor is a probe of
+    65,536 slots: executor.FILTER_COMPACT_MIN_SLOTS)."""
+    from spark_tpu.execution import executor
+    monkeypatch.setattr(executor, "FILTER_COMPACT_MIN_SLOTS", 1024)
+
+
+def _probe_and_build():
+    """A probe of 5,000 rows in 8,192 slots, every third row out of its
+    selection, with a nullable and a dictionary column; and the build
+    side's 100 keys."""
+    import jax.numpy as jnp
+    import pyarrow as pa
+    from spark_tpu.columnar import Batch
+    rs = np.random.RandomState(11)
+    n = 5000
+    k = rs.randint(0, 1000, n).astype(np.int64)
+    v = pa.array(np.arange(n, dtype=np.int64),
+                 mask=(np.arange(n) % 7 == 0))
+    s = pa.array([None if i % 11 == 0 else f"s{i % 13}"
+                  for i in range(n)])
+    d = pa.array(rs.randint(8000, 9000, n).astype(np.int32),
+                 type=pa.date32())
+    probe = Batch.from_arrow(pa.table({"k": k, "v": v, "s": s, "d": d}))
+    iota = jnp.arange(probe.capacity)
+    probe = probe.with_selection(probe.selection_mask() & (iota % 3 != 0))
+    build = Batch.from_arrow(pa.table({
+        "k2": np.arange(0, 1000, 10, dtype=np.int64)}))
+    return probe, build
+
+
+@pytest.mark.parametrize("cap", [512, 2048, 64, 8192])
+def test_compacted_output_is_the_masked_output_on_its_live_rows(
+        session, cap):
+    """One filter, masked and compacted: on the live rows, in order,
+    every column's data and validity are the same arrays, a dictionary
+    is the same dictionary, and the selection is a prefix. A capacity
+    under what was kept (64) raises the overflow flag and holds the
+    first rows kept; one at or over the probe's (8,192) changes
+    nothing."""
+    from spark_tpu.expr import ColumnRef
+    from spark_tpu.plan import physical as P
+    probe, build = _probe_and_build()
+
+    def run(out_cap):
+        node = P.RuntimeFilterExec(None, None, ColumnRef("k"),
+                                   ColumnRef("k2"), est_items=128,
+                                   out_cap=out_cap)
+        ctx = P.ExecContext(session.conf)
+        return node.compute(ctx, [probe, build]), ctx
+
+    masked, mctx = run(None)
+    live = np.asarray(masked.selection_mask())
+    kept = int(live.sum())
+    assert 64 < kept <= 512 and masked.capacity == 8192
+    assert mctx.host["rtf_slots_rf0"] == 8192 and not mctx.flags
+    assert int(mctx.metrics["rtf_tested_rf0"]) \
+        - int(mctx.metrics["rtf_pruned_rf0"]) == kept
+
+    out, ctx = run(cap)
+    for name in ("rtf_tested_rf0", "rtf_pruned_rf0"):
+        assert int(ctx.metrics[name]) == int(mctx.metrics[name])
+    if cap >= probe.capacity:
+        assert out.capacity == probe.capacity and not ctx.flags
+        assert (np.asarray(out.selection_mask()) == live).all()
+        return
+    assert out.capacity == cap == ctx.host["rtf_slots_rf0"]
+    assert bool(ctx.flags["rtf_overflow_rf0"]) == (kept > cap)
+    n = min(kept, cap)
+    assert (np.asarray(out.selection_mask())
+            == (np.arange(cap) < kept)).all()
+    for name, want in masked.columns.items():
+        got = out.columns[name]
+        assert got.dtype == want.dtype
+        assert got.dictionary is want.dictionary
+        assert (np.asarray(got.data)[:n]
+                == np.asarray(want.data)[live][:n]).all(), name
+        assert (got.validity is None) == (want.validity is None), name
+        if want.validity is not None:
+            assert (np.asarray(got.validity)[:n]
+                    == np.asarray(want.validity)[live][:n]).all(), name
+
+
+def _sorted(df):
+    return df.sort_values("v").reset_index(drop=True)
+
+
+def test_a_capacity_set_too_small_overflows_and_replans(tables):
+    """A compacted filter never drops a row silently: with a capacity
+    of 8 set by hand on the node the first attempt raises
+    `rtf_overflow_rf0`, the capacity loop grows the capacity to the
+    bucket of what the filter kept and runs the stage again, and the
+    answer is the masked plan's."""
+    from spark_tpu.columnar import bucket_capacity
+    want = _sorted(_selective_join(tables).to_pandas())
+    dispatches = tables.metrics.counter("stage_dispatches")
+    qe = _selective_join(tables)._qe()
+    node = _set_cap(qe.executed_plan, 8)
+    assert "cap=8, " in node.simple_string()
+    before = dispatches.value
+    batch, flags, _ = qe.execute_batch()
+    assert dispatches.value - before == 2
+    marks = [s for s in qe.spans.to_dicts() if s["name"] == "aqe_overflow"]
+    assert [m["attrs"]["flags"] for m in marks] == [["rtf_overflow_rf0"]]
+    assert not bool(flags["rtf_overflow_rf0"])
+    m = qe.last_metrics
+    kept = m["rtf_tested_rf0"] - m["rtf_pruned_rf0"]
+    assert node.out_cap == bucket_capacity(kept) == m["rtf_slots_rf0"]
+    assert not qe.fault_summary
+    got = _sorted(batch.to_arrow().to_pandas())
+    pd.testing.assert_frame_equal(got, want)
+
+
+def test_the_capacity_is_learned_for_the_next_execution(tables, engaged):
+    """The first execution answers from the masked stage and leaves
+    `rtf:rf0` among the converged capacities; the second applies it and
+    compiles the compacted stage, once; the third finds it. The
+    filter's counts repeat exactly, its slots shrink, the answers are
+    the same frame."""
+    misses = tables.metrics.counter("compile_cache_misses")
+    seen = []
+    for _ in range(3):
+        before = misses.value
+        # a text of its own: the session's stage cache is the module's
+        qe = _selective_join(tables, flag=1)._qe()
+        batch, flags, _ = qe.execute_batch()
+        assert not any(bool(v) for v in flags.values()), flags
+        seen.append((misses.value - before, qe.last_metrics,
+                     _sorted(batch.to_arrow().to_pandas()),
+                     _rf_nodes(qe.executed_plan)[0].out_cap))
+    assert [s[0] for s in seen] == [1, 1, 0]
+    assert [s[3] for s in seen] == [None, 4096, 4096]
+    assert [s[1]["rtf_slots_rf0"] for s in seen] == [32768, 4096, 4096]
+    for key in ("rtf_tested_rf0", "rtf_pruned_rf0", "join_rows_j0"):
+        assert len({s[1][key] for s in seen}) == 1, key
+    for s in seen[1:]:
+        pd.testing.assert_frame_equal(s[2], seen[0][2])
+    saved = [caps for caps in tables._aqe_caps.values()
+             if "rtf:rf0" in caps]
+    assert saved and saved[-1]["rtf:rf0"] == 4096
+
+
+def test_a_filter_that_keeps_most_of_its_probe_is_left_masked(
+        tables, engaged):
+    """The rule reads the filter's own counts: one that keeps more than
+    a quarter of its probe's slots learns no capacity."""
+    def half():
+        d = tables.table("rtf_dim").filter(col("flag") < lit(5))
+        return tables.table("rtf_fact").join(
+            d, left_on=col("k"), right_on=col("k2"))
+
+    misses = tables.metrics.counter("compile_cache_misses")
+    half()._qe().execute_batch()
+    after_first = misses.value
+    qe = half()._qe()
+    qe.execute_batch()
+    assert misses.value == after_first
+    m = qe.last_metrics
+    assert m["rtf_pruned_rf0"] > 0 and m["rtf_slots_rf0"] == 32768
+    assert _rf_nodes(qe.executed_plan)[0].out_cap is None
+
+
+def test_under_a_mesh_the_filter_stays_masked(tables, engaged):
+    """Under `mesh.size` 2 the counts are sums over the shards: nothing
+    is learned, and a capacity on the node is not applied."""
+    from spark_tpu.parallel.mesh import get_mesh
+    tables.conf.set(MESH_KEY, 2)
+    want = None
+    for cap in (None, 512):
+        for _ in range(2):
+            qe = _selective_join(tables)._qe()
+            node = _set_cap(qe.executed_plan, cap)
+            batch, flags, _ = qe.execute_batch()
+            assert "rtf_overflow_rf0" not in flags
+            # every shard hands on its part of the probe's slots
+            assert qe.last_metrics["rtf_slots_rf0"] == 32768
+            assert node.out_cap == cap
+            if cap is None:
+                learned = tables._aqe_caps.get(
+                    qe._aqe_cache_key(get_mesh(tables.conf)), {})
+                assert not any(k.startswith("rtf:") for k in learned)
+            got = _sorted(batch.to_arrow().to_pandas())
+            if want is None:
+                want = got
+            pd.testing.assert_frame_equal(got, want)
+    tables.conf.set(MESH_KEY, 0)
+    pd.testing.assert_frame_equal(
+        _sorted(_selective_join(tables).to_pandas()), want)
+
+
+def test_the_capacity_shows_where_an_operator_looks(tables, engaged,
+                                                    tmp_path):
+    """`explain(runtime=True)`, the plan analyzer, the predictions and
+    `history.runtime_filter_summary` show or accept the capacity."""
+    from spark_tpu import history
+    from spark_tpu.analysis.plan_analyzer import analyze_plan
+    from spark_tpu.analysis.predictions import predict_plan
+    log_dir = str(tmp_path / "events")
+    tables.conf.set("spark_tpu.sql.eventLog.dir", log_dir)
+    _selective_join(tables)._qe().execute_batch()  # learns
+    qe = _selective_join(tables)._qe()
+    qe.execute_batch()
+    tables.conf.set("spark_tpu.sql.eventLog.dir", "")
+    text = qe.explain(runtime=True)
+    assert "cap=4096, " in text and "slots out: 4,096" in text
+
+    def codes(plan):
+        return [f.code for f in analyze_plan(plan, tables.conf)
+                if f.detail and f.detail.get("kind")
+                == "runtime_filter.out_cap"]
+
+    assert codes(qe.executed_plan) == []
+    odd = _selective_join(tables)._qe().executed_plan
+    _set_cap(odd, 3000)
+    assert codes(odd) == ["UNBUCKETED_CAPACITY"]
+    # the join above the filter is graded against the filter's slots
+    join = [p for p in predict_plan(qe.executed_plan, tables.conf)
+            if p["kind"] == "join_rows"]
+    assert [p["predicted"] for p in join] == [4096]
+    summary = history.runtime_filter_summary(
+        history.read_event_log(log_dir))
+    assert summary["slots"].tolist() == [32768, 4096]
+    assert summary["tested"].nunique() == 1

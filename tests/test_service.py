@@ -827,3 +827,67 @@ def test_standalone_session_result_cache_unbounded(session):
     conf.set(RESULT_CACHE_BYTES_KEY, 1234)
     bounded = SparkTpuSession(conf=conf, register_active=False)
     assert bounded._data_cache.max_bytes == 1234
+
+
+_ARENAS = """
+import sys, threading
+sys.path.insert(0, {repo!r})
+from spark_tpu.service import server
+
+HEAP = server._ARENA_HEAP_BYTES
+
+
+def whole_heaps():
+    # heap-sized, heap-aligned blocks that lie whole inside one
+    # readable and writable mapping (a neighbour may be merged in)
+    n = 0
+    for line in open("/proc/self/maps"):
+        span, perms = line.split()[:2]
+        lo, hi = (int(x, 16) for x in span.split("-"))
+        if perms.startswith("rw"):
+            n += max(0, hi // HEAP - -(-lo // HEAP))
+    return n
+
+
+def from_a_new_thread(out, looked, done):
+    keep = [bytearray(100_000) for _ in range(50)]
+    out.append(whole_heaps())
+    looked.set()
+    done.wait(60)  # alive, so that its arena is no one else's
+
+
+seen, done = [], threading.Event()
+for tuned in (False, True):
+    if tuned:
+        server._open_thread_arenas_whole()
+    looked = threading.Event()
+    threading.Thread(target=from_a_new_thread,
+                     args=(seen, looked, done)).start()
+    assert looked.wait(60)
+done.set()
+print("SEEN", *seen)
+"""
+
+
+def test_a_started_service_opens_a_threads_arena_whole():
+    """A served query runs on a handler's thread, whose glibc arena
+    grows by one `mprotect` a few pages; `SqlService.start` pads the
+    growth so that a new 64 MiB heap is readable and writable whole
+    (PR 38: where that call is dear, a stage's executable took 9 s to
+    come out of the compile cache on such a thread and 2 s on the
+    main one). In a process of its own: the setting is the process's."""
+    import os
+    import subprocess
+    import sys
+    if not os.path.exists("/proc/self/maps"):
+        pytest.skip("no /proc/self/maps to read the heaps from")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run(
+        [sys.executable, "-c", _ARENAS.format(repo=repo)],
+        capture_output=True, text=True, timeout=120,
+        env={k: v for k, v in os.environ.items()
+             if not k.startswith("MALLOC_")})
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    line = [ln for ln in proc.stdout.splitlines() if ln.startswith("SEEN")]
+    before, after = (int(x) for x in line[-1].split()[1:])
+    assert after > before, (before, after)
