@@ -248,9 +248,9 @@ class DataFrame:
 
     # -- actions ------------------------------------------------------------
 
-    def _qe(self):
+    def _qe(self, spans=None):
         from .execution.executor import QueryExecution
-        return QueryExecution(self.session, self.plan)
+        return QueryExecution(self.session, self.plan, spans)
 
     def collect(self) -> pa.Table:
         return self._qe().collect()

@@ -19,7 +19,7 @@ from typing import Callable, Optional
 import jax
 
 from ..columnar import bucket_capacity
-from ..observability.spans import span
+from ..observability.spans import current_recorder, span
 from .recovery import ChunkRetrier
 
 
@@ -60,26 +60,38 @@ def drive(leaf, chunk_rows: int, conf, recovery,
     (fault, cancellation) unwinding mid-stream: no ingest daemon
     outlives its query."""
     from ..io.sources import maybe_prefetch
-    # chunk-granular retry (execution/recovery.py) on both threads: the
-    # carry only advances after a chunk succeeds, so a TRANSIENT fault
-    # replays exactly the failed chunk against the pre-chunk state
-    retrier, ingest = (ChunkRetrier(conf, recovery, site=site)
-                       for site in ("stream_chunk", "ingest_prefetch"))
-    chunks = maybe_prefetch(
-        leaf.source.load_chunks(leaf.required_columns,
-                                leaf.pushed_filters, chunk_rows),
-        conf, recovery, retry=ingest.run)
+    # the prefetch worker's spans are caused by the span the stream
+    # runs under (`streaming`), not by `stream.open`, a leaf beside them
+    rec = current_recorder()
+    cause = rec.current() if rec is not None else None
+    chunks = None
     try:
-        if start and (not hasattr(chunks, "skip_chunks")
-                      or chunks.skip_chunks(start) < start):
-            return None  # stream shorter than the checkpoint cursor
+        # up to the first chunk's taking: the retriers, the source's
+        # chunk stream, the prefetcher, a checkpoint's skip
+        with span("stream.open"):
+            # chunk-granular retry (execution/recovery.py) on both
+            # threads: the carry only advances after a chunk succeeds,
+            # so a TRANSIENT fault replays exactly the failed chunk
+            # against the pre-chunk state
+            retrier, ingest = (ChunkRetrier(conf, recovery, site=site)
+                               for site in ("stream_chunk",
+                                            "ingest_prefetch"))
+            chunks = maybe_prefetch(
+                leaf.source.load_chunks(leaf.required_columns,
+                                        leaf.pushed_filters, chunk_rows),
+                conf, recovery, retry=ingest.run, cause=cause)
+            if start and (not hasattr(chunks, "skip_chunks")
+                          or chunks.skip_chunks(start) < start):
+                return None  # stream shorter than the checkpoint cursor
         t_in0 = time.perf_counter()
         b = next(iter(chunks), None)
         t_in1 = time.perf_counter()
         if b is None:
             return empty()
-        sink = begin(b, lambda: dict(
-            getattr(chunks, "dictionaries", None) or {}))
+        # the chunk program found or built on the first chunk
+        with span("stream.begin"):
+            sink = begin(b, lambda: dict(
+                getattr(chunks, "dictionaries", None) or {}))
         if sink is None:
             return None
         ci = int(start)

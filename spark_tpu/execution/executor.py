@@ -134,7 +134,7 @@ def _sync_dispatched(outs, conf, span=None):
 
 
 class QueryExecution:
-    def __init__(self, session, logical: L.LogicalPlan):
+    def __init__(self, session, logical: L.LogicalPlan, spans=None):
         from ..observability import SpanRecorder
         self.session = session
         self.logical = logical
@@ -147,12 +147,16 @@ class QueryExecution:
         # -trace exportable) + the XLA cost/memory analysis of every
         # stage this execution compiled or reused (observability/)
         self.query_id: int = session._next_query_id()
-        self.spans = SpanRecorder(
-            self.query_id,
-            max_spans=int(session.conf.get(
-                "spark_tpu.sql.observability.maxSpans")),
-            max_shard_records=int(session.conf.get(
-                "spark_tpu.sql.observability.maxShardRecords")))
+        # `spans`: the recorder a served request was born with
+        # (service/server.py), which this query adopts, so that its
+        # `t0_ms` count from the request's first instant and the front
+        # end's spans stand in the same tree
+        self.spans = spans if spans is not None else SpanRecorder()
+        self.spans.query_id = self.query_id
+        self.spans.max_spans = int(session.conf.get(
+            "spark_tpu.sql.observability.maxSpans"))
+        self.spans.max_shard_records = int(session.conf.get(
+            "spark_tpu.sql.observability.maxShardRecords"))
         self.stage_costs: Dict[str, dict] = {}
         # capacity/size predictions harvested from the planned tree
         # (analysis/predictions.py) — graded against observed metrics
@@ -231,6 +235,12 @@ class QueryExecution:
             self.spans.record("analysis", t0, t1)
         return self._analyzed
 
+    def _fingerprint(self, plan: L.LogicalPlan) -> str:
+        """`session._plan_fingerprint` as a query pays for it: the
+        tree's string and a `cache_token` of every scan's source."""
+        with self.spans.span("plan.fingerprint"):
+            return self.session._plan_fingerprint(plan)
+
     def _apply_cache(self, plan: L.LogicalPlan) -> L.LogicalPlan:
         """Substitute cached subtrees with scans over their materialized
         tables (reference: CacheManager.useCachedData). A MARKED but
@@ -240,10 +250,10 @@ class QueryExecution:
         session = self.session
         if not session._data_cache and not session._cache_requests:
             return plan
-        root_fp = session._plan_fingerprint(plan)
+        root_fp = self._fingerprint(plan)
 
         def f(node):
-            fp = session._plan_fingerprint(node)
+            fp = self._fingerprint(node)
             table = session._data_cache.get(fp)
             if table is not None:
                 # shared (service) or per-session result-cache hit: the
@@ -780,13 +790,13 @@ class QueryExecution:
                 out_specs=(Psp(AXIS), Psp(), Psp()),
                 check_vma=False)
 
-    def _compile_stage(self, root: P.PhysicalPlan, mesh=None, args=None):
-        from ..observability.listener import StageCompiledEvent
+    def _find_stage(self, root: P.PhysicalPlan, mesh, args):
+        """The `stage.lookup` span's body: the stage's key, the disk
+        cache that applies, and what the stage cache holds under the
+        key: (key, cc, fn, partial), `fn` None on a miss, `partial` a
+        wrapper whose key is warm and whose call signature is not."""
         from ..testing import faults
         from . import compile_cache as CC
-        from . import lifecycle
-        # cooperative boundary before paying (or re-paying) a compile
-        lifecycle.checkpoint("compile")
         key = self._stage_key(root, mesh)
         self._last_stage_key = key  # recovery evicts exactly this entry
         cc = CC.get_cache(self._conf) if args is not None else None
@@ -831,10 +841,22 @@ class QueryExecution:
         if fn is not None:
             self.session.metrics.counter("compile_cache_hits").inc()
             self._capture_stage_cost(fn, key, args)
-            self._last_compile_was_miss = False
+        return key, cc, fn, partial
+
+    def _compile_stage(self, root: P.PhysicalPlan, mesh=None, args=None):
+        from ..observability.listener import StageCompiledEvent
+        from ..testing import faults
+        from . import compile_cache as CC
+        from . import lifecycle
+        # cooperative boundary before paying (or re-paying) a compile
+        lifecycle.checkpoint("compile")
+        # once a dispatch attempt; on a miss it ends where `compile`
+        # begins
+        with self.spans.span("stage.lookup"):
+            key, cc, fn, partial = self._find_stage(root, mesh, args)
+        if fn is not None:
             return fn
         self.session.metrics.counter("compile_cache_misses").inc()
-        self._last_compile_was_miss = True
         t_compile = time.perf_counter()
         faults.fire("stage_compile")  # chaos seam: pre-jit, cache miss
         if mesh is not None:
@@ -851,12 +873,6 @@ class QueryExecution:
                 disk_hit = True
                 self.spans.record("deserialize", t_deser,
                                   time.perf_counter())
-        if cc is not None:
-            # either cc branch pays compile/deserialize EAGERLY here,
-            # so the first dispatch carries no jit compile — the
-            # dispatch span's includes_jit_compile flag must not
-            # attribute cost this span already carries
-            self._last_compile_was_miss = False
         if compiled is not None:
             if partial is not None:
                 fn = partial
@@ -884,8 +900,8 @@ class QueryExecution:
         cost = self._capture_stage_cost(fn, key, args, compiled=compiled)
         t1 = time.perf_counter()
         # honesty note: jax.jit is lazy — the EXECUTING program's XLA
-        # compile happens inside the first dispatch (that dispatch span
-        # carries includes_jit_compile=True). Under the compile cache
+        # compile happens inside the first dispatch (its
+        # `dispatch.launch`). Under the compile cache
         # the AOT path is EAGER, so this span carries the true compile
         # (or deserialize) cost. Without it, the span covers stage
         # setup plus, when capture is on, the AOT analysis compile
@@ -978,11 +994,14 @@ class QueryExecution:
         if self._jaxpr_analysis_on(strict):
             from ..analysis import analyze_jaxpr, trace_stage
             from ..testing import faults
+            # the memo's key is the stage's, a rendering of the whole
+            # tree: a hit pays it too, so the interval is recorded
+            # either way
+            t0 = time.perf_counter()
             key = "jaxpr#" + self._stage_key(root, mesh)
             memo = self.session._analysis_memo
             found = memo.get(key)
             if found is None:
-                t0 = time.perf_counter()
                 try:
                     # suppressed(): abstract evaluation re-traces the
                     # stage; trace-time chaos sites must count once per
@@ -1001,9 +1020,8 @@ class QueryExecution:
                     memo[key] = found
                     while len(memo) > 512:
                         memo.pop(next(iter(memo)))
-                self.spans.record("analyze_jaxpr", t0,
-                                  time.perf_counter(),
-                                  findings=len(found))
+            self.spans.record("analyze_jaxpr", t0, time.perf_counter(),
+                              findings=len(found))
             if found:
                 known = {(f.code, f.op) for f in
                          (self.analysis_findings or [])}
@@ -1110,8 +1128,11 @@ class QueryExecution:
         if isinstance(root, P.RuntimeFilterExec) and root.tag == tag:
             root.out_cap = cap
 
-    def execute_batch(self) -> Tuple[Batch, Dict, Dict]:
+    def execute_batch(self, t_begin: Optional[float] = None
+                      ) -> Tuple[Batch, Dict, Dict]:
         """Run the query, returning (device Batch, flags, metrics).
+        `t_begin`: where the span `query.begin` starts, `collect`'s
+        entry when it is the caller.
 
         Joins whose many-to-many expansion overflows the seeded output
         capacity surface a `join_overflow_<tag>` flag plus the true row
@@ -1133,6 +1154,7 @@ class QueryExecution:
         from .failures import RetryPolicy
         from .recovery import RecoveryContext
         from . import lifecycle
+        t_begin = t_begin or time.perf_counter()
         self._activate_conf()
         # degraded-mode state was sticky across executions of one
         # QueryExecution: a warm-loop re-execution after a transient
@@ -1180,6 +1202,11 @@ class QueryExecution:
                 query_id=self.query_id, ts=time.time(),
                 plan=self.logical.tree_string()))
         self.session._exec_depth += 1
+        # `collect`'s scopes and the prologue above (fault arming, the
+        # lifecycle and arbiter scopes, the recovery context, the
+        # retry and elastic state, the start event), handed over: it
+        # begins in `collect`
+        self.spans.record("query.begin", t_begin, time.perf_counter())
         try:
             for _replan in range(4):
                 try:
@@ -1611,11 +1638,17 @@ class QueryExecution:
         # The key includes every scan's source identity stamp: caps
         # learned on old data must not seed (possibly too small) after a
         # table is re-registered or a file rewritten.
-        aqe_key = self._aqe_cache_key(mesh)
-        saved_caps = self.session._aqe_caps.get(aqe_key) \
-            if aqe_key is not None else None
-        if saved_caps:
-            self._apply_saved_caps(self.executed_plan, saved_caps)
+        # planned before the span opens, in the phases' own order (each
+        # is timed where it is first asked for: `analysis`, `optimize`,
+        # then `plan`), so that `replan.key` holds none of them
+        self.optimized_plan
+        planned = self.executed_plan
+        with self.spans.span("replan.key"):
+            aqe_key = self._aqe_cache_key(mesh)
+            saved_caps = self.session._aqe_caps.get(aqe_key) \
+                if aqe_key is not None else None
+            if saved_caps:
+                self._apply_saved_caps(planned, saved_caps)
         # static analysis, plan half: after planning (with persisted AQE
         # caps applied — they are part of the stage key the recompile
         # check audits), before any streaming splice or compile. Strict
@@ -1626,9 +1659,10 @@ class QueryExecution:
         # — the analyzer-self-grading loop (history.prediction_report)
         try:
             from ..analysis.predictions import predict_plan
-            self.plan_predictions = predict_plan(
-                self.executed_plan, self._conf,
-                int(mesh.devices.size) if mesh is not None else 1)
+            with self.spans.span("predict"):
+                self.plan_predictions = predict_plan(
+                    self.executed_plan, self._conf,
+                    int(mesh.devices.size) if mesh is not None else 1)
         except Exception as e:  # noqa: BLE001 — predictions are advisory
             import warnings
             warnings.warn(f"plan prediction walk failed (skipped): "
@@ -1680,6 +1714,11 @@ class QueryExecution:
         if root is not root0:
             # chunked ingest + chunk compute happen inside the splice
             self.phase_times["streaming"] = sp.t1 - sp.t0
+        else:
+            # nothing streams: the walk that found so (a scan's
+            # `estimated_rows`, the device-table cache's key and the
+            # residency verdict) stands as a leaf of its own
+            self.spans.record("stream.verdict", sp.t0, sp.t1)
         scans: List[P.LeafExec] = []
         self._collect_scans(root, scans)
 
@@ -1739,13 +1778,7 @@ class QueryExecution:
             fn = self._compile_stage(root, mesh, args)
             # one per dispatch span, capacity re-plans' attempts included
             self.session.metrics.counter("stage_dispatches").inc()
-            # jit compiles lazily: the first dispatch after a stage
-            # -cache miss pays trace + XLA compile in-line, so flag
-            # it — trace readers must not read that as execution
-            with self.spans.span(
-                    "dispatch", attempt=_attempt,
-                    includes_jit_compile=getattr(
-                        self, "_last_compile_was_miss", False)) as disp:
+            with self.spans.span("dispatch", attempt=_attempt) as disp:
                 if mesh is not None:
                     disp.attrs["mesh"] = int(mesh.devices.size)
                 faults.fire("stage_run")  # chaos seam: pre-dispatch
@@ -1888,7 +1921,7 @@ class QueryExecution:
             # visible next to the device metrics and in the event log
             self.last_metrics["mesh_fallback"] = 1
         # fill the data cache on the first action over a marked plan
-        fp = self.session._plan_fingerprint(self.logical)
+        fp = self._fingerprint(self.logical)
         if fp in self.session._cache_requests and \
                 fp not in self.session._data_cache:
             self.session._data_cache[fp] = batch.to_arrow()
@@ -2079,14 +2112,16 @@ class QueryExecution:
         if not self._observe_events:
             return
         cost = self.stage_costs.get(self._last_stage_key or "")
-        self.session.listeners.post(
-            "on_stage_completed", StageCompletedEvent(
-                query_id=self.query_id, ts=time.time(),
-                stage_key=self._last_stage_key or "",
-                key_hash=(cost or {}).get("key_hash", ""),
-                attempt=attempt,
-                elapsed_ms=round((time.perf_counter() - t_att) * 1e3, 2),
-                metrics=metrics, overflow=list(overflow)))
+        with self.spans.span("stage_event"):
+            self.session.listeners.post(
+                "on_stage_completed", StageCompletedEvent(
+                    query_id=self.query_id, ts=time.time(),
+                    stage_key=self._last_stage_key or "",
+                    key_hash=(cost or {}).get("key_hash", ""),
+                    attempt=attempt,
+                    elapsed_ms=round(
+                        (time.perf_counter() - t_att) * 1e3, 2),
+                    metrics=metrics, overflow=list(overflow)))
 
     def _build_event(self, root: Optional[P.PhysicalPlan],
                      status: str = "ok", error=None) -> Dict:
@@ -2177,15 +2212,19 @@ class QueryExecution:
         from ..observability.listener import QueryEndEvent
         if not self._observe_events:
             return
-        try:
-            event = self._build_event(root, status, error)
-        except Exception as e:  # noqa: BLE001 — observability only
-            import warnings
-            warnings.warn(f"event build failed: {e}")
-            return
-        self.session.listeners.post("on_query_end", QueryEndEvent(
-            query_id=self.query_id, ts=event["ts"], status=status,
-            event=event, spans=self.spans))
+        # the event's build, the listener bus, the sinks, the status
+        # store; the event's own copy of the spans is taken inside, so
+        # it holds neither this span nor `egress`
+        with self.spans.span("end_event"):
+            try:
+                event = self._build_event(root, status, error)
+            except Exception as e:  # noqa: BLE001 — observability only
+                import warnings
+                warnings.warn(f"event build failed: {e}")
+                return
+            self.session.listeners.post("on_query_end", QueryEndEvent(
+                query_id=self.query_id, ts=event["ts"], status=status,
+                event=event, spans=self.spans))
 
     def _log_event(self, root: P.PhysicalPlan) -> None:
         """Publish the execution's event record on the listener bus
@@ -2202,6 +2241,7 @@ class QueryExecution:
         # bytes (the inner enter_query calls nest onto this owner).
         from ..service import arbiter as res_arbiter
         from . import lifecycle
+        t_begin = time.perf_counter()
         arb_token = res_arbiter.enter_query(
             f"{self.session.app_id}:q{self.query_id}")
         # lifecycle scope spans the external-collect gate too, so a
@@ -2220,7 +2260,7 @@ class QueryExecution:
                 raise
             if ext is not None:
                 return ext
-            batch, _, _ = self.execute_batch()
+            batch, _, _ = self.execute_batch(t_begin)
             # after the end event: the device-to-host pull and the
             # Arrow build stand in `self.spans` (and the service's
             # timeline), not in the event log's record

@@ -26,6 +26,7 @@ import numpy as np
 
 from .. import types as T
 from ..columnar import Batch, Column, bucket_capacity
+from ..observability.spans import span
 from ..plan import physical as P
 from .chunk_stream import Carry, drive, run_through_joins
 from .recovery import CHECKPOINT_EVERY_KEY
@@ -233,7 +234,20 @@ def stream_range_aggregate(agg: "P.HashAggregateExec", chain: List,
     analog of the reference's Janino codegen cache."""
     chunk_rows = int(conf.get(CHUNK_ROWS_KEY))
     rows_total = leaf.num_rows()
+    # this stream has no host loop (`chunk_stream.drive`): its two
+    # acts stand under `streaming` by the names a driven stream's have
+    with span("stream.begin"):
+        run = _range_program(agg, chain, leaf, conf, cache, chunk_rows,
+                             rows_total)
+    if run is None:
+        return None
+    with span("chunk.launch"):  # an enqueue: the one dispatch returning
+        return run()
 
+
+def _range_program(agg, chain, leaf, conf, cache, chunk_rows, rows_total):
+    """The fused chunk loop over a Range, found in `cache` or built and
+    kept there; None when the direct path does not apply."""
     key = (f"stream_range:{agg.describe()}:{chunk_rows}:{rows_total}"
            + conf_compile_suffix(conf))
     run = cache.get(key) if cache is not None else None
@@ -273,7 +287,7 @@ def stream_range_aggregate(agg: "P.HashAggregateExec", chain: List,
 
         if cache is not None:
             cache[key] = run
-    return run()
+    return run
 
 
 def stream_scan_aggregate(agg: "P.HashAggregateExec", chain: List,

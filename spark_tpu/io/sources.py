@@ -353,12 +353,16 @@ class ChunkIterator:
         self._shape = collections.Counter()
         self._closed = False
 
-    def observe(self, metrics, recorder) -> None:
+    def observe(self, metrics, recorder, cause=None) -> None:
         """Bind the stream to its query's counters and spans (called
-        on the consumer thread, by `maybe_prefetch`)."""
+        on the consumer thread, by `maybe_prefetch`); `cause` is the
+        span the worker's spans name as their parent, by default the
+        one open on the calling thread."""
         self._metrics = metrics
         self._recorder = recorder
-        self._cause = recorder.current() if recorder is not None else None
+        if cause is None and recorder is not None:
+            cause = recorder.current()
+        self._cause = cause
 
     def _host_span(self, name: str, **attrs):
         """A span of the host half (`_host_next`), which the prefetch
@@ -1021,12 +1025,16 @@ class PrefetchChunkIterator:
             raise StopIteration
         if not self._started:
             self._started = True
-            self._thread = threading.Thread(
-                target=self._worker, daemon=True,
-                name="spark-tpu-ingest-prefetch",
-                args=(self._inner._host_next, self._retry,
-                      self._queue, self._stop, self._chunk))
-            self._thread.start()
+            # the worker's thread made and running: once a stream, and
+            # not a wait for a chunk (25 ms where a thread's stack and
+            # arena are dear: my chip runs, PR 39, PERF.md)
+            with span("prefetch.start"):
+                self._thread = threading.Thread(
+                    target=self._worker, daemon=True,
+                    name="spark-tpu-ingest-prefetch",
+                    args=(self._inner._host_next, self._retry,
+                          self._queue, self._stop, self._chunk))
+                self._thread.start()
         # one interval, read twice: the `chunk.wait` span and the
         # `ingest_stall_ms` counter (every wait counts, the last one
         # for the end of the stream too)
@@ -1132,7 +1140,7 @@ def decode_stream_file(path: str, fmt: str) -> pa.Table:
                      f"(parquet, csv, json)")
 
 
-def maybe_prefetch(chunks, conf, recovery=None, retry=None):
+def maybe_prefetch(chunks, conf, recovery=None, retry=None, cause=None):
     """Wrap a chunk stream in the double-buffered prefetcher when
     ``spark_tpu.sql.ingest.prefetch`` is on. The chunk driver
     (execution/chunk_stream.py) routes every `load_chunks` result
@@ -1143,7 +1151,7 @@ def maybe_prefetch(chunks, conf, recovery=None, retry=None):
         return chunks
     from ..observability.spans import current_recorder
     metrics = getattr(recovery, "metrics", None)
-    chunks.observe(metrics, current_recorder())
+    chunks.observe(metrics, current_recorder(), cause)
     if not bool(conf.get(INGEST_PREFETCH_KEY)):
         return chunks
     return PrefetchChunkIterator(chunks, conf, retry=retry,

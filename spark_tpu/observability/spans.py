@@ -1,18 +1,29 @@
 """Per-query spans: a tree over both threads, on two clocks.
 
-Each QueryExecution owns one `SpanRecorder`. Its spans are the
-lifecycle phases (`analysis`, `optimize`, `plan`, `analyze`,
-`analyze_jaxpr`, `compile`, `deserialize`, `streaming`, `external`,
-`ingest`, `dispatch` with `dispatch.launch` / `dispatch.sync`,
-`egress`, the service's `queue`), the chunk pipeline of a streamed
-scan (`chunk.wait`; `chunk.decode`, `chunk.unify` and one
-`chunk.convert` per column on the thread that makes the chunk's host
-half, a large chunk's columns on threads of their own;
-`chunk.to_device` with one `chunk.put` per column; `chunk.launch`,
-`stream.drain`; a resident load leaves `chunk.convert` / `chunk.put`
-per column under `ingest`) and the marks (`aqe_replan`, `aqe_overflow`,
-`retry:<action>`, `cancelled`). Names are fixed and carry no ordinal:
-the benchmark's readers go by them (PERF.md section 3).
+Each QueryExecution owns one `SpanRecorder`; a served query adopts the
+one its request was born with (`SqlService.submit(spans=)`,
+`QueryExecution(spans=)`), whose origin is the request's first
+instant: the connection's accept, not the query's construction. Its
+spans are the front end's, on the handler's thread (`http.accept`,
+`http.read`, the service's `queue`, `parse`; after the query
+`finish`, `encode` and `http.write`), the lifecycle phases
+(`query.begin`, `analysis`, `optimize`, `plan`, `replan.key`,
+`analyze`, `predict`, `streaming` or for a query that streams nothing
+`stream.verdict`, `external`, `ingest`, `analyze_jaxpr`,
+`stage.lookup`, `compile`, `deserialize`, `dispatch` with
+`dispatch.launch` / `dispatch.sync`, `stage_event`,
+`plan.fingerprint`, `end_event`, `egress`), the chunk pipeline of a
+streamed scan (`stream.open`, `prefetch.start`, `chunk.wait`,
+`stream.begin`; `chunk.decode`, `chunk.unify` and one `chunk.convert`
+per column on the thread that makes the chunk's host half, a large
+chunk's columns on threads of their own; `chunk.to_device` with one
+`chunk.put` per column; `chunk.launch`, `stream.drain`; a resident
+load leaves `chunk.convert` / `chunk.put` per column under `ingest`)
+and the marks (`aqe_replan`, `aqe_overflow`, `retry:<action>`,
+`cancelled`). Names are fixed and carry no ordinal: the benchmark's
+readers go by them (PERF.md section 3). No span holds the whole
+request: the frame is the recorder's origin and the timeline's
+`request_ms`.
 
 A span knows the span that caused it (`parent`: the span open on the
 same thread when it started, else the one handed over from the thread
@@ -46,6 +57,15 @@ from jax.profiler import TraceAnnotation
 ANNOTATION_PREFIX = "spark_tpu."
 
 
+def _native_id() -> int:
+    """The calling thread's native id as `threading` noted it when the
+    thread started: `threading.get_native_id()` asks the kernel every
+    time, 5.8 us a call where the benchmark runs against 0.2 us for
+    this (my chip runs, PR 39, PERF.md), and a span asked once, a
+    record twice."""
+    return threading.current_thread().native_id
+
+
 @dataclass
 class Span:
     name: str
@@ -64,11 +84,19 @@ class Span:
 class SpanRecorder:
     """Bounded span list for one QueryExecution. Spans are appended as
     they END; `id` orders them by start. Safe to use from the query's
-    thread and its ingest-prefetch worker at once."""
+    thread and its ingest-prefetch worker at once. `origin` (a
+    `perf_counter` reading, now or earlier) is where `t0_ms` counts
+    from: a served request's recorder is made before its query and
+    starts at the request's first instant."""
 
-    def __init__(self, query_id: int, max_spans: int = 1000,
-                 max_shard_records: int = 4096):
+    def __init__(self, query_id: Optional[int] = None,
+                 max_spans: int = 1000, max_shard_records: int = 4096,
+                 origin: Optional[float] = None):
+        #: the engine's id of the query; None while a request's
+        #: recorder has no query yet (`QueryExecution(spans=)` sets it)
         self.query_id = query_id
+        #: the service's id of the request this recorder was born for
+        self.request_id: Optional[str] = None
         self.max_spans = max_spans
         self.spans: List[Span] = []
         #: spans dropped past the bound (surfaced so truncation is
@@ -80,8 +108,9 @@ class SpanRecorder:
         self.shard_records: List[Dict] = []
         self.max_shard_records = max_shard_records
         self.shard_dropped = 0
-        self._anchor_wall = time.time()
-        self._anchor_perf = time.perf_counter()
+        now = time.perf_counter()
+        self._anchor_perf = now if origin is None else origin
+        self._anchor_wall = time.time() - (now - self._anchor_perf)
         self._lock = threading.Lock()
         self._ids = 0
         #: native thread id -> ids of the spans open on that thread,
@@ -104,7 +133,7 @@ class SpanRecorder:
     def current(self) -> Optional[int]:
         """Id of the innermost span open on the calling thread: what a
         thread hands to the worker it starts, as the worker's cause."""
-        stack = self._open.get(threading.get_native_id())
+        stack = self._open.get(_native_id())
         return stack[-1] if stack else None
 
     def open_spans(self) -> Dict[int, List[int]]:
@@ -123,11 +152,13 @@ class SpanRecorder:
         """An interval handed over after the fact (no annotation in
         the profiler's trace); its parent is the span open on the
         calling thread."""
+        tid = _native_id()
         with self._lock:
             self._ids += 1
+            stack = self._open.get(tid)
             self._add_locked(Span(
                 name, t0, t1 if t1 is not None else t0, attrs, self._ids,
-                self.current(), threading.get_native_id()))
+                stack[-1] if stack else None, tid))
 
     @contextlib.contextmanager
     def span(self, name: str, parent: Optional[int] = None, **attrs):
@@ -137,7 +168,7 @@ class SpanRecorder:
         where the calling thread has no span open. The span is closed
         and recorded however the body leaves (`error` names what it
         raised)."""
-        tid = threading.get_native_id()
+        tid = _native_id()
         with self._lock:
             self._ids += 1
             stack = self._open.setdefault(tid, [])

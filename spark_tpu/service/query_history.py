@@ -38,13 +38,21 @@ class QueryHistoryStore:
             while len(self._entries) > self.max_entries:
                 self._entries.popitem(last=False)
 
-    def amend(self, query_id: str, **fields) -> None:
+    def amend(self, query_id: str, create: bool = False, **fields) -> None:
         """Replace fields of a stored detail (what closed after the
-        engine's end event: the `egress` span); no entry, no effect."""
+        engine's end event: `egress`, `encode`, `http.write`); no
+        entry, no effect, unless `create`: a request that never
+        reached the engine's end event then gets a detail of the
+        fields alone. One critical section either way, so an end event
+        that lands between is never overwritten."""
         with self._lock:
             detail = self._entries.get(query_id)
             if detail is not None:
                 self._entries[query_id] = dict(detail, **fields)
+            elif create:
+                self._entries[query_id] = fields
+                while len(self._entries) > self.max_entries:
+                    self._entries.popitem(last=False)
 
     def get(self, query_id: str) -> Optional[Dict]:
         with self._lock:

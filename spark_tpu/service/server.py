@@ -78,6 +78,7 @@ from ..observability import ListenerBus, MetricsRegistry, QueryListener
 from ..observability.flight_recorder import FlightRecorder
 from ..observability.listener import ServiceEvent
 from ..observability.sinks import json_default
+from ..observability.spans import SpanRecorder
 from ..observability.status_store import StatusStore
 from ..sql.lexer import ParseError
 from ..udf_worker import UdfError
@@ -436,33 +437,70 @@ class SqlService:
                 else "query_deadline_exceeded").inc()
         self._post(status, record["id"], session=session)
 
-    def _collect(self, entry, sql: str, rid: str, t_accept: float,
-                 t_started: float):
-        """Plan and collect `sql` on the leased session. The query's
-        recorder exists from here on, so the request's wait for the
-        session lock and the admission slot goes into it as `queue`;
-        `egress` closes after the engine's end event, so the history
-        store's copy of the spans is taken anew once the rows are
-        out."""
-        qe = entry.session.sql(sql)._qe()
-        qe.spans.record("queue", t_accept, t_started)
-        try:
-            return qe.collect()
-        finally:
-            self.history.amend(rid, spans=qe.spans.to_dicts(),
-                               spans_dropped=qe.spans.dropped)
+    def _note_spans(self, spans: SpanRecorder,
+                    t_written: Optional[float] = None) -> None:
+        """The timeline's copy of the request's spans, taken anew: what
+        closed after the engine's end event (`egress`, and after the
+        answer `encode` and `http.write`) reaches it so. A request that
+        never reached the engine's end event (it failed in `parse`, or
+        left the queue cancelled) has no stored detail yet and gets
+        one that holds the spans. `t_written`, the end of `http.write`,
+        goes on the status record as `request_ms`, last, so that who
+        reads it finds the spans complete."""
+        rid = spans.request_id
+        if rid is None:
+            return
+        self.history.amend(rid, create=True, spans=spans.to_dicts(),
+                           spans_dropped=spans.dropped)
+        record = self.get_query(rid)
+        if t_written is not None and record is not None:
+            record["request_ms"] = spans.rel_ms(t_written)
+
+    def _finish_spans(self, spans: SpanRecorder, t_out: Optional[float],
+                      note: bool) -> None:
+        """A submission's last act. `finish` is what stood between the
+        query's return (`t_out`; None: it never ran) and here: the
+        session and its slot released, the record's bookkeeping, the
+        service's event; handed over, since it runs through the exits
+        of the blocks the query ran in. With `note` the timeline's
+        copy is taken now; the HTTP handler, which has `encode` and
+        `http.write` still to come, takes it after those."""
+        if t_out is not None:
+            spans.record("finish", t_out, time.perf_counter())
+        if note:
+            self._note_spans(spans)
+
+    def _collect(self, entry, sql: str, spans: SpanRecorder,
+                 t_accept: float, t_started: float):
+        """Plan and collect `sql` on the leased session. The request's
+        wait for its quota, the session lock and the admission slot
+        goes into its recorder as `queue`, the text's way to a
+        `QueryExecution` as `parse`; the query adopts the recorder.
+        `egress` closes after the engine's end event, so the caller
+        has the history store's copy of the spans taken anew once the
+        rows are out (`_finish_spans`)."""
+        spans.record("queue", t_accept, t_started)
+        with spans.span("parse"):
+            qe = entry.session.sql(sql)._qe(spans)
+        return qe.collect()
 
     def submit(self, sql: str, session: str = "default",
-               conf: Optional[Dict] = None):
+               conf: Optional[Dict] = None,
+               spans: Optional[SpanRecorder] = None):
         """Run `sql` on the named pooled session under admission
         control. Returns (record, Arrow table). Raises AdmissionError /
         PoolExhausted / the structured lifecycle errors, or whatever
         the engine raised; the record reflects the outcome either
-        way."""
-        self._check_draining()
+        way. `spans` is the recorder the HTTP handler made at the
+        request's first instant; an embedded call's is made here."""
         t_accept = time.perf_counter()
+        self._check_draining()
+        embedded = spans is None
+        if embedded:
+            spans = SpanRecorder(origin=t_accept)
+        t_out = None
         record = self._new_record(sql, session, conf)
-        rid = record["id"]
+        rid = spans.request_id = record["id"]
         self._ensure_arbiter()
         self.metrics.counter("service_queries_submitted").inc()
         self._post("submitted", rid, session=session)
@@ -496,8 +534,9 @@ class SqlService:
                         try:
                             with entry.session.as_active():
                                 table = self._collect(
-                                    entry, sql, rid, t_accept, t_started)
+                                    entry, sql, spans, t_accept, t_started)
                         finally:
+                            t_out = time.perf_counter()
                             entry.current_record = None
                 finally:
                     entry.lock.release()
@@ -563,10 +602,12 @@ class SqlService:
         finally:
             lifecycle.uninstall(ctx_token)
             self._drop_token(rid)
+            self._finish_spans(spans, t_out, note=embedded)
         return record, table
 
     def submit_async(self, sql: str, session: str = "default",
-                     conf: Optional[Dict] = None) -> Dict:
+                     conf: Optional[Dict] = None,
+                     spans: Optional[SpanRecorder] = None) -> Dict:
         """Fire-and-poll submission: returns the record immediately;
         progress lands on it (GET /queries/<id>). The worker thread
         holds no result — async is for effects/status, sync for data.
@@ -577,9 +618,14 @@ class SqlService:
         The cancel token is created WITH the record, before the worker
         spawns: a DELETE arriving while the request is still queued
         cancels it out of the admission queue without it ever
-        executing."""
+        executing. `spans` as in `submit`: the same origin, the same
+        names."""
+        t_accept = time.perf_counter()
         self._check_draining()
+        if spans is None:
+            spans = SpanRecorder(origin=t_accept)
         record = self._new_record(sql, session, conf)
+        spans.request_id = record["id"]
         try:
             self.session_quota.acquire(session)
         except AdmissionError as err:
@@ -620,9 +666,9 @@ class SqlService:
             raise err
 
         tok = self._get_token(record["id"])
-        t_accept = time.perf_counter()
 
         def run():
+            t_out = None
             # re-drive through submit's machinery minus re-registration
             # (same ordering as submit: session lease, then slot). The
             # token installs on THIS worker thread: a cancel delivered
@@ -642,9 +688,12 @@ class SqlService:
                         record["started_ts"] = time.time()
                         t_started = time.perf_counter()
                         try:
-                            with entry.session.as_active():
-                                t = self._collect(entry, sql, record["id"],
-                                                  t_accept, t_started)
+                            try:
+                                with entry.session.as_active():
+                                    t = self._collect(entry, sql, spans,
+                                                      t_accept, t_started)
+                            finally:
+                                t_out = time.perf_counter()
                             record["row_count"] = int(t.num_rows)
                             record["status"] = "ok"
                             self.metrics.counter(
@@ -688,6 +737,9 @@ class SqlService:
                 self.session_quota.release(session)
                 with self._async_lock:
                     self._async_inflight -= 1
+                # the 202 was written long ago: the worker's copy of
+                # the spans is the timeline's last
+                self._finish_spans(spans, t_out, note=True)
             record["finished_ts"] = time.time()
 
         try:
@@ -770,6 +822,9 @@ class SqlService:
                 "engine_query_id": (rec.get("engine_query_id")
                                     or detail.get("engine_query_id")),
                 "elapsed_ms": rec.get("elapsed_ms"),
+                # the spans' origin (the request's first instant) to
+                # the end of `http.write`; None for an embedded call
+                "request_ms": rec.get("request_ms"),
                 "phase_times_s": detail.get("phase_times_s")
                 or rec.get("phase_times_s"),
                 "spans": detail.get("spans") or [],
@@ -899,10 +954,9 @@ class SqlService:
         _open_thread_arenas_whole()
         self.status_store.start()
         handler = _make_handler(self)
-        self._httpd = ThreadingHTTPServer(
+        self._httpd = _StampingHTTPServer(
             (str(self.conf.get(HOST_KEY)), int(self.conf.get(PORT_KEY))),
             handler)
-        self._httpd.daemon_threads = True
         self._serve_thread = threading.Thread(
             target=self._httpd.serve_forever, daemon=True,
             name="sql-service-http")
@@ -1052,12 +1106,77 @@ def _table_rows(table) -> list:
     return rows
 
 
+class _StampingHTTPServer(ThreadingHTTPServer):
+    """Keeps the instant of each connection's accept (on the serving
+    thread, before the handler's thread exists) until the connection
+    is shut down: a request's recorder counts from it."""
+
+    daemon_threads = True
+
+    def __init__(self, *args, **kwargs):
+        super().__init__(*args, **kwargs)
+        self.accepted: Dict[int, float] = {}
+
+    def get_request(self):
+        request, client_address = super().get_request()
+        self.accepted[id(request)] = time.perf_counter()
+        return request, client_address
+
+    def shutdown_request(self, request):
+        self.accepted.pop(id(request), None)
+        super().shutdown_request(request)
+
+
 def _make_handler(service: SqlService):
     class Handler(BaseHTTPRequestHandler):
         protocol_version = "HTTP/1.1"
 
         def log_message(self, fmt, *args):  # quiet: metrics cover it
             pass
+
+        def setup(self):
+            super().setup()
+            self._t_next = self.server.accepted.get(id(self.request))
+
+        def parse_request(self):
+            # where this request began: at its connection's accept, or,
+            # for a kept-alive connection's later requests, where the
+            # request line has arrived
+            self._t_request = self._t_next or time.perf_counter()
+            self._t_next = None
+            return super().parse_request()
+
+        def _respond(self, spans: SpanRecorder, status: int, encode,
+                     content_type: str = "application/json",
+                     headers=(), **attrs) -> None:
+            """The answer of a `POST /sql` that reached submission:
+            `encode()` makes the body (the span `encode`, with `attrs`
+            and the body's `bytes`), `http.write` is the status line,
+            the headers, the body and the flush. Both close after the
+            history store last copied the spans, so it copies them
+            once more, with `request_ms`."""
+            written = None
+            try:
+                with spans.span("encode", **attrs) as sp:
+                    body = encode()
+                    sp.attrs["bytes"] = len(body)
+                with spans.span("http.write") as written:
+                    self.send_response(status)
+                    self.send_header("Content-Type", content_type)
+                    self.send_header("Content-Length", str(len(body)))
+                    for k, v in headers:
+                        self.send_header(k, v)
+                    self.end_headers()
+                    self.wfile.write(body)
+                    self.wfile.flush()
+            finally:
+                service._note_spans(
+                    spans, written.t1 if written is not None else None)
+
+        def _respond_json(self, spans: SpanRecorder, status: int,
+                          payload: Dict) -> None:
+            self._respond(spans, status, lambda: json.dumps(
+                payload, default=json_default).encode())
 
         def _send_json(self, status: int, payload: Dict) -> None:
             body = json.dumps(payload, default=json_default).encode()
@@ -1180,9 +1299,18 @@ def _make_handler(service: SqlService):
                 self._send_json(404, {"error": "NOT_FOUND",
                                       "message": path})
                 return
+            spans = SpanRecorder(origin=self._t_request)
+            # what stood between the accept and this line: the
+            # handler's thread started, the request line and the
+            # headers read and parsed (handed over: it began on the
+            # serving thread, before this one existed)
+            spans.record("http.accept", self._t_request,
+                         time.perf_counter())
             try:
-                n = int(self.headers.get("Content-Length") or 0)
-                req = json.loads(self.rfile.read(n) or b"{}")
+                with spans.span("http.read") as sp:
+                    n = int(self.headers.get("Content-Length") or 0)
+                    sp.attrs["bytes"] = n
+                    req = json.loads(self.rfile.read(n) or b"{}")
                 sql = req.get("sql")
                 if not sql or not isinstance(sql, str):
                     self._send_json(400, {
@@ -1198,23 +1326,23 @@ def _make_handler(service: SqlService):
             conf = req.get("conf") or None
             if req.get("mode") == "async":
                 try:
-                    record = service.submit_async(sql, session, conf)
+                    record = service.submit_async(sql, session, conf, spans)
                 except AdmissionError as e:
-                    self._send_json(e.http_status, e.to_dict())
+                    self._respond_json(spans, e.http_status, e.to_dict())
                     return
-                self._send_json(202, {"query_id": record["id"],
-                                      "status": record["status"]})
+                self._respond_json(spans, 202, {
+                    "query_id": record["id"], "status": record["status"]})
                 return
             try:
-                record, table = service.submit(sql, session, conf)
+                record, table = service.submit(sql, session, conf, spans)
             except AdmissionError as e:
-                self._send_json(e.http_status, e.to_dict())
+                self._respond_json(spans, e.http_status, e.to_dict())
                 return
             except PoolExhausted as e:
-                self._send_json(429, e.to_dict())
+                self._respond_json(spans, 429, e.to_dict())
                 return
             except (ParseError, AnalysisError) as e:
-                self._send_json(400, {
+                self._respond_json(spans, 400, {
                     "error": "INVALID_SQL",
                     "message": f"{type(e).__name__}: {e}"[:400]})
                 return
@@ -1222,12 +1350,12 @@ def _make_handler(service: SqlService):
                 # the sync request's query was DELETEd mid-flight:
                 # structured body, 409 (the request conflicts with an
                 # explicit cancel of its own resource)
-                self._send_json(409, {
+                self._respond_json(spans, 409, {
                     "error": "QUERY_CANCELLED",
                     "message": f"{type(e).__name__}: {e}"[:400]})
                 return
             except lifecycle.QueryDeadlineError as e:
-                self._send_json(504, {
+                self._respond_json(spans, 504, {
                     "error": "QUERY_DEADLINE_EXCEEDED",
                     "message": f"{type(e).__name__}: {e}"[:400]})
                 return
@@ -1235,36 +1363,40 @@ def _make_handler(service: SqlService):
                 # user code raised inside a UDF worker: the query is at
                 # fault, not the engine — 400-class, with the worker-
                 # captured USER traceback in the structured body
-                self._send_json(400, {
+                self._respond_json(spans, 400, {
                     "error": "UDF_ERROR",
                     "message": f"{type(e).__name__}: {e}"[:400],
                     "traceback": e.worker_traceback})
                 return
             except Exception as e:  # noqa: BLE001 — structured surface
-                self._send_json(500, {
+                self._respond_json(spans, 500, {
                     "error": "EXECUTION_ERROR",
                     "message": f"{type(e).__name__}: {e}"[:400]})
                 return
             if req.get("format") == "arrow":
-                import io
-                import pyarrow as pa
-                buf = io.BytesIO()
-                with pa.ipc.new_stream(buf, table.schema) as w:
-                    w.write_table(table)
-                body = buf.getvalue()
-                self.send_response(200)
-                self.send_header("Content-Type",
-                                 "application/vnd.apache.arrow.stream")
-                self.send_header("Content-Length", str(len(body)))
-                self.send_header("X-Query-Id", record["id"])
-                self.end_headers()
-                self.wfile.write(body)
+                def arrow_stream() -> bytes:
+                    import io
+                    import pyarrow as pa
+                    buf = io.BytesIO()
+                    with pa.ipc.new_stream(buf, table.schema) as w:
+                        w.write_table(table)
+                    return buf.getvalue()
+
+                self._respond(spans, 200, arrow_stream,
+                              "application/vnd.apache.arrow.stream",
+                              headers=(("X-Query-Id", record["id"]),),
+                              rows=table.num_rows)
                 return
-            self._send_json(200, {
-                "query_id": record["id"], "status": record["status"],
-                "columns": table.column_names,
-                "rows": _table_rows(table),
-                "row_count": record.get("row_count"),
-                "elapsed_ms": record.get("elapsed_ms")})
+
+            def json_rows() -> bytes:
+                return json.dumps({
+                    "query_id": record["id"], "status": record["status"],
+                    "columns": table.column_names,
+                    "rows": _table_rows(table),
+                    "row_count": record.get("row_count"),
+                    "elapsed_ms": record.get("elapsed_ms")},
+                    default=json_default).encode()
+
+            self._respond(spans, 200, json_rows, rows=table.num_rows)
 
     return Handler

@@ -802,7 +802,24 @@ def test_stream_span_tree(session, stream_table, prefetch):
         assert len(named(name)) == per_chunk * n_chunks, name
         assert all(s.parent == streaming.id for s in named(name))
     assert len(named("stream.drain")) == 1
+    # once a streamed scan: what `drive` does before it takes the first
+    # chunk, and the chunk program found or built on it; leaves beside
+    # the chunks' spans, the worker's still caused by `streaming`
+    for name in ("stream.open", "stream.begin"):
+        (s,) = named(name)
+        assert s.parent == streaming.id and s.tid == consumer
+        assert not any(c.parent == s.id for c in spans), name
+    assert named("stream.open")[0].t1 <= min(
+        s.t0 for s in named("chunk.to_device"))
+    assert named("stream.open")[0].id < named("stream.begin")[0].id \
+        < named("chunk.launch")[0].id
     assert len(named("chunk.wait")) == (n_chunks + 1 if prefetch else 0)
+    # the worker's thread made and started, before the first wait
+    assert len(named("prefetch.start")) == (1 if prefetch else 0)
+    for s in named("prefetch.start"):
+        assert s.parent == streaming.id and s.tid == consumer
+        assert named("stream.open")[0].t1 <= s.t0 \
+            and s.t1 <= min(w.t0 for w in named("chunk.wait"))
     for td in named("chunk.to_device"):
         kids = [s.name for s in spans if s.parent == td.id]
         assert kids == ["chunk.put"] * 3
@@ -811,8 +828,8 @@ def test_stream_span_tree(session, stream_table, prefetch):
     assert {s.name for s in spans if s.name.startswith(
         ("chunk.", "stream."))} == {
         "chunk.decode", "chunk.unify", "chunk.to_device", "chunk.convert",
-        "chunk.put", "chunk.launch", "stream.drain"} | (
-        {"chunk.wait"} if prefetch else set())
+        "chunk.put", "chunk.launch", "stream.drain", "stream.open",
+        "stream.begin"} | ({"chunk.wait"} if prefetch else set())
     assert [s.attrs["chunk"] for s in sorted(
         named("chunk.launch"), key=lambda s: s.id)] == list(range(n_chunks))
 
@@ -980,6 +997,13 @@ def test_engine_spans_stand_in_profiler_trace(session, stream_table,
             "spark_tpu.chunk.put", "spark_tpu.egress"} <= set(names), names
     assert names["spark_tpu.chunk.decode"].isdisjoint(
         names["spark_tpu.streaming"])
+    # the names PR 39 gave the rest of a query, on the query's line
+    new = {"spark_tpu." + n for n in (
+        "replan.key", "predict", "stream.open", "prefetch.start",
+        "stream.begin", "stage.lookup", "plan.fingerprint")}
+    assert new <= set(names), sorted(new - set(names))
+    for name in new:
+        assert names[name] == names["spark_tpu.streaming"], name
 
 
 def test_profile_dir_holds_the_streamed_chunks(session, stream_table,
@@ -1014,6 +1038,23 @@ def test_resident_scan_ingest_names_its_columns(session, stream_table):
     assert not any(s.name == "streaming" for s in qe.spans.spans)
     assert _ingest_counters(session)["ingest_chunks"] \
         == before["ingest_chunks"]
+
+
+def test_range_stream_names_its_two_acts(session):
+    """A streamed Range has no host loop: the fused chunk loop found
+    or built, then its one launch, stand under `streaming` by the
+    names a driven stream's acts have."""
+    session.conf.set(CHUNK_KEY, 1024)
+    qe = (session.range(10_000).group_by((col("id") % 7).alias("k"))
+          .agg(F.sum(col("id")).alias("s")))._qe()
+    out = qe.collect().to_pandas()
+    assert int(out["s"].sum()) == 10_000 * 9_999 // 2
+    (streaming,) = [s for s in qe.spans.spans if s.name == "streaming"]
+    kids = sorted((s for s in qe.spans.spans if s.parent == streaming.id),
+                  key=lambda s: s.id)
+    assert [s.name for s in kids] == ["stream.begin", "chunk.launch"]
+    assert not any(s.name == "stream.verdict" for s in qe.spans.spans)
+    assert qe.spans.open_spans() == {}
 
 
 def test_span_recorder_parent_thread_and_discard():

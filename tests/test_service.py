@@ -490,10 +490,11 @@ def test_http_query_listing_timeline_and_plan(service, tpch_path):
 
 
 def test_timeline_holds_queue_and_egress(service):
-    """The request's wait for its session and slot (`queue`, before
-    the recorder's own start) and the pull of the rows (`egress`,
-    after the engine's end event) both reach the timeline, with the
-    tree's ids; a query that fails is amended the same way."""
+    """The request's wait for its session and slot (`queue`, at or
+    after the recorder's origin, which is the request's first instant
+    and no longer the query's construction) and the pull of the rows
+    (`egress`, after the engine's end event) both reach the timeline,
+    with the tree's ids; a query that fails is amended the same way."""
     svc = service()
     svc.start()
     _, resp = _post_sql(svc.port, {"sql": SQLQ.Q1})
@@ -502,7 +503,9 @@ def test_timeline_holds_queue_and_egress(service):
     assert {"queue", "dispatch", "dispatch.launch", "dispatch.sync",
             "egress"} <= set(by_name), sorted(by_name)
     assert tl["spans_dropped"] == 0
-    assert by_name["queue"]["t0_ms"] < 0 <= by_name["queue"]["dur_ms"]
+    assert by_name["queue"]["t0_ms"] >= 0 <= by_name["queue"]["dur_ms"]
+    assert by_name["queue"]["t0_ms"] + by_name["queue"]["dur_ms"] \
+        <= by_name["parse"]["t0_ms"]
     assert by_name["egress"]["t0_ms"] >= by_name["dispatch"]["t0_ms"] \
         + by_name["dispatch"]["dur_ms"]
     assert by_name["dispatch.sync"]["parent"] == by_name["dispatch"]["id"]
@@ -510,6 +513,178 @@ def test_timeline_holds_queue_and_egress(service):
     # a store that no longer holds the query is left alone
     svc.history.amend("q-unknown", spans=[])
     assert svc.history.get("q-unknown") is None
+
+
+def _written_timeline(port, rid, timeout_s=20.0):
+    """The timeline once the handler has copied the spans for the last
+    time, after `http.write`: the client holds its answer a moment
+    before that."""
+    deadline = time.monotonic() + timeout_s
+    while True:
+        _, tl = _get_json(port, f"/queries/{rid}/timeline")
+        if tl.get("request_ms") is not None \
+                and tl["status"] not in ("submitted", "running"):
+            return tl
+        assert time.monotonic() < deadline, tl
+        time.sleep(0.02)
+
+
+def _post_any(port, payload):
+    """(status, headers, body bytes) of a `POST /sql`, error or not."""
+    req = urllib.request.Request(
+        f"http://127.0.0.1:{port}/sql", data=json.dumps(payload).encode(),
+        headers={"Content-Type": "application/json"})
+    try:
+        with urllib.request.urlopen(req, timeout=120) as resp:
+            return resp.status, resp.headers, resp.read()
+    except urllib.error.HTTPError as e:
+        return e.code, e.headers, e.read()
+
+
+#: the front end's spans, on the handler's thread, once a request
+FRONT_SPANS = ["http.accept", "http.read", "queue", "parse"]
+ANSWER_SPANS = ["encode", "http.write"]
+#: once a query that reached the engine (`stage.lookup` once a
+#: dispatch attempt, which is once here)
+ENGINE_SPANS = ["query.begin", "replan.key", "predict", "stream.verdict",
+                "stage.lookup", "dispatch", "plan.fingerprint",
+                "stage_event", "end_event", "finish"]
+SLOW_STREAM = {CHUNK_KEY: 512, "spark_tpu.sql.memory.deviceBudget": 1,
+               "spark_tpu.faults.inject": "stream_chunk:slow:2:20000"}
+GROUPED = ("select l_returnflag, sum(l_quantity) as s from lineitem "
+           "group by l_returnflag")
+
+
+@pytest.mark.parametrize(
+    "case", ["json", "arrow", "async", "parse_error", "cancelled"])
+def test_request_spans_frame_the_request(service, monkeypatch, case):
+    """From the accept to the last byte written a request stands under
+    leaf spans of one recorder, born with the request: every `t0_ms`
+    at or after 0, each of the front end's names once, `http.write`
+    ending last and `request_ms` with it, no span left open; also for
+    a request that fails in `parse` and for one that is cancelled.
+    Counts and structure only, no duration."""
+    from spark_tpu.service import server as server_mod
+    made = []
+
+    class Tracked(server_mod.SpanRecorder):
+        def __init__(self, *args, **kwargs):
+            super().__init__(*args, **kwargs)
+            made.append(self)
+
+    monkeypatch.setattr(server_mod, "SpanRecorder", Tracked)
+    svc = service()
+    svc.start()
+    port = svc.port
+    if case == "json":
+        status, _, body = _post_any(port, {"sql": SQLQ.Q1})
+        rid = json.loads(body)["query_id"]
+    elif case == "arrow":
+        status, headers, body = _post_any(
+            port, {"sql": SQLQ.Q1, "format": "arrow"})
+        rid = headers["X-Query-Id"]
+    elif case == "async":
+        status, _, body = _post_any(port, {"sql": SQLQ.Q1, "mode": "async"})
+        rid = json.loads(body)["query_id"]
+    elif case == "parse_error":
+        status, _, body = _post_any(port, {"sql": "select from where"})
+        (rid,) = [q["id"] for q in svc.query_listing()["queries"]]
+    else:
+        answer = []
+        t = threading.Thread(target=lambda: answer.append(_post_any(
+            port, {"sql": GROUPED, "conf": SLOW_STREAM})), daemon=True)
+        t.start()
+        deadline = time.monotonic() + 60
+        while not svc.query_listing(status="running")["queries"]:
+            assert time.monotonic() < deadline
+            time.sleep(0.01)
+        (rid,) = [q["id"] for q in svc.query_listing()["queries"]]
+        time.sleep(0.3)  # into the chunk loop's slow sleep
+        svc.cancel_query(rid)
+        t.join(30)
+        ((status, _, body),) = answer
+    assert status == {"json": 200, "arrow": 200, "async": 202,
+                      "parse_error": 400, "cancelled": 409}[case], body
+    tl = _written_timeline(port, rid)
+    spans = tl["spans"]
+    assert tl["spans_dropped"] == 0
+    assert all(s["t0_ms"] >= 0 and s["dur_ms"] >= 0 for s in spans), spans
+    count = {}
+    for s in spans:
+        count[s["name"]] = count.get(s["name"], 0) + 1
+    by_name = {s["name"]: s for s in spans}
+    for name in FRONT_SPANS + ANSWER_SPANS:
+        assert count.get(name) == 1, (name, count)
+    # in order, on the handler's thread, each a leaf
+    starts = [by_name[n]["t0_ms"] for n in FRONT_SPANS]
+    assert starts == sorted(starts)
+    assert by_name["http.accept"]["t0_ms"] == 0
+    assert by_name["http.read"]["attrs"]["bytes"] > 0
+    assert by_name["encode"]["attrs"]["bytes"] == len(body)
+    front_tid = by_name["http.read"]["tid"]
+    for name in FRONT_SPANS[:2] + ANSWER_SPANS:
+        assert by_name[name]["tid"] == front_tid, name
+        assert by_name[name]["parent"] is None
+        assert not any(s["parent"] == by_name[name]["id"] for s in spans)
+    write_end = by_name["http.write"]["t0_ms"] + by_name["http.write"]["dur_ms"]
+    assert by_name["encode"]["t0_ms"] + by_name["encode"]["dur_ms"] \
+        <= by_name["http.write"]["t0_ms"]
+    assert write_end == pytest.approx(tl["request_ms"], abs=2e-3)
+    if case != "async":  # the 202 is written while the query runs
+        assert write_end == max(s["t0_ms"] + s["dur_ms"] for s in spans)
+        assert len({s["tid"] for s in spans}) == (
+            1 if case != "cancelled" else 2)  # its prefetch worker
+    # no root: no span holds the others
+    assert not any(s["t0_ms"] <= by_name["http.read"]["t0_ms"]
+                   and s["t0_ms"] + s["dur_ms"] >= write_end for s in spans)
+    if case == "parse_error":
+        assert sorted(count) == sorted(
+            FRONT_SPANS + ["finish"] + ANSWER_SPANS)
+        assert by_name["parse"]["attrs"]["error"] == "ParseError"
+    elif case == "cancelled":
+        assert count["cancelled"] == count["end_event"] == 1
+        assert count["stream.open"] == count["prefetch.start"] == 1
+        assert count["query.begin"] == count["finish"] == 1
+        assert tl["status"] == "cancelled"
+    else:
+        for name in ENGINE_SPANS:
+            assert count.get(name) == 1, (name, count)
+        # an answer's rows; the 202 of an async submission has none
+        assert by_name["encode"]["attrs"].get("rows") == (
+            None if case == "async" else 4)
+        assert tl["status"] == "ok"
+    # one recorder a request, the query's own, and nothing left open
+    (rec,) = made
+    assert rec.request_id == rid
+    assert rec.open_spans() == {}
+    assert rec.query_id == (None if case == "parse_error"
+                            else tl["engine_query_id"])
+
+
+def test_sync_and_async_submission_give_the_same_names(service):
+    """Embedded, with no HTTP around them: `submit` and `submit_async`
+    make the request's recorder at their entry and leave the same
+    names, `queue` at or after the origin."""
+    svc = service()
+    svc.submit(SQLQ.Q6)  # loads the scan: the two below find it held
+    record, _ = svc.submit(SQLQ.Q6)
+    queued = svc.submit_async(SQLQ.Q6)
+    deadline = time.monotonic() + 60
+    while svc.query_snapshot(queued["id"])["status"] in (
+            "submitted", "running"):
+        assert time.monotonic() < deadline
+        time.sleep(0.02)
+    names = []
+    for rid in (record["id"], queued["id"]):
+        tl = svc.query_timeline(rid)
+        assert tl["status"] == "ok" and tl["request_ms"] is None
+        assert all(s["t0_ms"] >= 0 for s in tl["spans"])
+        names.append(sorted(s["name"] for s in tl["spans"]))
+    assert names[0] == names[1]
+    assert {"queue", "parse", "query.begin", "stage.lookup", "end_event",
+            "egress", "finish"} <= set(names[0])
+    assert not {"http.accept", "http.read", "encode",
+                "http.write"} & set(names[0])
 
 
 def test_history_store_bounded(service):
