@@ -507,9 +507,14 @@ RUNTIME_FILTER_SEMI_AWARE = register(
 
 RUNTIME_FILTER_FPP = register(
     "spark_tpu.sql.runtimeFilter.expectedFpp", 0.03,
-    doc="Expected false-positive probability for runtime-filter Bloom "
-        "sketches (sizing follows BloomFilter.optimalNumOfBits). False "
-        "positives only reduce pruning, never correctness.",
+    doc="Upper bound on the false-positive probability of runtime-"
+        "filter Bloom sketches. The hash count k and the classic bit "
+        "count m follow BloomFilter.optimalNumOfBits; the filter is "
+        "register-blocked (a key's k bits in one 32-bit word, one "
+        "gather a probed key) and takes the power of two of words at "
+        "or above 4 m / 32, so at the design load the measured rate is "
+        "some twentieth of this. False positives only reduce pruning, "
+        "never correctness.",
     validator=lambda v: 0.0 < v < 1.0)
 
 CBO_JOIN_REORDER = register(

@@ -267,17 +267,24 @@ def build_runtime_filter(build_batch: Batch, key_expr, ctx,
                          ) -> RuntimeFilter:
     """Build a RuntimeFilter from the build-side key column. NULL keys
     are excluded (they never equi-match). Inside shard_map the per-shard
-    Bloom bits pmax-combine (bitwise OR over the one-bit-per-byte
-    layout) and the bounds pmin/pmax, so the filter covers every
-    shard's build rows while staying replicated."""
+    Bloom bits pmax-combine (bitwise OR over the build's one-bit-a-byte
+    staging array, packed to words after it) and the bounds pmin/pmax,
+    so the filter covers every shard's build rows while staying
+    replicated."""
     from ..sketch import BloomFilter
     vec = key_expr.eval(build_batch)
     hashed, validity, ordered = _runtime_filter_key(vec)
     mask = build_batch.selection_mask()
     if validity is not None:
         mask = mask & validity
-    bloom = BloomFilter.build(hashed, expected_items=expected_items,
-                              fpp=fpp, mask=mask)
+    sharded = ctx.axis_name is not None and ctx.n_shards > 1
+    # the engine's pmax/pmin, not lax's: see parallel/mesh.py for what
+    # XLA:TPU does to narrow and to 64-bit operands
+    from ..parallel.mesh import pmax, pmin
+    bloom = BloomFilter.build(
+        hashed, expected_items=expected_items, fpp=fpp, mask=mask,
+        combine=(lambda staged: pmax(staged, ctx.axis_name))
+        if sharded else None)
     lo = hi = None
     if ordered:
         raw = vec.data
@@ -298,15 +305,9 @@ def build_runtime_filter(build_batch: Batch, key_expr, ctx,
             neg = jnp.asarray(info.min, raw.dtype)
         lo = jnp.min(jnp.where(bmask, raw, pos))
         hi = jnp.max(jnp.where(bmask, raw, neg))
-    if ctx.axis_name is not None and ctx.n_shards > 1:
-        # the engine's pmax/pmin, not lax's: see parallel/mesh.py for
-        # what XLA:TPU does to narrow and to 64-bit operands
-        from ..parallel.mesh import pmax, pmin
-        bloom = BloomFilter(pmax(bloom.bits, ctx.axis_name),
-                            bloom.num_hashes)
-        if lo is not None:
-            lo = pmin(lo, ctx.axis_name)
-            hi = pmax(hi, ctx.axis_name)
+    if sharded and lo is not None:
+        lo = pmin(lo, ctx.axis_name)
+        hi = pmax(hi, ctx.axis_name)
     return RuntimeFilter(bloom, lo, hi)
 
 

@@ -156,6 +156,60 @@ def test_the_exchanges_collectives_keep_names_their_reader_knows(topo, sent):
     assert not is_collective("fusion.71") and not is_collective("copy.1")
 
 
+def test_a_mesh_built_filter_is_one_widened_all_reduce_and_one_gather(topo):
+    """A runtime filter built and probed under `shard_map`, at the sizes
+    of Q3's `rf1` (32,768 expected keys, ORDERS' 1 Mi slots probed),
+    compiled for a four-chip v5e mesh: the shards' staging bytes meet
+    in ONE all-reduce over 32 widened bytes a word that bears a name
+    `exchange_ms` counts, the words are packed after it, and a probed
+    key costs one gather of a 32-bit word."""
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from benchmark.layer_metrics.exchange_ms import is_collective
+    from spark_tpu import Conf
+    from spark_tpu import types as T
+    from spark_tpu.columnar import Batch, Column
+    from spark_tpu.execution.join import (apply_runtime_filter,
+                                          build_runtime_filter)
+    from spark_tpu.expr import ColumnRef
+    from spark_tpu.parallel.mesh import AXIS, shard_map
+    from spark_tpu.plan.physical import ExecContext
+    from spark_tpu.sketch import BloomFilter
+    n, est = 4, 32768
+    nw, _ = BloomFilter.sizing(est)
+
+    def stage(keys, live, probed):
+        filt = build_runtime_filter(
+            Batch({"k": Column(keys, T.LongType())}, selection=live),
+            ColumnRef("k"), ExecContext(Conf(), AXIS, n),
+            expected_items=est)
+        return apply_runtime_filter(
+            filt, Batch({"k": Column(probed, T.LongType())}),
+            ColumnRef("k"))
+
+    mesh = Mesh(np.array(topo.devices[:n]), (AXIS,))
+
+    def spec(rows, dtype):
+        return jax.ShapeDtypeStruct(
+            (rows,), dtype,
+            sharding=NamedSharding(mesh, PartitionSpec(AXIS)))
+
+    text = jax.jit(shard_map(
+        stage, mesh=mesh, in_specs=PartitionSpec(AXIS),
+        out_specs=PartitionSpec(AXIS), check_vma=False)).lower(
+            spec(1 << 18, jnp.int64), spec(1 << 18, jnp.bool_),
+            spec(1 << 20, jnp.int64)).compile().as_text()
+    staged = re.findall(
+        r"%([\w.-]+) = s32\[" + str(32 * nw) + r"\]\S* all-reduce\(", text)
+    assert len(staged) == 1 and is_collective(staged[0]), staged
+    assert not re.search(r"= u8\[\d+\]\S* all-reduce\(", text)
+    assert len(re.findall(r" gather\(", text)) == 1
+    assert re.search(r"= u32\[262144\]\S* gather\(", text)
+
+
 def _sorts_of(compiled_text):
     """The names of the instructions whose opcode is `sort`."""
     import re

@@ -37,11 +37,12 @@ JOIN_COUNTERS = ("spark_tpu_join_output_rows", "spark_tpu_rtf_tested",
 MS_KEYS = ("rtf_build_ms_", "join_build_ms_", "join_probe_ms_")
 SLOTS = "spark_tpu_rtf_slots"
 #: Q3's stage with no learned capacity on a filter, lowered at this
-#: file's scale and seed: the digest the parent of PR 38 (a9dae55)
-#: gives in the sandbox, and so the program that a machine's compile
-#: cache already holds
+#: file's scale and seed: the digest PR 40's tree gives in the sandbox
+#: (the Bloom filters' build and probe changed the text; from PR 37 to
+#: PR 39 it read a04f09dd...), held so that a change which moves the
+#: text, and so misses every machine's compile cache, is seen
 MASKED_SHA256 = \
-    "a04f09ddc125cab59b45b9a7b4daa445129177b75e59f61e43db3ce35fcea998"
+    "cc7bf213488c45f837f29c17306bd6a3fac24687a88b95fcf77b20190a0d447e"
 
 
 class JoinServed(Served):
@@ -299,7 +300,7 @@ def test_the_stage_text_is_the_same_in_two_processes(directories):
                 if ln.startswith("SHA256")][-1]
         seen.append(line.split()[1:])
     assert seen[0] == seen[1], seen
-    # no capacity learned: the program the parent of PR 38 ran
+    # no capacity learned: the masked program
     assert seen[0][0] == MASKED_SHA256
     assert int(seen[0][1]) > 10_000  # a whole stage, not a stub
 

@@ -453,3 +453,54 @@ def test_mesh_pmax_pmin_take_the_route_the_tpu_gets_right(dtype, via):
     else:
         assert via in text, text
         assert ("pmax" in text) == (via != "all_gather"), text
+
+
+def test_a_filter_built_under_a_mesh_ors_staging_bytes_then_packs():
+    """`build_runtime_filter` inside `shard_map`: each shard scatters
+    its keys' bits into the one-bit-a-byte staging array, the engine's
+    `pmax` ORs the shards' BYTES (widened to int32: a uint8 `lax.pmax`
+    lost bits on the chip, PR 22; a max over packed words would be no
+    OR at all) and the pack to 32-bit words comes after it. Every
+    shard then holds the filter one device builds from all the keys."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, PartitionSpec
+
+    from spark_tpu import Conf
+    from spark_tpu.columnar import Batch, Column
+    from spark_tpu.execution.join import build_runtime_filter
+    from spark_tpu.expr import ColumnRef
+    from spark_tpu.parallel.mesh import AXIS, shard_map
+    from spark_tpu.plan.physical import ExecContext
+    from spark_tpu.sketch import BloomFilter
+    from spark_tpu import types as T
+    n, rows = 4, 4096
+    mesh = Mesh(np.array(jax.devices()[:n]), (AXIS,))
+    rs = np.random.RandomState(5)
+    keys = jnp.asarray(rs.randint(0, 10**9, n * rows).astype(np.int64))
+    live = jnp.asarray(rs.rand(n * rows) < 0.5)
+
+    def build(k, sel, ctx):
+        batch = Batch({"k": Column(k, T.LongType())}, selection=sel)
+        filt = build_runtime_filter(batch, ColumnRef("k"), ctx,
+                                    expected_items=n * rows)
+        return filt.bloom.words, filt.lo, filt.hi
+
+    sharded = shard_map(
+        lambda k, sel: build(k, sel, ExecContext(Conf(), AXIS, n)),
+        mesh=mesh, in_specs=PartitionSpec(AXIS),
+        out_specs=PartitionSpec(), check_vma=False)
+    words, lo, hi = jax.jit(sharded)(keys, live)
+    want, want_lo, want_hi = build(keys, live, ExecContext(Conf()))
+    np.testing.assert_array_equal(np.asarray(words), np.asarray(want))
+    assert (int(lo), int(hi)) == (int(want_lo), int(want_hi))
+    assert want.dtype == jnp.uint32 and want.shape[0] \
+        == BloomFilter.sizing(n * rows)[0]
+    # the route, from the jaxpr: one pmax over 32 widened bytes a word,
+    # and the words' shifts and sum after it
+    text = str(jax.make_jaxpr(sharded)(keys, live))
+    staged = f"i32[{32 * want.shape[0]}] = pmax["
+    assert text.count(staged) == 1, text
+    after = text[text.index(staged):]
+    assert "shift_left" in after and "reduce_sum" in after
+    assert f"u32[{want.shape[0]}] = pmax" not in text
