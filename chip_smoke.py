@@ -8,7 +8,7 @@ One process, one TPU chip, the entry points a user calls:
             any work. There is no CPU path here.
 2. data     TPC-H at SF1 (the specification's smallest scale), made
             from --seed under <checkout>/data/tpch (git-ignored).
-3. serve    `SqlService` on an ephemeral port; Q1, Q6 and Q3 over HTTP
+3. serve    `SqlService` on an ephemeral port; Q1, Q6, Q3, Q5 over HTTP
             `POST /sql`, each three times, cold then warm twice, each
             compared with the independent pandas golden; the second
             and third submission must compile no stage
@@ -33,14 +33,16 @@ between them the request of the benchmark's four-chip cell, Q1 then
 that Q1 streams over the mesh, then as the cell runs it, both scans
 held sharded over the chips' device-table caches) and no other phase.
 
-`--queries` adds Q5 (`--queries Q1,Q6,Q3,Q5`). It is not in the
-default set because the whole script has 1200 seconds, compilation
-included, and may count on no compiled program from an earlier run:
-under the installed XLA:TPU a multi-key 64-bit `sort` takes minutes to
-compile, Q5's final stage holds 56 sorts, and its join capacities take
-four compiles to settle. On the chip Q5 had not answered when this
-script's 900 s HTTP timeout ended the run; the default set took 740 s,
-of which Q3's two submissions were 560 (PERF.md, PR 22).
+Q5 is in the default set since PR 41. Until then its plan joined
+CUSTOMER to SUPPLIER on the nation key alone and expanded some 18 M
+rows at SF1, and on the chip it had not answered when this script's
+900 s HTTP timeout ended the run (PERF.md, PR 22). With the reorder's
+domain estimate its first submission compiles and answers in 62.1-62.6
+s and its second, the stage whose filters compact, in 52.5-53.9 s with
+an empty cache (PERF.md, PR 41: the benchmark's cell on one v5e, the
+same stage text as here, which `--queries Q5` found in that cache:
+7.2 s and 4.0 s); the default set took 430 s of the script's 1200
+without it (PR 40). `--queries` picks a subset, e.g. `Q1,Q6`.
 
 Any failure in any phase raises and the process exits non-zero at
 once: nothing here catches an error and carries on, and nothing falls
@@ -73,8 +75,8 @@ MESH_KEY = "spark_tpu.sql.mesh.size"
 MESH_FALLBACK_KEY = "spark_tpu.execution.meshFallback.enabled"
 
 #: the TPC-H queries served by default, scan-aggregates first, the
-#: three-way join after; Q5 comes by --queries (see the docstring)
-SERVED = ("Q1", "Q6", "Q3")
+#: joins after (see the docstring)
+SERVED = ("Q1", "Q6", "Q3", "Q5")
 
 #: of --queries, what the mesh phase runs: the join queries (hash
 #: exchange, broadcast join, global sort)

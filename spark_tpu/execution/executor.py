@@ -143,6 +143,9 @@ class QueryExecution:
         self._executed: Optional[P.PhysicalPlan] = None
         self.phase_times: Dict[str, float] = {}
         self.last_metrics: Dict[str, float] = {}  # ints except the *_ms_* keys
+        #: the largest `join_rows_*` of this query's stages so far
+        #: (`_note_joins`: what `join_widest_rows` has counted of it)
+        self._join_widest = 0
         # observability: lifecycle identity + per-phase spans (Chrome
         # -trace exportable) + the XLA cost/memory analysis of every
         # stage this execution compiled or reused (observability/)
@@ -1988,10 +1991,16 @@ class QueryExecution:
         counter `/metrics` serves, from the stats channel
         `dispatch.sync` has just pulled (no sync of its own), whatever
         the conf: `join_output_rows`, the sum of the stage's
-        `join_rows_*` (the filters' `rtf_tested` / `rtf_pruned` are the
-        metrics sink's, folded at a query's end). The `dispatch` span
+        `join_rows_*`, and `join_widest_rows`, the largest of the
+        query's (it grows by what a stage's largest passes the query's
+        so far: the widest join the chosen order made). The filters'
+        `rtf_tested` / `rtf_pruned` are the metrics sink's, folded at a
+        query's end. The `dispatch` span
         gets `joins=<n>` and the kernel each resolved to while the
-        stage was traced (`join_kernels`, `<tag>=sort|hash`), and
+        stage was traced (`join_kernels`, `<tag>=sort|hash`), how each
+        join on several columns packed them into one key
+        (`join_keys`, `<tag>=exact|hashed`: `hashed` re-verifies every
+        column of a match), and
         `rtf_caps=<tag>=<K>,...` where a runtime filter of `root`
         hands on a compacted batch of K slots. A stage
         without a join touches nothing; one
@@ -2003,13 +2012,23 @@ class QueryExecution:
         if joined:
             self.session.metrics.counter("join_output_rows").inc(
                 sum(joined))
-        kernels = sorted(
-            (k[len("join_kernel_"):], v) for k, v in self._stage_host().items()
-            if k.startswith("join_kernel_"))
+            widest = max(joined)
+            if widest > self._join_widest:
+                self.session.metrics.counter("join_widest_rows").inc(
+                    widest - self._join_widest)
+                self._join_widest = widest
+        host = self._stage_host()
+        kernels = sorted((k[len("join_kernel_"):], v) for k, v in host.items()
+                         if k.startswith("join_kernel_"))
         if kernels:
             disp.attrs["joins"] = len(kernels)
             disp.attrs["join_kernels"] = ",".join(
                 f"{tag}={kernel}" for tag, kernel in kernels)
+        keys = sorted((k[len("join_keys_"):], v) for k, v in host.items()
+                      if k.startswith("join_keys_"))
+        if keys:
+            disp.attrs["join_keys"] = ",".join(
+                f"{tag}={packed}" for tag, packed in keys)
         rtf_caps = sorted((f.tag, f.out_cap)
                           for f in self._runtime_filters(root)
                           if f.out_cap is not None)

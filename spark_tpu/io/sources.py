@@ -796,6 +796,7 @@ class ParquetSource(TableSource):
                     read_options=pa_dataset.ParquetReadOptions(
                         dictionary_columns=dict_columns)))
         self._column_stats: Optional[dict] = None
+        self._schema: Optional[T.Schema] = None
 
     def column_stats(self) -> Optional[dict]:
         """Per-column min/max + null/row-group counts merged across
@@ -864,7 +865,11 @@ class ParquetSource(TableSource):
         return ("parquet", self.path, tuple(stamps))
 
     def schema(self) -> T.Schema:
-        return _arrow_schema_to_engine(self._dataset.schema)
+        # the dataset's schema is fixed at construction and the engine's
+        # is immutable: made once (a plan's every node asks for it)
+        if self._schema is None:
+            self._schema = _arrow_schema_to_engine(self._dataset.schema)
+        return self._schema
 
     def can_push(self, e: Expression) -> bool:
         return expr_to_arrow(e, self._dataset.schema) is not None

@@ -112,6 +112,8 @@ METRIC_PREFIXES = (
     "join_output_",    # join_output_rows: the sum of a dispatched
                        # stage's join_rows_* (under a mesh each is the
                        # fullest shard's)
+    "join_widest_",    # join_widest_rows: the sum over queries of the
+                       # largest join_rows_* of each query's stages
     # straggler detection (observability/straggler.py): REGISTRY
     # counter, listed for namespace closure like the ingest pair
     "straggler_",      # straggler_flagged: shards flagged this process

@@ -18,6 +18,19 @@ Cost model — the planner-statistics sliver, star-schema shaped:
 - an inner FK join of an accumulated side A with relation R produces
   `max(rows) x frac(smaller side)` rows — joining a filtered dimension
   scales the fact side by the dimension's selectivity;
+- a join on a key that repeats on both sides (TPC-H Q5's
+  `c_nationkey = s_nationkey`: 25 values under 150 k customers and
+  10 k suppliers) is no FK join: it produces `|A| x |R| / d` rows,
+  `d` the larger key domain of the two sides. A key column's domain
+  is its footer `max - min + 1`; it may be a key of its relation
+  while that is at least the relation's base rows, or unknown, and of
+  the accumulated side while every join so far kept it one. A step
+  with a key column AND such a key (the join that brings SUPPLIER in
+  on `l_suppkey` and the nation) takes the FK estimate over `d`;
+- equalities are transitive: `c_nationkey = s_nationkey` and
+  `s_nationkey = n_nationkey` let CUSTOMER join NATION directly, and
+  each join takes one key a group of equal columns
+  (`_Region.key_groups`);
 - the chosen order minimizes the SUM of intermediate result sizes
   (left-deep dynamic programming over connected subsets, Selinger
   -style, bounded by `spark_tpu.sql.cbo.maxReorderRelations`).
@@ -68,6 +81,10 @@ SEL_EQ = 0.1
 SEL_RANGE = 0.33
 SEL_ISNULL = 0.05
 SEL_DEFAULT = 0.5
+
+
+#: a region relation's column: (its index in the region, its name)
+Member = Tuple[int, str]
 
 
 def _plain_name(e: Expression) -> Optional[str]:
@@ -218,6 +235,40 @@ class _Region:
                 if name in r.schema().names]
         return hits[0] if len(hits) == 1 else None
 
+    def key_groups(self) -> List[List[Member]]:
+        """The region's equalities as groups of `(relation, column)`
+        that are all equal: an edge's two columns and, transitively,
+        every column an edge ties to either. A group that holds two
+        columns of one relation or two dtypes stays as its edges (an
+        equality inside one relation is a filter, not a join)."""
+        parent: Dict[Member, Member] = {}
+
+        def find(m):
+            while parent[m] != m:
+                m = parent[m]
+            return m
+
+        for a, na, b, nb in self.edges:
+            for m in ((a, na), (b, nb)):
+                parent.setdefault(m, m)
+            ra, rb = find((a, na)), find((b, nb))
+            if ra != rb:
+                parent[rb] = ra
+        members: Dict[Member, List[Member]] = {}
+        for m in parent:  # in the order the edges name them
+            members.setdefault(find(m), []).append(m)
+        groups = []
+        for root, group in members.items():
+            rels = [r for r, _ in group]
+            dtypes = {self.rels[r].schema().field(nm).dtype for r, nm in group}
+            if len(set(rels)) == len(rels) and len(dtypes) == 1:
+                groups.append(group)
+                continue
+            groups.extend([(a, na), (b, nb)]
+                          for a, na, b, nb in self.edges
+                          if find((a, na)) == root)
+        return groups
+
 
 def _flatten(node: L.LogicalPlan, region: _Region) -> None:
     if not region.ok:
@@ -258,34 +309,114 @@ def _join_estimate(rows_a: float, frac_a: float, rows_b: float,
     return max(1.0, rows_b * min(1.0, frac_a))
 
 
-def _best_order(est: List[Tuple[int, float]],
-                adj: List[int]) -> Optional[Tuple[Tuple[int, ...],
-                                                  List[int]]]:
+def _domain(stats: Dict[str, dict], name: str) -> Optional[int]:
+    """How many values an integer or date column can hold at most: its
+    footer `max - min + 1`; None when unknown."""
+    import datetime
+    st = stats.get(name) or {}
+    lo, hi = st.get("min"), st.get("max")
+    if type(lo) is datetime.date and type(hi) is datetime.date:
+        return (hi - lo).days + 1 if hi >= lo else None
+    if type(lo) is int and type(hi) is int and hi >= lo:
+        return hi - lo + 1
+    return None
+
+
+class _Costs:
+    """The estimates the order search and the rebuild share: each
+    relation's `(base, frac)`, its key columns' domains, and the
+    region's key groups."""
+
+    def __init__(self, est: List[Tuple[int, float]],
+                 groups: List[List[Member]],
+                 domains: Dict[Member, Optional[int]],
+                 edges: List[Tuple[int, str, int, str]]):
+        self.est = est
+        self.groups = groups
+        self.domains = domains
+        self.direct = {frozenset(((a, na), (b, nb)))
+                       for a, na, b, nb in edges}
+
+    def unique(self, m: Member) -> bool:
+        """May `m` be a key of its relation (unknown: it may)."""
+        d = self.domains.get(m)
+        return d is None or d >= self.est[m[0]][0]
+
+    def keys_of(self, i: int) -> frozenset:
+        return frozenset(m for g in self.groups for m in g
+                         if m[0] == i and self.unique(m))
+
+    def links(self, mask: int, i: int
+              ) -> List[Tuple[List[Member], Member, Member]]:
+        """One key a group that ties relation `i` to the relations of
+        `mask`: (the group's members in `mask`, the one to join on —
+        an edge's own partner where the query names one — and `i`'s)."""
+        out = []
+        for g in self.groups:
+            bound = [m for m in g if (mask >> m[0]) & 1]
+            if not bound:
+                continue
+            for mi in (m for m in g if m[0] == i):
+                pick = next((m for m in bound
+                             if frozenset((m, mi)) in self.direct),
+                            bound[0])
+                out.append((bound, pick, mi))
+        return out
+
+    def step(self, rows: float, frac: float, uniq: frozenset, mask: int,
+             i: int) -> Tuple[float, frozenset]:
+        """(estimated output rows, the accumulated side's key columns
+        after it) of joining relation `i` to the relations of `mask`."""
+        ri = max(1.0, self.est[i][0] * self.est[i][1])
+        keyed, keeps, carries = False, False, False
+        shared: List[int] = []  # domains of keys repeated on both sides
+        for bound, _pick, mi in self.links(mask, i):
+            key_i = self.unique(mi)
+            key_acc = any(m in uniq for m in bound)
+            doms = [self.domains.get(m) for m in bound + [mi]]
+            if key_i or key_acc or None in doms:
+                keyed = True
+            else:
+                shared.append(max(doms))
+            keeps |= key_i
+            carries |= key_acc
+        if keyed:
+            out = _join_estimate(rows, frac, ri, self.est[i][1])
+        else:
+            out = rows * ri
+        for d in shared:
+            out /= d
+        after = ((uniq if keeps else frozenset())
+                 | (self.keys_of(i) if carries else frozenset()))
+        return max(1.0, out), after
+
+
+def _best_order(costs: _Costs) -> Optional[Tuple[Tuple[int, ...],
+                                                 List[int]]]:
     """Minimal-cost left-deep order over connected subsets.
-    `est[i] = (rows_i, frac_i)`, `adj[i]` = bitmask of neighbors.
     Returns (order, per-join estimated output rows) or None when the
     region graph is disconnected."""
+    est = costs.est
     n = len(est)
     full = (1 << n) - 1
-    # state per subset: (cost, order, rows, frac, per_join_rows)
+    # state per subset: (cost, order, rows, frac, per_join_rows, keys)
     best: Dict[int, Tuple[float, Tuple[int, ...], float, float,
-                          List[int]]] = {}
+                          List[int], frozenset]] = {}
     for i in range(n):
         rows = max(1.0, est[i][0] * est[i][1])
-        best[1 << i] = (0.0, (i,), rows, est[i][1], [])
+        best[1 << i] = (0.0, (i,), rows, est[i][1], [], costs.keys_of(i))
     for mask in range(1, full + 1):
         state = best.get(mask)
         if state is None:
             continue
-        cost, order, rows, frac, per = state
+        cost, order, rows, frac, per, uniq = state
         for i in range(n):
             bit = 1 << i
-            if mask & bit or not (adj[i] & mask):
+            if mask & bit or not costs.links(mask, i):
                 continue
-            ri = max(1.0, est[i][0] * est[i][1])
-            out = _join_estimate(rows, frac, ri, est[i][1])
+            out, after = costs.step(rows, frac, uniq, mask, i)
             nxt = (cost + out, order + (i,), out,
-                   min(1.0, frac * est[i][1]), per + [int(out)])
+                   min(1.0, frac * est[i][1]), per + [int(out)], after)
             cur = best.get(mask | bit)
             # deterministic: strictly-better cost wins; ties keep the
             # lexicographically-earlier order (frontend bias)
@@ -385,20 +516,20 @@ class CostBasedJoinReorder(Rule):
             if e is None:
                 return None
             est.append(e)
-        n = len(region.rels)
-        adj = [0] * n
-        for a, _na, b, _nb in region.edges:
-            adj[a] |= 1 << b
-            adj[b] |= 1 << a
-        found = _best_order(est, adj)
+        groups = region.key_groups()
+        stats = [_scan_stats(rel, self.conf) for rel in region.rels]
+        domains = {m: _domain(stats[m[0]], m[1])
+                   for g in groups for m in g}
+        costs = _Costs(est, groups, domains, region.edges)
+        found = _best_order(costs)
         if found is None:
             return None  # disconnected region (cross joins): keep
         order, per_join = found
         # rewrite the region relations themselves first (nested regions
         # under aggregates/subqueries)
         rels = [self._rewrite(r) for r in region.rels]
-        rebuilt, new_leaf_index = self._build(rels, est, region.edges,
-                                              order)
+        rebuilt, new_leaf_index = self._build(rels, costs, order,
+                                              per_join)
         if rebuilt is None:
             return None
         orig_leaf_index = {id(r): i for i, r in enumerate(region.rels)}
@@ -430,13 +561,12 @@ class CostBasedJoinReorder(Rule):
                           "inner")
         return leaf_map[id(node)]
 
-    def _build(self, rels: List[L.LogicalPlan],
-               est: List[Tuple[int, float]],
-               edges: List[Tuple[int, str, int, str]],
-               order: Tuple[int, ...]
+    def _build(self, rels: List[L.LogicalPlan], costs: _Costs,
+               order: Tuple[int, ...], per_join: List[int]
                ) -> Tuple[Optional[L.LogicalPlan], Dict[int, int]]:
-        """Left-deep tree over `order`, orientation following the
-        engine convention: bigger estimated side on the probe (left).
+        """Left-deep tree over `order`, one key a group of equal
+        columns at each join, orientation following the engine
+        convention: bigger estimated side on the probe (left).
         Also returns the id(new leaf) -> region index map for the
         shape-signature change test.
 
@@ -450,33 +580,21 @@ class CostBasedJoinReorder(Rule):
         set after planning, from counts, and no order is chosen by
         it.)"""
         leaf_index = {id(rels[i]): i for i in range(len(rels))}
-        bound = {order[0]}
+        mask = 1 << order[0]
         acc = rels[order[0]]
-        acc_rows = max(1.0, est[order[0]][0] * est[order[0]][1])
-        acc_frac = est[order[0]][1]
-        acc_cap = float(est[order[0]][0])
-        for i in order[1:]:
-            acc_keys: List[Expression] = []
-            rel_keys: List[Expression] = []
-            for a, na, b, nb in edges:
-                if a in bound and b == i:
-                    acc_keys.append(ColumnRef(na))
-                    rel_keys.append(ColumnRef(nb))
-                elif b in bound and a == i:
-                    acc_keys.append(ColumnRef(nb))
-                    rel_keys.append(ColumnRef(na))
-            if not acc_keys:
+        acc_cap = float(costs.est[order[0]][0])
+        for i, out in zip(order[1:], per_join):
+            links = costs.links(mask, i)
+            if not links:
                 return None, leaf_index  # disconnected step
-            ri = max(1.0, est[i][0] * est[i][1])
-            if float(est[i][0]) > acc_cap:
+            acc_keys = [ColumnRef(pick[1]) for _b, pick, _mi in links]
+            rel_keys = [ColumnRef(mi[1]) for _b, _pick, mi in links]
+            if float(costs.est[i][0]) > acc_cap:
                 join = L.Join(rels[i], acc, rel_keys, acc_keys, "inner")
             else:
                 join = L.Join(acc, rels[i], acc_keys, rel_keys, "inner")
-            out = _join_estimate(acc_rows, acc_frac, ri, est[i][1])
-            join._cbo_est_rows = int(out)
+            join._cbo_est_rows = out
             acc = join
-            acc_rows = out
-            acc_frac = min(1.0, acc_frac * est[i][1])
-            acc_cap = max(acc_cap, float(est[i][0]))
-            bound.add(i)
+            acc_cap = max(acc_cap, float(costs.est[i][0]))
+            mask |= 1 << i
         return acc, leaf_index

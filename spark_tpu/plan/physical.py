@@ -1496,6 +1496,9 @@ class JoinExec(PhysicalPlan):
         # which kernel this join's program holds: the `dispatch` span's
         # `join_kernels` attribute
         ctx.host[f"join_kernel_{self.tag}"] = kernel
+        if len(lvecs) > 1:  # and how it packed a key of several columns
+            ctx.host[f"join_keys_{self.tag}"] = \
+                "exact" if exact else "hashed"
         hash_lc = None
         if kernel == "hash":
             slots = hash_kernels.table_slots(build_batch.capacity,

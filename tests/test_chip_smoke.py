@@ -57,9 +57,7 @@ def tpch_path(tmp_path_factory):
 def test_serve_phase(tpch_path):
     svc = chip_smoke.start_service(tpch_path)
     try:
-        # with Q5, which the script leaves to --queries on the chip
-        chip_smoke.phase_serve(svc, tpch_path,
-                               chip_smoke.SERVED + ("Q5",))
+        chip_smoke.phase_serve(svc, tpch_path, chip_smoke.SERVED)
     finally:
         svc.stop()
 

@@ -50,6 +50,20 @@ def test_tpch_parity_single_chip(tpch_session, tpch_path, qname):
     G.compare(got, want)
 
 
+def test_tpch_q5_parity_with_the_reorder_off(tpch_session, tpch_path):
+    """Q5 in the frontend's order too: the reorder's domain estimate
+    (PR 41) chooses another, and both give the golden answer."""
+    key = "spark_tpu.sql.cbo.joinReorder"
+    tpch_session.conf.set(key, False)
+    try:
+        got = _norm(Q.QUERIES["q5"](tpch_session).to_pandas())
+    finally:
+        tpch_session.conf.set(key, True)
+    want = G.GOLDEN["q5"](tpch_path)
+    G.compare(got.sort_values("n_name").reset_index(drop=True),
+              want.sort_values("n_name").reset_index(drop=True))
+
+
 @pytest.mark.parametrize("qname", ["q1", "q3", "q6"])
 def test_tpch_parity_mesh(tpch_session, tpch_path, qname):
     tpch_session.conf.set(MESH_KEY, 8)
