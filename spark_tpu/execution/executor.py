@@ -61,6 +61,16 @@ def _filter_kept(metrics: Dict, tag: str) -> int:
         - int(metrics[f"rtf_pruned_{tag}"])
 
 
+def _tag_attr(span, record: Dict, prefix: str, attr: str) -> int:
+    """`attr=<tag>=<value>,...` on `span` from `record`'s
+    `<prefix><tag>` keys, in tag order; how many there were."""
+    found = sorted((k[len(prefix):], v) for k, v in record.items()
+                   if k.startswith(prefix))
+    if found:
+        span.attrs[attr] = ",".join(f"{tag}={v}" for tag, v in found)
+    return len(found)
+
+
 #: name of the short-lived thread a sync that has to wait starts
 #: (`LockWatch.assert_no_thread_leak` finds a leak by this prefix)
 SYNC_WAITER_THREAD = "spark-tpu-dispatch-sync"
@@ -785,6 +795,10 @@ class QueryExecution:
                          "join_table_slots_")) \
                         else jax.lax.psum
                     metrics[k] = red(jnp.asarray(v), AXIS)
+                    if k.startswith("join_rows_"):
+                        # and the whole mesh's, which the counters take
+                        metrics["join_mesh_rows_" + k[len("join_rows_"):]] \
+                            = jax.lax.psum(jnp.asarray(v), AXIS)
                 return out, flags, metrics
 
             return shard_map(
@@ -1808,8 +1822,8 @@ class QueryExecution:
                             self.session.metrics.counter(counter).inc(
                                 sync.attrs.get(attr, 0))
                 self._note_joins(disp, root, metrics)
-            if mesh is not None:
-                self._count_mesh_stage(metrics)
+                if mesh is not None:
+                    self._count_mesh_stage(disp, metrics)
             # deadline BEFORE the stage-timeout check: an attempt
             # that outran the end-to-end budget raises the
             # lifecycle error (ladder stops), never a retryable
@@ -1915,12 +1929,14 @@ class QueryExecution:
         # join_probe_ms_*): trace-time costs from the host's record of
         # the stage, floats (sub-ms builds are the common case); and
         # its shapes (rtf_slots_*: what each filter handed on;
-        # rtf_build_indices_*: what each filter's build scattered)
+        # rtf_build_indices_*: what each filter's build scattered;
+        # exch_slot_bytes_*: what each exchange's collective carried)
         self.last_metrics.update(
             (k, round(v, 3) if isinstance(v, float) else v)
             for k, v in self._stage_host().items()
             if isinstance(v, float)
-            or k.startswith(("rtf_slots_", "rtf_build_indices_")))
+            or k.startswith(("rtf_slots_", "rtf_build_indices_",
+                             "exch_slot_bytes_")))
         if self._mesh_fallback:
             # degraded single-device result of a mesh-planned query:
             # visible next to the device metrics and in the event log
@@ -1993,7 +2009,9 @@ class QueryExecution:
         counter `/metrics` serves, from the stats channel
         `dispatch.sync` has just pulled (no sync of its own), whatever
         the conf: `join_output_rows`, the sum of the stage's
-        `join_rows_*`, and `join_widest_rows`, the largest of the
+        `join_rows_*` (under a mesh `join_mesh_rows_*`, the whole
+        mesh's: `join_rows_*` is the fullest shard's there, a
+        capacity), and `join_widest_rows`, the largest of the
         query's (it grows by what a stage's largest passes the query's
         so far: the widest join the chosen order made). The filters'
         `rtf_tested` / `rtf_pruned` are the metrics sink's, folded at a
@@ -2002,7 +2020,8 @@ class QueryExecution:
         stage was traced (`join_kernels`, `<tag>=sort|hash`), how each
         join on several columns packed them into one key
         (`join_keys`, `<tag>=exact|hashed`: `hashed` re-verifies every
-        column of a match), and
+        column of a match), under a mesh how each met its build
+        (`join_strategies`, `<tag>=shuffle|broadcast`), and
         `rtf_caps=<tag>=<K>,...` where a runtime filter of `root`
         hands on a compacted batch of K slots, and `rtf_build=<tag>=<n>,...`:
         the indices each filter's Bloom build scattered. A stage
@@ -2010,8 +2029,11 @@ class QueryExecution:
         deserialized from the engine's own compile cache
         (`compileCache.enabled`) was not traced in this process and has
         no host record, so its span and `last_metrics` go without."""
+        rows_of = "join_mesh_rows_" if any(
+            k.startswith("join_mesh_rows_") for k in metrics) \
+            else "join_rows_"
         joined = [int(v) for k, v in metrics.items()
-                  if k.startswith("join_rows_")]
+                  if k.startswith(rows_of)]
         if joined:
             self.session.metrics.counter("join_output_rows").inc(
                 sum(joined))
@@ -2021,29 +2043,19 @@ class QueryExecution:
                     widest - self._join_widest)
                 self._join_widest = widest
         host = self._stage_host()
-        kernels = sorted((k[len("join_kernel_"):], v) for k, v in host.items()
-                         if k.startswith("join_kernel_"))
-        if kernels:
-            disp.attrs["joins"] = len(kernels)
-            disp.attrs["join_kernels"] = ",".join(
-                f"{tag}={kernel}" for tag, kernel in kernels)
-        keys = sorted((k[len("join_keys_"):], v) for k, v in host.items()
-                      if k.startswith("join_keys_"))
-        if keys:
-            disp.attrs["join_keys"] = ",".join(
-                f"{tag}={packed}" for tag, packed in keys)
+        joins = _tag_attr(disp, host, "join_kernel_", "join_kernels")
+        if joins:
+            disp.attrs["joins"] = joins
+        for prefix, attr in (("join_keys_", "join_keys"),
+                             ("join_strategy_", "join_strategies"),
+                             ("rtf_build_indices_", "rtf_build")):
+            _tag_attr(disp, host, prefix, attr)
         rtf_caps = sorted((f.tag, f.out_cap)
                           for f in self._runtime_filters(root)
                           if f.out_cap is not None)
         if rtf_caps:
             disp.attrs["rtf_caps"] = ",".join(
                 f"{tag}={cap}" for tag, cap in rtf_caps)
-        built = sorted((k[len("rtf_build_indices_"):], v)
-                       for k, v in host.items()
-                       if k.startswith("rtf_build_indices_"))
-        if built:
-            disp.attrs["rtf_build"] = ",".join(
-                f"{tag}={n}" for tag, n in built)
 
     @staticmethod
     def _runtime_filters(root: P.PhysicalPlan
@@ -2094,13 +2106,17 @@ class QueryExecution:
         """The host's record of the stage last compiled or found."""
         return self.session._stage_host.get(self._last_stage_key or "", {})
 
-    def _count_mesh_stage(self, metrics: Dict) -> None:
+    def _count_mesh_stage(self, disp, metrics: Dict) -> None:
         """What a dispatched mesh stage did, into the process counters
         `/metrics` serves, from the stats channel `dispatch.sync` has
         just pulled (no sync of its own), whatever the conf:
         `mesh_stage_dispatches`, the exchanges' routed rows and bytes,
         and of each exchange's per-shard row vector the fullest
-        shard's rows and all shards'."""
+        shard's rows and all shards'. From the host's record of the
+        stage: `exchange_slot_bytes`, what the exchanges' collectives
+        carry, padding included (`exch_slot_bytes_*`), and on the
+        `dispatch` span `exch_blocks=<tag>=<slots>,...`, each
+        exchange's slots a (source, destination) pair."""
         registry = self.session.metrics
         registry.counter("mesh_stage_dispatches").inc()
         for k, v in metrics.items():
@@ -2110,6 +2126,12 @@ class QueryExecution:
                 registry.counter("exchange_bytes").inc(int(v))
             elif k.startswith("shard_rows_"):
                 registry.count_shard_rows(v.reshape(-1))
+        host = self._stage_host()
+        slot_bytes = sum(v for k, v in host.items()
+                         if k.startswith("exch_slot_bytes_"))
+        if slot_bytes:
+            registry.counter("exchange_slot_bytes").inc(slot_bytes)
+        _tag_attr(disp, host, "exch_block_", "exch_blocks")
 
     def _record_exchange_shards(self, metrics: Dict, mesh) -> None:
         """Unpack the exchanges' per-shard row/byte vectors (emitted as

@@ -40,9 +40,15 @@ from typing import Dict, List
 METRIC_PREFIXES = (
     "rows_",           # per-operator output rows (executor replay wrapper)
     "join_rows_",      # join true output-row total (AQE capacity channel)
+    "join_mesh_rows_",  # under a mesh: the join's output rows summed
+                       # over the shards (join_rows_ is the fullest
+                       # shard's there, a capacity)
     "exch_max_",       # exchange max per-(src,dst) bucket count
     "exch_rows_",      # exchange routed live rows
     "exch_bytes_",     # exchange routed payload bytes (shuffle volume)
+    "exch_slot_bytes_",  # HOST-side too: the bytes an exchange's
+                       # all_to_all carries, padding included (n x n x
+                       # the block x the row width): a shape
     "agg_groups",      # aggregate distinct-group counts (+ _<tag> forms)
     "gen_rows_",       # generate/explode output rows
     "rtf_tested_",     # runtime-filter probe rows tested
@@ -104,18 +110,21 @@ METRIC_PREFIXES = (
     "mesh_stage_",     # mesh_stage_dispatches: whole stages dispatched
                        # under a mesh (over stage_dispatches: the share
                        # of dispatches that cross chips)
-    "exchange_",       # exchange_rows / exchange_bytes: the sums of a
-                       # mesh stage's exch_rows_* / exch_bytes_*
+    "exchange_",       # exchange_rows / exchange_bytes /
+                       # exchange_slot_bytes: the sums of a mesh
+                       # stage's exch_rows_* / exch_bytes_* /
+                       # exch_slot_bytes_*
     # what a stage's joins did (executor._note_joins after
     # `dispatch.sync`, from the stats channel it pulled, whatever the
     # conf): REGISTRY counter, listed for namespace closure. The
     # filters' process counters are the metrics sink's `rtf_tested` /
     # `rtf_pruned` (observability/sinks.py), folded at a query's end
     "join_output_",    # join_output_rows: the sum of a dispatched
-                       # stage's join_rows_* (under a mesh each is the
-                       # fullest shard's)
+                       # stage's join_rows_* (under a mesh its
+                       # join_mesh_rows_*: the whole mesh's)
     "join_widest_",    # join_widest_rows: the sum over queries of the
-                       # largest join_rows_* of each query's stages
+                       # largest join_rows_* (under a mesh
+                       # join_mesh_rows_*) of each query's stages
     # straggler detection (observability/straggler.py): REGISTRY
     # counter, listed for namespace closure like the ingest pair
     "straggler_",      # straggler_flagged: shards flagged this process

@@ -168,6 +168,12 @@ def _exchange_by_target(batch: Batch, tgt, ctx, block: int,
                     + (1 if c.validity is not None else 0)
                     for c in batch.columns.values())
     ctx.add_metric(f"exch_bytes_{tag}", live_rows * row_width)
+    # what the all_to_all carries, padding included: every shard sends
+    # n blocks of `block` slots at the same width. A shape, so the
+    # host's record holds it beside the block (the `dispatch` span's
+    # `exch_blocks`, the `exchange_slot_bytes` counter)
+    ctx.host[f"exch_block_{tag}"] = block
+    ctx.host[f"exch_slot_bytes_{tag}"] = n * n * block * row_width
     # per-shard telemetry: one-hot at this shard's mesh position; the
     # executor's psum reduction turns the stack into a replicated [n]
     # per-shard vector — no all_gather, no host sync (the flight

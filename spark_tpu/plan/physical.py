@@ -1499,6 +1499,9 @@ class JoinExec(PhysicalPlan):
         if len(lvecs) > 1:  # and how it packed a key of several columns
             ctx.host[f"join_keys_{self.tag}"] = \
                 "exact" if exact else "hashed"
+        if ctx.axis_name is not None:  # and, across chips, how it met
+            # its build: hashed to the shards or replicated on each
+            ctx.host[f"join_strategy_{self.tag}"] = self.strategy
         hash_lc = None
         if kernel == "hash":
             slots = hash_kernels.table_slots(build_batch.capacity,

@@ -64,7 +64,7 @@ def test_tpch_q5_parity_with_the_reorder_off(tpch_session, tpch_path):
               want.sort_values("n_name").reset_index(drop=True))
 
 
-@pytest.mark.parametrize("qname", ["q1", "q3", "q6"])
+@pytest.mark.parametrize("qname", ["q1", "q3", "q5", "q6"])
 def test_tpch_parity_mesh(tpch_session, tpch_path, qname):
     tpch_session.conf.set(MESH_KEY, 8)
     try:
@@ -72,6 +72,9 @@ def test_tpch_parity_mesh(tpch_session, tpch_path, qname):
     finally:
         tpch_session.conf.set(MESH_KEY, 0)
     want = G.GOLDEN[qname](tpch_path)
+    if qname == "q5":  # ties in revenue: both by name, as on one chip
+        got = got.sort_values("n_name")
+        want = want.sort_values("n_name").reset_index(drop=True)
     G.compare(got.reset_index(drop=True), want)
 
 
