@@ -10,7 +10,8 @@ block files (`IndexShuffleBlockResolver.scala`), Netty fetch
    dictionary strings: codes hash through a host-computed per-dictionary
    value-hash table, so two tables with different dictionaries still
    co-partition equal strings);
-2. sort rows by target shard, scatter into an [n, L] send buffer;
+2. sort rows by target shard and cut the sorted rows into n contiguous
+   slices, the [n, block] send buffer (no scatter);
 3. `all_to_all` swaps bucket i to shard i; received rows flatten into a
    new local batch with a validity-derived selection.
 
@@ -101,22 +102,32 @@ def hash_rows(batch: Batch, key_names: Sequence[str]):
     return h
 
 
-def _scatter_to_buckets(batch: Batch, tgt, n: int, block: int):
-    """Sort rows by target shard and scatter into an [n*block] send layout
-    (`block` slots per destination). Returns (flat_idx, perm, max_count):
-    row perm[r] goes to flat slot flat_idx[r]; rows past a full bucket
-    drop (the caller flags overflow off max_count and retries bigger)."""
-    L = batch.capacity
+def _sort_to_buckets(tgt, n: int):
+    """Sort rows by target shard. Returns (perm, bounds, counts,
+    max_count): destination d's rows are positions bounds[d] ..
+    bounds[d+1] of the order `perm`, counts[d] of them; dead rows
+    (target n) sort past bounds[n] and go nowhere."""
+    L = tgt.shape[0]
     tgt_s, perm = jax.lax.sort(
         (tgt, jnp.arange(L, dtype=jnp.int32)), num_keys=1)
-    counts = jnp.zeros((n + 1,), jnp.int32).at[tgt].add(
-        jnp.ones((L,), jnp.int32), mode="drop")
-    starts = jnp.cumsum(counts) - counts  # exclusive, [n+1]
-    pos = jnp.arange(L, dtype=jnp.int32) - jnp.take(starts,
-                                                    jnp.clip(tgt_s, 0, n))
-    flat = jnp.where((tgt_s < n) & (pos < block), tgt_s * block + pos,
-                     n * block)
-    return flat, perm, jnp.max(counts[:n])
+    bounds = jnp.searchsorted(tgt_s, jnp.arange(n + 1, dtype=tgt_s.dtype),
+                              side="left").astype(jnp.int32)
+    counts = bounds[1:] - bounds[:-1]
+    return perm, bounds, counts, jnp.max(counts)
+
+
+def _cut_to_blocks(x, perm, bounds, sent):
+    """`x`'s rows in target order, cut into the [n, block] send buffer:
+    slot (d, p) holds sorted row bounds[d] + p where `sent[d, p]`, and 0
+    elsewhere. Destination d's rows are one contiguous run of the sorted
+    order, so the buffer is n copies and no scatter. `block` zero slots
+    behind the sorted rows keep every slice in range (a dynamic slice
+    would shift one that ran past the end)."""
+    n, block = sent.shape
+    x_s = jnp.concatenate([jnp.take(x, perm), jnp.zeros((block,), x.dtype)])
+    send = jnp.stack([jax.lax.dynamic_slice_in_dim(x_s, bounds[d], block)
+                      for d in range(n)])
+    return jnp.where(sent, send, jnp.zeros_like(send))
 
 
 def exchange_hash(batch: Batch, key_names: Sequence[str], ctx,
@@ -147,15 +158,15 @@ def exchange_hash(batch: Batch, key_names: Sequence[str], ctx,
 
 def _exchange_by_target(batch: Batch, tgt, ctx, block: int,
                         tag: str) -> Batch:
-    """Route each selected row to shard `tgt[row]` via scatter +
-    all_to_all; surfaces the max per-bucket count for the executor's
-    capacity-retry loop."""
+    """Route each selected row to shard `tgt[row]` (n for a dead row) via
+    a sort by target + all_to_all; surfaces the max per-bucket count for
+    the executor's capacity-retry loop."""
     from ..testing import faults
     faults.fire("shuffle")  # chaos seam: fires at trace time, per compile
     n = ctx.n_shards
     axis = ctx.axis_name
     sel = batch.selection_mask()
-    flat, perm, max_count = _scatter_to_buckets(batch, tgt, n, block)
+    perm, bounds, counts, max_count = _sort_to_buckets(tgt, n)
     ctx.add_metric(f"exch_max_{tag}", max_count)
     # total live rows routed (psum'd): max/(rows/n) is the skew factor
     # the adaptive re-planner reads (OptimizeSkewedJoin.scala:56 seat)
@@ -184,20 +195,22 @@ def _exchange_by_target(batch: Batch, tgt, ctx, block: int,
     ctx.add_metric(f"shard_bytes_{tag}", shard_hot * row_width)
     ctx.add_flag(f"exch_overflow_{tag}", max_count > block)
 
-    def send_recv(x, fill=0):
-        x_s = jnp.take(x, perm)
-        send = jnp.full((n * block,), fill, x.dtype).at[flat].set(
-            x_s, mode="drop")
-        return jax.lax.all_to_all(send.reshape(n, block), axis, 0, 0
-                                  ).reshape(n * block)
+    # slot p of destination d's block is sent while p < counts[d]: a
+    # bucket past `block` sends its first `block` rows (the overflow flag
+    # retries), and dead rows, sorted last, are never sent
+    sent = jnp.arange(block, dtype=jnp.int32)[None, :] < counts[:, None]
 
-    live = send_recv(sel, fill=False)  # scattered True marks live rows
-    # (dead rows never scatter: their flat index is out of bounds)
+    def exchange(send):
+        return jax.lax.all_to_all(send, axis, 0, 0).reshape(n * block)
+
+    def send_recv(x):
+        return exchange(_cut_to_blocks(x, perm, bounds, sent))
+
+    live = exchange(sent)
     cols: Dict[str, Column] = {}
     for name, col in batch.columns.items():
         data = send_recv(col.data)
-        validity = None if col.validity is None else send_recv(
-            col.validity, fill=False)
+        validity = None if col.validity is None else send_recv(col.validity)
         cols[name] = Column(data, col.dtype, validity, col.dictionary)
     return Batch(cols, live)
 

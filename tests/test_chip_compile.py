@@ -156,6 +156,50 @@ def test_the_exchanges_collectives_keep_names_their_reader_knows(topo, sent):
     assert not is_collective("fusion.71") and not is_collective("copy.1")
 
 
+def test_a_hash_exchange_compiles_with_no_scatter(topo):
+    """`exchange_hash` over two int64 columns, one with validity,
+    compiled for a four-chip v5e mesh: the send buffer is n contiguous
+    slices of the target-sorted rows, so the compiled text holds no
+    `scatter` (scattered, LINEITEM's four columns took 8.2 s of TPC-H
+    Q5's 21.6 s request at SF10 on a four-chip v5e host) and an
+    `all-to-all` for each column, validity array and the live mask."""
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from spark_tpu import Conf
+    from spark_tpu import types as T
+    from spark_tpu.columnar import Batch, Column
+    from spark_tpu.parallel.mesh import AXIS, shard_map
+    from spark_tpu.parallel.shuffle import exchange_hash
+    from spark_tpu.plan.physical import ExecContext
+    n = 4
+
+    def stage(key, val, val_valid, sel):
+        ctx = ExecContext(Conf(), AXIS, n)
+        out = exchange_hash(Batch({"k": Column(key, T.LONG),
+                                   "v": Column(val, T.LONG, val_valid)}, sel),
+                            ["k"], ctx)
+        return out, jax.tree.map(jnp.atleast_1d, (ctx.metrics, ctx.flags))
+
+    mesh = Mesh(np.array(topo.devices[:n]), (AXIS,))
+
+    def spec(dtype):
+        return jax.ShapeDtypeStruct(
+            (n * 1024,), jnp.dtype(dtype),
+            sharding=NamedSharding(mesh, PartitionSpec(AXIS)))
+
+    text = jax.jit(shard_map(
+        stage, mesh=mesh, in_specs=PartitionSpec(AXIS),
+        out_specs=PartitionSpec(AXIS), check_vma=False)).lower(
+            spec("int64"), spec("int64"), spec("bool"), spec("bool")) \
+        .compile().as_text()
+    assert not re.search(r" scatter\(", text), \
+        re.findall(r".* scatter\(.*", text)
+    assert len(re.findall(r"= [^=]*? all-to-all(?:-start)?\(", text)) >= 4
+
+
 def test_a_mesh_built_filter_is_one_widened_all_reduce_and_one_gather(topo):
     """A runtime filter built and probed under `shard_map`, at the sizes
     of Q3's `rf1` (32,768 expected keys, ORDERS' 1 Mi slots probed),

@@ -417,6 +417,116 @@ def test_distributed_streaming_aggregate(session):
     assert any(calls), "mesh streaming path never engaged"
 
 
+#: (row layout, block slots as a function of rows a shard L and shards n)
+EXCHANGE_CASES = {
+    "uniform": lambda L, n: 2 * L // n,
+    "dead_rows": lambda L, n: 2 * L // n,
+    "overflow": lambda L, n: 2 * L // n,     # destination 1 takes 70%
+    "empty_shard": lambda L, n: 2 * L // n,  # shard 0 sends nothing,
+                                             # shard n-1 receives nothing
+    "block_over_L": lambda L, n: 2 * L,
+}
+
+
+def _exchange_targets(layout, rs, n, L):
+    """A target shard a row, n for a dead row, [n * L]."""
+    tgt = rs.randint(0, n, n * L)
+    if layout == "dead_rows" or layout == "block_over_L":
+        tgt[rs.rand(n * L) < 0.3] = n
+    elif layout == "overflow":
+        tgt[rs.rand(n * L) < 0.7] = 1
+    elif layout == "empty_shard":
+        tgt = rs.randint(0, n - 1, n * L)
+        tgt[:L] = n
+        tgt[rs.rand(n * L) < 0.2] = n
+    return tgt.astype(np.int32)
+
+
+def _scatter_model(x, tgt, n, L, block, fill):
+    """The exchange's received rows as its scatter form placed them: a
+    source's rows bound for d, in row order, take d's block of `block`
+    slots from its start (the rows past it dropped, the slots after them
+    `fill`); shard d receives source s's block at slots s*block on."""
+    out = np.full((n, n * block), fill, x.dtype)
+    for s in range(n):
+        xs, ts = x[s * L:(s + 1) * L], tgt[s * L:(s + 1) * L]
+        for d in range(n):
+            rows = np.flatnonzero(ts == d)[:block]
+            out[d, s * block:s * block + len(rows)] = xs[rows]
+    return out.reshape(-1)
+
+
+@pytest.mark.parametrize("n", [4, 8])
+@pytest.mark.parametrize("layout", sorted(EXCHANGE_CASES))
+def test_the_exchange_sends_what_its_scatter_did(n, layout):
+    """`_exchange_by_target` under `shard_map` on the CPU's virtual
+    devices: the columns (int64, float64, int32, bool, a dictionary
+    string; each with validity), the validity arrays, the live mask,
+    `exch_max_*` and the overflow flag are, slot for slot, what the send
+    side's scatter into [n, block] gave before it was cut into slices
+    of the target-sorted rows."""
+    import jax
+    import jax.numpy as jnp
+    import pyarrow as pa
+    from jax.sharding import Mesh, PartitionSpec
+
+    from spark_tpu import Conf
+    from spark_tpu import types as T
+    from spark_tpu.columnar import Batch, Column
+    from spark_tpu.parallel.mesh import AXIS, shard_map
+    from spark_tpu.parallel.shuffle import _exchange_by_target
+    from spark_tpu.plan.physical import ExecContext
+    L = 96
+    block = EXCHANGE_CASES[layout](L, n)
+    rs = np.random.RandomState(44 + n)
+    tgt = _exchange_targets(layout, rs, n, L)
+    words = pa.array(["ASIA", "EUROPE", "AFRICA"])
+    cols = {"i64": (rs.randint(-10**12, 10**12, n * L), T.LONG, None),
+            "f64": (rs.randn(n * L), T.DOUBLE, None),
+            "i32": (rs.randint(-10**9, 10**9, n * L).astype(np.int32),
+                    T.INT, None),
+            "b": (rs.rand(n * L) < 0.5, T.BOOLEAN, None),
+            "s": (rs.randint(0, 3, n * L).astype(np.int32), T.STRING, words)}
+    valid = {name: rs.rand(n * L) < 0.8 for name in cols}
+
+    def run(tgt, *arrays):
+        ctx = ExecContext(Conf(), AXIS, n)
+        batch = Batch({name: Column(arrays[2 * i], dtype, arrays[2 * i + 1],
+                                    dictionary)
+                       for i, (name, (_, dtype, dictionary))
+                       in enumerate(cols.items())}, tgt < n)
+        out = _exchange_by_target(batch, tgt, ctx, block, "t")
+        return (tuple(c.data for c in out.columns.values()),
+                tuple(c.validity for c in out.columns.values()),
+                out.selection, ctx.metrics["exch_max_t"].reshape(1),
+                ctx.flags["exch_overflow_t"].reshape(1))
+
+    mesh = Mesh(np.array(jax.devices()[:n]), (AXIS,))
+    args = [jnp.asarray(tgt)]
+    for name, (data, _, _) in cols.items():
+        args += [jnp.asarray(data), jnp.asarray(valid[name])]
+    data, validity, live, max_count, overflow = jax.jit(shard_map(
+        run, mesh=mesh, in_specs=PartitionSpec(AXIS),
+        out_specs=PartitionSpec(AXIS), check_vma=False))(*args)
+
+    for i, (name, (x, _, _)) in enumerate(cols.items()):
+        got = np.asarray(data[i])
+        assert got.dtype == x.dtype, name
+        assert np.array_equal(got, _scatter_model(x, tgt, n, L, block, 0)), \
+            name
+        assert np.array_equal(np.asarray(validity[i]), _scatter_model(
+            valid[name], tgt, n, L, block, False)), name
+    assert np.array_equal(np.asarray(live), _scatter_model(
+        tgt < n, tgt, n, L, block, False))
+    per_shard = np.array([np.bincount(tgt[s * L:(s + 1) * L], minlength=n + 1)
+                          [:n].max() for s in range(n)])
+    assert np.array_equal(np.asarray(max_count), per_shard)
+    assert np.array_equal(np.asarray(overflow), per_shard > block)
+    assert overflow.any() == (layout == "overflow")
+    if layout == "empty_shard":
+        assert not np.asarray(live).reshape(n, -1)[n - 1].any()
+
+
 @pytest.mark.parametrize("dtype,via", [
     ("uint8", "convert_element_type"),   # Bloom bits: widened to int32
     ("int32", None),                     # the library's all-reduce as is
