@@ -54,6 +54,17 @@ DISPATCH_POLL_KEY = "spark_tpu.execution.dispatchPollMs"
 FILTER_COMPACT_MIN_SLOTS = 1 << 16
 FILTER_COMPACT_SHRINK = 4
 
+#: When a hash or range exchange's block is learned from the bucket it
+#: filled (`QueryExecution._learn_exchange_caps`): a shard receives at
+#: least MIN_SLOTS slots (n x the block), and the block the fullest
+#: bucket needs is at most half the seeded one. The join's gathers
+#: above an exchange cost some 8 ns a slot on one TPU v5e whatever the
+#: row (PERF.md section 7), so halving 1 Mi receive slots saves a few
+#: ms of gathers an execution, which a second compile of the stage
+#: (minutes cold, seconds from the compile cache) does not repay in a
+#: process's life; Q5 at SF10 receives 32 Mi slots of LINEITEM a shard
+EXCHANGE_SHRINK_MIN_SLOTS = 1 << 20
+
 
 def _filter_kept(metrics: Dict, tag: str) -> int:
     """The rows runtime filter `tag` kept, from its two counts."""
@@ -1913,6 +1924,9 @@ class QueryExecution:
             self._collect_caps(root, converged)
             if mesh is None and adaptive:
                 self._learn_filter_caps(root, metrics, converged)
+            elif adaptive:
+                self._learn_exchange_caps(root, metrics, converged,
+                                          int(mesh.devices.size))
             if converged:
                 store = self.session._aqe_caps
                 store.setdefault(aqe_key, {}).update(converged)
@@ -2051,18 +2065,17 @@ class QueryExecution:
                              ("rtf_build_indices_", "rtf_build")):
             _tag_attr(disp, host, prefix, attr)
         rtf_caps = sorted((f.tag, f.out_cap)
-                          for f in self._runtime_filters(root)
+                          for f in self._nodes(root, P.RuntimeFilterExec)
                           if f.out_cap is not None)
         if rtf_caps:
             disp.attrs["rtf_caps"] = ",".join(
                 f"{tag}={cap}" for tag, cap in rtf_caps)
 
     @staticmethod
-    def _runtime_filters(root: P.PhysicalPlan
-                         ) -> List[P.RuntimeFilterExec]:
-        """`root`'s runtime filters, each once (creation chains are
-        shared under them: the tree is a DAG)."""
-        found: Dict[int, P.RuntimeFilterExec] = {}
+    def _nodes(root: P.PhysicalPlan, kind: type) -> List[P.PhysicalPlan]:
+        """`root`'s operators of `kind`, each once (runtime filters'
+        creation chains are shared under them: the tree is a DAG)."""
+        found: List[P.PhysicalPlan] = []
         seen = set()
 
         def walk(node):
@@ -2071,11 +2084,11 @@ class QueryExecution:
             seen.add(id(node))
             for c in node.children:
                 walk(c)
-            if isinstance(node, P.RuntimeFilterExec):
-                found[id(node)] = node
+            if isinstance(node, kind):
+                found.append(node)
 
         walk(root)
-        return list(found.values())
+        return found
 
     def _learn_filter_caps(self, root: P.PhysicalPlan, metrics: Dict,
                            converged: Dict[str, int]) -> None:
@@ -2090,7 +2103,7 @@ class QueryExecution:
         after that the capacity only grows (`rtf_overflow_<tag>`)."""
         from ..columnar import bucket_capacity
         host = self._stage_host()
-        for node in self._runtime_filters(root):
+        for node in self._nodes(root, P.RuntimeFilterExec):
             # the probe's slots, which a masked filter hands on: from
             # the host's record of the trace (none: nothing is learned)
             slots = host.get(f"rtf_slots_{node.tag}")
@@ -2101,6 +2114,44 @@ class QueryExecution:
             if slots >= FILTER_COMPACT_MIN_SLOTS \
                     and cap * FILTER_COMPACT_SHRINK <= slots:
                 converged[f"rtf:{node.tag}"] = cap
+
+    def _learn_exchange_caps(self, root: P.PhysicalPlan, metrics: Dict,
+                             converged: Dict[str, int], n: int) -> None:
+        """After a converged attempt over a mesh of `n`: a hash or range
+        exchange still on its seeded block (2x a uniform spread of its
+        input) whose fullest bucket, `exch_max_<tag>` in the stats
+        channel `dispatch.sync` has pulled, fits half of it gets the
+        block that bucket fills among the converged capacities,
+        `exch:<tag>`, when a shard receives EXCHANGE_SHRINK_MIN_SLOTS
+        slots or more. This execution's result stands; the next
+        execution of the same text on the same data applies the block
+        and compiles the smaller stage once, and after that the block
+        only grows (`exch_overflow_<tag>`). Each block learned counts
+        in `exchange_blocks_learned`, and the stage that ran the seeded
+        blocks leaves the stage cache."""
+        from ..columnar import bucket_capacity
+        host = self._stage_host()
+        learned = 0
+        for node in self._nodes(root, P.ExchangeExec):
+            # the block it ran with, from the host's record of the trace
+            # (none: a broadcast, or an exchange the trace never reached)
+            block = host.get(f"exch_block_{node.tag}")
+            fullest = metrics.get(f"exch_max_{node.tag}")
+            if node.block_cap is not None or block is None \
+                    or fullest is None:
+                continue
+            cap = bucket_capacity(max(int(fullest), 8))
+            if n * block >= EXCHANGE_SHRINK_MIN_SLOTS and 2 * cap <= block:
+                converged[f"exch:{node.tag}"] = cap
+                learned += 1
+        if learned:
+            self.session.metrics.counter("exchange_blocks_learned").inc(
+                learned)
+            # the next execution runs the learned blocks: this stage's
+            # program, kept loaded, would hold its code in the chip's
+            # memory beside the learned one's for nothing (on a TPU a
+            # loaded program's code counts in `bytes_in_use`)
+            self.session._stage_cache.pop(self._last_stage_key, None)
 
     def _stage_host(self) -> Dict[str, object]:
         """The host's record of the stage last compiled or found."""

@@ -113,7 +113,8 @@ METRIC_PREFIXES = (
     "exchange_",       # exchange_rows / exchange_bytes /
                        # exchange_slot_bytes: the sums of a mesh
                        # stage's exch_rows_* / exch_bytes_* /
-                       # exch_slot_bytes_*
+                       # exch_slot_bytes_*; exchange_blocks_learned:
+                       # the blocks learned from their fullest bucket
     # what a stage's joins did (executor._note_joins after
     # `dispatch.sync`, from the stats channel it pulled, whatever the
     # conf): REGISTRY counter, listed for namespace closure. The

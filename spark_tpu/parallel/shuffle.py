@@ -141,9 +141,12 @@ def exchange_hash(batch: Batch, key_names: Sequence[str], ctx,
     at mesh 8, an OOM at any serious scale. The default now seeds
     2*ceil(L/n) (2x a uniform hash spread, the `MapOutputTracker`-style
     size assumption); the actual per-bucket max is surfaced as the
-    `exch_max_<tag>` metric and an `exch_overflow_<tag>` flag, and the
-    executor's stats->re-plan loop re-jits with a sufficient capacity when
-    skew overflows it — the AQE pattern joins already use."""
+    `exch_max_<tag>` metric and an `exch_overflow_<tag>` flag. The
+    executor learns the block from that max once an execution has
+    converged (a large seeded block the fullest bucket fits at half or
+    less: `CoalesceShufflePartitions`' move for a static shape), and its
+    stats->re-plan loop re-jits with a sufficient capacity when skew
+    overflows it — the AQE pattern joins already use."""
     n = ctx.n_shards
     L = batch.capacity
     if block_cap is None:
@@ -233,7 +236,8 @@ def exchange_range(batch: Batch, orders, ctx,
     globally sorted result — no shard ever materializes the full dataset
     (the round-2 design all_gather'ed everything to every shard).
     Sampling skew only unbalances bucket sizes; the exch_overflow retry
-    loop keeps it correct."""
+    loop keeps it correct. `block_cap` is seeded, learned and grown as
+    `exchange_hash`'s is."""
     from ..execution.sort import sort_operands
     n = ctx.n_shards
     axis = ctx.axis_name

@@ -1939,7 +1939,9 @@ class ExchangeExec(UnaryExec):
         self.children = (child,)
         self.partitioning = partitioning
         #: per-(src,dst) receive block size; None = seeded from the input
-        #: capacity (2x uniform spread), grown by the executor on overflow
+        #: capacity (2x uniform spread). The executor learns it from the
+        #: fullest bucket a converged execution filled (a large block
+        #: that would at least halve) and grows it on overflow
         self.block_cap: Optional[int] = None
         self.tag = "e0"
 

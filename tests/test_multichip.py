@@ -614,3 +614,63 @@ def test_a_filter_built_under_a_mesh_ors_staging_bytes_then_packs():
     after = text[text.index(staged):]
     assert "shift_left" in after and "reduce_sum" in after
     assert f"u32[{want.shape[0]}] = pmax" not in text
+
+
+@pytest.mark.parametrize("kept", ["every row", "a quarter"])
+def test_an_exchange_learns_its_block_only_where_it_halves(monkeypatch,
+                                                           kept):
+    """A shuffle join of full shards: each (source, destination) bucket
+    takes a little over half the seeded block (2x a uniform spread), so
+    no block would halve and none is learned; with a quarter of the
+    probe's rows kept, the probe's exchange learns the block its
+    fullest bucket fills and the next execution runs it. Either way the
+    answers are the single device's."""
+    from spark_tpu import Conf, SparkTpuSession
+    from spark_tpu.columnar import bucket_capacity
+    from spark_tpu.execution import executor
+    monkeypatch.setattr(executor, "EXCHANGE_SHRINK_MIN_SLOTS", 1)
+    n, rows = 4, 8192
+    rs = np.random.RandomState(11)
+    left = pd.DataFrame({"k": rs.randint(0, 1 << 20, rows).astype(np.int64),
+                         "lv": np.arange(rows, dtype=np.int64)})
+    right = pd.DataFrame({"k": left["k"].values[::2],
+                          "rv": np.arange(rows // 2, dtype=np.int64)})
+    session = SparkTpuSession(
+        conf=Conf().set(MESH_KEY, n)
+        .set("spark_tpu.sql.autoBroadcastJoinThreshold", 0)
+        .set("spark_tpu.execution.meshFallback.enabled", False),
+        register_active=False)
+    session.register_table("l", session.create_dataframe(left, "l"))
+    session.register_table("r", session.create_dataframe(right, "r"))
+    where = "" if kept == "every row" else " WHERE l.lv % 4 = 0"
+    text = f"SELECT l.lv, r.rv FROM l JOIN r ON l.k = r.k{where}"
+    want = left.merge(right, on="k")
+    if where:
+        want = want[want["lv"] % 4 == 0]
+    want = want[["lv", "rv"]].sort_values(["lv", "rv"]) \
+        .reset_index(drop=True)
+
+    def execute():
+        qe = session.sql(text)._qe()
+        got = qe.collect().to_pandas().sort_values(["lv", "rv"]) \
+            .reset_index(drop=True)
+        pd.testing.assert_frame_equal(got, want, check_dtype=False)
+        dispatch = [s for s in qe.spans.spans if s.name == "dispatch"][-1]
+        return qe, {t: int(b) for t, b in (
+            kv.split("=") for kv in dispatch.attrs["exch_blocks"].split(","))}
+
+    first, seeded = execute()
+    assert len(seeded) == 2, seeded
+    fills = {t: bucket_capacity(max(first.last_metrics[f"exch_max_{t}"], 8))
+             for t in seeded}
+    learned = {k[len("exch:"):]: v for caps in session._aqe_caps.values()
+               for k, v in caps.items() if k.startswith("exch:")}
+    assert learned == {t: fills[t] for t in seeded
+                       if 2 * fills[t] <= seeded[t]}
+    assert len(learned) == (0 if kept == "every row" else 1), \
+        (seeded, fills)
+    # a stage whose blocks are learned leaves the cache, else it stays
+    assert (first._last_stage_key in session._stage_cache) \
+        == (not learned)
+    _second, blocks = execute()
+    assert blocks == {**seeded, **learned}

@@ -247,3 +247,126 @@ def test_the_counters_are_the_whole_meshs(tables, at_sf10):
                 attrs["join_strategies"].split(",")) == {
         "j0": "broadcast", "j1": "broadcast", "j2": "shuffle",
         "j3": "shuffle", "j4": "broadcast"}
+
+
+def _blocks(qe):
+    """{tag: block} of the last `dispatch` span's `exch_blocks`."""
+    dispatch = [s for s in qe.spans.spans if s.name == "dispatch"][-1]
+    return {t: int(b) for t, b in (kv.split("=") for kv in
+                                   dispatch.attrs["exch_blocks"].split(","))}
+
+
+def _learned(session):
+    """{tag: block} of the exchange blocks the capacity store holds."""
+    return {k[len("exch:"):]: v for caps in session._aqe_caps.values()
+            for k, v in caps.items() if k.startswith("exch:")}
+
+
+def _executed(session, text, tables):
+    """One execution of `text`: its record, its counters' growth, and
+    whether its answer is the reference's."""
+    before = session.metrics.snapshot()["counters"]
+    qe = session.sql(text)._qe()
+    answer = qe.collect().to_pandas()
+    after = session.metrics.snapshot()["counters"]
+    grown = {k: after[k] - before.get(k, 0) for k in after}
+    verdict = _verdict([{"status": "ok", "answer": {
+        c: compare.as_served(answer[c].tolist()) for c in answer}}], tables)
+    assert verdict["correct"], verdict
+    return qe, grown
+
+
+def _fills(qe):
+    """{tag: the block its fullest bucket fills} of every exchange."""
+    from spark_tpu.columnar import bucket_capacity
+    return {t: bucket_capacity(max(qe.last_metrics[f"exch_max_{t}"], 8))
+            for t in _blocks(qe)}
+
+
+def test_a_converged_mesh_learns_each_large_exchanges_block(
+        tables, at_sf10, monkeypatch):
+    """Under the cell's plan, every exchange over the threshold: the
+    first execution runs the seeded blocks and stores for each whose
+    fullest bucket fits half of it the block that bucket fills,
+    counting each in `exchange_blocks_learned`, and drops the seeded
+    stage; the second compiles the stage with those blocks once, the
+    third compiles nothing, and every answer is the reference's."""
+    from spark_tpu.execution import executor
+    monkeypatch.setattr(executor, "EXCHANGE_SHRINK_MIN_SLOTS", 1)
+    session, text = _session(_register(tables), 4), _text("q5")
+    first, grown = _executed(session, text, tables)
+    seeded, fills = _blocks(first), _fills(first)
+    want = {t: fills[t] for t in seeded if 2 * fills[t] <= seeded[t]}
+    assert "e0" in want and len(want) >= 2, (seeded, fills)
+    assert _learned(session) == want
+    assert grown["compile_cache_misses"] == 1
+    assert grown["exchange_blocks_learned"] == len(want)
+    # the seeded stage is not kept: no later execution runs it
+    assert first._last_stage_key not in session._stage_cache
+
+    second, grown = _executed(session, text, tables)
+    assert grown["compile_cache_misses"] == 1
+    assert grown.get("exchange_blocks_learned", 0) == 0
+    assert second._last_stage_key in session._stage_cache
+    assert _blocks(second) == {**seeded, **want}
+    assert all(block >= second.last_metrics[f"exch_max_{t}"]
+               for t, block in _blocks(second).items())
+    # the same rows cross the mesh in fewer slots
+    m1, m2 = first.last_metrics, second.last_metrics
+    assert {k: v for k, v in m2.items() if k.startswith("exch_rows_")} \
+        == {k: v for k, v in m1.items() if k.startswith("exch_rows_")}
+    assert sum(v for k, v in m2.items() if k.startswith("exch_slot_")) \
+        < sum(v for k, v in m1.items() if k.startswith("exch_slot_"))
+
+    third, grown = _executed(session, text, tables)
+    assert grown["compile_cache_misses"] == 0
+    assert grown.get("exchange_blocks_learned", 0) == 0
+    assert _blocks(third) == _blocks(second)
+
+
+def test_an_exchange_under_the_threshold_keeps_its_seeded_block(
+        tables, at_sf10, monkeypatch):
+    """The threshold at LINEITEM's receive buffer: its exchange learns,
+    the smaller ones keep the blocks they were seeded with."""
+    from spark_tpu.execution import executor
+    session, text = _session(_register(tables), 4), _text("q5")
+    monkeypatch.setattr(executor, "EXCHANGE_SHRINK_MIN_SLOTS", 1 << 40)
+    first, grown = _executed(session, text, tables)
+    assert _learned(session) == {}
+    assert grown.get("exchange_blocks_learned", 0) == 0
+    seeded, rows = _blocks(first), first.last_metrics
+    widest = max(seeded, key=lambda t: rows[f"exch_rows_{t}"])
+    monkeypatch.setattr(executor, "EXCHANGE_SHRINK_MIN_SLOTS",
+                        4 * seeded[widest])
+    # a fresh session: the store learns from a first execution alone
+    session = _session(_register(tables), 4)
+    _executed(session, text, tables)
+    assert _learned(session) == {widest: _fills(first)[widest]}
+    second, grown = _executed(session, text, tables)
+    assert len(seeded) > 1
+    assert _blocks(second) == {**seeded, widest: _fills(first)[widest]}
+
+
+def test_a_learned_block_too_small_grows_back_on_overflow(
+        tables, at_sf10, monkeypatch):
+    """A learned block below the rows that arrive: the exchange
+    overflows, the retry grows the block to what its fullest bucket
+    fills, and the answer is the reference's."""
+    from spark_tpu.execution import executor
+    monkeypatch.setattr(executor, "EXCHANGE_SHRINK_MIN_SLOTS", 1)
+    session, text = _session(_register(tables), 4), _text("q5")
+    first, _grown = _executed(session, text, tables)
+    fills = _fills(first)
+    widest = max(fills, key=lambda t: first.last_metrics[f"exch_rows_{t}"])
+    (caps,) = session._aqe_caps.values()
+    caps[f"exch:{widest}"] = fills[widest] // 4
+    second, grown = _executed(session, text, tables)
+    dispatches = [s for s in second.spans.spans if s.name == "dispatch"]
+    assert len(dispatches) == 2
+    assert second.last_metrics[f"exch_max_{widest}"] > fills[widest] // 4
+    assert _blocks(second)[widest] == fills[widest]
+    assert _learned(session)[widest] == fills[widest]
+    assert grown.get("exchange_blocks_learned", 0) == 0
+    # grown, not re-planned: the joins are the cell's
+    assert not second._join_overrides
+    assert _joins(second.executed_plan.tree_string()) == CELL_JOINS
