@@ -83,7 +83,7 @@ SERVED = ("Q1", "Q6", "Q3", "Q5")
 MESH_QUERIES = ("Q3", "Q5")
 
 #: reference AggregateBenchmark, "linear keys": range(20 << 22) into
-#: 65,536 groups (bench.py builds the same DataFrame)
+#: 65,536 groups
 AGG_ROWS = 20 << 22
 AGG_GROUPS = 65536
 

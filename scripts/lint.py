@@ -1,11 +1,10 @@
 #!/usr/bin/env python
 """Unified source-lint runner (spark_tpu/analysis/lints framework).
 
-The generalization of scripts/metrics_lint.py: one registry of AST
-passes over the repository — metric prefixes, conf-key registration,
-fault-site wiring, tracer-leak shapes, the concurrency analyzer's
-guarded-by and lock-order passes — run together from preflight and
-tests/test_analysis.py.
+One registry of AST passes over the repository — metric prefixes,
+conf-key registration, fault-site wiring, tracer-leak shapes, the
+concurrency analyzer's guarded-by and lock-order passes — run together
+from preflight and tests/test_analysis.py.
 
 Usage:
     scripts/lint.py --all            # every registered pass

@@ -1,123 +1,27 @@
 #!/usr/bin/env bash
-# Preflight gate: run before committing/snapshotting so the round-5
-# class of "snapshot committed with a broken mesh path" cannot recur.
-# Any stage failing exits this script NONZERO (set -e + explicit rc
-# checks), enforcing the ROADMAP pre-snapshot gate.
-#
-# Eighteen stages, all mandatory:
-#   1. full tier-1 pytest suite (virtual 8-device CPU mesh via conftest)
+# Preflight gate: run before committing. Any stage failing exits this
+# script nonzero (set -e + explicit rc checks). Four stages:
+#   1. the tier-1 test suite (`-m 'not slow'`, six xdist workers, one
+#      file a worker, a virtual 8-device CPU mesh via tests/conftest.py)
 #   2. dryrun_multichip(8): jit + run the distributed collectives path
-#      end-to-end with single-chip parity checks
-#   3. bench smoke + perf-regression gate: the headline aggregate
-#      shape at a reduced size (bench entrypoint known-runnable before
-#      the driver spends a TPU slot), then scripts/perf_gate.py runs
-#      the TPC-H Q1/Q3 smoke and FAILS on >25% tpch_*_ms regression
-#      against the recorded platform baseline (PERF_BASELINE.json,
-#      seeded from the last good BENCH_*.json on TPU)
-#   4. chaos smoke: one injected OOM + one injected transient against
-#      TPC-H Q1 with golden parity — the failure-recovery ladder
-#      (executor taxonomy + fault injection) must survive end-to-end —
-#      plus one mid-stream `stream_chunk` fault against chunked Q1
-#      asserting a `chunk_retry` recovery action (partial-progress
-#      recovery replays ONE chunk, never restarts the stream) with
-#      golden parity
-#   5. observability + analysis smoke: TPC-H Q1/Q3 with eventLog +
-#      trace + Prometheus sinks on AND the pre-compile static analyzer
-#      explicitly enabled (enabled=true, non-strict); golden parity
-#      must hold, the event line (spans + XLA cost fields), the Chrome
-#      trace JSON and the metrics exposition file must all exist and
-#      parse, and the analyzer must report ZERO findings on the TPC-H
-#      plans — observability and analysis must never be the thing that
-#      breaks (or noises up) a query. Then the observability-overhead
-#      gate (Q1 warm, everything ON vs OFF, must stay ≤10% /
-#      `obs_overhead_ms`), and scripts/events_tool.py validates the
-#      written event log against the versioned schema
-#   6. source lint: every registered pass of the unified lint framework
-#      (scripts/lint.py --all — metric prefixes, conf-key
-#      registration, fault-site wiring, tracer-leak shapes; absorbs
-#      the former metrics-lint stage)
-#   7. service smoke: start the SQL service (spark_tpu/service/) on an
+#      end to end with single-chip parity checks
+#   3. source lint: every registered pass of the lint framework
+#      (scripts/lint.py --all: metric prefixes, conf-key registration,
+#      fault-site wiring, tracer-leak shapes, the concurrency passes,
+#      the rule registry)
+#   4. service smoke: start the SQL service (spark_tpu/service/) on an
 #      ephemeral port, POST TPC-H Q1 over HTTP, assert golden parity
-#      of the JSON result, that GET /metrics parses as Prometheus
-#      text exposition, that the live history API serves the query
+#      of the JSON result, that GET /metrics parses as Prometheus text
+#      exposition, that the live history API serves the query
 #      (GET /queries listing + /queries/<id>/timeline with spans and
 #      stage peak-HBM + /queries/<id>/plan), and a clean shutdown
-#   8. join-kernel + ingest parity smoke: TPC-H Q3+Q5 byte-identical
-#      across join.kernelMode hash vs sort (the hash path PROVEN to
-#      have run via join_table_slots_*) and ingest.prefetch on vs off,
-#      plus a reduced-size join_microbench section run
-#   9. TPC-DS smoke: SF0.01 datagen + two tranche queries (q3 + the
-#      6-way q19) at pandas golden parity, and the cost-based join
-#      reorder proven live — cbo.joinReorder on/off byte-identical
-#      with the reorder decisions actually changing q19's join order
-#  10. elastic mesh smoke: a fatal mesh fault injected mid-stream on an
-#      8-device virtual mesh must GANG-RESTART (mesh_restart==1, no
-#      single-device fallback), resume from the last checkpoint with
-#      at most checkpoint.everyChunks chunks replayed, and hit TPC-H
-#      Q1 golden parity
-#  11. streaming durability smoke: a file-source stateful streaming
-#      query crashed at the stream_state_commit seam, the query object
-#      discarded, and a FRESH StreamingQuery over the same checkpoint
-#      must recover to output byte-identical to an uninterrupted run
-#      (incremental state store: delta restore), with the
-#      streaming_batches metric and per-batch event records sane
-#  12. concurrency smoke: the guarded-by + lock-order passes in --json
-#      form must report zero violations (machine-readable gate), and a
-#      concurrent service run (2 sessions x 2 queries, prefetch on)
-#      under the runtime lockwatch must show an observed lock
-#      acquisition order consistent with the static registry ranking,
-#      golden parity per query, and no prefetch daemon outliving its
-#      query
-#  13. compile-cache smoke: cold TPC-H Q1 in-process with the
-#      persistent AOT compile cache on, then Q1 in a FRESH subprocess
-#      over the same cache dir asserting compile_cache_disk_hits >= 1
-#      with ZERO disk misses (no backend recompiles of cached shapes)
-#      and byte-identical results, plus a corrupted-entry run proving
-#      the compile_cache_corrupt fallback never fails the query
-#  14. cancellation smoke: start a chunked TPC-H Q3 via the service,
-#      DELETE it mid-stream, and assert the structured QUERY_CANCELLED
-#      record, no leaked prefetch daemon (assert_no_thread_leak), the
-#      arbiter lease pool drained to idle, and an immediate identical
-#      re-run at golden parity — the query-lifecycle hard guarantee
-#      (execution/lifecycle.py) end to end over HTTP
-#  15. python-UDF worker smoke: the out-of-process Arrow lane
-#      (spark_tpu.sql.udf.mode=worker) must match the in-process lane
-#      byte-for-byte across scalar + pandas UDFs, an injected
-#      udf_batch:fatal SIGKILL mid-batch must replay EXACTLY one
-#      batch (rec_chunks_replayed delta 1) at parity, and after pool
-#      shutdown ZERO worker children may survive
-#  16. unattended-streaming smoke: a socket FrameProducer feeds a
-#      stateful network-source query under the supervised trigger loop
-#      (start(trigger_ms=50)); the producer connection is killed
-#      mid-stream and the consumer must reconnect exactly once
-#      (streaming_reconnects delta 1) with zero loss/duplication; an
-#      injected trigger_tick:fatal must park the query in structured
-#      FAILED status; a FRESH query over the same checkpoint must
-#      recover byte-identical to an uninterrupted twin; and after a
-#      clean stop ZERO spark-tpu-stream-trigger threads may survive
-#  17. status-store + flight-recorder smoke: GET /status on a live
-#      service must parse with latency p50/p95/p99 present after one
-#      query, /status/timeseries must carry heartbeat-sampled series,
-#      and a query failed by an injected `stage_run:fatal` must leave
-#      a flight-recorder bundle whose spans + conf snapshot + thread
-#      stacks all parse — the crash-time diagnostics must exist
-#      exactly when a query dies. (The ≤10% observability-overhead
-#      gate in stage 5 already measures with the status store and
-#      flight recorder ON: bench.py's obs_conf_on includes both.)
-#  18. plan-integrity smoke: the rule-registry lint (RL100, part of
-#      stage 6's scripts/lint.py --all) green, a 64-seed differential
-#      fuzz campaign (scripts/plan_fuzz.py: optimizer-on vs -off under
-#      planChangeValidation=full plus one rule ablation per seed, all
-#      byte-identical with stable stage keys), and TPC-H Q3 under
-#      validation=full at golden parity with the schema-v7 rule_trace
-#      record present in the event log. (The stage-5 overhead gate
-#      already measures validation=full in obs_conf_on, so the
-#      verifier itself is held to the ≤10% budget.)
+#
+# A timing taken on a CPU is no evidence of speed: the benchmark
+# (BENCHMARK.json, benchmark/) measures on the chip.
 #
 # Usage: scripts/preflight.sh [--fast]
-#   --fast skips the full pytest suite (stages 2-19 still run) for
-#   quick inner-loop checks; CI and end-of-round runs must use the
-#   default.
+#   --fast skips the tier-1 suite (stages 2-4 still run) for quick
+#   inner-loop checks; CI and end-of-round runs must use the default.
 
 set -euo pipefail
 cd "$(dirname "$0")/.."
@@ -128,15 +32,14 @@ FAST=0
 echo "== preflight: $(date -u +%FT%TZ) =="
 
 if [ "$FAST" -eq 0 ]; then
-    echo "-- stage 1/19: tier-1 test suite --"
-    rm -f /tmp/_preflight_t1.log
+    echo "-- stage 1/4: tier-1 test suite --"
+    log="${TMPDIR:-/tmp}/_preflight_t1.log"
+    rm -f "$log"
     set +e  # keep control on pytest failure so the diagnostic prints
-    # budget sized for the grown suite (773 tests, ~15min on one CPU
-    # mesh) — the old 870s cap was tripping on wall clock, not failures
-    timeout -k 10 1500 env JAX_PLATFORMS=cpu \
+    timeout -k 10 1470 env JAX_PLATFORMS=cpu ALLOW_MULTIPLE_LIBTPU_LOAD=1 \
         python -m pytest tests/ -q -m 'not slow' \
         --continue-on-collection-errors -p no:cacheprovider \
-        -p no:xdist -p no:randomly 2>&1 | tee /tmp/_preflight_t1.log
+        -p xdist -n 6 --dist loadfile -p no:randomly 2>&1 | tee "$log"
     rc=${PIPESTATUS[0]}
     set -e
     if [ "$rc" -ne 0 ]; then
@@ -144,198 +47,19 @@ if [ "$FAST" -eq 0 ]; then
         exit "$rc"
     fi
 else
-    echo "-- stage 1/19: SKIPPED (--fast) --"
+    echo "-- stage 1/4: SKIPPED (--fast) --"
 fi
 
-echo "-- stage 2/19: dryrun_multichip(8) --"
+echo "-- stage 2/4: dryrun_multichip(8) --"
 env JAX_PLATFORMS=cpu python -c "
 import __graft_entry__ as g
 g.dryrun_multichip(8)
 "
 
-echo "-- stage 3/19: bench smoke --"
-# Reduced-size smoke of the bench entrypoint: section harness, JSON
-# emission and the aggregate hot path must run end-to-end on CPU.
-env JAX_PLATFORMS=cpu python - <<'EOF'
-import json
-import bench
-from spark_tpu import SparkTpuSession
-from spark_tpu import functions as F
-from spark_tpu.functions import col
-
-spark = SparkTpuSession.builder().get_or_create()
-
-
-def smoke():
-    df = (spark.range(1 << 16)
-          .select(F.pmod(col("id"), 256).alias("k"))
-          .group_by(col("k")).agg(F.sum(col("k")).alias("s")))
-    pdf = df.to_pandas()
-    assert len(pdf) == 256, len(pdf)
-    return {"groups": int(len(pdf))}
-
-
-out = bench._run_section("bench_smoke", smoke, 300)
-assert out.get("groups") == 256, out
-print(json.dumps({"preflight_bench_smoke": "ok"}))
-EOF
-
-# perf-regression gate: TPC-H Q1/Q3 smoke vs the recorded platform
-# baseline; >25% tpch_*_ms regression fails the preflight (recalibrate
-# deliberate changes with scripts/perf_gate.py --update)
-env JAX_PLATFORMS=cpu python scripts/perf_gate.py
-
-echo "-- stage 4/19: chaos smoke --"
-# One injected RESOURCE_EXHAUSTED (rung 1: device-cache evict + retry)
-# and one injected transient UNAVAILABLE (backoff retry), then Q1 must
-# still hit golden parity with both recoveries visible in fault_summary.
-env JAX_PLATFORMS=cpu python - <<'EOF'
-import json
-import tempfile
-import warnings
-
-from spark_tpu import SparkTpuSession
-from spark_tpu.testing import faults
-from spark_tpu.tpch import golden as G
-from spark_tpu.tpch import queries as Q
-from spark_tpu.tpch.datagen import write_parquet
-
-spark = SparkTpuSession.builder().get_or_create()
-spark.conf.set("spark_tpu.execution.backoffMs", 1)
-path = tempfile.mkdtemp(prefix="preflight_tpch_") + "/sf"
-write_parquet(path, 0.001)
-Q.register_tables(spark, path)
-
-with warnings.catch_warnings():
-    warnings.simplefilter("ignore")  # the retry warnings are the point
-    with faults.inject(
-            spark.conf,
-            "stage_run:resource_exhausted:1,stage_run:unavailable:2"):
-        qe = Q.QUERIES["q1"](spark)._qe()
-        got = G.normalize_decimals(qe.collect().to_pandas())
-assert qe.fault_summary.get("oom_cache_evict", 0) >= 1, qe.fault_summary
-assert qe.fault_summary.get("transient_retry", 0) >= 1, qe.fault_summary
-G.compare(got.reset_index(drop=True), G.GOLDEN["q1"](path))
-
-# mid-stream fault: partial-progress recovery (execution/recovery.py)
-# must replay ONE chunk (chunk_retry) — never surface to the
-# whole-query loop and restart the stream (no transient_retry)
-spark.conf.set("spark_tpu.sql.execution.streamingChunkRows", 1024)
-spark.conf.set("spark_tpu.sql.io.deviceCacheBytes", 0)
-spark._stage_cache.clear()
-with warnings.catch_warnings():
-    warnings.simplefilter("ignore")
-    with faults.inject(spark.conf, "stream_chunk:unavailable:2") as fp:
-        qe2 = Q.QUERIES["q1"](spark)._qe()
-        got2 = G.normalize_decimals(qe2.collect().to_pandas())
-assert fp.fired_log, "stream_chunk never fired — smoke is vacuous"
-assert qe2.fault_summary.get("chunk_retry", 0) == 1, qe2.fault_summary
-assert "transient_retry" not in qe2.fault_summary, qe2.fault_summary
-G.compare(got2.reset_index(drop=True), G.GOLDEN["q1"](path))
-print(json.dumps({"preflight_chaos_smoke": "ok",
-                  "fault_summary": {k: v for k, v in
-                                    qe.fault_summary.items()},
-                  "stream_fault_summary": {k: v for k, v in
-                                           qe2.fault_summary.items()}}))
-EOF
-
-echo "-- stage 5/19: observability + analysis smoke --"
-env JAX_PLATFORMS=cpu python - <<'EOF2'
-import json
-import os
-import tempfile
-
-from spark_tpu import SparkTpuSession
-from spark_tpu.observability.metrics import parse_prometheus
-from spark_tpu.tpch import golden as G
-from spark_tpu.tpch import queries as Q
-from spark_tpu.tpch.datagen import write_parquet
-
-spark = SparkTpuSession.builder().get_or_create()
-base = tempfile.mkdtemp(prefix="preflight_obs_")
-spark.conf.set("spark_tpu.sql.eventLog.dir", base + "/events")
-spark.conf.set("spark_tpu.sql.trace.dir", base + "/traces")
-spark.conf.set("spark_tpu.sql.metrics.sink", "jsonl,prometheus")
-spark.conf.set("spark_tpu.sql.metrics.dir", base + "/metrics")
-# pre-compile static analyzer explicitly on (non-strict): Q1/Q3 golden
-# parity must hold end to end and the analyzer must stay at zero
-# findings on the TPC-H plans (noise gate)
-spark.conf.set("spark_tpu.sql.analysis.enabled", "true")
-spark.conf.set("spark_tpu.sql.analysis.strict", "false")
-
-path = base + "/sf"
-write_parquet(path, 0.001)
-Q.register_tables(spark, path)
-qe = Q.QUERIES["q1"](spark)._qe()
-got = G.normalize_decimals(qe.collect().to_pandas())
-G.compare(got.reset_index(drop=True), G.GOLDEN["q1"](path))
-assert qe.analysis_findings == [], qe.analysis_findings
-
-qe3 = Q.QUERIES["q3"](spark)._qe()
-got3 = G.normalize_decimals(qe3.collect().to_pandas())
-G.compare(got3.reset_index(drop=True), G.GOLDEN["q3"](path))
-assert qe3.analysis_findings == [], qe3.analysis_findings
-
-# (a) event line with spans + XLA cost fields
-from spark_tpu import history
-events = history.read_event_log(base + "/events")
-assert len(events) >= 1, events
-stages = history.compile_summary(events)
-assert len(stages) >= 1 and stages["flops"].notna().any(), stages
-assert len(history.stage_summary(events)) >= 3
-assert len(history.hbm_summary(events)) >= 1
-
-# (b) Chrome trace parses and has complete events
-traces = [f for f in os.listdir(base + "/traces")
-          if f.endswith(".trace.json")]
-assert traces, os.listdir(base + "/traces")
-t = json.load(open(os.path.join(base + "/traces", traces[-1])))
-assert t["traceEvents"] and any(e.get("ph") == "X"
-                                for e in t["traceEvents"])
-
-# (c) Prometheus exposition scrape-parses
-prom = parse_prometheus(base + "/metrics/metrics.prom")
-assert prom.get("spark_tpu_queries_total", 0) >= 1, prom
-
-# (d) observability-overhead gate: Q1 warm best-of-5 with every sink
-# + xlaCost + shard spans ON vs everything OFF must stay within 10%
-# (a tiny absolute floor absorbs scheduler jitter on CI boxes). The
-# ON/OFF conf sets and the timed runner are bench.py's — ONE
-# definition, so this gate and the BENCH obs_overhead sidecar can
-# never measure different things. Measured at SF0.01, not the smoke's
-# SF0.001: the per-query fixed cost (event line + trace file + prom
-# rewrite, ~3ms) would read as ~30% of a 10ms query — the gate must
-# measure the RATIO at a query size where the ratio is meaningful.
-import bench
-
-path10 = base + "/sf10x"
-write_parquet(path10, 0.01)
-Q.register_tables(spark, path10)
-obs = bench.measure_obs_overhead(
-    spark, lambda: Q.QUERIES["q1"](spark)._qe().collect(),
-    base + "/ovh", best_of=5)
-assert obs["obs_overhead_pct"] <= 10.0 \
-    or obs["obs_overhead_ms"] <= 25.0, (
-    f"observability overhead exceeds the 10% gate: {obs}")
-
-with open("/tmp/_preflight_obs_dir", "w") as f:
-    f.write(base + "/events")
-print(json.dumps({"preflight_observability_smoke": "ok",
-                  "stages": int(len(stages)),
-                  "trace_events": len(t["traceEvents"]),
-                  "obs_overhead_ms": obs["obs_overhead_ms"],
-                  "obs_overhead_pct": obs["obs_overhead_pct"]}))
-EOF2
-
-# event-log schema validation (scripts/events_tool.py): every line the
-# smoke above wrote must parse against the versioned schema
-env JAX_PLATFORMS=cpu python scripts/events_tool.py validate \
-    "$(cat /tmp/_preflight_obs_dir)"
-
-echo "-- stage 6/19: source lint (scripts/lint.py --all) --"
+echo "-- stage 3/4: source lint (scripts/lint.py --all) --"
 env JAX_PLATFORMS=cpu python scripts/lint.py --all
 
-echo "-- stage 7/19: SQL service smoke --"
+echo "-- stage 4/4: SQL service smoke --"
 # Start the concurrent SQL service on an ephemeral port, POST TPC-H Q1
 # over HTTP, check golden parity of the JSON rows, scrape-parse
 # GET /metrics, then shut down cleanly.
@@ -408,1015 +132,5 @@ finally:
 print(json.dumps({"preflight_service_smoke": "ok",
                   "rows": int(resp["row_count"])}))
 EOF3
-
-echo "-- stage 8/19: join-kernel + ingest parity smoke --"
-# Q3+Q5 byte-identical across join.kernelMode hash/sort and
-# ingest.prefetch on/off; the hash path must actually have run (a
-# join_table_slots_* metric) so the parity check can't go vacuous.
-env JAX_PLATFORMS=cpu BENCH_JOIN_PROBE_ROWS=262144 python - <<'EOF4'
-import json
-import tempfile
-
-import pandas as pd
-
-import bench
-from spark_tpu import SparkTpuSession
-from spark_tpu.tpch import golden as G
-from spark_tpu.tpch import queries as Q
-from spark_tpu.tpch.datagen import write_parquet
-
-spark = SparkTpuSession.builder().get_or_create()
-path = tempfile.mkdtemp(prefix="preflight_hj_") + "/sf"
-write_parquet(path, 0.002)
-Q.register_tables(spark, path)
-spark.conf.set("spark_tpu.sql.execution.streamingChunkRows", 4096)
-
-MODE = "spark_tpu.sql.join.kernelMode"
-PREFETCH = "spark_tpu.sql.ingest.prefetch"
-hash_proven = 0
-for qname in ("q3", "q5"):
-    outs = {}
-    for mode, prefetch in (("sort", True), ("hash", True),
-                           ("sort", False), ("hash", False)):
-        spark.conf.set(MODE, mode)
-        spark.conf.set(PREFETCH, prefetch)
-        qe = Q.QUERIES[qname](spark)._qe()
-        outs[(mode, prefetch)] = qe.collect().to_pandas()
-        if mode == "hash":
-            hash_proven += any(k.startswith("join_table_slots_")
-                               for k in qe.last_metrics)
-    base = outs[("sort", True)]
-    # normalize a COPY: normalize_decimals casts in place, and `base`
-    # must stay byte-identical for the cross-config comparisons below
-    got_n = G.normalize_decimals(base.copy()).reset_index(drop=True)
-    want = G.GOLDEN[qname](path)
-    if qname == "q5":  # revenue ties: compare in n_name order
-        got_n = got_n.sort_values("n_name").reset_index(drop=True)
-        want = want.sort_values("n_name").reset_index(drop=True)
-    G.compare(got_n, want)
-    for key, got in outs.items():
-        try:
-            pd.testing.assert_frame_equal(base, got)
-        except AssertionError as e:
-            raise AssertionError(
-                f"{qname} diverged at (kernelMode, prefetch)={key}") from e
-assert hash_proven == 4, f"hash kernel ran {hash_proven}/4 configs"
-mb = bench.bench_join_microbench(spark)
-assert any(k.endswith("_hash_rows_per_sec_M") for k in mb), mb
-print(json.dumps({"preflight_join_kernel_smoke": "ok",
-                  "microbench": mb}))
-EOF4
-
-echo "-- stage 9/19: TPC-DS + join-reorder smoke --"
-# SF0.01 datagen, q3 + q19 golden parity, and the cost-based join
-# reorder proven live: on/off byte-identical with q19's join order
-# demonstrably changed (decision log + differing physical plans).
-env JAX_PLATFORMS=cpu python - <<'EOF5'
-import json
-import tempfile
-
-import pandas as pd
-
-from spark_tpu import SparkTpuSession
-from spark_tpu.tpcds import SQL_QUERIES, register_tables
-from spark_tpu.tpcds import golden as G
-from spark_tpu.tpcds.datagen import write_parquet
-
-spark = SparkTpuSession.builder().get_or_create()
-path = tempfile.mkdtemp(prefix="preflight_tpcds_") + "/sf"
-write_parquet(path, 0.01)
-register_tables(spark, path)
-
-CBO = "spark_tpu.sql.cbo.joinReorder"
-reordered = 0
-for qname in ("q3", "q19"):
-    spark.conf.set(CBO, True)
-    qe_on = spark.sql(SQL_QUERIES[qname])._qe()
-    on = qe_on.collect().to_pandas()
-    spark.conf.set(CBO, False)
-    qe_off = spark.sql(SQL_QUERIES[qname])._qe()
-    off = qe_off.collect().to_pandas()
-    spark.conf.set(CBO, True)
-    pd.testing.assert_frame_equal(on, off)
-    if any(d.get("kind") == "order"
-           for d in (qe_on.reorder_decisions or [])):
-        reordered += 1
-        assert qe_on.executed_plan.describe() != \
-            qe_off.executed_plan.describe(), qname
-    want = G.GOLDEN[qname](path)
-    got = G.normalize_decimals(on.copy())[list(want.columns)]
-    G.compare(got.reset_index(drop=True), want, float_atol=1e-4)
-assert reordered >= 1, "join reorder never changed an order (vacuous)"
-print(json.dumps({"preflight_tpcds_smoke": "ok",
-                  "reordered_queries": reordered}))
-EOF5
-
-echo "-- stage 10/19: elastic mesh smoke --"
-# A host lost mid-stream (fatal at the 2nd mesh snapshot point) must
-# gang-restart the mesh — NOT degrade to single-device — resume from
-# the chunk-2 checkpoint with a bounded replay, and hit golden parity.
-env JAX_PLATFORMS=cpu \
-    XLA_FLAGS="--xla_force_host_platform_device_count=8" python - <<'EOF6'
-import json
-import tempfile
-import warnings
-
-import jax
-
-jax.config.update("jax_platforms", "cpu")
-
-from spark_tpu import SparkTpuSession
-from spark_tpu.testing import faults
-from spark_tpu.tpch import golden as G
-from spark_tpu.tpch import queries as Q
-from spark_tpu.tpch.datagen import write_parquet
-
-spark = SparkTpuSession.builder().get_or_create()
-path = tempfile.mkdtemp(prefix="preflight_elastic_") + "/sf"
-write_parquet(path, 0.001)
-Q.register_tables(spark, path)
-conf = spark.conf
-conf.set("spark_tpu.execution.backoffMs", 1)
-conf.set("spark_tpu.sql.execution.streamingChunkRows", 1024)
-conf.set("spark_tpu.sql.io.deviceCacheBytes", 0)
-conf.set("spark_tpu.sql.mesh.size", 8)
-conf.set("spark_tpu.execution.checkpoint.everyChunks", 2)
-
-rec0 = spark.metrics.counter("rec_chunks_replayed").value
-with warnings.catch_warnings():
-    warnings.simplefilter("ignore")  # the restart warnings are the point
-    with faults.inject(conf, "mesh_checkpoint:fatal:2") as fp:
-        qe = Q.QUERIES["q1"](spark)._qe()
-        got = G.normalize_decimals(qe.collect().to_pandas())
-assert fp.fired_log, "mesh_checkpoint seam never fired — smoke is vacuous"
-assert qe.fault_summary.get("mesh_restart") == 1, qe.fault_summary
-assert "mesh_fallback" not in qe.fault_summary, qe.fault_summary
-assert qe.fault_summary.get("checkpoint_restore") == 1, qe.fault_summary
-replayed = spark.metrics.counter("rec_chunks_replayed").value - rec0
-assert replayed <= 2, f"replayed {replayed} chunks > everyChunks=2"
-conf.set("spark_tpu.sql.mesh.size", 0)
-G.compare(got.reset_index(drop=True), G.GOLDEN["q1"](path))
-print(json.dumps({"preflight_elastic_smoke": "ok",
-                  "replayed_chunks": int(replayed),
-                  "fault_summary": dict(qe.fault_summary)}))
-EOF6
-
-echo "-- stage 11/19: streaming durability smoke --"
-# File source -> stateful query -> crash at the state-commit seam ->
-# query object discarded -> fresh query over the same checkpoint must
-# recover exactly-once (output byte-identical to an uninterrupted run)
-# with the streaming_* metrics and v4 event records sane.
-env JAX_PLATFORMS=cpu python - <<'EOF7'
-import json
-import os
-import tempfile
-
-import numpy as np
-import pandas as pd
-
-from spark_tpu import SparkTpuSession, history
-from spark_tpu import functions as F
-from spark_tpu.functions import col
-from spark_tpu.testing import faults
-
-spark = SparkTpuSession.builder().get_or_create()
-base = tempfile.mkdtemp(prefix="preflight_stream_")
-spark.conf.set("spark_tpu.sql.eventLog.dir", base + "/events")
-schema = pd.DataFrame({"k": pd.Series([], dtype=np.int64),
-                       "v": pd.Series([], dtype=np.int64)})
-
-
-def setup(tag):
-    src_dir = os.path.join(base, f"src_{tag}")
-    os.makedirs(src_dir, exist_ok=True)
-
-    def feed(i):
-        # batch 0 touches every group (snapshot); later batches touch
-        # 16 of 64 (deltas — the incremental-store steady state)
-        n = 256 if i == 0 else 16
-        pd.DataFrame({"k": np.arange(n, dtype=np.int64),
-                      "v": np.full(n, i + 1, dtype=np.int64)}) \
-            .to_parquet(os.path.join(src_dir, f"b{i:03d}.parquet"))
-
-    def build():
-        src = spark.file_stream(src_dir, schema_df=schema)
-        return (src.to_df()
-                .group_by(F.pmod(col("k"), 64).alias("g"))
-                .agg(F.sum(col("v")).alias("s"), F.count().alias("c"))
-                .write_stream(os.path.join(base, f"ck_{tag}")))
-
-    return feed, build
-
-
-# uninterrupted twin
-feed_u, build_u = setup("clean")
-qu = build_u()
-for i in range(3):
-    feed_u(i)
-    qu.process_available()
-want = qu.latest().sort_values("g").reset_index(drop=True)
-
-# crashed run: batch 0 commits, batch 1 dies AT the state commit
-b0 = spark.metrics.counter("streaming_batches").value
-feed_c, build_c = setup("crash")
-q = build_c()
-feed_c(0)
-q.process_available()
-feed_c(1)
-crashed = False
-with faults.inject(spark.conf, "stream_state_commit:fatal:1") as fp:
-    try:
-        q.process_available()
-    except faults.FaultInjected:
-        crashed = True
-assert crashed and fp.fired_log, "stream_state_commit never fired — smoke is vacuous"
-del q  # the hard crash: only the checkpoint dir survives
-feed_c(2)
-q2 = build_c()
-q2.process_available()
-got = q2.latest().sort_values("g").reset_index(drop=True)
-pd.testing.assert_frame_equal(got, want)
-batches = spark.metrics.counter("streaming_batches").value - b0
-assert batches == 3, batches  # batch 0 + replayed batch 1 + batch 2
-events = history.read_event_log(base + "/events")
-ss = history.streaming_summary(events)
-# 3 clean-run + 3 crash-run committed batches, snapshot at each v0
-assert len(ss) == 6 and set(ss["kind"]) == {"snapshot", "delta"}, ss
-spark.conf.set("spark_tpu.sql.eventLog.dir", "")
-with open("/tmp/_preflight_stream_dir", "w") as f:
-    f.write(base + "/events")
-print(json.dumps({"preflight_streaming_smoke": "ok",
-                  "batches": int(batches),
-                  "kinds": ss["kind"].tolist()}))
-EOF7
-
-# the streaming event lines validate against the versioned schema
-env JAX_PLATFORMS=cpu python scripts/events_tool.py validate \
-    "$(cat /tmp/_preflight_stream_dir)"
-
-echo "-- stage 12/19: concurrency smoke --"
-# (a) the concurrency passes gate machine-readably at zero violations
-env JAX_PLATFORMS=cpu python - <<'EOF8'
-import json
-import subprocess
-import sys
-
-out = subprocess.run(
-    [sys.executable, "scripts/lint.py", "--json", "guarded-by",
-     "lock-order"], capture_output=True, text=True)
-payload = json.loads(out.stdout)
-assert out.returncode == 0 and payload["ok"], payload
-assert payload["violations"] == [], payload["violations"]
-assert any(n.startswith("waiver:") for n in payload["notes"])
-print(json.dumps({"preflight_concurrency_lint": "ok",
-                  "waivers": sum(n.startswith("waiver:")
-                                 for n in payload["notes"])}))
-EOF8
-
-# (b) lockwatch smoke: concurrent service queries with prefetch on —
-# observed lock order must be consistent with the static registry
-# ranking, golden parity per query, no leaked prefetch daemons
-env JAX_PLATFORMS=cpu python - <<'EOF9'
-import json
-import tempfile
-import threading
-
-from spark_tpu import Conf
-from spark_tpu.service.arbiter import install_arbiter
-from spark_tpu.service.server import SqlService
-from spark_tpu.testing.lockwatch import LockWatch
-from spark_tpu.tpch import golden as G
-from spark_tpu.tpch import queries as Q
-from spark_tpu.tpch import sql_queries as SQLQ
-from spark_tpu.tpch.datagen import write_parquet
-
-path = tempfile.mkdtemp(prefix="preflight_lockwatch_") + "/sf"
-write_parquet(path, 0.001)
-conf = Conf()
-conf.set("spark_tpu.service.port", 0)
-conf.set("spark_tpu.service.hbmBudget", 1 << 30)
-conf.set("spark_tpu.sql.execution.streamingChunkRows", 2048)
-conf.set("spark_tpu.sql.io.deviceCacheBytes", 0)
-svc = SqlService(conf,
-                 init_session=lambda s: Q.register_tables(s, path))
-watch = LockWatch()
-try:
-    for name in ("a", "b"):  # warm the pool, then watch it
-        svc.submit(SQLQ.Q1, session=name)
-    watch.install_service(svc)
-    results, errors = [], []
-
-    def run(name):
-        try:
-            for _ in range(2):
-                results.append(svc.submit(SQLQ.Q1, session=name)[1])
-        except Exception as e:  # noqa: BLE001
-            errors.append(repr(e))
-
-    ts = [threading.Thread(target=run, args=(n,)) for n in ("a", "b")]
-    [t.start() for t in ts]
-    [t.join(300) for t in ts]
-    # a wedged worker (the deadlock class this stage exists to catch)
-    # must FAIL here, not pass vacuously and hang interpreter exit
-    assert not any(t.is_alive() for t in ts), "query thread wedged"
-    assert not errors, errors
-    assert len(results) == 4, f"expected 4 results, got {len(results)}"
-    want = G.GOLDEN["q1"](path).reset_index(drop=True)
-    for table in results:
-        got = G.normalize_decimals(table.to_pandas())[list(want.columns)]
-        G.compare(got.reset_index(drop=True), want)
-    edges = watch.edges()
-    assert edges, "no lock nesting observed — smoke is vacuous"
-    watch.assert_order_consistent()
-    watch.assert_no_thread_leak()
-finally:
-    watch.uninstall()
-    svc.stop()
-    install_arbiter(None)
-print(json.dumps({"preflight_lockwatch_smoke": "ok",
-                  "observed_edges": len(edges)}))
-EOF9
-
-echo "-- stage 13/19: compile-cache smoke --"
-# Cold Q1 in-process fills the persistent AOT compile cache; a FRESH
-# subprocess over the same dir must open warm (disk_hits >= 1, ZERO
-# disk misses = no backend recompiles of cached shapes) with
-# byte-identical results; a corrupted entry must fall back to a fresh
-# compile (compile_cache_corrupt) and still hit parity.
-env JAX_PLATFORMS=cpu python - <<'EOF11'
-import json
-import os
-import subprocess
-import sys
-import tempfile
-import warnings
-
-from spark_tpu import SparkTpuSession
-from spark_tpu.tpch import golden as G
-from spark_tpu.tpch import queries as Q
-from spark_tpu.tpch.datagen import write_parquet
-
-base = tempfile.mkdtemp(prefix="preflight_cc_")
-path = base + "/sf"
-cc_dir = base + "/cache"
-write_parquet(path, 0.001)
-
-spark = SparkTpuSession.builder().get_or_create()
-spark.conf.set("spark_tpu.sql.compileCache.enabled", True)
-spark.conf.set("spark_tpu.sql.compileCache.dir", cc_dir)
-Q.register_tables(spark, path)
-
-# (a) cold in-process run: entries + manifest land on disk
-qe = Q.QUERIES["q1"](spark)._qe()
-cold = G.normalize_decimals(qe.collect().to_pandas())
-G.compare(cold.reset_index(drop=True), G.GOLDEN["q1"](path))
-entries = [f for f in os.listdir(cc_dir) if f.startswith("cc-")]
-assert entries, "cold run stored no compile-cache entries"
-cold_csv = cold.to_csv(index=False)
-
-# (b) warm FRESH-PROCESS run: deserialization only, byte parity
-CHILD = r'''
-import json, sys
-import jax
-jax.config.update("jax_platforms", "cpu")
-from spark_tpu import SparkTpuSession
-from spark_tpu.tpch import golden as G
-from spark_tpu.tpch import queries as Q
-path, cc_dir = sys.argv[1], sys.argv[2]
-spark = SparkTpuSession.builder().get_or_create()
-spark.conf.set("spark_tpu.sql.compileCache.enabled", True)
-spark.conf.set("spark_tpu.sql.compileCache.dir", cc_dir)
-Q.register_tables(spark, path)
-got = G.normalize_decimals(
-    Q.QUERIES["q1"](spark)._qe().collect().to_pandas())
-m = spark.metrics
-print("CCSMOKE " + json.dumps({
-    "csv": got.to_csv(index=False),
-    "disk_hits": int(m.counter("compile_cache_disk_hits").value),
-    "disk_misses": int(m.counter("compile_cache_disk_misses").value),
-    "corrupt": int(m.counter("compile_cache_corrupt").value),
-}), flush=True)
-'''
-
-
-def run_child():
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    proc = subprocess.run([sys.executable, "-c", CHILD, path, cc_dir],
-                          capture_output=True, text=True, timeout=600,
-                          env=env)
-    for line in proc.stdout.splitlines():
-        if line.startswith("CCSMOKE "):
-            return json.loads(line[len("CCSMOKE "):])
-    raise AssertionError(
-        f"child rc={proc.returncode}: {proc.stderr[-800:]}")
-
-
-warm = run_child()
-assert warm["disk_hits"] >= 1, warm
-assert warm["disk_misses"] == 0, \
-    f"warm process recompiled a cached shape: {warm}"
-assert warm["csv"] == cold_csv, "warm-process result diverged"
-
-# (c) corrupted entry: fresh subprocess must log+count+recompile,
-# never fail, and still hit byte parity
-victim = os.path.join(cc_dir, sorted(
-    f for f in os.listdir(cc_dir) if f.startswith("cc-"))[0])
-with open(victim, "wb") as f:
-    f.write(b"torn")
-fixed = run_child()
-assert fixed["corrupt"] >= 1, fixed
-assert fixed["csv"] == cold_csv, "corrupt-fallback result diverged"
-assert os.path.getsize(victim) > 4, "bad entry was not overwritten"
-
-print(json.dumps({"preflight_compile_cache_smoke": "ok",
-                  "entries": len(entries),
-                  "warm_disk_hits": warm["disk_hits"],
-                  "corrupt_recovered": fixed["corrupt"]}))
-EOF11
-
-echo "-- stage 14/19: query-lifecycle cancellation smoke --"
-# Start a chunked Q3 via the service, DELETE it mid-stream, assert the
-# structured error + no thread leak + arbiter drained + an immediate
-# clean re-run at golden parity (the cancellation hard guarantee).
-env JAX_PLATFORMS=cpu python - <<'EOF12'
-import json
-import tempfile
-import time
-import urllib.error
-import urllib.request
-
-import pandas as pd
-
-from spark_tpu import Conf
-from spark_tpu.service.server import SqlService
-from spark_tpu.testing.lockwatch import LockWatch
-from spark_tpu.tpch import golden as G
-from spark_tpu.tpch import queries as Q
-from spark_tpu.tpch import sql_queries as SQLQ
-from spark_tpu.tpch.datagen import write_parquet
-
-path = tempfile.mkdtemp(prefix="preflight_lifecycle_") + "/sf"
-write_parquet(path, 0.002)
-
-conf = Conf()
-conf.set("spark_tpu.service.port", 0)
-conf.set("spark_tpu.service.hbmBudget", 1 << 30)
-svc = SqlService(conf,
-                 init_session=lambda s: Q.register_tables(s, path)).start()
-
-
-def post(body, timeout=300):
-    req = urllib.request.Request(
-        f"http://127.0.0.1:{svc.port}/sql",
-        data=json.dumps(body).encode(),
-        headers={"Content-Type": "application/json"})
-    try:
-        with urllib.request.urlopen(req, timeout=timeout) as r:
-            return r.status, json.loads(r.read())
-    except urllib.error.HTTPError as e:
-        return e.code, json.loads(e.read())
-
-
-try:
-    # async chunked Q3 held mid-stream by an interruptible slow fault
-    # (a >=10s uncancelled floor), then DELETE while running
-    status, body = post({
-        "sql": SQLQ.Q3, "mode": "async",
-        "conf": {"spark_tpu.sql.execution.streamingChunkRows": 512,
-                 "spark_tpu.sql.memory.deviceBudget": 1,
-                 "spark_tpu.faults.inject": "stream_chunk:slow:2:10000"}})
-    assert status == 202, (status, body)
-    rid = body["query_id"]
-    deadline = time.monotonic() + 60
-    while time.monotonic() < deadline:
-        rec = svc.query_snapshot(rid)
-        if rec.get("status") == "running":
-            break
-        time.sleep(0.01)
-    time.sleep(0.3)  # into the chunk loop / slow sleep
-    t0 = time.perf_counter()
-    req = urllib.request.Request(
-        f"http://127.0.0.1:{svc.port}/queries/{rid}", method="DELETE")
-    resp = json.load(urllib.request.urlopen(req, timeout=30))
-    assert resp["status"] == "cancel_requested", resp
-    deadline = time.monotonic() + 15
-    while time.monotonic() < deadline:
-        rec = svc.query_snapshot(rid)
-        if rec.get("status") not in ("submitted", "running"):
-            break
-        time.sleep(0.02)
-    latency_s = time.perf_counter() - t0
-    assert rec["status"] == "cancelled", rec
-    assert rec["error"]["error"] == "QUERY_CANCELLED", rec
-    assert latency_s < 5.0, f"cancel took {latency_s:.2f}s"
-    # hard guarantee: no daemon outlives the query, leases drained
-    LockWatch().assert_no_thread_leak(timeout_s=10.0)
-    arb = svc.arbiter.stats()
-    assert arb["leased_bytes"] == 0 and arb["owners"] == 0, arb
-    # immediate identical re-run (chaos disarmed): golden parity
-    status, again = post({
-        "sql": SQLQ.Q3,
-        "conf": {"spark_tpu.faults.inject": "",
-                 "spark_tpu.sql.memory.deviceBudget": 0}})
-    assert status == 200, (status, again)
-    got = pd.DataFrame(again["rows"], columns=again["columns"])
-    want = G.GOLDEN["q3"](path)
-    G.compare(G.normalize_decimals(got)[list(want.columns)]
-              .reset_index(drop=True), want.reset_index(drop=True))
-    assert svc.metrics.counter("query_cancelled").value >= 1
-finally:
-    svc.stop()
-print(json.dumps({"preflight_cancellation_smoke": "ok",
-                  "cancel_latency_s": round(latency_s, 3)}))
-EOF12
-
-echo "-- stage 15/19: python-UDF worker pool smoke --"
-# Worker-lane parity with in-process, an injected SIGKILL mid-batch
-# replaying exactly one batch, and the zero-leaked-children contract.
-env JAX_PLATFORMS=cpu python - <<'EOF13'
-import json
-
-import numpy as np
-import pandas as pd
-
-from spark_tpu import SparkTpuSession
-from spark_tpu.functions import col, pandas_udf, udf
-from spark_tpu.testing import faults
-
-s = SparkTpuSession.builder().get_or_create()
-s.conf.set("spark_tpu.sql.udf.arrow.maxRecordsPerBatch", 64)
-pdf = pd.DataFrame({
-    "x": np.where(np.arange(256) % 7 == 0, np.nan,
-                  np.arange(256, dtype="float64")),
-    "s": [None if i % 5 == 0 else f"v{i}" for i in range(256)]})
-s.register_table("udf_pf", pdf)
-
-plus = udf(lambda v: None if v is None else v + 1.5, "double")
-shout = udf(lambda v: None if v is None else v.upper(), "string")
-
-
-@pandas_udf(returnType="double")
-def scaled(v: pd.Series) -> pd.Series:
-    return v * 3.0
-
-
-def run():
-    return s.table("udf_pf").select(
-        plus(col("x")).alias("a"), shout(col("s")).alias("b"),
-        scaled(col("x")).alias("c")).to_pandas()
-
-
-s.conf.set("spark_tpu.sql.udf.mode", "inprocess")
-want = run()
-s.conf.set("spark_tpu.sql.udf.mode", "worker")
-got = run()
-pd.testing.assert_frame_equal(got, want)
-
-# SIGKILL mid-batch: exactly ONE batch replays, results identical
-replayed0 = s.metrics.counter("rec_chunks_replayed").value
-restarts0 = s.metrics.counter("udf_worker_restarts").value
-with faults.inject(s.conf, "udf_batch:fatal:2") as plan:
-    chaos = run()
-    assert plan.fired_log == [("udf_batch", 2, "fatal")], plan.fired_log
-pd.testing.assert_frame_equal(chaos, want)
-replayed = s.metrics.counter("rec_chunks_replayed").value - replayed0
-assert replayed == 1, f"expected exactly 1 replayed batch, got {replayed}"
-assert s.metrics.counter("udf_worker_restarts").value - restarts0 == 1
-
-# zero leaked children after shutdown
-s._udf_pool.shutdown()
-leaked = [p.pid for p in s._udf_pool.child_procs() if p.poll() is None]
-assert not leaked, f"leaked udf workers: {leaked}"
-print(json.dumps({
-    "preflight_udf_worker_smoke": "ok",
-    "udf_batches": int(s.metrics.counter("udf_batches").value),
-    "udf_rows": int(s.metrics.counter("udf_rows").value),
-    "replayed_batches": int(replayed),
-    "workers_spawned": len(s._udf_pool.child_procs())}))
-EOF13
-
-echo "-- stage 16/19: unattended streaming smoke --"
-# Socket producer under the supervised trigger loop: a mid-stream
-# connection kill must reconnect exactly once with zero loss, an
-# injected trigger_tick fatal must park the query in structured FAILED,
-# a fresh query over the same checkpoint must land byte-identical to an
-# uninterrupted twin, and no trigger thread may outlive its query.
-env JAX_PLATFORMS=cpu python - <<'EOF14'
-import json
-import os
-import tempfile
-import time
-
-import numpy as np
-import pandas as pd
-
-from spark_tpu import SparkTpuSession
-from spark_tpu import functions as F
-from spark_tpu.functions import col
-from spark_tpu.io.network_source import FrameProducer
-from spark_tpu.testing import faults
-from spark_tpu.testing.lockwatch import LockWatch
-
-spark = SparkTpuSession.builder().get_or_create()
-base = tempfile.mkdtemp(prefix="preflight_unattended_")
-
-SCHEMA = pd.DataFrame({"k": pd.Series([], dtype=np.int64),
-                       "v": pd.Series([], dtype=np.int64)})
-
-
-def round_df(i):
-    return pd.DataFrame({"k": np.arange(6, dtype=np.int64) + i,
-                         "v": np.arange(6, dtype=np.int64) * (i + 1)})
-
-
-def build(producer, ck):
-    src = spark.network_stream("127.0.0.1", producer.port, SCHEMA)
-    plan = (src.to_df()
-            .group_by(F.pmod(col("k"), 5).alias("g"))
-            .agg(F.sum(col("v")).alias("s"), F.count().alias("c")))
-    return plan.write_stream(ck, output_mode="complete")
-
-
-def wait_commit(q, want, timeout_s=30.0):
-    deadline = time.monotonic() + timeout_s
-    while q._committed_batch < want and time.monotonic() < deadline:
-        time.sleep(0.01)
-    assert q._committed_batch >= want, (q._committed_batch, q.state())
-
-
-prod = FrameProducer()
-prod.start()
-ck = os.path.join(base, "ck")
-
-rc0 = spark.metrics.counter("streaming_reconnects").value
-q = build(prod, ck)
-q.start(trigger_ms=50)
-prod.send(round_df(0))
-wait_commit(q, 0)
-committed0 = q._committed_batch
-
-# mid-stream socket kill: the reconnect ladder re-establishes via the
-# durable-offset handshake; the next round commits with zero loss
-prod.kill_connection()
-prod.send(round_df(1))
-wait_commit(q, committed0 + 1)
-q.stop()
-assert q.status == "STOPPED", q.state()
-rec = spark.metrics.counter("streaming_reconnects").value - rc0
-assert rec == 1, f"expected exactly 1 reconnect, got {rec}"
-
-# injected fatal at the trigger seam parks the loop in FAILED
-with faults.inject(spark.conf, "trigger_tick:fatal:1") as plan:
-    q.start(trigger_ms=50)
-    deadline = time.monotonic() + 30.0
-    while q.status == "RUNNING" and time.monotonic() < deadline:
-        time.sleep(0.01)
-    assert plan.fired_log, "trigger_tick fault never fired"
-assert q.status == "FAILED", q.state()
-assert "FaultInjected" in (q.exception() or ""), q.exception()
-
-# hard crash (query object GONE), then a FRESH query over the same
-# checkpoint must recover byte-identical to an uninterrupted twin
-q.stream.close()
-del q
-prod.send(round_df(2))
-q2 = build(prod, ck)
-q2.process_available()
-got = q2.latest().sort_values("g").reset_index(drop=True)
-
-twin = FrameProducer()
-twin.start()
-q3 = build(twin, os.path.join(base, "ck_twin"))
-for i in range(3):
-    twin.send(round_df(i))
-q3.process_available()
-want = q3.latest().sort_values("g").reset_index(drop=True)
-pd.testing.assert_frame_equal(got, want)
-
-q2.stream.close()
-q3.stream.close()
-LockWatch().assert_no_thread_leak("spark-tpu-stream-trigger")
-prod.close()
-twin.close()
-print(json.dumps({
-    "preflight_unattended_streaming_smoke": "ok",
-    "reconnects": int(rec),
-    "committed_batches": int(q2._committed_batch + 1),
-    "groups": int(len(got))}))
-EOF14
-
-echo "-- stage 17/19: status store + flight recorder smoke --"
-# Live /status must parse with latency percentiles after one query,
-# /status/timeseries must carry heartbeat-sampled series, and an
-# injected stage_run fatal must leave a flight-recorder bundle whose
-# spans + conf + thread stacks parse.
-env JAX_PLATFORMS=cpu python - <<'EOF15'
-import glob
-import json
-import os
-import tempfile
-import time
-import urllib.error
-import urllib.request
-
-from spark_tpu import Conf
-from spark_tpu.service.server import SqlService
-from spark_tpu.tpch import queries as Q
-from spark_tpu.tpch import sql_queries as SQLQ
-from spark_tpu.tpch.datagen import write_parquet
-
-base = tempfile.mkdtemp(prefix="preflight_status_")
-path = base + "/sf"
-fr_dir = base + "/flightrec"
-write_parquet(path, 0.001)
-
-conf = Conf()
-conf.set("spark_tpu.service.port", 0)
-conf.set("spark_tpu.sql.status.heartbeatMs", 50)
-conf.set("spark_tpu.sql.flightRecorder.dir", fr_dir)
-svc = SqlService(conf,
-                 init_session=lambda s: Q.register_tables(s, path)).start()
-
-
-def post(body, timeout=300):
-    req = urllib.request.Request(
-        f"http://127.0.0.1:{svc.port}/sql",
-        data=json.dumps(body).encode(),
-        headers={"Content-Type": "application/json"})
-    try:
-        with urllib.request.urlopen(req, timeout=timeout) as r:
-            return r.status, json.loads(r.read())
-    except urllib.error.HTTPError as e:
-        return e.code, json.loads(e.read())
-
-
-def get(route):
-    return json.load(urllib.request.urlopen(
-        f"http://127.0.0.1:{svc.port}{route}", timeout=30))
-
-
-try:
-    status, body = post({"sql": SQLQ.Q1})
-    assert status == 200 and body["status"] == "ok", (status, body)
-
-    # (a) /status parses; latency percentiles present after the query
-    st = get("/status")
-    assert st["enabled"] is True, st
-    e2e = st["latency"]["e2e_ms"]
-    assert e2e["count"] >= 1, e2e
-    for pk in ("p50", "p95", "p99"):
-        assert isinstance(e2e[pk], (int, float)), (pk, e2e)
-    assert st["statuses"].get("ok", 0) >= 1, st["statuses"]
-    assert "admission" in st["providers"], st["providers"]
-    assert "arbiter" in st["providers"], st["providers"]
-
-    # (b) heartbeat-sampled time series accumulate in bounded rings
-    deadline = time.monotonic() + 15
-    ts = get("/status/timeseries")
-    while time.monotonic() < deadline and ts["heartbeats"] < 3:
-        time.sleep(0.05)
-        ts = get("/status/timeseries")
-    assert ts["heartbeats"] >= 3, ts["heartbeats"]
-    assert ts["series"], "no time series sampled"
-    for pts in ts["series"].values():
-        assert len(pts) <= ts["ring_capacity"], (len(pts), ts)
-
-    # (c) injected fatal fails the query AND leaves a parseable bundle
-    status, body = post({
-        "sql": SQLQ.Q1,
-        "conf": {"spark_tpu.faults.inject": "stage_run:fatal:1"}})
-    assert status != 200 and body.get("error"), (status, body)
-    bundles = glob.glob(os.path.join(fr_dir, "bundle-*"))
-    assert len(bundles) == 1, bundles
-    b = bundles[0]
-    manifest = json.load(open(os.path.join(b, "MANIFEST.json")))
-    assert manifest["reason"] == "fatal", manifest
-    assert "FaultInjected" in manifest["error"], manifest
-    spans = json.load(open(os.path.join(b, "spans.json")))
-    assert any(spans["spans"].values()), spans
-    conf_snap = json.load(open(os.path.join(b, "conf.json")))
-    assert "spark_tpu.faults.inject" in conf_snap["explicitly_set"], \
-        conf_snap["explicitly_set"]
-    threads = open(os.path.join(b, "threads.txt")).read()
-    assert "MainThread" in threads or "Thread-" in threads, threads[:200]
-    rings = [json.loads(line)
-             for line in open(os.path.join(b, "rings.jsonl"))]
-    assert {"query", "stage"} <= {r["subsystem"] for r in rings}, rings
-
-    # the failed query is visible in /status too
-    st2 = get("/status")
-    assert st2["statuses"].get("error", 0) >= 1, st2["statuses"]
-finally:
-    svc.stop()
-print(json.dumps({"preflight_status_smoke": "ok",
-                  "heartbeats": int(ts["heartbeats"]),
-                  "series": len(ts["series"]),
-                  "bundle": os.path.basename(b)}))
-EOF15
-
-echo "-- stage 18/19: plan-integrity smoke --"
-# (a) 64-seed differential fuzz: optimizer-on vs -off (full validation)
-# plus one rule ablation per seed — byte parity, zero integrity
-# findings, stable stage keys (the RL100 rule-registry lint already
-# gated green inside stage 6's scripts/lint.py --all)
-env JAX_PLATFORMS=cpu python scripts/plan_fuzz.py --seeds 64 --ablate one
-
-# (b) TPC-H Q3 under planChangeValidation=full: golden parity must
-# hold with the verifier on, and the executed query's event-log line
-# must carry the schema-v7 rule_trace record with >=1 effective rule
-env JAX_PLATFORMS=cpu python - <<'EOF16'
-import json
-import tempfile
-
-from spark_tpu import SparkTpuSession, history
-from spark_tpu.tpch import golden as G
-from spark_tpu.tpch import queries as Q
-from spark_tpu.tpch.datagen import write_parquet
-
-spark = SparkTpuSession.builder().get_or_create()
-base = tempfile.mkdtemp(prefix="preflight_plan_integrity_")
-spark.conf.set("spark_tpu.sql.eventLog.dir", base + "/events")
-spark.conf.set("spark_tpu.sql.planChangeValidation", "full")
-path = base + "/sf"
-write_parquet(path, 0.001)
-Q.register_tables(spark, path)
-qe = Q.QUERIES["q3"](spark)._qe()
-got = G.normalize_decimals(qe.collect().to_pandas())
-G.compare(got.reset_index(drop=True), G.GOLDEN["q3"](path))
-assert qe.rule_trace, "no rule_trace recorded under validation=full"
-effective = sum(r["effective"] for r in qe.rule_trace)
-assert effective >= 1, qe.rule_trace
-spark.conf.set("spark_tpu.sql.eventLog.dir", "")
-events = history.read_event_log(base + "/events")
-traces = [t for t in events.get("rule_trace", []) if isinstance(t, list)]
-assert traces and traces[-1], "event log carries no rule_trace record"
-rr = history.rule_report(events)
-assert len(rr) >= 1 and (rr["effective"] >= 1).any(), rr
-with open("/tmp/_preflight_pi_dir", "w") as f:
-    f.write(base + "/events")
-print(json.dumps({"preflight_plan_integrity_smoke": "ok",
-                  "effective_rules": int(effective),
-                  "trace_records": len(qe.rule_trace)}))
-EOF16
-
-# the v7 rule_trace lines validate against the versioned schema
-env JAX_PLATFORMS=cpu python scripts/events_tool.py validate \
-    "$(cat /tmp/_preflight_pi_dir)"
-
-echo "-- stage 19/19: serving-fleet smoke --"
-# Crash-only fleet loop end-to-end: 2 supervised worker subprocesses
-# behind the session-affinity router, Q1 golden parity through the
-# router AND direct at the owning worker (same bytes), kill -9 the
-# home worker mid-query (a slow-stage fault holds it on device) and
-# require the idempotent-read failover answer — 200 with
-# X-Fleet-Failover and parity, or the structured 503 WORKER_LOST —
-# then the fleet back at 2 ready with the respawned worker serving
-# Q1 from the SHARED persistent compile cache (disk hit, no
-# recompile), and a SIGTERM-path drain that exits clean with zero
-# orphaned worker processes. warmStart stays off here so the
-# respawn's cache heat is visible on the disk-hit counter (the
-# warm-start replay path is stage 13's surface).
-env JAX_PLATFORMS=cpu python - <<'EOF17'
-import json
-import os
-import signal
-import tempfile
-import time
-import urllib.error
-import urllib.request
-
-import pandas as pd
-
-from spark_tpu import Conf
-from spark_tpu.observability.metrics import parse_prometheus_text
-from spark_tpu.service.fleet import FleetSupervisor
-from spark_tpu.tpch import golden as G
-from spark_tpu.tpch import sql_queries as SQLQ
-from spark_tpu.tpch.datagen import write_parquet
-
-base = tempfile.mkdtemp(prefix="preflight_fleet_")
-path = base + "/sf"
-write_parquet(path, 0.001)
-os.makedirs(base + "/init")
-with open(base + "/init/preflight_fleet_init.py", "w") as f:
-    f.write("import spark_tpu.tpch.queries as Q\n"
-            f"PATH = {path!r}\n"
-            "def init(session):\n"
-            "    Q.register_tables(session, PATH)\n")
-os.environ["PYTHONPATH"] = base + "/init" + (
-    os.pathsep + os.environ["PYTHONPATH"]
-    if os.environ.get("PYTHONPATH") else "")
-
-conf = (Conf()
-        .set("spark_tpu.service.port", 0)
-        .set("spark_tpu.service.fleet.workers", 2)
-        .set("spark_tpu.service.fleet.healthIntervalMs", 100)
-        .set("spark_tpu.service.fleet.restartBackoffMs", 100)
-        .set("spark_tpu.service.fleet.init",
-             "preflight_fleet_init:init")
-        .set("spark_tpu.service.fleet.dir", base + "/fleet")
-        .set("spark_tpu.sql.warehouse.dir", base + "/wh")
-        .set("spark_tpu.sql.compileCache.enabled", True)
-        .set("spark_tpu.sql.compileCache.dir", base + "/cc")
-        .set("spark_tpu.sql.compileCache.warmStart", False))
-sup = FleetSupervisor(conf).start()
-assert sup.wait_ready(300), sup.fleet_health()
-
-
-def post(port, sql, session, extra=None, timeout=300):
-    body = {"sql": sql, "session": session}
-    body.update(extra or {})
-    req = urllib.request.Request(
-        f"http://127.0.0.1:{port}/sql",
-        data=json.dumps(body).encode(),
-        headers={"Content-Type": "application/json"})
-    try:
-        r = urllib.request.urlopen(req, timeout=timeout)
-        return r.status, dict(r.headers), json.loads(r.read())
-    except urllib.error.HTTPError as e:
-        return e.code, dict(e.headers), json.loads(e.read())
-
-
-def check_parity(resp):
-    got = pd.DataFrame(resp["rows"], columns=resp["columns"])
-    want = G.GOLDEN["q1"](path)
-    G.compare(G.normalize_decimals(got)[list(want.columns)]
-              .reset_index(drop=True), want.reset_index(drop=True))
-
-
-worker_pids = sup.worker_pids()
-home = sup._route("pf")[0]
-home_snap = sup._workers[home].snapshot()
-
-# routed vs direct: same golden bytes through both doors
-st, hdrs, resp = post(sup.port, SQLQ.Q1, "pf")
-assert st == 200 and resp["status"] == "ok", resp
-assert int(hdrs["X-Fleet-Worker"]) == home, hdrs
-check_parity(resp)
-st, _, direct = post(home_snap["port"], SQLQ.Q1, "pf")
-assert st == 200, direct
-assert direct["rows"] == resp["rows"], "router vs direct divergence"
-
-# kill -9 the home worker mid-query: the sync read either fails over
-# (200 + X-Fleet-Failover + parity) or sheds the structured 503
-import threading
-out = []
-t = threading.Thread(target=lambda: out.append(post(
-    sup.port, SQLQ.Q1, "pf",
-    {"conf": {"spark_tpu.faults.inject": "stage_run:slow:1:2500"}})),
-    daemon=True)
-t.start()
-deadline = time.monotonic() + 60
-while time.monotonic() < deadline:
-    listing = json.loads(urllib.request.urlopen(
-        f"http://127.0.0.1:{sup.port}/queries", timeout=30).read())
-    if any(q.get("status") == "running"
-           for q in listing.get("queries", [])):
-        break
-    time.sleep(0.05)
-os.kill(home_snap["pid"], signal.SIGKILL)
-t.join(300)
-st, hdrs, resp = out[0]
-if st == 200:
-    assert hdrs.get("X-Fleet-Failover") == "1", hdrs
-    check_parity(resp)
-    failover = "parity"
-else:
-    assert st == 503 and resp["error"] in (
-        "WORKER_LOST", "FLEET_UNAVAILABLE"), resp
-    failover = resp["error"]
-
-# crash-only recovery: back at 2 ready, and the RESPAWNED worker
-# serves Q1 hot from the shared persistent cache (disk hit)
-assert sup.wait_ready(300), sup.fleet_health()
-respawn = sup._workers[home].snapshot()
-assert respawn["generation"] >= 2, respawn
-st, _, resp = post(respawn["port"], SQLQ.Q1, "pf2")
-assert st == 200, resp
-check_parity(resp)
-prom = parse_prometheus_text(urllib.request.urlopen(
-    f"http://127.0.0.1:{respawn['port']}/metrics",
-    timeout=30).read().decode())
-assert prom.get("spark_tpu_compile_cache_disk_hits", 0) >= 1, \
-    "respawned worker recompiled instead of loading the shared cache"
-
-# SIGTERM-path drain: clean exit, zero orphans
-assert sup.shutdown(), "fleet drain was not clean"
-worker_pids += [respawn["pid"]]
-for pid in worker_pids:
-    for _ in range(200):
-        try:
-            os.kill(pid, 0)
-        except ProcessLookupError:
-            break
-        time.sleep(0.05)
-    else:
-        raise AssertionError(f"orphaned worker pid {pid}")
-print(json.dumps({"preflight_fleet_smoke": "ok",
-                  "failover": failover,
-                  "respawned_generation": respawn["generation"],
-                  "disk_hits": int(
-                      prom["spark_tpu_compile_cache_disk_hits"])}))
-EOF17
 
 echo "== preflight PASSED =="

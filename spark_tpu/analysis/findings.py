@@ -34,11 +34,10 @@ CAT_HOST_SYNC = "host-sync"
 CAT_RECOMPILE = "recompile"
 CAT_MESH = "mesh"
 CAT_X64 = "x64"
-CAT_KERNEL = "kernel"
 CAT_PLAN = "plan-integrity"
 
 CATEGORIES = (CAT_OVERFLOW, CAT_HOST_SYNC, CAT_RECOMPILE, CAT_MESH,
-              CAT_X64, CAT_KERNEL, CAT_PLAN)
+              CAT_X64, CAT_PLAN)
 
 #: finding code -> (category, severity, one-line doc). The registry is
 #: closed on purpose: an ad-hoc code would dodge the README table and
@@ -104,13 +103,6 @@ FINDING_CODES: Dict[str, tuple] = {
         "a 64-bit column (long/double/timestamp/decimal) is used while "
         "JAX x64 is disabled: device arrays silently truncate to 32 "
         "bits"),
-    "JOIN_HASH_TABLE_PRESSURE": (
-        CAT_KERNEL, "warn",
-        "a join the conf would run on the hash kernel degrades: the "
-        "hashMaxTableSlots-clamped table either forces the sort "
-        "fallback (load factor > 0.7) or its slot bytes exceed the "
-        "device HBM budget — the kernel choice silently falls back or "
-        "pressures the lease"),
     "JAXPR_I32_ACCUMULATOR": (
         CAT_X64, "warn",
         "the traced stage reduces into an int32 accumulator with JAX "

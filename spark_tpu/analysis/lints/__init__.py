@@ -1,18 +1,19 @@
 """Source-lint framework: a registry of AST passes over the package.
 
-`scripts/metrics_lint.py` proved the shape — one static pass that turns
-a hand-found bug class (unregistered traced-metric names) into a CI
+The metric-prefix pass proved the shape — one static pass that turns a
+hand-found bug class (unregistered traced-metric names) into a CI
 failure. This package generalizes it: each *pass* is a small class with
 a name, a file scope, and a `check(tree, relpath, ctx)` method over a
 parsed `ast` module; `run_passes` walks the repository once, parses
 each file once, and feeds every in-scope pass. `scripts/lint.py --all`
-is the CLI (preflight stage 6); `tests/test_analysis.py` runs each pass
-against both a seeded synthetic violation and the real tree.
+is the CLI (a stage of `scripts/preflight.sh`); `tests/test_analysis.py`
+runs each pass against both a seeded synthetic violation and the real
+tree.
 
 Built-in passes (lints/passes.py):
 
 - ``metric-prefix``: every `ctx.add_metric` name uses a registered
-  METRIC_PREFIXES prefix (the original metrics_lint).
+  METRIC_PREFIXES prefix.
 - ``conf-key``: every `spark_tpu.*` conf-key string literal read or
   written through a Conf method (or bound to a `*_KEY` constant) is
   `register()`ed in config.py — a typo'd key silently reads `None`.

@@ -9,7 +9,7 @@ from typing import List, Tuple
 from . import LintContext, LintPass, register_lint
 
 # ---------------------------------------------------------------------------
-# metric-prefix (the original scripts/metrics_lint.py, framework-hosted)
+# metric-prefix
 # ---------------------------------------------------------------------------
 
 
@@ -85,8 +85,7 @@ class ConfKeyPass(LintPass):
     def scope(self, relpath: str) -> bool:
         if relpath == "spark_tpu/config.py":
             return False  # register() calls DEFINE the keys
-        return (relpath.startswith(("spark_tpu/", "tests/", "scripts/"))
-                or relpath == "bench.py")
+        return relpath.startswith(("spark_tpu/", "tests/", "scripts/"))
 
     def check(self, tree, relpath, ctx: LintContext
               ) -> List[Tuple[int, str]]:
@@ -145,8 +144,7 @@ class FaultSitePass(LintPass):
         self._uses: list = []  # (relpath, line, site, via)
 
     def scope(self, relpath: str) -> bool:
-        return (relpath.startswith(("spark_tpu/", "tests/", "scripts/"))
-                or relpath == "bench.py")
+        return relpath.startswith(("spark_tpu/", "tests/", "scripts/"))
 
     def _spec_rules(self, text: str, ctx: LintContext):
         """Parse `text` as an inject spec; None unless EVERY comma part

@@ -400,72 +400,6 @@ def _check_recompile(root: P.PhysicalPlan, conf,
     walk(root)
 
 
-def _check_hash_join(root: P.PhysicalPlan, conf,
-                     out: List[Finding]) -> None:
-    """Predict degraded hash-kernel choices (JOIN_HASH_TABLE_PRESSURE):
-    for each join the conf would run on the hash kernel, size the
-    open-addressing table from the ESTIMATED (bucketed) build capacity
-    — exactly `hash_join.table_slots` — and warn when the
-    hashMaxTableSlots clamp forces the sort fallback (load factor
-    > 0.7) or the table's slot bytes exceed the device HBM budget.
-    Mirrors `resolve_kernel`, so `explain(analysis=True)` shows the
-    fallback BEFORE a trace silently takes it."""
-    from ..execution import hash_join as HJ
-    mode = str(conf.get(HJ.KERNEL_MODE_KEY))
-    if mode == "sort":
-        return
-    budget = int(conf.get("spark_tpu.sql.memory.deviceBudget")) \
-        or int(conf.get("spark_tpu.service.hbmBudget"))
-    seen = set()
-
-    def walk(node):
-        if id(node) in seen:  # runtime-filter creation chains DAG-share
-            return
-        seen.add(id(node))
-        for c in node.children:
-            walk(c)
-        if not isinstance(node, P.JoinExec):
-            return
-        build_rows = _estimate_rows(node.right)
-        probe_rows = _estimate_rows(node.left)
-        if build_rows is None:
-            return
-        if node.hash_fallback is False:
-            return  # already pinned to sort by the AQE loop
-        build_cap = bucket_capacity(max(int(build_rows), 8))
-        probe_cap = bucket_capacity(max(int(probe_rows or 0), 8))
-        # the EXACT runtime decision procedure: heuristic sort choices
-        # ('small-probe'/'ratio') are not degradations, only the clamp
-        # fallback and HBM pressure on a chosen hash path are
-        kernel, reason = HJ.kernel_choice(conf, probe_cap, build_cap)
-        if kernel == "sort" and reason != "clamp":
-            return
-        slots = HJ.table_slots(build_cap, conf)
-        table_bytes = slots * HJ.SLOT_BYTES
-        if reason == "clamp":
-            out.append(Finding(
-                "JOIN_HASH_TABLE_PRESSURE",
-                f"estimated build capacity {build_cap:,} under the "
-                f"hashMaxTableSlots clamp ({slots:,} slots) pushes the "
-                f"load factor past 0.7: this join silently falls back "
-                f"to the sort kernel",
-                op=_node_loc(node),
-                detail={"build_cap": int(build_cap),
-                        "slots": int(slots), "fallback": "sort"}))
-        elif budget > 0 and table_bytes > budget:
-            out.append(Finding(
-                "JOIN_HASH_TABLE_PRESSURE",
-                f"hash table for this join needs {slots:,} slots "
-                f"(~{table_bytes:,} bytes) against a device budget of "
-                f"{budget:,}: the build pressures the HBM lease",
-                op=_node_loc(node),
-                detail={"slots": int(slots),
-                        "table_bytes": int(table_bytes),
-                        "budget_bytes": int(budget)}))
-
-    walk(root)
-
-
 def _check_mesh(root: P.PhysicalPlan, mesh_n: int,
                 out: List[Finding]) -> None:
     if mesh_n <= 1:
@@ -544,7 +478,6 @@ def analyze_plan(root: P.PhysicalPlan, conf,
         lambda: _walk_aggregates(root, out, conf),
         lambda: _check_host_sync(root, conf, mesh_n, out),
         lambda: _check_recompile(root, conf, out),
-        lambda: _check_hash_join(root, conf, out),
         lambda: _check_mesh(root, mesh_n, out),
         lambda: _check_x64(root, out),
     )

@@ -19,10 +19,8 @@ The executor attaches the list to the event-log record
 record against the observed metric of the same tag
 (`exch_bytes_<tag>`, `join_rows_<tag>`, `agg_groups_<tag>`) and grades
 it hit / over / under; `history.prediction_report` runs that over a
-replayed event log, and bench.py emits the per-query mean |error| as
-the `tpch_*_pred_err_pct` sidecar. Event-log `analysis_findings`
-carrying byte bounds (mesh replication, hash-table pressure, spill
-estimates) are graded by the same report against observed exchange
+replayed event log. Event-log `analysis_findings` carrying byte bounds
+(mesh replication, spill estimates) are graded by the same report against observed exchange
 bytes and stage peak-HBM.
 """
 

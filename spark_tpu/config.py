@@ -129,65 +129,6 @@ AGG_TABLE_SIZE = register(
     doc="Estimated distinct group count used to size hash-aggregate output "
         "when no tighter bound can be inferred (AQE may revise).")
 
-JOIN_KERNEL_MODE = register(
-    "spark_tpu.sql.join.kernelMode", "auto",
-    doc="Equi-join match kernel (execution/hash_join.py vs the sorted-"
-        "build binary search in execution/join.py): 'hash' builds a "
-        "power-of-two open-addressing table over the (sorted) build "
-        "keys and probes it with a fixed-bound vectorized loop — the "
-        "BytesToBytesMap.java seat, replacing the probe-side "
-        "searchsorted sorts that dominated the join-bound TPC-H "
-        "profile; 'sort' keeps the binary-search path; 'auto' picks "
-        "hash only for large probes over comparatively small builds "
-        "(join.hashMinProbeRows / hashProbeBuildRatio), so small joins "
-        "and CPU test runs keep the sort path, and never on a TPU, "
-        "where a gather costs several times a sort's share of a row "
-        "and the probe loop runs as far as the data's longest cluster "
-        "of keys (hash_join._auto_keeps_sort). Results are "
-        "byte-identical across modes (both kernels emit matches in the "
-        "same sorted-build order).",
-    validator=lambda v: v in ("auto", "hash", "sort"))
-
-JOIN_HASH_LOAD_FACTOR = register(
-    "spark_tpu.sql.join.hashLoadFactor", 0.5,
-    doc="Target load factor for the hash-join table: slots = the "
-        "smallest power of two >= build capacity / loadFactor (clamped "
-        "by join.hashMaxTableSlots). Lower = fewer probe steps, more "
-        "HBM.",
-    validator=lambda v: 0.0 < v <= 0.9)
-
-JOIN_HASH_MAX_PROBE = register(
-    "spark_tpu.sql.join.hashMaxProbe", 64,
-    doc="Fixed bound on linear-probe steps for hash-join build inserts "
-        "and probes. A build whose longest collision cluster exceeds it "
-        "raises the join_hashsat_<tag> flag and the AQE loop re-jits "
-        "that join on the sort kernel (correctness never depends on "
-        "the bound).",
-    validator=lambda v: v >= 1)
-
-JOIN_HASH_MAX_SLOTS = register(
-    "spark_tpu.sql.join.hashMaxTableSlots", 1 << 26,
-    doc="Upper bound on hash-join table slots (HBM guard: ~16 bytes "
-        "per slot). A build capacity that would push the effective "
-        "load factor past 0.7 under this clamp falls back to the sort "
-        "kernel at trace time (surfaced by the analyzer's "
-        "JOIN_HASH_TABLE_PRESSURE finding).",
-    validator=lambda v: v >= 16)
-
-JOIN_HASH_MIN_PROBE_ROWS = register(
-    "spark_tpu.sql.join.hashMinProbeRows", 1 << 19,
-    doc="kernelMode=auto: minimum probe-side capacity for the hash "
-        "kernel. Below it the sorted-build binary search wins (the "
-        "probe-side sort it pays is tiny) and tier-1 CPU runs stay on "
-        "the extensively-exercised sort path.")
-
-JOIN_HASH_PROBE_BUILD_RATIO = register(
-    "spark_tpu.sql.join.hashProbeBuildRatio", 4.0,
-    doc="kernelMode=auto: minimum probe/build capacity ratio for the "
-        "hash kernel. The hash table amortizes its build cost over "
-        "probe rows; near-square joins keep the sort path.",
-    validator=lambda v: v >= 0)
-
 INGEST_PREFETCH = register(
     "spark_tpu.sql.ingest.prefetch", True,
     doc="Double-buffered chunk ingest for the streaming drivers "

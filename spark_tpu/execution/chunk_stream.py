@@ -118,13 +118,11 @@ def drive(leaf, chunk_rows: int, conf, recovery,
 
 
 def apply_join_overflow(flags, metrics, joins) -> bool:
-    """Parse one chunk update's `join_overflow_`/`join_nonunique_`/
-    `join_hashsat_` flag families and apply capacity growth /
-    unique-build / hash-kernel fallbacks to `joins`. Returns True when
-    anything changed."""
+    """Parse one chunk update's `join_overflow_`/`join_nonunique_` flag
+    families and apply capacity growth / unique-build fallbacks to
+    `joins`. Returns True when anything changed."""
     overflow = [k for k, v in flags.items()
-                if k.startswith(("join_overflow_", "join_nonunique_",
-                                 "join_hashsat_"))
+                if k.startswith(("join_overflow_", "join_nonunique_"))
                 and bool(v)]
     for k in overflow:
         _join, family, tag = k.split("_", 2)
@@ -133,8 +131,6 @@ def apply_join_overflow(flags, metrics, joins) -> bool:
                 continue
             if family == "nonunique":
                 j.unique_build = False
-            elif family == "hashsat":
-                j.hash_fallback = False
             else:
                 j.out_cap = bucket_capacity(
                     max(int(metrics[f"join_rows_{tag}"]), 8))

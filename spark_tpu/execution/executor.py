@@ -473,13 +473,6 @@ class QueryExecution:
                 # the reorder cost model's output estimate, next to the
                 # observed rows it is graded against
                 notes.append(f"cbo est: {node.cbo_est_rows:,}")
-            slots = m.get(f"join_table_slots_{tag}")
-            if slots is not None:
-                # present only when the hash kernel ran this join
-                notes.append(
-                    f"hash table: {slots:,} slots, build "
-                    f"{m.get(f'join_build_ms_{tag}', 0)}ms, probe "
-                    f"{m.get(f'join_probe_ms_{tag}', 0)}ms")
         elif isinstance(node, P.ExchangeExec):
             mx = m.get(f"exch_max_{tag}")
             if mx is not None:
@@ -802,8 +795,7 @@ class QueryExecution:
                     # capacity-sizing stats take the worst shard (pmax);
                     # row counts sum across shards
                     red = pmax if k.startswith(
-                        ("join_rows_", "exch_max_", "agg_groups_",
-                         "join_table_slots_")) \
+                        ("join_rows_", "exch_max_", "agg_groups_")) \
                         else jax.lax.psum
                     metrics[k] = red(jnp.asarray(v), AXIS)
                     if k.startswith("join_rows_"):
@@ -1083,8 +1075,6 @@ class QueryExecution:
                 out[f"join:{root.tag}"] = root.out_cap
             if root.unique_build is False:
                 out[f"uniq:{root.tag}"] = 0
-            if root.hash_fallback is False:
-                out[f"hashfb:{root.tag}"] = 0
         elif isinstance(root, P.ExchangeExec) and root.block_cap is not None:
             out[f"exch:{root.tag}"] = root.block_cap
         elif isinstance(root, P.HashAggregateExec) and root.est_groups:
@@ -1101,8 +1091,6 @@ class QueryExecution:
                 self._set_join_cap(root, tag, cap)
             elif kind == "uniq":
                 self._set_join_nonunique(root, tag)
-            elif kind == "hashfb":
-                self._set_join_hash_fallback(root, tag)
             elif kind == "exch":
                 self._set_exchange_cap(root, tag, cap)
             elif kind == "rtf":
@@ -1123,17 +1111,6 @@ class QueryExecution:
             QueryExecution._set_join_nonunique(c, tag)
         if isinstance(root, P.JoinExec) and root.tag == tag:
             root.unique_build = False
-
-    @staticmethod
-    def _set_join_hash_fallback(root: P.PhysicalPlan, tag: str) -> None:
-        """The hash kernel's open table saturated for this join (a
-        collision cluster outran join.hashMaxProbe): pin it to the sort
-        kernel and re-jit — a correctness re-plan like the unique-build
-        fallback, never capacity growth."""
-        for c in root.children:
-            QueryExecution._set_join_hash_fallback(c, tag)
-        if isinstance(root, P.JoinExec) and root.tag == tag:
-            root.hash_fallback = False
 
     @staticmethod
     def _set_exchange_cap(root: P.PhysicalPlan, tag: str, cap: int) -> None:
@@ -1851,7 +1828,6 @@ class QueryExecution:
             overflow = [k for k, v in flags.items()
                         if k.startswith(("join_overflow_",
                                          "join_nonunique_",
-                                         "join_hashsat_",
                                          "exch_overflow_",
                                          "agg_overflow_",
                                          "rtf_overflow_"))
@@ -1861,12 +1837,10 @@ class QueryExecution:
             if not overflow:
                 break
             self.spans.mark("aqe_overflow", flags=overflow[:8])
-            # unique-build / hash-saturation fallbacks are
-            # correctness re-plans, not capacity growth — never
-            # gated by the adaptive conf
+            # unique-build fallbacks are correctness re-plans, not
+            # capacity growth — never gated by the adaptive conf
             if not adaptive and any(
-                    not k.startswith(("join_nonunique_",
-                                      "join_hashsat_"))
+                    not k.startswith("join_nonunique_")
                     for k in overflow):
                 raise RuntimeError(
                     f"capacity overflow in {overflow} with adaptive "
@@ -1876,9 +1850,6 @@ class QueryExecution:
                 if k.startswith("join_nonunique_"):
                     self._set_join_nonunique(
                         root, k[len("join_nonunique_"):])
-                elif k.startswith("join_hashsat_"):
-                    self._set_join_hash_fallback(
-                        root, k[len("join_hashsat_"):])
                 elif k.startswith("join_overflow_"):
                     tag = k[len("join_overflow_"):]
                     total = int(metrics[f"join_rows_{tag}"])
@@ -1939,9 +1910,9 @@ class QueryExecution:
             self._record_exchange_shards(metrics, mesh)
         self.last_metrics = {k: int(v) for k, v in metrics.items()
                              if not k.startswith("shard_")}
-        # the *_ms_* keys (rtf_build_ms_*, join_build_ms_*,
-        # join_probe_ms_*): trace-time costs from the host's record of
-        # the stage, floats (sub-ms builds are the common case); and
+        # the *_ms_* keys (rtf_build_ms_*): trace-time costs from the
+        # host's record of the stage, floats (sub-ms builds are the
+        # common case); and
         # its shapes (rtf_slots_*: what each filter handed on;
         # rtf_build_indices_*: what each filter's build scattered;
         # exch_slot_bytes_*: what each exchange's collective carried)
@@ -2030,8 +2001,7 @@ class QueryExecution:
         so far: the widest join the chosen order made). The filters'
         `rtf_tested` / `rtf_pruned` are the metrics sink's, folded at a
         query's end. The `dispatch` span
-        gets `joins=<n>` and the kernel each resolved to while the
-        stage was traced (`join_kernels`, `<tag>=sort|hash`), how each
+        gets `joins=<n>`, the stage's join operators, how each
         join on several columns packed them into one key
         (`join_keys`, `<tag>=exact|hashed`: `hashed` re-verifies every
         column of a match), under a mesh how each met its build
@@ -2042,7 +2012,8 @@ class QueryExecution:
         without a join touches nothing; one
         deserialized from the engine's own compile cache
         (`compileCache.enabled`) was not traced in this process and has
-        no host record, so its span and `last_metrics` go without."""
+        no host record, so its span keeps only `joins` and `rtf_caps`
+        and its `last_metrics` go without."""
         rows_of = "join_mesh_rows_" if any(
             k.startswith("join_mesh_rows_") for k in metrics) \
             else "join_rows_"
@@ -2056,10 +2027,10 @@ class QueryExecution:
                 self.session.metrics.counter("join_widest_rows").inc(
                     widest - self._join_widest)
                 self._join_widest = widest
-        host = self._stage_host()
-        joins = _tag_attr(disp, host, "join_kernel_", "join_kernels")
+        joins = self._nodes(root, P.JoinExec)
         if joins:
-            disp.attrs["joins"] = joins
+            disp.attrs["joins"] = len(joins)
+        host = self._stage_host()
         for prefix, attr in (("join_keys_", "join_keys"),
                              ("join_strategy_", "join_strategies"),
                              ("rtf_build_indices_", "rtf_build")):

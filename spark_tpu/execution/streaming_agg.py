@@ -48,15 +48,7 @@ def conf_compile_suffix(conf) -> str:
             # so a decommission that changed the device pool (same n,
             # different devices) must not reuse a program compiled
             # over a mesh containing the drained device
-            f"#x{conf.get('spark_tpu.sql.mesh.excludeDevices')}"
-            # join kernel choice + table-shape confs are baked into the
-            # traced probe/build programs (execution/hash_join.py)
-            f"#j{conf.get('spark_tpu.sql.join.kernelMode')}"
-            f"#jl{conf.get('spark_tpu.sql.join.hashLoadFactor')}"
-            f"#jp{conf.get('spark_tpu.sql.join.hashMaxProbe')}"
-            f"#js{conf.get('spark_tpu.sql.join.hashMaxTableSlots')}"
-            f"#jm{conf.get('spark_tpu.sql.join.hashMinProbeRows')}"
-            f"#jr{conf.get('spark_tpu.sql.join.hashProbeBuildRatio')}")
+            f"#x{conf.get('spark_tpu.sql.mesh.excludeDevices')}")
 
 
 #: join types where per-probe-chunk execution is sound: each probe row's
@@ -178,7 +170,6 @@ def _materialize_subtree(root: P.PhysicalPlan, conf, recovery=None) -> Batch:
         flags, metrics = jax.device_get((flags, metrics))
         overflow = [k for k, v in flags.items()
                     if k.startswith(("join_overflow_", "join_nonunique_",
-                                     "join_hashsat_",
                                      "exch_overflow_", "agg_overflow_"))
                     and bool(v)]
         if not overflow:
@@ -186,8 +177,7 @@ def _materialize_subtree(root: P.PhysicalPlan, conf, recovery=None) -> Batch:
                 recovery.memo_put(("build", id(root)), batch)
             return batch
         if not adaptive and any(
-                not k.startswith(("join_nonunique_", "join_hashsat_"))
-                for k in overflow):
+                not k.startswith("join_nonunique_") for k in overflow):
             raise RuntimeError(
                 f"build-side capacity overflow in {overflow} with "
                 f"adaptive re-planning disabled")
@@ -195,9 +185,6 @@ def _materialize_subtree(root: P.PhysicalPlan, conf, recovery=None) -> Batch:
             if k.startswith("join_nonunique_"):
                 QueryExecution._set_join_nonunique(
                     root, k[len("join_nonunique_"):])
-            elif k.startswith("join_hashsat_"):
-                QueryExecution._set_join_hash_fallback(
-                    root, k[len("join_hashsat_"):])
             elif k.startswith("join_overflow_"):
                 tag = k[len("join_overflow_"):]
                 total = int(metrics[f"join_rows_{tag}"])

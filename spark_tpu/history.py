@@ -366,9 +366,8 @@ def grade_predictions(predictions, metrics) -> List[dict]:
     against one execution's observed metrics dict. hit = the bound
     held without gross waste (obs <= pred <= 4*obs); under = the
     prediction was exceeded (an AQE overflow / undersized filter);
-    over = more than 4x slack (wasted capacity/HBM). Shared by
-    history.prediction_report (event-log replay) and the bench
-    `tpch_*_pred_err_pct` sidecar (live qe)."""
+    over = more than 4x slack (wasted capacity/HBM). Used by
+    history.prediction_report (event-log replay)."""
     out: List[dict] = []
     for p in predictions or []:
         kind, tag = p.get("kind"), p.get("tag")
@@ -401,7 +400,6 @@ def grade_predictions(predictions, metrics) -> List[dict]:
 _FINDING_BOUNDS = {
     "MESH_FULL_REPLICATION": ("replicated_bytes_bound", "exch_bytes"),
     "MESH_GATHER_RESULT": ("replicated_bytes_bound", "exch_bytes"),
-    "JOIN_HASH_TABLE_PRESSURE": ("table_bytes", "peak_hbm"),
     "SPILL_HOST_SYNC": ("estimated_bytes", "peak_hbm"),
 }
 

@@ -51,8 +51,7 @@ Chaos seams: `stream_net_connect` fires before every connect attempt
 (first connect and each ladder rung), `stream_net_recv` before every
 frame read (testing/faults.py).
 
-`FrameProducer` at the bottom is the in-process peer (tests, bench,
-preflight): it speaks the handshake, serves frames from the agreed
+`FrameProducer` at the bottom is the in-process peer (tests): it speaks the handshake, serves frames from the agreed
 offset, and survives `kill_connection()` so reconnect scenarios are
 one method call.
 """
@@ -340,7 +339,7 @@ class NetworkStreamSource:
 
 
 class FrameProducer:
-    """In-process protocol peer for tests/bench/preflight: listens on
+    """In-process protocol peer for tests: listens on
     an ephemeral port, answers each connection's offset handshake by
     serving frames FROM THAT OFFSET, and exposes `kill_connection()` /
     `kill_connection_midframe()` so reconnect and stall scenarios are

@@ -30,7 +30,7 @@ the engine-sized analog, organized the same way:
 - ``metrics``: process metrics registry (counters/gauges/timers/
   log-bucketed latency histograms) with JSONL + Prometheus
   text-exposition sinks, plus the registered traced-metric name
-  prefixes ``scripts/metrics_lint.py`` enforces.
+  prefixes the lint framework's ``metric-prefix`` pass enforces.
 - ``sinks``: the built-in bus subscribers (event-log writer with
   rotation, Chrome-trace writer, metrics-sink updater) a session
   installs at construction.

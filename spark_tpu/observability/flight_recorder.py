@@ -14,8 +14,8 @@ Dump triggers:
 - the executor's surfaced-failure path (execute_batch): OOM-ladder
   exhaustion (`StageOOMError`), non-convergent recovery, and any other
   FATAL — reasons "oom" / "recovery_nonconvergent" / "fatal";
-- on demand: `GET /debug/bundle` on the SQL service, bench.py section
-  timeouts/errors, or `FlightRecorder.of(session).dump("reason")`.
+- on demand: `GET /debug/bundle` on the SQL service, or
+  `FlightRecorder.of(session).dump("reason")`.
 
 Bundle layout (`bundle-<app_id>-<seq>-<reason>/`, versioned by
 MANIFEST.json `bundle_version`):
@@ -189,7 +189,8 @@ class FlightRecorder(QueryListener):
                      query_id=event.query_id,
                      batch_id=r.get("batch_id"),
                      rows_in=r.get("rows_in"),
-                     rows_out=r.get("rows_out"), kind=r.get("kind"))
+                     rows_out=r.get("rows_out"),
+                     batch_kind=r.get("kind"))
 
     def on_streaming_trigger(self, event) -> None:
         r = event.record or {}

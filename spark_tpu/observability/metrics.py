@@ -16,7 +16,7 @@ Sink selection is conf-driven (`spark_tpu.sql.metrics.sink` =
 `METRIC_PREFIXES` is the registered namespace for TRACED per-operator
 metrics (`ctx.add_metric` inside compiled stages). Registration is
 enforced twice: `ExecContext.add_metric` rejects unregistered names at
-trace time, and `scripts/metrics_lint.py` statically asserts every
+trace time, and the `metric-prefix` lint pass statically asserts every
 call site — so history summaries can never silently miss columns.
 """
 
@@ -62,9 +62,6 @@ METRIC_PREFIXES = (
                        # compacted one): a shape, not a traced value
     "rtf_build_indices_",  # HOST-side too: the indices a filter's Bloom
                        # build scatters, one a creation-side slot
-    "join_build_ms_",  # hash-join table build cost (trace-time)
-    "join_probe_ms_",  # hash-join probe-program build cost
-    "join_table_slots_",  # hash-join open-addressing table capacity
     # per-shard telemetry ([n] arrays: one slot per mesh position, the
     # executor unpacks them into event-log `shards` records; consumer:
     # history.shard_summary / straggler_report)

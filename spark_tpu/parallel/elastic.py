@@ -49,8 +49,7 @@ attempts to relaunch:
 All three flow through `_record_fault` -> fault_summary -> event
 log/history/`GET /queries/<id>/timeline` as the actions
 `mesh_restart`, `decommission`, `shard_rebalance`; the registry counts
-`mesh_restart_attempts` and `rebalance_rows` (bench sidecars
-`tpch_*_mesh_restarts` / `tpch_*_rebalanced_rows`).
+`mesh_restart_attempts` and `rebalance_rows`.
 """
 
 from __future__ import annotations
@@ -399,7 +398,7 @@ class RebalanceState:
             sel[seg:seg + k] = True
             off += k
         # accounting: rows shifted OFF the flagged shards vs the even
-        # split (the `rebalance_rows` counter / bench sidecar evidence)
+        # split (the `rebalance_rows` counter)
         fair = live // n
         moved = sum(max(0, fair - int(t[i])) for i in self.slow)
         self.moved_rows += moved

@@ -169,7 +169,7 @@ class ExecContext:
         # registered prefixes only (observability/metrics.py): an
         # unregistered name would flow into the event log but silently
         # miss every history summary — fail at trace time instead.
-        # scripts/metrics_lint.py enforces the same statically.
+        # The metric-prefix lint pass enforces the same statically.
         from ..observability.metrics import is_registered_metric
         if not is_registered_metric(name):
             raise ValueError(
@@ -1264,12 +1264,6 @@ class JoinExec(PhysicalPlan):
         # join_nonunique_<tag> flag and the AQE loop re-jits with the
         # general expansion path (False). None/True = try it.
         self.unique_build: Optional[bool] = None
-        # hash-kernel AQE state (execution/hash_join.py): None = the
-        # conf/cardinality heuristic decides; False = a previous
-        # attempt saturated the open-addressing table within
-        # join.hashMaxProbe steps (join_hashsat_<tag> flag) — stay on
-        # the sort kernel for this join.
-        self.hash_fallback: Optional[bool] = None
         # True for left_semi joins SYNTHESIZED by the runtime-filter
         # rule to narrow a creation chain (plan/runtime_filter.py):
         # tagged from a separate counter (cj<n>) so real joins keep
@@ -1387,24 +1381,15 @@ class JoinExec(PhysicalPlan):
         return left_names, out_names
 
     def _compute_unique(self, ctx, probe_batch, build_batch,
-                        lvecs, rvecs, lk, keys_s, perm, n_valid, valid_s,
-                        hash_lc=None):
+                        lvecs, rvecs, lk, keys_s, perm, n_valid, valid_s):
         """Unique-build fast path: probe-layout output, zero expansion
         (HashedRelation keyIsUnique analog). Raises join_nonunique_<tag>
         when the build side has duplicate keys; the AQE loop then
-        re-jits with unique_build=False. `hash_lc` is the hash kernel's
-        (lo, cnt) probe result when that kernel ran (the sort kernel's
-        single-searchsorted match_unique otherwise)."""
+        re-jits with unique_build=False."""
         ctx.add_flag(f"join_nonunique_{self.tag}",
                      join_kernels.build_has_duplicates(keys_s, valid_s))
-        if hash_lc is not None:
-            lo, cnt = hash_lc
-            build_idx = jnp.take(perm, jnp.minimum(lo,
-                                                   keys_s.shape[0] - 1))
-            found = cnt > 0
-        else:
-            build_idx, found = join_kernels.match_unique(
-                keys_s, n_valid, perm, lk, probe_batch.selection)
+        build_idx, found = join_kernels.match_unique(
+            keys_s, n_valid, perm, lk, probe_batch.selection)
         psel = probe_batch.selection_mask()
         exact = len(lvecs) == 1
         if not exact:
@@ -1478,66 +1463,25 @@ class JoinExec(PhysicalPlan):
         return mask
 
     def compute(self, ctx, inputs):
-        from ..execution import hash_join as hash_kernels
         probe_batch, build_batch = inputs
         lvecs, rvecs, lk, rk, exact = self._eval_keys(probe_batch, build_batch)
-        t_build = time.perf_counter()
         keys_s, perm, n_valid, _valid_s = join_kernels.build_sorted(
             rk, build_batch.selection)
-        # kernel choice (join.kernelMode): hash builds an open-
-        # addressing table over the sorted build's distinct keys and
-        # probes it with a bounded vectorized loop; both kernels return
-        # the same (lo, cnt) sorted-order contract, so everything
-        # downstream (expansion, gathers, output order) is shared and
-        # results are byte-identical across modes.
-        kernel = hash_kernels.resolve_kernel(
-            ctx.conf, probe_batch.capacity, build_batch.capacity,
-            self.hash_fallback)
-        # which kernel this join's program holds: the `dispatch` span's
-        # `join_kernels` attribute
-        ctx.host[f"join_kernel_{self.tag}"] = kernel
-        if len(lvecs) > 1:  # and how it packed a key of several columns
+        if len(lvecs) > 1:  # how it packed a key of several columns:
+            # the `dispatch` span's `join_keys` attribute
             ctx.host[f"join_keys_{self.tag}"] = \
                 "exact" if exact else "hashed"
         if ctx.axis_name is not None:  # and, across chips, how it met
             # its build: hashed to the shards or replicated on each
             ctx.host[f"join_strategy_{self.tag}"] = self.strategy
-        hash_lc = None
-        if kernel == "hash":
-            slots = hash_kernels.table_slots(build_batch.capacity,
-                                             ctx.conf)
-            max_probe = int(ctx.conf.get(hash_kernels.MAX_PROBE_KEY))
-            # both sides hash under the promoted common dtype: mixed-
-            # precision keys (float32 probe vs float64 build) must hash
-            # the same bit pattern wherever `==` calls them equal
-            hash_dt = jnp.promote_types(lk.data.dtype, keys_s.dtype)
-            t_pos, cnt_all, saturated = hash_kernels.build_table(
-                keys_s, _valid_s, slots, max_probe, hash_dtype=hash_dt)
-            # a cluster longer than the probe bound: re-jit on sort
-            ctx.add_flag(f"join_hashsat_{self.tag}", saturated)
-            ctx.add_metric(f"join_table_slots_{self.tag}",
-                           jnp.asarray(slots, jnp.int64))
-            # trace-time program-construction cost, the rtf_build_ms
-            # convention: the kernels fuse into the stage, so this is
-            # the honest per-join observable; kept on the host
-            ctx.add_host_ms(f"join_build_ms_{self.tag}", t_build)
-            t_probe = time.perf_counter()
-            hash_lc = hash_kernels.probe_table(
-                t_pos, cnt_all, keys_s, lk, probe_batch.selection,
-                slots, max_probe, hash_dtype=hash_dt)
-            ctx.add_host_ms(f"join_probe_ms_{self.tag}", t_probe)
         if (self.unique_build is not False
                 and self.how in ("inner", "left", "left_semi",
                                  "left_anti")):
             return self._compute_unique(ctx, probe_batch, build_batch,
                                         lvecs, rvecs, lk, keys_s, perm,
-                                        n_valid, _valid_s,
-                                        hash_lc=hash_lc)
-        if hash_lc is not None:
-            lo, cnt = hash_lc
-        else:
-            lo, cnt = join_kernels.match_ranges(keys_s, n_valid, lk,
-                                                probe_batch.selection)
+                                        n_valid, _valid_s)
+        lo, cnt = join_kernels.match_ranges(keys_s, n_valid, lk,
+                                            probe_batch.selection)
         psel = probe_batch.selection_mask()
         semi_anti = self.how in ("left_semi", "left_anti")
 
@@ -1689,11 +1633,6 @@ class JoinExec(PhysicalPlan):
                 f"cond={self.condition!r}, cap={self.out_cap}, "
                 f"uniq={self.unique_build}, "
                 + ("null_aware, " if self.null_aware else "")
-                # only when the AQE loop forced the sort fallback, so
-                # pre-existing plan strings (and cached stage keys) are
-                # untouched on the common path
-                + ("hash_fallback, " if self.hash_fallback is False
-                   else "")
                 + f"strategy={self.strategy})")
 
 
@@ -1799,9 +1738,9 @@ class RuntimeFilterExec(PhysicalPlan):
         return out
 
     def simple_string(self):
-        # `cap` only when set, as JoinExec's hash_fallback: a filter
-        # that hands on its probe's slots keeps the text, and so the
-        # stage key and the compiled program, it always had
+        # `cap` only when set: a filter that hands on its probe's slots
+        # keeps the text, and so the stage key and the compiled
+        # program, it always had
         return (f"RuntimeFilterExec({self.probe_key!r} IN "
                 f"bloom({self.build_key!r}), est={self.est_items}, "
                 + (f"cap={self.out_cap}, " if self.out_cap is not None

@@ -1,7 +1,6 @@
 """TPC-DS tranche queries as DataFrame programs.
 
-DataFrame forms for a representative subset (the bench/preflight trio
-plus the kernel-matrix pair); the full tranche lives in
+DataFrame forms for a representative subset; the full tranche lives in
 `sql_queries.py`, the `tpch/` split. Join orders follow the frontend
 convention — fact on the probe (left) side, dimensions on the build
 side — which is exactly the order the cost-based reorder pass

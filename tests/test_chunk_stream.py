@@ -26,6 +26,9 @@ from spark_tpu.functions import col
 from spark_tpu.observability.spans import SpanRecorder, use_recorder
 from spark_tpu.plan import physical as P
 from spark_tpu.testing import faults
+from spark_tpu.tpch import golden as G
+from spark_tpu.tpch import queries as Q
+from spark_tpu.tpch.datagen import write_parquet
 
 CHUNK_KEY = "spark_tpu.sql.execution.streamingChunkRows"
 CACHE_KEY = "spark_tpu.sql.io.deviceCacheBytes"
@@ -33,12 +36,14 @@ BUDGET_KEY = "spark_tpu.sql.memory.deviceBudget"
 MESH_KEY = "spark_tpu.sql.mesh.size"
 DOMAIN_KEY = "spark_tpu.sql.aggregate.maxDirectDomain"
 EVERY_KEY = "spark_tpu.execution.checkpoint.everyChunks"
+PREFETCH_KEY = "spark_tpu.sql.ingest.prefetch"
 
 CHUNK = 1024
 ROWS = 6000
 N_CHUNKS = -(-ROWS // CHUNK)
 WORKER = "spark-tpu-ingest-prefetch"
 FAN = 4
+SF = 0.002  # the TPC-H scale the prefetch cases stream
 
 #: driver -> (conf that routes the query to it, stage-cache key prefix)
 DRIVERS = {
@@ -407,3 +412,121 @@ def test_join_free_direct_stream_donates_its_tables(session, data):
     assert step._jit_info.donate_argnums == (0,)
     assert list(step._jit_info.fun_signature.parameters) == [
         "tables", "b", "row_base"]
+
+
+# -- double-buffered ingest over TPC-H ----------------------------------------
+
+@pytest.fixture(scope="module")
+def tpch_path(tmp_path_factory):
+    path = str(tmp_path_factory.mktemp("tpch_prefetch") / "sf_small")
+    write_parquet(path, SF)
+    return path
+
+
+@pytest.fixture(scope="module")
+def tpch_session(session, tpch_path):
+    Q.register_tables(session, tpch_path)
+    return session
+
+
+@pytest.fixture
+def streaming_conf(tpch_session):
+    conf = tpch_session.conf
+    conf.set("spark_tpu.execution.backoffMs", 1)
+    conf.set(CHUNK_KEY, 1024)
+    conf.set(CACHE_KEY, 0)
+    faults.reset()
+    yield conf
+    faults.reset()
+
+
+def _golden(session, qname, tpch_path):
+    got = G.normalize_decimals(
+        Q.QUERIES[qname](session).to_pandas()).reset_index(drop=True)
+    G.compare(got, G.GOLDEN[qname](tpch_path))
+    return got
+
+
+def test_prefetch_parity_on_off(tpch_session, tpch_path,
+                                streaming_conf):
+    chunks0 = tpch_session.metrics.counter("ingest_chunks").value
+    on = _golden(tpch_session, "q1", tpch_path)
+    # the pipeline ran and was counted (its waits: test_observability)
+    assert tpch_session.metrics.counter("ingest_chunks").value > chunks0
+    streaming_conf.set(PREFETCH_KEY, False)
+    off = _golden(tpch_session, "q1", tpch_path)
+    pd.testing.assert_frame_equal(on, off)
+
+
+def test_prefetch_parity_spill_path(tpch_session, tpch_path,
+                                    streaming_conf):
+    streaming_conf.set(BUDGET_KEY, 1)  # force the partial-spill driver
+    on = _golden(tpch_session, "q3", tpch_path)
+    streaming_conf.set(PREFETCH_KEY, False)
+    off = _golden(tpch_session, "q3", tpch_path)
+    pd.testing.assert_frame_equal(on, off)
+
+
+def test_prefetch_fault_replays_one_chunk(tpch_session, tpch_path,
+                                          streaming_conf):
+    """A transient fault at the prefetcher's host-decode seam replays
+    exactly one chunk through the standard per-chunk retry path."""
+    replayed0 = tpch_session.metrics.counter(
+        "rec_chunks_replayed").value
+    with faults.inject(streaming_conf,
+                       "ingest_prefetch:unavailable:3") as plan:
+        _golden(tpch_session, "q1", tpch_path)
+    assert ("ingest_prefetch", 3, "unavailable") in plan.fired_log
+    assert tpch_session.metrics.counter(
+        "rec_chunks_replayed").value == replayed0 + 1
+
+
+def test_prefetch_fatal_fault_propagates(tpch_session, streaming_conf):
+    """A FATAL fault on the worker thread surfaces on the consumer —
+    never a hang, never a truncated result."""
+    with faults.inject(streaming_conf, "ingest_prefetch:fatal:2"):
+        with pytest.raises(Exception, match="INTERNAL|fatal"):
+            Q.QUERIES["q1"](tpch_session).to_pandas()
+
+
+def test_prefetch_mesh_checkpoint_restore(tpch_session, tpch_path,
+                                          streaming_conf):
+    """Prefetcher + mesh checkpoint/restore compose: the restored
+    stream skips checkpointed chunks through the prefetcher's
+    skip_chunks cursor (PR-5 semantics unchanged)."""
+    streaming_conf.set(MESH_KEY, 8)
+    streaming_conf.set("spark_tpu.execution.checkpoint.everyChunks", 4)
+    with faults.inject(streaming_conf, "mesh:unavailable:2"):
+        _golden(tpch_session, "q1", tpch_path)
+
+
+def test_prefetch_worker_exits_on_abandonment(tpch_session, tpch_path,
+                                              streaming_conf):
+    """A chunk driver unwound mid-stream (fault escalation, replan)
+    abandons its PrefetchChunkIterator without close(); the worker
+    thread must exit via the abandonment finalizer instead of spinning
+    forever holding a decoded chunk."""
+    import gc
+    import threading
+    import time
+
+    import os
+
+    from spark_tpu.io.sources import ParquetSource, PrefetchChunkIterator
+
+    def workers():
+        return [t for t in threading.enumerate()
+                if t.name == "spark-tpu-ingest-prefetch" and t.is_alive()]
+
+    src = ParquetSource(os.path.join(tpch_path, "lineitem.parquet"),
+                        "lineitem")
+    chunks = PrefetchChunkIterator(
+        src.load_chunks(None, (), 1024), streaming_conf)
+    next(chunks)  # starts the worker; stream has many chunks left
+    assert len(workers()) >= 1
+    del chunks  # abandoned: no close(), as on an error unwind
+    gc.collect()
+    deadline = time.monotonic() + 5.0
+    while workers() and time.monotonic() < deadline:
+        time.sleep(0.05)
+    assert not workers(), "prefetch worker leaked after abandonment"

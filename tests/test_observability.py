@@ -267,13 +267,8 @@ def test_sink_validator_rejects_unknown(session):
 
 
 def test_metrics_lint_clean():
-    import importlib.util
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location(
-        "metrics_lint", os.path.join(root, "scripts", "metrics_lint.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    assert mod.run() == []
+    from spark_tpu.analysis.lints import run_passes
+    assert run_passes(["metric-prefix"]) == []
 
 
 def test_unregistered_metric_name_rejected(session):

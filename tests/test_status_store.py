@@ -268,6 +268,34 @@ def test_flightrec_installed_and_rings_fill(session, tmp_path):
     assert {"start", "end"} <= kinds
 
 
+def test_flightrec_streaming_ring_holds_the_batch(session, tmp_path):
+    """A streaming batch lands in the `streaming` ring, its own kind
+    (snapshot / delta / stateless) beside the record's `kind`."""
+    import warnings
+
+    import numpy as np
+
+    from spark_tpu.streaming import MemoryStream
+    rec = FlightRecorder.of(session)
+    schema = pd.DataFrame({"k": pd.Series([], dtype=np.int64),
+                           "v": pd.Series([], dtype=np.int64)})
+    src = MemoryStream(session, schema)
+    q = (src.to_df().group_by(F.pmod(col("k"), 4).alias("g"))
+         .agg(F.sum(col("v")).alias("s"))
+         .write_stream(str(tmp_path / "ck")))
+    src.add_data(pd.DataFrame({"k": np.arange(8, dtype=np.int64),
+                               "v": np.ones(8, dtype=np.int64)}))
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")  # a listener that raised warns
+        q.process_available()
+    with rec._lock:
+        batches = [r for r in rec._rings.get("streaming", ())
+                   if r["kind"] == "batch"]
+    assert len(batches) == 1, batches
+    assert batches[0]["batch_id"] == 0 and batches[0]["rows_in"] == 8
+    assert batches[0]["batch_kind"] == "snapshot"
+
+
 def test_flightrec_bundle_on_injected_fatal(session, tmp_path):
     session.conf.set(EVENT_KEY, str(tmp_path / "ev"))
     session.conf.set(FR_DIR_KEY, str(tmp_path / "fr"))

@@ -19,6 +19,7 @@ live loops."""
 import json
 import os
 import socket
+import threading
 import time
 
 import numpy as np
@@ -572,22 +573,37 @@ def test_trigger_event_log_v6_summary_and_validator(session, tmp_path):
     the batch rows; events_tool validates v6 and rejects a pre-v6 line
     smuggling a trigger record."""
     from spark_tpu import history
+    from spark_tpu.observability.listener import QueryListener
+
+    class Recorded(QueryListener):
+        """Released once a tick's trigger record is posted: the tick
+        that committed a batch has finished with it."""
+
+        def __init__(self):
+            self.ticks = threading.Semaphore(0)
+
+        def on_streaming_trigger(self, event):
+            self.ticks.release()
+
+    recorded = Recorded()
     ev_dir = str(tmp_path / "events")
     session.conf.set("spark_tpu.sql.eventLog.dir", ev_dir)
-    src = MemoryStream(session, _schema_df("stateful"))
-    q = (src.to_df().group_by(F.pmod(col("k"), 5).alias("g"))
-         .agg(F.sum(col("v")).alias("s"))
-         .write_stream(str(tmp_path / "ck")))
-    src.add_data(_round_df("stateful", 0))
-    q.start(trigger_ms=10)
-    deadline = time.monotonic() + 15
-    while q._committed_batch < 0 and time.monotonic() < deadline:
-        time.sleep(0.01)
-    src.add_data(_round_df("stateful", 1))
-    while q._committed_batch < 1 and time.monotonic() < deadline:
-        time.sleep(0.01)
-    q.stop()
-    session.conf.set("spark_tpu.sql.eventLog.dir", "")
+    session.add_listener(recorded)
+    try:
+        src = MemoryStream(session, _schema_df("stateful"))
+        q = (src.to_df().group_by(F.pmod(col("k"), 5).alias("g"))
+             .agg(F.sum(col("v")).alias("s"))
+             .write_stream(str(tmp_path / "ck")))
+        src.add_data(_round_df("stateful", 0))
+        q.start(trigger_ms=10)
+        # the bound only keeps a broken loop from hanging the suite
+        assert recorded.ticks.acquire(timeout=600), q.state()
+        src.add_data(_round_df("stateful", 1))
+        assert recorded.ticks.acquire(timeout=600), q.state()
+        q.stop()
+    finally:
+        session.remove_listener(recorded)
+        session.conf.set("spark_tpu.sql.eventLog.dir", "")
     events = history.read_event_log(ev_dir)
     assert (events["schema_version"].dropna() == 7).all()
     ss = history.streaming_summary(events)

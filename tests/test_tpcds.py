@@ -1,5 +1,5 @@
-"""TPC-DS tranche-1: golden parity, join-kernel matrix, cost-based
-join-reorder on/off identity, and plan-stability snapshots.
+"""TPC-DS tranche-1: golden parity, cost-based join-reorder on/off
+identity, and plan-stability snapshots.
 
 The TPC-DS analog of test_tpch/test_sql's parity suites plus the
 reference's `TPCDSQueryTestSuite.scala:54` plan-golden discipline:
@@ -18,7 +18,6 @@ from spark_tpu.tpcds.datagen import write_parquet
 
 SF = 0.01
 CBO_KEY = "spark_tpu.sql.cbo.joinReorder"
-KERNEL_KEY = "spark_tpu.sql.join.kernelMode"
 PLAN_DIR = os.path.join(os.path.dirname(__file__), "tpcds_plans")
 
 #: queries whose reorder decisions must change the join SEQUENCE at
@@ -27,8 +26,6 @@ PLAN_DIR = os.path.join(os.path.dirname(__file__), "tpcds_plans")
 #: 21 tranche queries re-sequence at SF0.01; these three keep the
 #: tier-1 wall-clock down (q61 re-sequences too but costs ~23s alone)
 REORDER_CHANGED = ("q19", "q73", "q79")
-#: kernel-matrix pair: multi-join queries with large-enough probes
-KERNEL_MATRIX = ("q19", "q68")
 #: plan-stability snapshot subset
 PLAN_SNAPSHOT = ("q3", "q19", "q55", "q73", "q96")
 
@@ -62,8 +59,7 @@ def _no_runtime_filters(tpcds_session):
     compiles a creation-chain stage per eligible join, which is ~55%
     of the snowflake queries' tier-1 wall-clock, and rf is
     results-identical on/off by design. rf-on TPC-DS coverage lives in
-    the kernel-matrix / reorder / event-log tests and preflight stage
-    9, which all keep the default."""
+    the reorder and event-log tests, which keep the default."""
     key = "spark_tpu.sql.runtimeFilter.enabled"
     tpcds_session.conf.set(key, False)
     yield
@@ -84,33 +80,12 @@ def test_tpcds_dataframe_parity(tpcds_session, tpcds_path,
     _check(got, qname, tpcds_path)
 
 
-@pytest.mark.parametrize("qname", KERNEL_MATRIX)
-def test_tpcds_join_kernel_matrix(tpcds_session, tpcds_path, qname):
-    """Both join kernels must produce byte-identical results on the
-    snowflake queries, with the hash path PROVEN to have run (its
-    join_table_slots metric) so the parity check can't go vacuous."""
-    outs = {}
-    hash_ran = False
-    for mode in ("sort", "hash"):
-        tpcds_session.conf.set(KERNEL_KEY, mode)
-        try:
-            qe = tpcds_session.sql(SQL_QUERIES[qname])._qe()
-            outs[mode] = qe.collect().to_pandas()
-        finally:
-            tpcds_session.conf.set(KERNEL_KEY, "auto")
-        if mode == "hash":
-            hash_ran = any(k.startswith("join_table_slots_")
-                           for k in qe.last_metrics)
-    assert hash_ran, "hash kernel never ran — forced mode was ignored"
-    pd.testing.assert_frame_equal(outs["sort"], outs["hash"])
-    _check(outs["hash"], qname, tpcds_path)
-
-
 @pytest.mark.parametrize("qname", REORDER_CHANGED)
 def test_tpcds_reorder_on_off_identical(tpcds_session, tpcds_path,
                                         qname):
-    """cbo.joinReorder on vs off: byte-identical results; off restores
-    the frontend order (no decisions logged at all)."""
+    """cbo.joinReorder on vs off: byte-identical results, at golden
+    parity with the runtime filters on; off restores the frontend order
+    (no decisions logged at all)."""
     tpcds_session.conf.set(CBO_KEY, True)
     qe_on = tpcds_session.sql(SQL_QUERIES[qname])._qe()
     on = qe_on.collect().to_pandas()
@@ -123,6 +98,7 @@ def test_tpcds_reorder_on_off_identical(tpcds_session, tpcds_path,
     finally:
         tpcds_session.conf.set(CBO_KEY, True)
     pd.testing.assert_frame_equal(on, off)
+    _check(on, qname, tpcds_path)
     # a genuine SEQUENCE change (kind "order"), not just a probe/build
     # orientation flip
     changed = [d for d in qe_on.reorder_decisions
